@@ -222,9 +222,6 @@ class ImagePageIterator(IIterator):
         self._parse_image_conf()
         assert len(self.path_imgbin) == len(self.path_imglst), \
             "List/Bin number not consist"
-        if self.silent == 0:
-            print("ImagePageIterator: image_list=%s, bin=%s" %
-                  (",".join(self.path_imglst), ",".join(self.path_imgbin)))
         # kRandMagic = 121, mirroring the reference's sampler seed
         self._rnd = np.random.RandomState(self.seed_data + 121)
         self._part_order = list(range(len(self.path_imgbin)))
@@ -234,6 +231,13 @@ class ImagePageIterator(IIterator):
         # approximate (doc/robustness.md)
         self.stable_epoch_order = not self.shuffle
         self.before_first()
+        if self.silent == 0:
+            # which reader serves the pages is not the caller's choice
+            # (utils/native.py falls back when the .so is absent): say it
+            print("ImagePageIterator: image_list=%s, bin=%s, page_reader=%s"
+                  % (",".join(self.path_imglst), ",".join(self.path_imgbin),
+                     "native" if self.native_reader is not None
+                     else "python"))
 
     def _epoch_paths(self):
         if self.shuffle and len(self._part_order) > 1:

@@ -8,6 +8,7 @@ TPU trainer instead of GPU worker threads.
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 import threading
@@ -23,7 +24,7 @@ from .utils import health
 from .utils import perf
 from .utils import serializer
 from .utils import statusd
-from .utils import telemetry
+from .utils import compile_cache_stats, telemetry
 from .utils.config import ConfigIterator
 
 
@@ -506,12 +507,17 @@ class LearnTask:
                 # iterators, no jax use — replicas own the models
                 if self.task != "route":
                     self.init()
+                    if self._perf_enabled:
+                        # the backend is live now: a chip the peak table
+                        # does not hold stops the run here, by name
+                        perf.ledger().spec = perf.current_device_spec()
+            # serve's stdout carries exactly one response line per
+            # request — every other line of that task goes to stderr
+            chat = sys.stderr if self.task == "serve" else sys.stdout
+            if self.task != "route" and not self.silent:
+                print(self._device_line(), file=chat, flush=True)
             if not self.silent:
-                # serve's stdout carries exactly one response line per
-                # request — startup chatter goes to stderr there
-                print("initializing end, start working",
-                      file=sys.stderr if self.task == "serve"
-                      else sys.stdout)
+                print("initializing end, start working", file=chat)
             if self.task in ("train", "finetune"):
                 self.task_train()
             elif self.task == "pred":
@@ -528,6 +534,8 @@ class LearnTask:
                 self.task_serve()
             elif self.task == "route":
                 self.task_route()
+            if self.task != "route" and not self.silent:
+                print(self._device_summary(), file=chat, flush=True)
         finally:
             if self._perf_enabled:
                 # let queued card analyses land in the JSONL before the
@@ -552,6 +560,30 @@ class LearnTask:
                 telemetry.disable()
                 self._status_telemetry = False
         return 0
+
+    def _device_line(self) -> str:
+        """What this run computes on, as jax reports it."""
+        import jax
+        devs = jax.devices()
+        mesh = self.net_trainer.mesh if self.net_trainer else None
+        return ("device: platform=%s device_kind=%r devices=%d/%d jax=%s "
+                "compile_cache=%s"
+                % (devs[0].platform, devs[0].device_kind,
+                   mesh.devices.size if mesh is not None else 1, len(devs),
+                   jax.__version__, compile_cache_stats()["dir"]))
+
+    @staticmethod
+    def _device_summary() -> str:
+        """End-of-run account: each local device's peak allocation (the
+        CPU backend keeps none) and the persistent compile cache's use."""
+        import jax
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in jax.local_devices()]
+        cc = compile_cache_stats()
+        return ("device: peak_bytes_in_use=%s compile_cache requests=%d "
+                "hits=%d misses=%d"
+                % (json.dumps(peaks), cc["requests"], cc["hits"],
+                   cc["misses"]))
 
     def set_param(self, name: str, val: str) -> None:
         if val == "default":
@@ -1280,14 +1312,17 @@ class LearnTask:
                 self._on_anomaly(anomaly)
         if self.test_io == 0:
             t0 = time.perf_counter()
-            sys.stderr.write("[%d]" % self.start_counter)
+            # one write for the whole line: whatever else lands on stderr
+            # while the evals run (a compiler's log line) must not split
+            # the "[round] metric:value ..." record tools parse
+            line = "[%d]" % self.start_counter
             if not self.itr_evals:
                 with telemetry.span("eval", dataset="train"):
-                    sys.stderr.write(self.net_trainer.evaluate(None, "train"))
+                    line += self.net_trainer.evaluate(None, "train")
             for itr, nm in zip(self.itr_evals, self.eval_names):
                 with telemetry.span("eval", dataset=nm):
-                    sys.stderr.write(self.net_trainer.evaluate(itr, nm))
-            sys.stderr.write("\n")
+                    line += self.net_trainer.evaluate(itr, nm)
+            sys.stderr.write(line + "\n")
             sys.stderr.flush()
             t_eval = time.perf_counter() - t0
         t0 = time.perf_counter()

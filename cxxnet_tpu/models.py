@@ -422,12 +422,14 @@ metric = seq
     return txt
 
 
-def transformer_lm_trainer(vocab: int = 50, seq: int = 16,
-                           batch_size: int = 8, dim: int = 64,
-                           nhead: int = 4, nlayer: int = 2,
-                           dev: str = "cpu", extra_cfg: str = "",
-                           attn_extra: str = "") -> Trainer:
-    conf = (transformer_lm_netconfig(vocab, dim=dim, nhead=nhead,
+def transformer_lm_conf(vocab: int = 50, seq: int = 16,
+                        batch_size: int = 8, dim: int = 64,
+                        nhead: int = 4, nlayer: int = 2,
+                        dev: str = "cpu", extra_cfg: str = "",
+                        attn_extra: str = "") -> str:
+    """The whole training conf of the LM recipe (netconfig + shapes +
+    adam), as text — what a ``task = serve`` conf is appended to."""
+    return (transformer_lm_netconfig(vocab, dim=dim, nhead=nhead,
                                      nlayer=nlayer,
                                      attn_extra=attn_extra) +
             "input_shape = 1,1,%d\n" % seq +
@@ -435,6 +437,11 @@ def transformer_lm_trainer(vocab: int = 50, seq: int = 16,
             "label_vec[0,%d) = label\n" % seq +
             "updater = adam\neta = 0.003\n" +
             "dev = %s\n" % dev + extra_cfg)
+
+
+def transformer_lm_trainer(**kw) -> Trainer:
+    """Initialized trainer for ``transformer_lm_conf(**kw)``."""
+    conf = transformer_lm_conf(**kw)
     tr = Trainer()
     for k, v in parse_config_string(conf):
         tr.set_param(k, v)

@@ -240,6 +240,13 @@ def use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def pallas_interpret() -> bool:
+    """Off the TPU the Pallas kernels run in the interpreter, so forced-on
+    tests (and CPU debugging) execute the exact kernel code; on the TPU
+    they are compiled by Mosaic."""
+    return jax.default_backend() != "tpu"
+
+
 def lrn_nhwc(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
              knorm: float) -> jnp.ndarray:
     """Channels-last LRN: with C minor the cross-channel window sum is a
@@ -282,14 +289,13 @@ def flash_supported(L: int, d: int) -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                     window: int = 0):
-    """Memory-O(L) blocked attention (ops/flash_attn.py). Off-TPU the
-    kernels run in the Pallas interpreter so forced-on tests (and any CPU
-    debugging) execute the exact kernel code. window > 0 (causal only)
-    keeps the last ``window`` keys per query — sliding-window attention;
-    out-of-window kv tiles are skipped wholesale."""
+    """Memory-O(L) blocked attention (ops/flash_attn.py). window > 0
+    (causal only) keeps the last ``window`` keys per query —
+    sliding-window attention; out-of-window kv tiles are skipped
+    wholesale."""
     from . import flash_attn as _fa
-    interpret = jax.default_backend() != "tpu"
-    return _fa.flash_attention(q, k, v, causal, scale, interpret, window)
+    return _fa.flash_attention(q, k, v, causal, scale, pallas_interpret(),
+                               window)
 
 
 def softmax(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:

@@ -14,8 +14,8 @@ mshadow-ps push/pull parameter server + per-GPU worker threads
   within the single jitted train step
 """
 
-from .mesh import (backend_initialized, create_mesh,  # noqa: F401
-                   ensure_platform, parse_device_spec)
+from .mesh import (create_mesh, ensure_platform,  # noqa: F401
+                   parse_device_spec)
 from .sharding import (batch_sharding, replicated,  # noqa: F401
                        zero_sharding)
 from . import collectives  # noqa: F401

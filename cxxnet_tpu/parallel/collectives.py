@@ -26,7 +26,6 @@ from typing import Optional, Sequence, Union
 import jax
 from jax import lax
 
-from . import _compat
 from ..utils import telemetry
 
 AxisName = Union[str, Sequence[str]]
@@ -69,7 +68,7 @@ def ppermute(x, axis_name: AxisName, perm):
 def ring_shift(x, axis_name: str, shift: int = 1):
     """Rotate shards around the ring: device i's value goes to i+shift."""
     telemetry.count("collective.ring_shift")
-    n = _compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -86,4 +85,4 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    return _compat.axis_size(axis_name)
+    return lax.axis_size(axis_name)

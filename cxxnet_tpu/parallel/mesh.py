@@ -29,42 +29,32 @@ def parse_device_spec(spec: str) -> Tuple[str, List[int]]:
 
 def backend_initialized() -> bool:
     """True when a jax backend is already live in this process. Peeks at
-    jax's internal registry so the check itself never initializes (and
-    thus never blocks on) a backend."""
-    try:
-        from jax._src import xla_bridge as xb
-        return bool(getattr(xb, "_backends", None))
-    except Exception:
-        return False
-
-
-_cpu_pinned = False
+    jax's internal registry, so the check itself initializes nothing."""
+    from jax._src import xla_bridge as xb
+    return bool(xb._backends)
 
 
 def ensure_platform(kind: str) -> None:
-    """Make ``dev = cpu`` actually select the CPU backend even when the
-    environment pins another jax platform (JAX_PLATFORMS is read before
-    user code runs, so the env route cannot be overridden later). No-op
-    unless kind is cpu and no backend has been initialized yet.
-
-    The selection is process-wide (a jax constraint): once a dev=cpu
-    trainer pinned the CPU backend, a later dev=tpu/gpu trainer in the
-    same process would silently run on CPU — that case raises instead."""
-    global _cpu_pinned
-    if kind != "cpu":
-        if _cpu_pinned:
-            raise RuntimeError(
-                "dev=%s requested, but this process already selected the "
-                "CPU backend for an earlier dev=cpu trainer; jax supports "
-                "one platform per process — use a separate process" % kind)
-        return
-    if backend_initialized():
-        return  # backend already live; too late and unnecessary
-    try:
+    """Hold ``dev = <kind>`` against the backend this process runs on.
+    ``cpu`` pins the CPU backend
+    when none is live yet, so ``dev = cpu`` selects the CPU even on a
+    machine whose default is an accelerator. Afterwards the backend must
+    be the one the config named — ``tpu`` needs a TPU, ``gpu`` (the
+    reference-era spelling, doc/migration.md) any accelerator, ``cpu`` the
+    CPU — and anything else raises, naming what was found: a run never
+    lands on a device the config did not ask for."""
+    if kind not in ("cpu", "tpu", "gpu"):
+        raise ValueError("dev: unknown device kind %r (cpu, tpu or gpu)"
+                         % kind)
+    if kind == "cpu" and not backend_initialized():
         jax.config.update("jax_platforms", "cpu")
-        _cpu_pinned = True
-    except Exception:
-        pass
+    found = jax.default_backend()
+    if found != kind and (kind != "gpu" or found == "cpu"):
+        raise RuntimeError(
+            "dev = %s requested, but this process runs on the %s backend "
+            "(%d x %s; jax supports one platform per process)"
+            % (kind, found, len(jax.devices()),
+               jax.devices()[0].device_kind))
 
 
 def create_mesh(device_ids: Optional[Sequence[int]] = None,
@@ -79,12 +69,17 @@ def create_mesh(device_ids: Optional[Sequence[int]] = None,
     devs = jax.devices()
     if device_ids:
         id_map = {d.id: d for d in devs}
-        picked = [id_map[i] for i in device_ids if i in id_map]
-        # multi-process runs have non-contiguous global device ids (each
-        # process numbers its own block), so `dev=tpu:0-7` style specs fall
-        # back to positional selection when ids don't all resolve
-        devs = picked if len(picked) == len(device_ids) \
-            else jax.devices()[: len(device_ids)]
+        if all(i in id_map for i in device_ids):
+            devs = [id_map[i] for i in device_ids]
+        elif jax.process_count() > 1 and len(device_ids) <= len(devs):
+            # multi-process runs have non-contiguous global device ids
+            # (each process numbers its own block), so `dev=tpu:0-7` style
+            # specs select positionally there
+            devs = devs[: len(device_ids)]
+        else:
+            raise ValueError(
+                "dev: device ids %s requested, but this process has only "
+                "%s" % (list(device_ids), sorted(id_map)))
     if shape is None:
         shape = (len(devs),) + (1,) * (len(axes) - 1)
     arr = np.array(devs[: int(np.prod(shape))]).reshape(shape)

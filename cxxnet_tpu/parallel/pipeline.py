@@ -25,7 +25,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import collectives
-from ._compat import axis_size, shard_map
+from ._compat import shard_map
 
 
 def stage_sharding(mesh: Mesh, axis: str = "pipe") -> NamedSharding:
@@ -35,7 +35,7 @@ def stage_sharding(mesh: Mesh, axis: str = "pipe") -> NamedSharding:
 
 def _pipeline_local(params, x, *, axis_name: str, n_micro: int,
                     stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray]):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     # local slice of the stacked stage params: leading dim 1 -> this stage
     params = jax.tree.map(lambda p: p[0], params)
@@ -78,7 +78,7 @@ def _pipeline_local_switch(params, x, state0=None, *, axis_name: str,
     combines the per-stage slots via ``state_masks`` (a (n_stages, S)
     ownership mask) with a psum over the pipe axis; ``data_axis`` names a
     composed data axis to pmean per-shard statistics over."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     cur = jnp.zeros_like(x[0])
     perm = [(i, i + 1) for i in range(n - 1)]

@@ -30,7 +30,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import collectives
-from ._compat import axis_size, shard_map
+from ._compat import shard_map
 
 
 def attention_reference(q, k, v, *, causal: bool = False,
@@ -154,7 +154,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     only transiently for the tile compute. Runs axis_size steps; at step t
     the device holds the K/V block originally on device (idx - t) mod n.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     skv = k.shape[2]
@@ -266,7 +266,7 @@ def _ring_flash_local(q, k, v, axis_name, causal, scale, interpret,
 def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
                     window=0):
     from ..ops import ring_flash as rf
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     skv = k.shape[2]
@@ -311,7 +311,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
 def _ring_flash_bwd(axis_name, causal, scale, interpret, window, res, g):
     from ..ops import ring_flash as rf
     q, k, v, out, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     skv = k.shape[2]
@@ -420,7 +420,8 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "sp",
     n = mesh.shape[axis_name]
     sq = q.shape[2] // n
     if _ring_flash_enabled(sq, k.shape[2] // n, q.shape[-1]):
-        interpret = jax.default_backend() != "tpu"
+        from .. import ops
+        interpret = ops.pallas_interpret()
         fn = shard_map(
             lambda q_, k_, v_: _ring_flash_local(
                 q_, k_, v_, axis_name, causal, scale, interpret, window),
@@ -436,7 +437,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "sp",
 
 def _ulysses_local(q, k, v, *, axis_name: str, causal: bool, scale: float,
                    window: int = 0):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
 
     def seq_to_heads(x):
         # (b, h, s/n, d) -> (b, h/n, s, d): split heads, gather sequence

@@ -62,7 +62,7 @@ __all__ = ["CAUSES", "classify_record", "classify_route",
            "stitch_route", "TRANSITION_EVENTS", "POINT_EVENTS",
            "INCIDENT_CAUSES", "incidents", "selftest"]
 
-# The cause taxonomy (doc/observability.md "Request autopsy & incident
+# The cause set (doc/observability.md "Request autopsy & incident
 # timeline"). Order is the primary-verdict tie-break: a named cause
 # beats decode_baseline at equal seconds, and earlier names win ties —
 # deterministic, so the same record always gets the same verdict.

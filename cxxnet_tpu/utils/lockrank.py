@@ -354,7 +354,7 @@ def _selftest_enforced(a, b, c) -> None:
     assert not held(), "rank stack leaked after inversion: %r" % held()
 
     # a condition-entered inversion reports the CALLER's site, not the
-    # threading.py internals the acquisition tunnels through
+    # threading.py internals the acquisition passes through
     try:
         with b:
             with c:

@@ -938,12 +938,15 @@ def programz_html(snap: dict) -> str:
     hbm = snap.get("hbm") or {}
     parts = ["<html><head><title>cxxnet programz</title></head>"
              "<body><h1>program performance ledger</h1><pre>"]
-    parts.append("device spec: %s  peak %.1f TFLOP/s  HBM %.0f GB/s  "
-                 "capacity %.1f GiB"
-                 % (esc(str(spec.get("name", "?"))),
-                    (spec.get("peak_flops") or 0.0) / 1e12,
-                    (spec.get("hbm_bw") or 0.0) / 1e9,
-                    (spec.get("hbm_capacity") or 0.0) / 2.0**30))
+    if spec:
+        parts.append("device spec: %s  peak %.1f TFLOP/s  HBM %.0f GB/s  "
+                     "capacity %.1f GiB"
+                     % (esc(str(spec["name"])), spec["peak_flops"] / 1e12,
+                        spec["hbm_bw"] / 1e9,
+                        spec["hbm_capacity"] / 2.0**30))
+    else:
+        parts.append("device spec: none (CPU backend: no MFU, roofline "
+                     "or headroom figure)")
     peak = hbm.get("peak_bytes")
     head = hbm.get("headroom_bytes")
     dkv = hbm.get("decode_kv_bytes")

@@ -1370,19 +1370,20 @@ def audit_sweep() -> Dict[str, Optional[str]]:
 
 
 def sample_device_memory() -> Optional[dict]:
-    """Record the first local device's allocator stats as gauges (device
-    memory high-water). Backends without memory_stats (CPU, some tunneled
-    runtimes) make this a silent no-op."""
-    if not _REG.enabled:
-        return None
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
+    """The first local device's allocator stats, recorded as gauges
+    (device memory high-water) when telemetry is on. The CPU backend keeps
+    no such stats and yields None; an accelerator that reports none is an
+    error, not a quiet gap in the record."""
+    import jax
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
     if not stats:
+        if dev.platform != "cpu":
+            raise RuntimeError("%s (%s) reports no memory_stats()"
+                               % (dev, dev.device_kind))
         return None
-    for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
-        if k in stats:
-            gauge("device." + k, int(stats[k]))
+    if _REG.enabled:
+        for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
+            if k in stats:
+                gauge("device." + k, int(stats[k]))
     return stats

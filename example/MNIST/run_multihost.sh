@@ -16,7 +16,7 @@ REPO=../..
 [ -f data/train-images-idx3-ubyte.gz ] || { echo "run ./run.sh first"; exit 1; }
 
 export XLA_FLAGS="--xla_force_host_platform_device_count=4"
-export CXXNET_JAX_PLATFORM=cpu
+export JAX_PLATFORMS=cpu
 COORD=127.0.0.1:9911
 # batch 96: the global batch must divide across the 8 mesh devices
 ARGS="coordinator=$COORD num_worker=2 dev=cpu:0-7 num_round=3 batch_size=96 model_dir=models_mh"

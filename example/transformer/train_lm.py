@@ -30,24 +30,25 @@ VOCAB = 28
 SEQ = 64
 
 
-def make_batch(rs, batch=16):
-    """Cyclic walks: tok[t] = (phase + stride * t) % VOCAB."""
+def make_batch(rs, batch=16, seq=SEQ):
+    """Cyclic walks: tok[t] = (phase + stride * t) % VOCAB. The alphabet
+    stays VOCAB however wide the model's own vocabulary is."""
     phase = rs.randint(0, VOCAB, (batch, 1))
     stride = rs.randint(1, 5, (batch, 1))
-    t = np.arange(SEQ + 1)[None, :]
-    toks = (phase + stride * t) % VOCAB          # (b, SEQ+1)
+    t = np.arange(seq + 1)[None, :]
+    toks = (phase + stride * t) % VOCAB          # (b, seq+1)
     b = DataBatch()
-    b.data = toks[:, :SEQ].reshape(batch, 1, 1, SEQ).astype(np.float32)
-    b.label = toks[:, 1:].astype(np.float32)     # next-token targets (b, SEQ)
+    b.data = toks[:, :seq].reshape(batch, 1, 1, seq).astype(np.float32)
+    b.label = toks[:, 1:].astype(np.float32)     # next-token targets (b, seq)
     b.batch_size = batch
     return b
 
 
 def next_token_accuracy(tr, batch):
-    probs = tr.extract_feature(batch, "top[-1]")   # (b, VOCAB, 1, SEQ)
-    pred = probs.reshape(probs.shape[0], VOCAB, SEQ).argmax(axis=1)
+    probs = tr.extract_feature(batch, "top[-1]")   # (b, vocab, 1, seq)
+    pred = probs.reshape(probs.shape[0], probs.shape[1], -1).argmax(axis=1)
     # score the second half: the prefix there always determines the walk
-    half = SEQ // 2
+    half = pred.shape[1] // 2
     return float((pred[:, half:] == batch.label[:, half:]).mean())
 
 

@@ -67,7 +67,7 @@ def test_seq_parallel_matches_single_device(sp_mode, causal):
     outputs (same seed => same init params)."""
     x, _ = _data(1)
     single = _build("cpu", causal=causal, sp_mode=sp_mode)
-    sharded = _build("tpu:0-7", causal=causal, sp_mode=sp_mode,
+    sharded = _build("cpu:0-7", causal=causal, sp_mode=sp_mode,
                      extra="seq_parallel = 4\n")
     assert sharded.net_.mesh is not None
     assert dict(zip(sharded.net_.mesh.axis_names,
@@ -83,7 +83,7 @@ def test_seq_parallel_trains():
     # reach the same fit (seed-2 data happens to be a hard draw at this eta
     # on a single device too, so it is not used here)
     x, y = _data()
-    net = _build("tpu:0-7", extra="seq_parallel = 4\n")
+    net = _build("cpu:0-7", extra="seq_parallel = 4\n")
     for _ in range(400):
         net.update(x, y)
     assert (net.predict(x) == y).mean() >= 0.85
@@ -111,7 +111,7 @@ def test_attention_save_load_and_weight_tags(tmp_path):
 
 def test_seq_len_divisibility_error():
     bad = CFG.replace("input_shape = 16,1,16", "input_shape = 16,1,10")
-    net = api.Net(dev="tpu:0-7",
+    net = api.Net(dev="cpu:0-7",
                   cfg=bad % {"causal": 0, "sp_mode": "ring"}
                   + "seq_parallel = 4\nbatch_size = 8\n")
     net.init_model()

@@ -528,7 +528,7 @@ def test_lockrank_inversion_names_both_locks_and_sites(monkeypatch):
         "diagnostic must carry both acquisition sites: " + msg
     assert lockrank.held() == [], "stack leaked after the raise"
     # a condition-entered inversion reports THIS file as the site, not
-    # the threading.py internals the acquisition tunnels through
+    # the threading.py internals the acquisition passes through
     cond = lockrank.condition("servd.conn")      # rank 30
     with pytest.raises(lockrank.LockOrderError) as ei2:
         with inner:                              # rank 100

@@ -502,7 +502,7 @@ def test_cli_serve_task(tmp_path):
     #                                           backend raises mid-loop
     stdin = "\n".join(bad
                       + [" ".join(map(str, r)) for r in lines]) + "\n"
-    env = dict(os.environ, CXXNET_JAX_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [_sys.executable, os.path.join(REPO, "bin", "cxxnet"), cf],
         input=stdin, capture_output=True, text=True, timeout=600,

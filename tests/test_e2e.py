@@ -239,10 +239,10 @@ def test_mnist_conv_trains(tmp_path, mnist_data):
 
 
 def test_mnist_mlp_multidevice(tmp_path, mnist_data):
-    """Data-parallel over the virtual 8-device CPU mesh (dev=tpu:0-3 maps to
+    """Data-parallel over the virtual 8-device CPU mesh (dev=cpu:0-3 maps to
     4 devices; replaces the reference's dev=gpu:0-3 worker threads)."""
     conf = write_conf(tmp_path, MLP_CONF, mnist_data, num_round=4)
-    task = run_task(conf, "dev=tpu:0-3")
+    task = run_task(conf, "dev=cpu:0-3")
     assert task.net_trainer.mesh is not None
     assert task.net_trainer.mesh.devices.size == 4
     err = task.net_trainer.metric.evals[0].get()
@@ -254,7 +254,7 @@ def test_mnist_mlp_composed_parallelism(tmp_path, mnist_data):
     composed mesh: pp x tp x dp + ZeRO-1 (fsdp=1) over the 8-device
     virtual mesh — training must converge exactly like the plain run."""
     conf = write_conf(tmp_path, MLP_CONF, mnist_data, num_round=4)
-    task = run_task(conf, "dev=tpu:0-7", "pipeline_parallel=2",
+    task = run_task(conf, "dev=cpu:0-7", "pipeline_parallel=2",
                     "model_parallel=2", "fsdp=1")
     mesh = task.net_trainer.mesh
     assert (mesh.shape["data"], mesh.shape["pipe"],
@@ -290,7 +290,7 @@ def test_test_on_server_consistency(tmp_path, mnist_data):
     bitwise in sync across the mesh (reference semantics:
     async_updater-inl.hpp:148-153 CheckWeight against the server copy)."""
     conf = write_conf(tmp_path, MLP_CONF, mnist_data, num_round=2)
-    task = run_task(conf, "dev=tpu:0-3", "test_on_server=1")
+    task = run_task(conf, "dev=cpu:0-3", "test_on_server=1")
     tr = task.net_trainer
     # the explicit call must also pass after training
     tr.check_replica_consistency()

@@ -75,7 +75,7 @@ def _random_conf(rs):
     node = nxt
     lines.append("layer[%d->%d] = softmax" % (node, node))
     lines += ["netconfig = end", "input_shape = 3,16,16",
-              "batch_size = 4", "eta = 0.05"]
+              "batch_size = 4", "eta = 0.05", "dev = cpu"]
     return "\n".join(lines) + "\n"
 
 

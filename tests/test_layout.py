@@ -270,7 +270,8 @@ def test_transformer_lm_channels_last_exact():
     conf = transformer_lm_netconfig(20, dim=16, nhead=4, nlayer=2,
                                     attn_extra="rope = 1\n")
     conf += ("input_shape = 1,1,12\nbatch_size = 8\n"
-             "label_vec[0,12) = label\nupdater = adamw\neta = 0.003\n")
+             "label_vec[0,12) = label\nupdater = adamw\neta = 0.003\n"
+             "dev = cpu\n")
     outs = []
     for cl in (0, 1):
         tr = Trainer()

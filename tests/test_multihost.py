@@ -46,7 +46,7 @@ netconfig = end
 input_shape = 1,1,32
 batch_size = 16
 eta = 0.1
-dev = tpu:0-7
+dev = cpu:0-7
 seed = 3
 """
 tr = Trainer()
@@ -209,18 +209,6 @@ print("RANK%%d_PP_OK" %% rank)
 ''')
 
 
-_CPU_BACKEND = os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
-_CPU_MULTIPROC_XFAIL = pytest.mark.xfail(
-    _CPU_BACKEND, strict=True,
-    reason="pre-existing (PR <= 8): this jax build's CPU backend "
-           "refuses cross-process device_put ('Multiprocess "
-           "computations aren't implemented on the CPU backend') — "
-           "the 2-process Gloo tunnel dies in _shard_batch (passes on "
-           "a real multi-host backend); ROADMAP item 7 owns the "
-           "revival")
-
-
-@_CPU_MULTIPROC_XFAIL
 def test_two_process_distributed_training(tmp_path):
     prog = WORKER % {"repo": REPO, "coord": "localhost:45683"}
     from cxxnet_tpu.parallel import virtual_cpu_env
@@ -281,7 +269,7 @@ batch_size = 16
 eta = 0.1
 momentum = 0.9
 update_on_server = 1
-dev = tpu:0-7
+dev = cpu:0-7
 seed = 3
 """
 
@@ -341,7 +329,6 @@ elif phase == "resume":
 '''
 
 
-@_CPU_MULTIPROC_XFAIL
 def test_kill_and_resume_bitwise(tmp_path):
     """Kill a worker mid-round; relaunch; continuation from the checkpoint
     (incl. ZeRO-sharded optimizer state) is BITWISE identical to the

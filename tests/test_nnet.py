@@ -251,6 +251,7 @@ batch_size = 16
 updater = adamw
 eta = 0.01
 wd = 0.01
+dev = cpu
 """
     tr = Trainer()
     for k, v in parse_config_string(conf):

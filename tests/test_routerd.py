@@ -598,8 +598,7 @@ def test_cli_route_task_sigterm_drain():
                                                    r.status_port)
                               for r in fleet))
         conf.close()
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   CXXNET_JAX_PLATFORM="cpu", CXXNET_LOCKRANK="1")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", CXXNET_LOCKRANK="1")
         p = subprocess.Popen(
             [sys.executable, "bin/cxxnet", conf.name],
             stderr=subprocess.PIPE, stdout=subprocess.DEVNULL,

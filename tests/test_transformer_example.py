@@ -37,7 +37,8 @@ def test_lm_learns_grammar():
 def test_lm_pipeline_conf_learns_grammar():
     """lm_pipeline.conf: the composed pp x tp x dp + ZeRO-1 example
     trains the same grammar through the example driver."""
-    acc = train_lm.main(steps=120, conf_name="lm_pipeline.conf")
+    acc = train_lm.main(steps=120, dev="cpu:0-7",
+                        conf_name="lm_pipeline.conf")
     assert acc > 0.7, "composed-mesh LM accuracy %.3f" % acc
 
 
@@ -50,11 +51,11 @@ def test_serve_lm_demo_agrees_across_surfaces():
     token-exactness is pinned in tier-1 by test_decode/test_export;
     this adds the cross-surface demo agreement."""
     import subprocess
-    env = dict(os.environ, CXXNET_JAX_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable,
          os.path.join(os.path.dirname(__file__), "..", "example",
-                      "transformer", "serve_lm.py"), "25"],
+                      "transformer", "serve_lm.py"), "25", "cpu"],
         capture_output=True, text=True, timeout=900, env=env,
         cwd=os.path.join(os.path.dirname(__file__), "..", "example",
                          "transformer"))

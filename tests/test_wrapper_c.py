@@ -28,7 +28,7 @@ def test_c_abi_end_to_end(wrapper_bin, tmp_path):
     make_dataset(str(tmp_path), n_train=200, n_test=50)
     env = dict(os.environ)
     env["CXXNET_TPU_ROOT"] = REPO
-    env["CXXNET_JAX_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     # the C process embeds its own interpreter; drop this pytest process's
     # forced-host-device XLA flags so they don't leak in
     env.pop("XLA_FLAGS", None)

@@ -63,11 +63,7 @@ bool EnsurePython() {
         "import os, sys\n"
         "_root = os.environ.get('CXXNET_TPU_ROOT', os.getcwd())\n"
         "if _root not in sys.path:\n"
-        "    sys.path.insert(0, _root)\n"
-        "_plat = os.environ.get('CXXNET_JAX_PLATFORM')\n"
-        "if _plat:\n"
-        "    import jax\n"
-        "    jax.config.update('jax_platforms', _plat)\n";
+        "    sys.path.insert(0, _root)\n";
     if (PyRun_SimpleString(bootstrap) != 0) {
       SetError("bootstrap failed");
     } else {

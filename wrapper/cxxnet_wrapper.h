@@ -9,9 +9,9 @@
  *
  * Since the compute path is JAX, the library embeds a CPython interpreter
  * and drives cxxnet_tpu.api — one implementation behind both the Python and
- * the C surface. Environment knobs read at first call:
+ * the C surface. Environment read at first call:
  *   CXXNET_TPU_ROOT       repo/package root to put on sys.path (default cwd)
- *   CXXNET_JAX_PLATFORM   optional jax platform override (e.g. "cpu")
+ * The jax platform is chosen the stock way (JAX_PLATFORMS, e.g. "cpu").
  *
  * All functions return NULL / a negative count on error; the message is
  * printed to stderr and retrievable via CXNGetLastError().

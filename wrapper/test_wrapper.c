@@ -4,7 +4,7 @@
  * Builds a small MLP from a config string, memorizes one random batch,
  * checks predictions, round-trips weights and a model file. Exits 0 on
  * success, prints FAIL + nonzero otherwise. Run with CXXNET_TPU_ROOT set
- * to the repo and (optionally) CXXNET_JAX_PLATFORM=cpu.
+ * to the repo and (optionally) JAX_PLATFORMS=cpu.
  */
 #define _GNU_SOURCE /* pthread_timedjoin_np */
 #include "cxxnet_wrapper.h"
