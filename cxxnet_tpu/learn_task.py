@@ -1200,7 +1200,9 @@ class LearnTask:
                 jax.profiler.stop_trace()
                 profiling = False
                 if not self.silent:
-                    print("profiler trace written to %s" % self.profile_dir)
+                    print("profiler trace written to %s (device time by "
+                          "layer and phase: python tools/trace_layers.py %s)"
+                          % (self.profile_dir, self.profile_dir))
             if self._stop_training:
                 telemetry.event({"ev": "preempt_exit", "round": rnd})
                 if not self.silent:

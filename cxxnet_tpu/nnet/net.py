@@ -13,6 +13,7 @@ shared gradients, matching the reference's accumulation into one gwmat.
 
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -28,6 +29,15 @@ from ..utils import serializer
 from .config import NetConfig
 
 Params = List[Dict[str, jnp.ndarray]]
+
+_SCOPE_UNSAFE = re.compile(r"[^A-Za-z0-9_.+\-]")
+
+
+def scope_name(name: str) -> str:
+    """A name fit for one component of an ``op_name`` path: the profiler's
+    ``tf_op`` reads ``jit(step)/jvp(<scope>)/<primitive>``, so a scope
+    holds no ``/``, no parentheses, no ``:`` and no blank."""
+    return _SCOPE_UNSAFE.sub("_", name)
 
 
 class NeuralNet:
@@ -102,6 +112,19 @@ class NeuralNet:
             self.layers.append(lay)
             self.is_shared.append(False)
 
+    def layer_scope(self, i: int) -> str:
+        """The ``jax.named_scope`` of connection ``i`` in every compiled
+        program: the name the conf gives the layer (``layer[...] =
+        conv:conv1`` -> ``conv1``), else ``<type>_<index>``. It is what
+        puts a device operation of the profiler's trace to its layer
+        (utils/devtrace.py)."""
+        return scope_name(self.cfg.layers[i].name
+                          or "%s_%d" % (self.layers[i].type_name, i))
+
+    def group_scope(self, members) -> str:
+        """One scope for a fused group, named by its members."""
+        return "+".join(self.layer_scope(j) for j in members)
+
     def _infer_shapes(self) -> None:
         """Shape inference sweep (InitConnection semantics)."""
         cfg = self.cfg
@@ -165,12 +188,13 @@ class NeuralNet:
         (layer.state_keys(), e.g. BN running stats) stays f32 so EMAs never
         accumulate bf16 rounding."""
         cdt = self.compute_dtype
-        return [
-            {k: (jnp.asarray(v).astype(cdt)
-                 if (jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating)
-                     and k not in self.layers[i].state_keys()) else v)
-             for k, v in p.items()}
-            for i, p in enumerate(params)]
+        with jax.named_scope("cast_params"):
+            return [
+                {k: (jnp.asarray(v).astype(cdt)
+                     if (jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating)
+                         and k not in self.layers[i].state_keys()) else v)
+                 for k, v in p.items()}
+                for i, p in enumerate(params)]
 
     # --- sibling-conv fusion (TPU perf pass; beyond the reference) ---
     def _sibling_conv_plan(self) -> Dict[int, List[int]]:
@@ -351,7 +375,8 @@ class NeuralNet:
     def _relayout(v, frm: str, to: str):
         if frm == to or v.ndim != 4:
             return v
-        return ops.to_nhwc(v) if to == "NHWC" else ops.to_nchw(v)
+        with jax.named_scope("relayout"):
+            return ops.to_nhwc(v) if to == "NHWC" else ops.to_nchw(v)
 
     def _apply_fused_siblings(self, g: List[int], params, values,
                               layouts, ctx=None) -> None:
@@ -400,15 +425,17 @@ class NeuralNet:
 
         if all(self.layers[j].remat for j in g):
             fused = jax.checkpoint(fused)
-        y = fused(x, [params[j] for j in g])
-        off = 0
-        for j in g:
-            n = self.layers[j].param.num_channel
-            out_n = cfg.layers[j].nindex_out[0]
-            values[out_n] = (y[..., off:off + n] if want == "NHWC"
-                             else y[:, off:off + n])
-            layouts[out_n] = want
-            off += n
+        # one scope for the group: the fused conv is no member's alone
+        with jax.named_scope(self.group_scope(g)):
+            y = fused(x, [params[j] for j in g])
+            off = 0
+            for j in g:
+                n = self.layers[j].param.num_channel
+                out_n = cfg.layers[j].nindex_out[0]
+                values[out_n] = (y[..., off:off + n] if want == "NHWC"
+                                 else y[:, off:off + n])
+                layouts[out_n] = want
+                off += n
 
     def _apply_fused_cross(self, g: List[int], pl: int, pj: int,
                            params, values, layouts, ctx,
@@ -445,7 +472,8 @@ class NeuralNet:
                 pool_in, layouts[pool_info.nindex_in[0]], want)
             values[pool_info.nindex_in[0]] = pool_in
             layouts[pool_info.nindex_in[0]] = want
-        (pooled,) = pool_lay.apply(params[pl], [pool_in], ctx)
+        with jax.named_scope(self.layer_scope(pl)):
+            (pooled,) = pool_lay.apply(params[pl], [pool_in], ctx)
         values[pool_info.nindex_out[0]] = pooled
         layouts[pool_info.nindex_out[0]] = want
 
@@ -472,7 +500,6 @@ class NeuralNet:
 
         if all(self.layers[j].remat for j in members):
             fused = jax.checkpoint(fused)
-        y = fused(x, pooled, [params[j] for j in members])
         b, _, h, w = self.node_shapes[cfg.layers[g[0]].nindex_out[0]]
         bsz = x.shape[0]
 
@@ -488,10 +515,14 @@ class NeuralNet:
             layouts[out_n] = want
             return off + n
 
-        off = 0
-        for j in g:
-            off = publish(j, y[0], off)
-        publish(pj, y[1], 0)
+        # one scope for the stacked matmul and its slices, named by all
+        # its members (the pool keeps its own above)
+        with jax.named_scope(self.group_scope(members)):
+            y = fused(x, pooled, [params[j] for j in members])
+            off = 0
+            for j in g:
+                off = publish(j, y[0], off)
+            publish(pj, y[1], 0)
 
     def _apply_remat(self, lay, pidx, p, ins, ctx):
         """jax.checkpoint around a pure layer apply (config key ``remat``):
@@ -577,18 +608,20 @@ class NeuralNet:
                     values[j] = v
                     layouts[j] = want
                 ins.append(v)
-            if cdt is not None and lay.is_loss:
-                # losses always in f32 (softmax/log numerics)
-                ins = [x.astype(jnp.float32) for x in ins]
-            if (lay.remat and not lay.is_loss and not lay.state_keys()
-                    and ctx.decode_pos is None
-                    and not isinstance(lay, factory.PairTestLayer)):
-                # remat is a training-memory trade; the KV-cached decode
-                # forward skips it (no backward — and cache updates could
-                # not escape a jax.checkpoint body anyway)
-                outs = self._apply_remat(lay, pidx, params[pidx], ins, ctx)
-            else:
-                outs = lay.apply(params[pidx], ins, ctx)
+            with jax.named_scope(self.layer_scope(i)):
+                if cdt is not None and lay.is_loss:
+                    # losses always in f32 (softmax/log numerics)
+                    ins = [x.astype(jnp.float32) for x in ins]
+                if (lay.remat and not lay.is_loss and not lay.state_keys()
+                        and ctx.decode_pos is None
+                        and not isinstance(lay, factory.PairTestLayer)):
+                    # remat is a training-memory trade; the KV-cached
+                    # decode forward skips it (no backward — and cache
+                    # updates could not escape a jax.checkpoint body anyway)
+                    outs = self._apply_remat(lay, pidx, params[pidx], ins,
+                                             ctx)
+                else:
+                    outs = lay.apply(params[pidx], ins, ctx)
             for j, v in zip(info.nindex_out, outs):
                 values[j] = v
                 layouts[j] = want if v.ndim == 4 else "NCHW"
@@ -603,12 +636,13 @@ class NeuralNet:
         matches the augmenter's mean_value key (b, g, r)."""
         if self.input_scale == 1.0 and self.input_mean is None:
             return x
-        x = x.astype(jnp.float32)
-        if self.input_mean is not None:
-            x = x - jnp.asarray(self.input_mean).reshape(1, -1, 1, 1)
-        if self.input_scale != 1.0:
-            x = x * self.input_scale
-        return x
+        with jax.named_scope("input"):
+            x = x.astype(jnp.float32)
+            if self.input_mean is not None:
+                x = x - jnp.asarray(self.input_mean).reshape(1, -1, 1, 1)
+            if self.input_scale != 1.0:
+                x = x * self.input_scale
+            return x
 
     def forward(self, params: Params, data, extra_data=(),
                 labels: Optional[LabelInfo] = None, train: bool = False,
@@ -640,8 +674,9 @@ class NeuralNet:
             values[i + 1] = jnp.asarray(ex)
         if cdt is not None:
             id_nodes = self._integer_id_nodes()
-            values = [v if v is None or i in id_nodes else v.astype(cdt)
-                      for i, v in enumerate(values)]
+            with jax.named_scope("input"):
+                values = [v if v is None or i in id_nodes else v.astype(cdt)
+                          for i, v in enumerate(values)]
             params = self._cast_params_compute(params)
         ctx = ApplyContext(train=train, labels=labels, epoch=epoch,
                            mesh=mesh, decode_pos=decode_pos,
@@ -654,7 +689,7 @@ class NeuralNet:
         # values the caller never reads are dead code XLA eliminates
         for n, lo_ in enumerate(layouts):
             if lo_ == "NHWC" and values[n] is not None:
-                values[n] = ops.to_nchw(values[n])
+                values[n] = self._relayout(values[n], "NHWC", "NCHW")
         total_loss = sum(ctx.losses) if ctx.losses else jnp.zeros(())
         self._last_pairtest_diffs = getattr(ctx, "pairtest_diffs", [])
         # non-gradient param updates (BN running stats); valid only when
@@ -941,7 +976,7 @@ class NeuralNet:
             for n in boundaries[s + 1]:
                 if louts[n] == "NHWC":
                     # the stage stream carries reference-NCHW bytes
-                    vals[n] = ops.to_nchw(vals[n])
+                    vals[n] = self._relayout(vals[n], "NHWC", "NCHW")
             ys = [vals[n].reshape(vals[n].shape[0], -1)
                   .astype(stream_dtype) for n in boundaries[s + 1]]
             y = jnp.concatenate(ys, axis=1) if len(ys) > 1 else ys[0]
@@ -1025,7 +1060,7 @@ class NeuralNet:
                                         first_loss, len(cfg.layers))
         for n, lo_ in enumerate(louts):
             if lo_ == "NHWC" and values[n] is not None:
-                values[n] = ops.to_nchw(values[n])
+                values[n] = self._relayout(values[n], "NHWC", "NCHW")
         total_loss = sum(ctx.losses) if ctx.losses else jnp.zeros(())
         self._last_pairtest_diffs = getattr(ctx, "pairtest_diffs", [])
         # prefix state came back through the pipeline's state carry; tail

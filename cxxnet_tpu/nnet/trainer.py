@@ -407,10 +407,17 @@ class Trainer:
             self.updaters.append(ups)
 
     def init_model(self) -> None:
-        self._init_net_structure()
-        self.params = self.net.init_params(self.seed)
-        self._init_opt()
-        self._pp_pack()
+        # phases, not spans: set-up happens once, and `setup_s` wants it
+        # read in runs where nothing enabled telemetry
+        with telemetry.phase("init.model"):
+            with telemetry.phase("init.structure"):
+                self._init_net_structure()
+            with telemetry.phase("init.params"):
+                self.params = self.net.init_params(self.seed)
+            with telemetry.phase("init.opt"):
+                self._init_opt()
+            with telemetry.phase("init.pack"):
+                self._pp_pack()
 
     # ------------------------------------------------------------------
     # pipeline-parallel parameter packing: each pipe rank OWNS its stage's
@@ -932,12 +939,15 @@ class Trainer:
     def _apply_updates(self, params, grads, opt_state, epoch):
         new_params = [dict(p) for p in params]
         new_opt = [dict(s) for s in opt_state]
+        # scopes ``update/<layer>``: the profiler's trace puts each device
+        # operation of the optimizer to its layer (utils/devtrace.py)
         for i, ups in enumerate(self.updaters):
             for key, up in ups.items():
                 if key not in params[i]:
                     continue   # lives in the PP packed array (below)
-                w, st = up.apply(params[i][key], grads[i][key],
-                                 opt_state[i][key], epoch)
+                with jax.named_scope("update/" + self.net.layer_scope(i)):
+                    w, st = up.apply(params[i][key], grads[i][key],
+                                     opt_state[i][key], epoch)
                 new_params[i][key] = w
                 new_opt[i][key] = st
         if self._pp_entries is not None:
@@ -956,12 +966,13 @@ class Trainer:
             gid = self._pp_gid   # pipe-sharded device array (see _pp_pack)
             new_pk = packed
             new_spk = dict(spk)
-            for g_id, up in enumerate(self._pp_groups):
-                w2, st2 = up.apply(packed, gpk, spk, epoch)
-                sel = gid == np.int8(g_id)
-                new_pk = jnp.where(sel, w2, new_pk)
-                for sk, v2 in st2.items():
-                    new_spk[sk] = jnp.where(sel, v2, new_spk[sk])
+            with jax.named_scope("update/packed"):
+                for g_id, up in enumerate(self._pp_groups):
+                    w2, st2 = up.apply(packed, gpk, spk, epoch)
+                    sel = gid == np.int8(g_id)
+                    new_pk = jnp.where(sel, w2, new_pk)
+                    for sk, v2 in st2.items():
+                        new_spk[sk] = jnp.where(sel, v2, new_spk[sk])
             sh = NamedSharding(self.mesh, P("pipe", None))
             opt_sh = NamedSharding(self.mesh, P("pipe", "data")) \
                 if self._pp_zero1() else sh
@@ -1032,37 +1043,44 @@ class Trainer:
                                              epoch, with_stats)
             health = None
             ok = None
+            # the scopes below (health, accum, clip, guard; update/<layer>
+            # in _apply_updates) name what is no layer's in the trace
             if with_health:
-                leaves = jax.tree_util.tree_leaves(grads)
-                gn_sq = sum(jnp.vdot(g, g) for g in leaves) \
-                    .astype(jnp.float32)
-                # the elements updater._clip_nan would silently zero
-                # (telemetry counter health/nan_grads_zeroed, read by the
-                # host monitor)
-                nan_elems = sum(jnp.sum(jnp.isnan(g)) for g in leaves)
-                lossf = loss.astype(jnp.float32)
-                ok = jnp.isfinite(lossf) & jnp.isfinite(gn_sq)
-                health = jnp.stack([lossf, gn_sq,
-                                    nan_elems.astype(jnp.float32),
-                                    ok.astype(jnp.float32)])
+                with jax.named_scope("health"):
+                    leaves = jax.tree_util.tree_leaves(grads)
+                    gn_sq = sum(jnp.vdot(g, g) for g in leaves) \
+                        .astype(jnp.float32)
+                    # the elements updater._clip_nan would silently zero
+                    # (telemetry counter health/nan_grads_zeroed, read by
+                    # the host monitor)
+                    nan_elems = sum(jnp.sum(jnp.isnan(g)) for g in leaves)
+                    lossf = loss.astype(jnp.float32)
+                    ok = jnp.isfinite(lossf) & jnp.isfinite(gn_sq)
+                    health = jnp.stack([lossf, gn_sq,
+                                        nan_elems.astype(jnp.float32),
+                                        ok.astype(jnp.float32)])
             if guard:
                 prev = (params, opt_state, grad_accum, metric_accum)
             if accumulate:
-                grads = jax.tree.map(jnp.add, grad_accum, grads)
+                with jax.named_scope("accum"):
+                    grads = jax.tree.map(jnp.add, grad_accum, grads)
             if do_update:
                 if self.clip_global_norm > 0:
                     # whole-model norm clip (beyond the reference's
                     # per-tensor clip_gradient): one scale for every
                     # tensor preserves the gradient direction
-                    leaves = jax.tree_util.tree_leaves(grads)
-                    gn = jnp.sqrt(sum(jnp.vdot(g, g) for g in leaves))
-                    scale = jnp.minimum(
-                        1.0, self.clip_global_norm / jnp.maximum(gn, 1e-12))
-                    grads = jax.tree.map(lambda g: g * scale, grads)
+                    with jax.named_scope("clip"):
+                        leaves = jax.tree_util.tree_leaves(grads)
+                        gn = jnp.sqrt(sum(jnp.vdot(g, g) for g in leaves))
+                        scale = jnp.minimum(
+                            1.0,
+                            self.clip_global_norm / jnp.maximum(gn, 1e-12))
+                        grads = jax.tree.map(lambda g: g * scale, grads)
                 params, opt_state = self._apply_updates(
                     params, grads, opt_state, epoch)
                 if with_accum:
-                    grads = jax.tree.map(jnp.zeros_like, grads)
+                    with jax.named_scope("accum"):
+                        grads = jax.tree.map(jnp.zeros_like, grads)
             if state_ups:
                 # non-gradient updates (BN running stats): direct assignment
                 params = [dict(p) for p in params]
@@ -1090,12 +1108,13 @@ class Trainer:
                 # a buffer-aliasing hint, not a consume.
                 def sel(n, o):
                     return jnp.where(ok, n, o)
-                params = jax.tree.map(sel, params, prev[0])
-                opt_state = jax.tree.map(sel, opt_state, prev[1])
-                if with_accum:
-                    grads = jax.tree.map(sel, grads, prev[2])
-                if with_stats:
-                    metric_accum = sel(metric_accum, prev[3])
+                with jax.named_scope("guard"):
+                    params = jax.tree.map(sel, params, prev[0])
+                    opt_state = jax.tree.map(sel, opt_state, prev[1])
+                    if with_accum:
+                        grads = jax.tree.map(sel, grads, prev[2])
+                    if with_stats:
+                        metric_accum = sel(metric_accum, prev[3])
             # when update_period == 1 no grad-accumulator state is carried
             # at all (no params-sized zero tree in HBM, no donate/add)
             return (params, opt_state,
@@ -1186,45 +1205,51 @@ class Trainer:
 
     def update(self, batch) -> None:
         """One mini-batch (reference Update, nnet_impl-inl.hpp:141-185)."""
-        need_update = (self.sample_counter + 1) % self.update_period == 0
-        accumulate = self.sample_counter % self.update_period != 0
-        with_accum = self.update_period > 1
-        with_stats = self.eval_train != 0 and len(self.train_metric) > 0
-        with_health = self.health_monitor != 0
-        step = self._get_step(need_update, accumulate, with_accum,
-                              with_stats, with_health)
-        with telemetry.span("train.h2d"):
-            data = self._shard_batch(batch.data)
-            label = self._shard_batch(batch.label)
-        if with_accum and self.grad_accum is None:
-            self.grad_accum = jax.tree.map(
-                lambda x: jnp.zeros_like(x),
-                [{k: v for k, v in p.items()} for p in self.params])
-        if with_stats and self._metric_accum is None:
-            self._metric_accum = jnp.zeros(
-                (len(self.train_metric), 2), jnp.float32)
-        # the span covers DISPATCH (plus any trace+compile, which the
-        # jit watch separates out) — execution is async; the input-wait
-        # fraction the train loop reports is what exposes device stalls
-        # cxxlint: disable=timed-dispatch — dispatch-only by design (the
-        # comment above): device time shows up as the round's io-wait
-        # complement, compiles via the jit watch
-        with telemetry.span("train.step"):
-            (self.params, self.opt_state, self.grad_accum,
-             self._metric_accum, self.last_health) = \
-                step(self.params, self.opt_state, self.grad_accum,
-                     self._metric_accum, data, label,
-                     jnp.asarray(self.epoch_counter, jnp.int32),
-                     self._next_rng())
-        if telemetry.enabled():
-            telemetry.count("train.images",
-                            batch.batch_size - batch.num_batch_padd)
-            if need_update and with_accum:
-                telemetry.count("train.accum_flush")
-        self.sample_counter += 1
-        if self.sample_counter >= self.update_period:
-            self.sample_counter = 0
-            self.epoch_counter += 1
+        # the whole call: under a profiler session the three train.* spans
+        # land on the host plane of the trace (telemetry.span), where each
+        # idle gap of the device is put down to one of them
+        # cxxlint: disable=timed-dispatch — host time by design, like
+        # train.step inside it: device time is read from the trace
+        with telemetry.span("train.update"):
+            need_update = (self.sample_counter + 1) % self.update_period == 0
+            accumulate = self.sample_counter % self.update_period != 0
+            with_accum = self.update_period > 1
+            with_stats = self.eval_train != 0 and len(self.train_metric) > 0
+            with_health = self.health_monitor != 0
+            step = self._get_step(need_update, accumulate, with_accum,
+                                  with_stats, with_health)
+            with telemetry.span("train.h2d"):
+                data = self._shard_batch(batch.data)
+                label = self._shard_batch(batch.label)
+            if with_accum and self.grad_accum is None:
+                self.grad_accum = jax.tree.map(
+                    lambda x: jnp.zeros_like(x),
+                    [{k: v for k, v in p.items()} for p in self.params])
+            if with_stats and self._metric_accum is None:
+                self._metric_accum = jnp.zeros(
+                    (len(self.train_metric), 2), jnp.float32)
+            # the span covers DISPATCH (plus any trace+compile, which the
+            # jit watch separates out) — execution is async; the input-wait
+            # fraction the train loop reports is what exposes device stalls
+            # cxxlint: disable=timed-dispatch — dispatch-only by design (the
+            # comment above): device time shows up as the round's io-wait
+            # complement, compiles via the jit watch
+            with telemetry.span("train.step"):
+                (self.params, self.opt_state, self.grad_accum,
+                 self._metric_accum, self.last_health) = \
+                    step(self.params, self.opt_state, self.grad_accum,
+                         self._metric_accum, data, label,
+                         jnp.asarray(self.epoch_counter, jnp.int32),
+                         self._next_rng())
+            if telemetry.enabled():
+                telemetry.count("train.images",
+                                batch.batch_size - batch.num_batch_padd)
+                if need_update and with_accum:
+                    telemetry.count("train.accum_flush")
+            self.sample_counter += 1
+            if self.sample_counter >= self.update_period:
+                self.sample_counter = 0
+                self.epoch_counter += 1
 
     def scale_lr(self, factor: float) -> None:
         """Multiply every updater's base learning rate by ``factor`` —

@@ -398,6 +398,16 @@ def prometheus_metrics(snapshot: dict, progress: Optional[dict] = None,
          help_="jit recompiles detected since run start")
     emit("cxxnet_compile_seconds_total", "counter",
          float(snapshot.get("compile_s", 0.0)))
+    phases = snapshot.get("phases") or {}
+    if phases:
+        # telemetry's always-on account: init.* and jit.build/<program>
+        # with jax's own trace / lower / compile / cache_load beside it
+        out.append("# HELP cxxnet_phase_seconds seconds of a set-up phase's "
+                   "first occurrence in this process")
+        out.append("# TYPE cxxnet_phase_seconds gauge")
+        for name, secs in sorted(phases.items()):
+            out.append('cxxnet_phase_seconds{process="%s",phase="%s"} %s'
+                       % (_lesc(p), _lesc(name), _fmt(float(secs))))
     if health_failures is not None:
         emit("cxxnet_healthy", "gauge", 0 if health_failures else 1,
              help_="1 when /healthz (readiness) returns 200")
@@ -1887,8 +1897,8 @@ class _Endpoint(BaseHTTPRequestHandler):
                     ok, detail = prof.start(secs)
                     if ok:
                         body = ("profiling for %gs into %s\n(xprof/"
-                                "TensorBoard-profile format; summarize "
-                                "with tools/summarize_trace.py)\n"
+                                "TensorBoard-profile format; put it to "
+                                "layers with tools/trace_layers.py)\n"
                                 % (secs, detail))
                         if prev_err:
                             body += ("WARNING: previous capture FAILED: "
@@ -2237,6 +2247,8 @@ class StatusServer:
             table("recompiles", [("count", comp["count"]),
                                  ("total_s", comp["total_s"])] +
                   sorted(comp.get("by_cause", {}).items()))
+        table("set-up phases (seconds, first occurrence)",
+              sorted(snap.get("phases", {}).items()))
         table("counters", sorted(snap["counters"].items()))
         table("gauges", sorted(snap["gauges"].items()))
 

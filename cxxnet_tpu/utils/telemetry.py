@@ -9,6 +9,19 @@ arxiv 1802.04799). This module is that measurement substrate:
 * **span timers** — ``with telemetry.span("io.decode"):`` records wall time
   per named region; spans nest (a per-thread stack tracks depth/parent) and
   are safe to emit from worker threads (the decode pool, the prefetcher).
+  While a jax profiler session records, a span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, telemetry enabled or not,
+  so it lands on the host plane of the same ``.xplane.pb`` as the device
+  operations (utils/devtrace.py names each idle gap by it).
+* **phases** — ``with telemetry.phase("init.params"):`` is a span that also
+  writes its seconds to a small always-on account (``phases()``), for what
+  happens once a process and is wanted in runs that enabled nothing:
+  ``Trainer.init_model``'s parts and each program's first call
+  (``jit.build/<name>``, with jax's own trace / lower / compile /
+  cache_load seconds beside it). The account keeps the first occurrence
+  of each name and outlives ``reset()``: it says what this process's
+  set-up cost. ``summary()``, ``/metrics`` (``cxxnet_phase_seconds``) and
+  ``/statusz`` show it.
 * **counters / gauges** — ``telemetry.count("train.images", n)`` accumulates
   monotonically; ``telemetry.gauge("device.bytes_in_use", v)`` records the
   latest value of a level. ``sample_device_memory()`` snapshots the
@@ -84,7 +97,8 @@ from typing import Dict, List, Optional
 from . import lockrank
 
 __all__ = [
-    "enable", "disable", "enabled", "reset", "span", "count", "gauge",
+    "enable", "disable", "enabled", "reset", "span", "phase", "phases",
+    "count", "gauge",
     "hist", "event", "record_compile", "jit_watch",
     "sample_device_memory",
     "flush", "finish", "summary", "brief_summary", "events",
@@ -225,16 +239,36 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_TRACE_ANNOTATION = None     # jax.profiler.TraceAnnotation, once jax is seen
+
+
+def _recording_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None. jax is looked up only where it is already loaded: servd and
+    statusd import this module without it."""
+    global _TRACE_ANNOTATION
+    ta = _TRACE_ANNOTATION
+    if ta is None:
+        ta = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                     "TraceAnnotation", None)
+        if ta is None:
+            return None
+        _TRACE_ANNOTATION = ta
+    return ta if ta.is_enabled() else None
+
 
 class _Span:
-    __slots__ = ("reg", "name", "attrs", "t0", "depth")
+    __slots__ = ("reg", "name", "attrs", "t0", "depth", "ann")
 
-    def __init__(self, reg: "_Registry", name: str, attrs):
+    def __init__(self, reg: "_Registry", name: str, attrs, ann=None):
         self.reg = reg
         self.name = name
         self.attrs = attrs
+        self.ann = ann       # the profiler's annotation of the same name
 
     def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
         stack = self.reg._stack()
         self.depth = len(stack)
         stack.append(self.name)
@@ -248,6 +282,79 @@ class _Span:
             stack.pop()
         self.reg._record_span(self.name, self.t0, dur, self.depth,
                               self.attrs)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
+# jax's monitoring events that split a program's first call, and the name
+# each goes by under the call's ``jit.build/<program>`` phase
+_BUILD_PARTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_BUILD_TLS = threading.local()   # .parts: the build open on this thread
+_BUILD_LISTENING = False
+
+
+def _on_build_duration(event, secs, **_kw):
+    parts = getattr(_BUILD_TLS, "parts", None)
+    part = _BUILD_PARTS.get(event)
+    if parts is not None and part is not None:
+        # the longest: a nested jit's trace fires the event too, inside
+        # the outermost's; the others come once a program
+        parts[part] = max(parts.get(part, 0.0), secs)
+
+
+def _listen_for_build_parts() -> None:
+    """One listener a process on jax's monitoring events (jax has no way to
+    take one off again), registered at the first build phase: it adds to
+    the build that is open on the calling thread, and is a dictionary miss
+    otherwise (the events fire when something is traced or compiled, not
+    per call)."""
+    global _BUILD_LISTENING
+    if not _BUILD_LISTENING:
+        _BUILD_LISTENING = True
+        sys.modules["jax"].monitoring \
+            .register_event_duration_secs_listener(_on_build_duration)
+
+
+class _Phase:
+    """A span that also writes its seconds to the registry's always-on
+    account (``phases()``), which keeps the first occurrence of a name.
+    With ``parts``, jax's own durations of a program's build that arrive
+    on this thread meanwhile are kept beside it as ``<name>/<part>``."""
+
+    __slots__ = ("reg", "name", "parts", "outer", "span", "t0")
+
+    def __init__(self, reg: "_Registry", name: str, parts: bool = False):
+        self.reg = reg
+        self.name = name
+        self.parts = {} if parts else None
+
+    def __enter__(self):
+        if self.parts is not None:
+            _listen_for_build_parts()
+            self.outer = getattr(_BUILD_TLS, "parts", None)
+            _BUILD_TLS.parts = self.parts
+        self.span = self.reg.span(self.name)
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        self.span.__exit__(*exc)
+        found = {self.name: dur}
+        if self.parts is not None:
+            _BUILD_TLS.parts = self.outer
+            for part, secs in self.parts.items():
+                found["%s/%s" % (self.name, part)] = secs
+        with self.reg._lock:
+            if self.name not in self.reg.phase_s:
+                self.reg.phase_s.update(found)
         return False
 
 
@@ -341,6 +448,11 @@ class _Registry:
         # every detected compile. Survives reset()/enable() — bench
         # resets telemetry between rows without re-wiring the ledger.
         self.compile_hook = None
+        # the always-on account of phases: name -> seconds of its first
+        # occurrence in this process (a few dozen entries). Outlives
+        # reset()/enable() as compile_hook does: it is the process's
+        # set-up, and a JitWatch's first call does not come again.
+        self.phase_s: Dict[str, float] = {}
         self.reset()
 
     # -- lifecycle -----------------------------------------------------
@@ -485,9 +597,20 @@ class _Registry:
             del self._pending[: _PENDING_CAP // 2]
 
     def span(self, name: str, **attrs):
+        ann = _recording_annotation()
+        if ann is not None:
+            # a profiler session records: the span goes on its clock too
+            ann = ann(name, **attrs)
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, attrs or None)
+            return _NULL_SPAN if ann is None else ann
+        return _Span(self, name, attrs or None, ann)
+
+    def phase(self, name: str, parts: bool = False) -> _Phase:
+        return _Phase(self, name, parts)
+
+    def phases(self) -> Dict[str, float]:
+        with self._lock:
+            return dict(self.phase_s)
 
     def span_event(self, name: str, start_perf: float, dur: float,
                    **attrs) -> None:
@@ -670,13 +793,15 @@ class _Registry:
                 "hists": {k: h.to_dict() for k, h in self.hists.items()},
                 "compiles": len(self.compiles),
                 "compile_s": round(sum(c["dur"] for c in self.compiles), 6),
+                "phases": dict(self.phase_s),
                 "uptime_s": time.perf_counter() - self.t0_perf,
                 "process": self.process_index,
             }
 
     def summary(self) -> dict:
         """Aggregate view: per-span totals, counters, gauges, compiles,
-        and p50/p90/p99 duration percentiles per span name."""
+        the set-up phases, and p50/p90/p99 duration percentiles per span
+        name."""
         with self._lock:
             spans = {}
             for name, (n, total, mx) in self.span_agg.items():
@@ -702,6 +827,8 @@ class _Registry:
                     "by_cause": count_by(self.compiles, "cause"),
                     "by_name": count_by(self.compiles, "name"),
                 },
+                "phases": {k: round(v, 6)
+                           for k, v in self.phase_s.items()},
             }
 
     def brief_summary(self, top: int = 8,
@@ -1031,7 +1158,7 @@ class JitWatch:
     decode_cache_drop); later growth on the same program means the inputs'
     shapes/shardings changed ("shape_change")."""
 
-    __slots__ = ("_fn", "_name", "_cause_next", "_reg", "_key")
+    __slots__ = ("_fn", "_name", "_cause_next", "_reg", "_key", "_built")
 
     def __init__(self, fn, name: str, cause: str = "new_signature",
                  registry: Optional[_Registry] = None, key=None):
@@ -1042,8 +1169,19 @@ class JitWatch:
         # the caller's program key (the trainer's jit-cache key): rides
         # the compile event and the perf ledger's ProgramCard
         self._key = key
+        self._built = False
+
+    def _first_call(self, args, kwargs):
+        """The one call that traces, lowers and compiles (or loads) the
+        program: its seconds go to the always-on phase account as
+        ``jit.build/<name>``, with jax's own split of them beside it."""
+        self._built = True
+        with self._reg.phase("jit.build/" + self._name, parts=True):
+            return self(*args, **kwargs)
 
     def __call__(self, *args, **kwargs):
+        if not self._built:
+            return self._first_call(args, kwargs)
         reg = self._reg
         if not reg.enabled and reg.current_trace() is None \
                 and reg.current_compile_window() is None \
@@ -1245,6 +1383,17 @@ def reset() -> None:
 
 def span(name: str, **attrs):
     return _REG.span(name, **attrs)
+
+
+def phase(name: str) -> _Phase:
+    """A span that also writes the always-on phase account."""
+    return _REG.phase(name)
+
+
+def phases() -> Dict[str, float]:
+    """The phase account: name -> seconds of the name's first occurrence
+    in this process, recorded whether telemetry is enabled or not."""
+    return _REG.phases()
 
 
 def span_event(name: str, start_perf: float, dur: float, **attrs) -> None:

@@ -4,8 +4,8 @@
 Usage: python tools/profile_bench.py [alexnet|googlenet] [outdir]
 
 Writes a jax profiler trace (xplane) under outdir (default
-./profile_out/<model>); inspect hot ops with
-tools/summarize_trace.py or TensorBoard's profile plugin offline.
+./profile_out/<model>); put its device time to layers with
+tools/trace_layers.py, or open it in TensorBoard's profile plugin.
 """
 
 import os

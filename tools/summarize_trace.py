@@ -1,45 +1,25 @@
 #!/usr/bin/env python
-"""Summarize a Chrome-format trace: a jax profiler capture
-(tools/profile_bench.py) OR a per-request serving trace
-(statusd ``/trace?request=<id>``, utils/servd flight recorder).
+"""Summarize a per-request serving trace (statusd
+``/trace?request=<id>``, utils/servd flight recorder).
 
-Usage: python tools/summarize_trace.py <trace-dir-or-trace.json[.gz]>
-                                       [top_n]
+Usage: python tools/summarize_trace.py <trace.json[.gz]>
 
-Profiler traces (plugins/profile/*/**.trace.json.gz): aggregates
-complete events by name across the TensorCore lanes and prints the
-top-N ops by total self duration — enough to rank hot HLO/fusion ops
-without TensorBoard. No TPU or network needed.
+Prints the phase split (queue_wait / dispatch / prefill / decode,
+doc/observability.md) with percentages of the request's wall-clock, the
+recompiles the request paid, and the phase coverage — the one-slow-request
+triage view without opening Perfetto.
 
-Per-request traces (detected by their phase lanes — queue_wait /
-dispatch / prefill / decode, doc/observability.md): prints the phase
-split with percentages of the request's wall-clock, the recompiles the
-request paid, and the phase coverage — the one-slow-request triage view
-without opening Perfetto.
+A jax profiler capture (``profile_dir``, ``/profilez``) is read by
+``tools/trace_layers.py``, which puts device time to layers.
 """
 
 import gzip
-import glob
 import json
-import os
 import sys
-from collections import defaultdict
 
 # the serving request-phase lanes (telemetry.REQUEST_PHASES — literal
 # here so the tool stays dependency-free and runs on a bare checkout)
 REQUEST_PHASES = ("queue_wait", "dispatch", "prefill", "decode")
-
-
-def find_trace(path: str) -> str:
-    if os.path.isfile(path):
-        return path
-    hits = sorted(glob.glob(os.path.join(
-        path, "plugins", "profile", "*", "*.trace.json.gz")))
-    if not hits:
-        hits = sorted(glob.glob(os.path.join(path, "*.trace.json.gz")))
-    if not hits:
-        raise SystemExit("no *.trace.json.gz under %r" % path)
-    return hits[-1]
 
 
 def load_trace(path: str) -> dict:
@@ -81,51 +61,17 @@ def summarize_request(events) -> None:
 
 
 def main():
-    path = find_trace(sys.argv[1] if len(sys.argv) > 1 else "profile_out")
-    top_n = int(sys.argv[2]) if len(sys.argv) > 2 else 25
-    trace = load_trace(path)
-    events = trace.get("traceEvents", [])
-    if any(e.get("ph") == "X" and e.get("name") in REQUEST_PHASES
-           for e in events):
-        print("trace: %s" % path)
-        summarize_request(events)
-        return
-    # name the process/thread lanes so we can keep device lanes only
-    # (host-side Python/runtime lanes would double-count wall time)
-    pids = {}
-    tids = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pids[e["pid"]] = e["args"].get("name", "")
-        if e.get("ph") == "M" and e.get("name") == "thread_name":
-            tids[(e["pid"], e.get("tid"))] = e["args"].get("name", "")
-    dur_by_name = defaultdict(float)
-    cnt_by_name = defaultdict(int)
-    total = 0.0
-    for e in events:
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        lane = (pids.get(e["pid"], "")
-                + "/" + tids.get((e["pid"], e.get("tid")), ""))
-        low = lane.lower()
-        if not ("tpu" in low or "xla" in low or "tensorcore" in low
-                or "/device" in low or "sparsecore" in low):
-            continue
-        if "step" in low:   # step-marker lanes duplicate op time
-            continue
-        name = e["name"]
-        dur_by_name[name] += e["dur"]
-        cnt_by_name[name] += 1
-        total += e["dur"]
-    rows = sorted(dur_by_name.items(), key=lambda kv: -kv[1])[:top_n]
+    if len(sys.argv) < 2:
+        raise SystemExit(__doc__)
+    path = sys.argv[1]
+    events = load_trace(path).get("traceEvents", [])
+    if not any(e.get("ph") == "X" and e.get("name") in REQUEST_PHASES
+               for e in events):
+        raise SystemExit(
+            "%s holds no request phase lanes; a jax profiler capture is "
+            "read by tools/trace_layers.py" % path)
     print("trace: %s" % path)
-    print("device-lane total: %.1f ms over %d distinct ops"
-          % (total / 1e3, len(dur_by_name)))
-    print("%-72s %10s %8s %6s" % ("op", "total_ms", "calls", "pct"))
-    for name, d in rows:
-        print("%-72s %10.2f %8d %5.1f%%"
-              % (name[:72], d / 1e3, cnt_by_name[name],
-                 100.0 * d / max(total, 1e-9)))
+    summarize_request(events)
 
 
 if __name__ == "__main__":

@@ -196,6 +196,7 @@ def aggregate(events):
     compiles = []
     program_compiles = []
     counters_by_p = {}
+    setup_phases = {}   # set-up phase -> seconds, the slowest process's
     hists_by_p = {}
     gauges = {}
     gauges_by_p = {}
@@ -259,6 +260,9 @@ def aggregate(events):
             p = proc(ev)
             s = ev.get("summary", {})
             counters_by_p[p] = s.get("counters", counters_by_p.get(p, {}))
+            for name, secs in (s.get("phases") or {}).items():
+                setup_phases[name] = max(setup_phases.get(name, 0.0),
+                                         float(secs))
         elif kind == "health_anomaly":
             health["anomalies"].append(ev)
         elif kind in ("health_rollback", "health_skip", "health_abort",
@@ -646,7 +650,7 @@ def aggregate(events):
            "serving": serving, "requests": req_agg, "fleet": fleet,
            "slo": slo, "programs": programs, "batch": batch,
            "autopsy": autopsy_agg, "books": books,
-           "hists": {}}
+           "hists": {}, "setup_phases": setup_phases}
     for name, h in sorted(merged_hists.items()):
         st = h.stats()
         st["buckets"] = h.to_dict()["buckets"]
@@ -729,6 +733,13 @@ def print_report(agg, top=15):
         print("%-20s %8d %10.3f %9.2f %9.2f %9.2f %9.2f" %
               (name, a["count"], a["total_s"], a["p50_ms"], a["p90_ms"],
                a["p99_ms"], a["max_ms"]))
+    if agg.get("setup_phases"):
+        # telemetry's always-on account, from the run's summary event: a
+        # build's own parts (trace / lower / compile / cache_load) stand
+        # under it
+        print("\n== set-up phases (first occurrence, seconds) ==")
+        for name, secs in sorted(agg["setup_phases"].items()):
+            print("%-40s %10.3f" % (name, secs))
     comp = agg["compiles"]
     print("\n== recompiles ==")
     print("count: %d   total: %.2fs" % (comp["count"], comp["total_s"]))
