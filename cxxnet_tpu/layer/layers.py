@@ -837,8 +837,29 @@ class LRNLayer(Layer):
 
     def apply(self, params, inputs, ctx):
         layout = "NHWC" if ctx.channels_last else "NCHW"
-        return [ops.lrn(inputs[0], self.nsize, self.alpha, self.beta,
-                        self.knorm, layout=layout)]
+        x = inputs[0]
+        args = (self.nsize, self.alpha, self.beta, self.knorm, layout)
+        mesh = ctx.mesh
+        data = 1
+        if mesh is not None and "data" in mesh.axis_names:
+            data = mesh.shape["data"]
+        if layout != "NHWC" or data == 1 or ctx.manual_tp:
+            # one device, or inside a pipeline stage (manual_tp), where
+            # the code is per-device already
+            return [ops.lrn(x, *args)]
+        # the channels-last kernel is pointwise in the batch and
+        # pallas_call has no GSPMD partitioning rule: under shard_map with
+        # the batch left on "data" (as attention's flash kernel), or the
+        # partitioner gathers the global batch on every chip. Decided by
+        # the rows a device holds
+        if x.shape[0] % data or not ops.lrn_fused(
+                (x.shape[0] // data,) + x.shape[1:], x.dtype, layout):
+            return [ops.lrn_reduce_window(x, *args)]
+        from ..parallel._compat import shard_map
+        from jax.sharding import PartitionSpec as P
+        spec = P("data", None, None, None)
+        return [shard_map(lambda v: ops.lrn(v, *args), mesh=mesh,
+                          in_specs=(spec,), out_specs=spec)(x)]
 
 
 class BatchNormLayer(Layer):

@@ -37,6 +37,7 @@ from ..utils.metric import MetricSet
 # re-exported: the paged DecodeSession raises it; the jax-free servd
 # catches it by type from utils.kvblocks directly
 from ..utils.kvblocks import KVPoolExhausted  # noqa: F401
+from .. import ops
 from .. import parallel
 from .config import NetConfig
 from .net import NeuralNet
@@ -412,6 +413,10 @@ class Trainer:
         with telemetry.phase("init.model"):
             with telemetry.phase("init.structure"):
                 self._init_net_structure()
+                if any(lay.type_name == "lrn" for lay in self.net.layers):
+                    # its kernel is needed when the step is traced: fetch
+                    # the library while set-up waits on the device
+                    ops.preload_pallas()
             with telemetry.phase("init.params"):
                 self.params = self.net.init_params(self.seed)
             with telemetry.phase("init.opt"):

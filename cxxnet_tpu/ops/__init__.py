@@ -240,6 +240,22 @@ def use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def preload_pallas() -> None:
+    """Start importing the Pallas kernels on a helper thread, where they
+    will be taken (use_pallas). jax's Pallas package takes a second to
+    import (1.25 s of ``setup_s`` on a one-chip machine, 2 s on four, PR 28) and is
+    first needed when a step with such a layer is traced; until then
+    set-up waits on the device for seconds with the interpreter idle.
+    Whoever asks for the module before the import has ended waits for it
+    (the import lock)."""
+    if use_pallas():
+        import importlib
+        import threading
+        threading.Thread(target=importlib.import_module,
+                         args=(__name__ + ".pallas_kernels",),
+                         name="preload-pallas", daemon=True).start()
+
+
 def pallas_interpret() -> bool:
     """Off the TPU the Pallas kernels run in the interpreter, so forced-on
     tests (and CPU debugging) execute the exact kernel code; on the TPU
@@ -249,36 +265,59 @@ def pallas_interpret() -> bool:
 
 def lrn_nhwc(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
              knorm: float) -> jnp.ndarray:
-    """Channels-last LRN: with C minor the cross-channel window sum is a
-    reduce_window directly over the last axis — no layout change, no
-    custom kernel, O(C * nsize) work. (A full C x C banded matmul also
-    expresses it but wastes C/nsize of the MXU — measured 45% off
-    AlexNet's step at C=256.)"""
+    """Channels-last LRN in plain HLO: the cross-channel window sum is a
+    reduce_window over the last axis and the backward is jax's autodiff of
+    it. The non-TPU path, the golden model of the fused kernel
+    (pallas_kernels.lrn_nhwc) and what CXXNET_LRN=xla selects."""
     salpha = alpha / nsize
     norm = chpool_sum(jnp.square(x), nsize, axis=3) * salpha + knorm
     return x * jnp.power(norm, -beta)
 
 
+def lrn_fused(shape, dtype, layout: str = "NCHW") -> bool:
+    """Whether ``lrn`` takes a fused Pallas kernel for this input: on a TPU
+    (use_pallas), not CXXNET_LRN=xla, and channels-last only a shape the
+    kernel tiles. LRNLayer asks with the per-device shape: on a mesh the
+    kernel has to run inside shard_map (pallas_call has no partitioning
+    rule)."""
+    import os
+    if not use_pallas() or os.environ.get("CXXNET_LRN") == "xla":
+        return False
+    if layout == "NHWC":
+        from . import pallas_kernels
+        return pallas_kernels.lrn_nhwc_fits(shape, dtype)
+    return True
+
+
+def lrn_reduce_window(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
+                      knorm: float, layout: str = "NCHW") -> jnp.ndarray:
+    """The reduce_window path of either layout (lrn_xla / lrn_nhwc), where
+    no fused kernel is taken; counts ``lrn.fallback``."""
+    from ..utils import telemetry
+    telemetry.count("lrn.fallback")
+    if layout == "NHWC":
+        return lrn_nhwc(x, nsize, alpha, beta, knorm)
+    return lrn_xla(x, nsize, alpha, beta, knorm)
+
+
 def lrn(x: jnp.ndarray, nsize: int, alpha: float, beta: float, knorm: float,
         layout: str = "NCHW") -> jnp.ndarray:
     """Local response normalization across channels
-    (reference: src/layer/lrn_layer-inl.hpp:52-60). NHWC inputs window-sum
-    over the minor axis in place (lrn_nhwc — a reduce_window, no layout
-    change). NCHW dispatches to the fused Pallas kernel on TPU
-    (banded-matmul window sum on the MXU), XLA reduce_window elsewhere;
-    CXXNET_LRN=xla forces the reduce_window path on TPU too — the banded
-    matmul costs O(C^2) MACs per pixel (conv-sized at AlexNet's C=256), so
-    which wins is measured, not assumed (tools/mfu_experiments.py
-    ablation)."""
-    import os
-    if layout == "NHWC":
-        if os.environ.get("CXXNET_LRN") == "xla":
-            return to_nhwc(lrn_xla(to_nchw(x), nsize, alpha, beta, knorm))
-        return lrn_nhwc(x, nsize, alpha, beta, knorm)
-    if use_pallas() and os.environ.get("CXXNET_LRN") != "xla":
-        from . import pallas_kernels
-        return pallas_kernels.lrn(x, nsize, alpha, beta, knorm)
-    return lrn_xla(x, nsize, alpha, beta, knorm)
+    (reference: src/layer/lrn_layer-inl.hpp:52-60). On a TPU both layouts
+    take a fused Pallas kernel (one HBM pass each way, analytic backward,
+    the window sum a band product on the MXU): NCHW always, NHWC where
+    the shape tiles (batch a multiple of 128, channels of the sublane
+    tile); elsewhere the reduce_window path, which CXXNET_LRN=xla also
+    forces on a TPU (the A/B control). Counts ``lrn.fused`` /
+    ``lrn.fallback`` once per traced layer."""
+    if not lrn_fused(x.shape, x.dtype, layout):
+        return lrn_reduce_window(x, nsize, alpha, beta, knorm, layout)
+    from ..utils import telemetry
+    from . import pallas_kernels
+    telemetry.count("lrn.fused")
+    kernel = (pallas_kernels.lrn_nhwc if layout == "NHWC"
+              else pallas_kernels.lrn)
+    return kernel(x, nsize, alpha, beta, knorm, pallas_interpret())
 
 
 def flash_supported(L: int, d: int) -> bool:

@@ -10,6 +10,9 @@ and mshadow's chpool for LRN); on TPU that escape hatch is Pallas
   0/1 matrix multiplied on the MXU — (c, c) x (c, h*w) — instead of nsize
   shifted adds on the VPU: one systolic pass computes the whole window sum,
   and the band matrix transposes for the mirrored-window term in backward.
+* ``lrn_nhwc``: the same layer for channels-last nets, on the layout XLA
+  gives their activations on the chip (batch minor), the backward
+  recomputing the norm: the residual is x alone.
 * ``uniform`` / ``rrelu_mask``: the insanity layer's per-element random
   negative slope drawn with the on-core PRNG (pltpu.prng_random_bits) — no
   HBM round trip for the mask.
@@ -130,6 +133,153 @@ def _lrn_bwd(nsize, alpha, beta, knorm, interpret, res, g):
 
 
 lrn.defvjp(_lrn_fwd, _lrn_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Channels-last LRN: one HBM pass each way
+# ---------------------------------------------------------------------------
+# XLA lays a channels-last activation of a conv net out batch-minor on the
+# TPU ((N, H, W, C) as {0,3,2,1}: N on the lanes, C on the sublanes), so the
+# kernel takes the array as (H*W, C, N) -- a bitcast of that layout, no copy
+# -- and the channel window sum is the same band product as the NCHW
+# kernel's, (C, C) x (C, lanes). The backward recomputes the norm from x:
+# an f32 norm residual would cost four more HBM passes than the recompute.
+_LRN_NHWC_BLOCK_BYTES = 2 << 20    # one operand's block in VMEM
+_LRN_NHWC_SLAB_BYTES = 2 << 20     # one (C, lanes) float32 intermediate
+_LRN_NHWC_VMEM_BYTES = 64 << 20    # of the 128 MiB a v5e core has
+
+
+def lrn_nhwc_fits(shape, dtype) -> bool:
+    """True when the channels-last kernel tiles (N, H, W, C): the batch
+    fills whole lane tiles and the channels whole sublane tiles."""
+    if len(shape) != 4 or jnp.dtype(dtype) not in (
+            jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    n, _, _, c = shape
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return n % 128 == 0 and c % sublanes == 0 and c <= 512
+
+
+def _band_sum(band, v, split):
+    """band (C, C) of 0/1, exact in bf16, times v (C, lanes) f32 on the
+    MXU, which is idle otherwise: measured within 2-8% of a kernel with no
+    window sum at all. One bf16 pass carries the bits today's bf16 square
+    has; ``split`` (float32 activations) sends v as a bf16 hi/lo pair, 16
+    bits a term, for 40% more time (an f32 product at ``highest`` takes
+    180% more)."""
+    # the precision spelled out: bf16 operands are exact here, and a
+    # process-wide jax_default_matmul_precision must not reach the kernel
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.DEFAULT)
+    hi = v.astype(jnp.bfloat16)
+    s = dot(band, hi)
+    if split:
+        s = s + dot(band, (v - hi.astype(jnp.float32)).astype(jnp.bfloat16))
+    return s
+
+
+def _neg_pow(norm, beta):
+    """norm ** -beta; the usual 0.75 as two square roots."""
+    if beta == 0.75:
+        r = jax.lax.rsqrt(norm)
+        return r * jnp.sqrt(r)
+    return jnp.exp(-beta * jnp.log(norm))
+
+
+def _lrn_nhwc_fwd_kernel(x_ref, band_ref, o_ref, *, salpha, beta, knorm):
+    band = band_ref[...]
+    split = x_ref.dtype == jnp.float32
+
+    def slab(i, carry):
+        x = x_ref[i].astype(jnp.float32)
+        norm = knorm + salpha * _band_sum(band, x * x, split)
+        o_ref[i] = (x * _neg_pow(norm, beta)).astype(o_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, x_ref.shape[0], slab, 0)
+
+
+def _lrn_nhwc_bwd_kernel(x_ref, g_ref, band_ref, bandt_ref, dx_ref, *,
+                         salpha, beta, knorm):
+    band = band_ref[...]
+    bandt = bandt_ref[...]
+    split = x_ref.dtype == jnp.float32
+
+    def slab(i, carry):
+        x = x_ref[i].astype(jnp.float32)
+        g = g_ref[i].astype(jnp.float32)
+        norm = knorm + salpha * _band_sum(band, x * x, split)
+        gp = g * _neg_pow(norm, beta)
+        # the rule of _lrn_bwd_kernel, the norm recomputed
+        s = _band_sum(bandt, gp * x / norm, split)
+        dx_ref[i] = (gp - (2.0 * salpha * beta) * x * s).astype(dx_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, x_ref.shape[0], slab, 0)
+
+
+def _lrn_nhwc_call(kernel, acts, bands, interpret):
+    """``kernel`` over (H*W, C, N) operands, a block of a few H*W rows by
+    all channels by one lane tile of the batch, the widest that divides N
+    (measured: the wider the faster); the ragged last block of rows is
+    masked by the pipeline (rows are independent)."""
+    hw, c, n = acts[0].shape
+    widest = max(128, _LRN_NHWC_SLAB_BYTES // (4 * c) // 128 * 128)
+    nb = next(t for t in range(min(n, widest), 0, -128) if n % t == 0)
+    hb = max(1, min(hw, _LRN_NHWC_BLOCK_BYTES
+                    // (c * nb * acts[0].dtype.itemsize)))
+    act = pl.BlockSpec((hb, c, nb), lambda i, j: (i, 0, j))
+    band = pl.BlockSpec((c, c), lambda i, j: (0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(hw, hb), n // nb),
+        in_specs=[act] * len(acts) + [band] * len(bands),
+        out_specs=act,
+        out_shape=jax.ShapeDtypeStruct((hw, c, n), acts[0].dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_LRN_NHWC_VMEM_BYTES),
+        interpret=interpret,
+    )(*acts, *bands)
+
+
+def _to_hw_c_n(x):
+    n, h, w, c = x.shape
+    return jnp.transpose(x, (1, 2, 3, 0)).reshape(h * w, c, n)
+
+
+def _from_hw_c_n(y, shape):
+    n, h, w, c = shape
+    return jnp.transpose(y.reshape(h, w, c, n), (3, 0, 1, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def lrn_nhwc(x, nsize: int, alpha: float, beta: float, knorm: float,
+             interpret: bool = False):
+    """Fused channels-last LRN, x (N, H, W, C) with ``lrn_nhwc_fits``:
+    the forward reads x and writes y, the backward reads x and g and
+    writes dx; arithmetic in f32 inside, the residual is x alone."""
+    return _lrn_nhwc_fwd(x, nsize, alpha, beta, knorm, interpret)[0]
+
+
+def _lrn_nhwc_fwd(x, nsize, alpha, beta, knorm, interpret):
+    band = jnp.asarray(_band_matrix(x.shape[3], nsize), jnp.bfloat16)
+    kernel = functools.partial(_lrn_nhwc_fwd_kernel, salpha=alpha / nsize,
+                               beta=beta, knorm=knorm)
+    y = _lrn_nhwc_call(kernel, [_to_hw_c_n(x)], [band], interpret)
+    return _from_hw_c_n(y, x.shape), x
+
+
+def _lrn_nhwc_bwd(nsize, alpha, beta, knorm, interpret, x, g):
+    band = _band_matrix(x.shape[3], nsize)
+    kernel = functools.partial(_lrn_nhwc_bwd_kernel, salpha=alpha / nsize,
+                               beta=beta, knorm=knorm)
+    dx = _lrn_nhwc_call(
+        kernel, [_to_hw_c_n(x), _to_hw_c_n(g)],
+        [jnp.asarray(band, jnp.bfloat16), jnp.asarray(band.T, jnp.bfloat16)],
+        interpret)
+    return (_from_hw_c_n(dx, x.shape),)
+
+
+lrn_nhwc.defvjp(_lrn_nhwc_fwd, _lrn_nhwc_bwd)
 
 
 # ---------------------------------------------------------------------------

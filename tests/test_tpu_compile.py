@@ -1,0 +1,61 @@
+"""Kernels of the training path compiled for a described TPU v5e, at the
+widths the benchmark's cells run: what interpret mode cannot see (Mosaic's
+tiling rules, the VMEM a block takes, the layout XLA gives the operands).
+Nothing runs: there is no chip here, so no time and no result.
+
+The topology is described inside a fixture, never at import: only the
+worker that is handed this file loads the TPU's library (see the
+on-chip-measurement guide, section 2). Keep such tests in this one file.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from cxxnet_tpu.ops import pallas_kernels
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (N, H, W, C) of the two LRN layers of GoogLeNet b512 and AlexNet b2048,
+# and one float32 case (the hi/lo pair of the band product)
+@pytest.mark.parametrize("shape,dtype", [
+    ((512, 56, 56, 64), jnp.bfloat16),
+    ((512, 56, 56, 192), jnp.bfloat16),
+    ((2048, 27, 27, 96), jnp.bfloat16),
+    ((2048, 13, 13, 256), jnp.bfloat16),
+    ((256, 56, 56, 192), jnp.float32),
+])
+def test_channels_last_lrn_compiles_without_a_copy(one_chip, shape, dtype):
+    n, h, w, c = shape
+    assert pallas_kernels.lrn_nhwc_fits(shape, dtype)
+    # the layout XLA gives a conv net's activations on the chip: batch
+    # minor, (N, H, W, C) as {0,3,2,1}, here as a (H, W, C, N) argument
+    arg = jax.ShapeDtypeStruct((h, w, c, n), dtype, sharding=one_chip)
+
+    def both(xt, gt):
+        y, vjp = jax.vjp(
+            lambda v: pallas_kernels.lrn_nhwc(v, 5, 1e-4, 0.75, 1.0),
+            jnp.transpose(xt, (3, 0, 1, 2)))
+        dx, = vjp(jnp.transpose(gt, (3, 0, 1, 2)))
+        return (jnp.transpose(y, (1, 2, 3, 0)),
+                jnp.transpose(dx, (1, 2, 3, 0)))
+    text = jax.jit(both).lower(arg, arg).compile().as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
+    # the kernel's (H*W, C, N) view is a bitcast of that layout: a copy
+    # or a transpose beside it would cost a pass over the tensor each
+    assert not re.search(r"= \S+ (copy|transpose)\(", text), text

@@ -6,7 +6,8 @@ kernels (pallas_kernels.uniform / rrelu_mask) use pltpu.prng_random_bits,
 which has no CPU interpret path, so this script exercises them on the real
 chip: distribution sanity of the uniform draw, the insanity layer's
 train-mode forward/backward through the on-core mask, and the Pallas-vs-XLA
-LRN numerics compiled for TPU.
+LRN numerics compiled for TPU (NCHW, and channels-last at the four shapes
+of the benchmark's cells).
 
 Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
 """
@@ -99,6 +100,44 @@ def main():
             ops.lrn_xla(v, 5, 0.001, 0.75, 1.0))))(xd), np.float32)
         np.testing.assert_allclose(ga, gb, rtol=rtol * 10, atol=rtol * 10)
         print("pallas lrn vs xla on TPU (%s): OK" % np.dtype(dt).name)
+
+    # --- channels-last LRN at the four shapes of the benchmark's cells,
+    # through ops.lrn's own dispatch, against the reduce_window path in
+    # float32: forward and gradient, compared on the device ---
+    from cxxnet_tpu.utils import telemetry
+    lrn_args = (5, 0.0001, 0.75, 1.0)
+
+    def lrn_pair(f, x, g):
+        y, vjp = jax.vjp(f, x)
+        return y, vjp(g.astype(y.dtype))[0]
+
+    def worst(a, b):
+        # the largest gap over the golden's own scale
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                     / jnp.max(jnp.abs(b)))
+    for shape in ((512, 56, 56, 64), (512, 56, 56, 192),
+                  (2048, 27, 27, 96), (2048, 13, 13, 256)):
+        for dt, tol in ((jnp.bfloat16, 1e-2), (jnp.float32, 1e-4)):
+            if dt == jnp.float32 and shape[3] == 192:
+                continue        # 2.5 GB a tensor: the bf16 case covers it
+            kx, kg = jax.random.split(jax.random.PRNGKey(shape[3]))
+            x = (2 * jax.random.normal(kx, shape, jnp.float32)).astype(dt)
+            g = jax.random.normal(kg, shape, jnp.float32).astype(dt)
+            assert ops.lrn_fused(shape, dt, "NHWC"), (shape, dt)
+            y, dx = jax.jit(lambda x, g: lrn_pair(
+                lambda v: ops.lrn(v, *lrn_args, layout="NHWC"), x, g))(x, g)
+            ry, rdx = jax.jit(lambda x, g: lrn_pair(
+                lambda v: ops.lrn_nhwc(v, *lrn_args),
+                x.astype(jnp.float32), g.astype(jnp.float32)))(x, g)
+            ey, edx = worst(y, ry), worst(dx, rdx)
+            assert ey < tol and edx < tol, (shape, dt, ey, edx)
+            del x, g, y, dx, ry, rdx
+        print("channels-last lrn %s on TPU: OK" % (shape,))
+    with telemetry.trace_context("lrn-dispatch") as tc:
+        jax.eval_shape(lambda v: ops.lrn(v, *lrn_args, layout="NHWC"),
+                       jax.ShapeDtypeStruct((100, 8, 8, 64), jnp.bfloat16))
+    assert tc.counts == {"lrn.fallback": 1}, tc.counts
+    print("channels-last lrn: a batch of 100 falls back: OK")
 
     # --- flash attention: compiled kernels vs dense reference ---
     # tolerance covers the dense reference's default-precision MXU einsums
