@@ -210,6 +210,11 @@ class ApplyContext:
     # position-updated caches are written to cache_updates
     kv_cache: Dict = field(default_factory=dict)
     cache_updates: Dict = field(default_factory=dict)
+    # small per-layer readings of the step itself, {conn_index: float32
+    # vector} in the order of the layer's ``stat_names`` (moe: pairs held,
+    # fullest expert's load); with health_monitor = 1 the train step
+    # returns them behind the health vector
+    layer_stats: Dict = field(default_factory=dict)
 
 
 class Layer:
@@ -276,6 +281,15 @@ class Layer:
     # contract lives in one place
     def state_keys(self) -> Tuple[str, ...]:
         return ()
+
+
+def sub_scope(name: str):
+    """A ``jax.named_scope`` a layer opens inside its own, for a part of its
+    work that a trace should show apart. The leading ``~`` marks it as such:
+    ``utils/devtrace.scope_of`` splits the layer's rows by any component so
+    marked (``jvp(b0_att)/~core/...`` is the row ``b0_att/core``) and needs
+    no list of the names layers choose."""
+    return jax.named_scope("~" + name)
 
 
 def check(cond: bool, msg: str, *args) -> None:

@@ -51,6 +51,7 @@ kEmbed = 33
 kAdd = 34
 kMoE = 35
 kIm2Seq = 36
+kRMSNorm = 37
 kPairTestGap = 1024
 
 _NAME2TYPE = {
@@ -91,6 +92,7 @@ _NAME2TYPE = {
     "add": kAdd,
     "moe": kMoE,
     "im2seq": kIm2Seq,
+    "rmsnorm": kRMSNorm,
 }
 
 _TYPE2CLS = {
@@ -127,6 +129,7 @@ _TYPE2CLS = {
     kAdd: L.AddLayer,
     kMoE: L.MoELayer,
     kIm2Seq: L.Im2SeqLayer,
+    kRMSNorm: L.RMSNormLayer,
 }
 
 

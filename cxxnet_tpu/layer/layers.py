@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import ops
-from .base import ApplyContext, Layer, LayerParam, Shape4, check
+from .base import (ApplyContext, Layer, LayerParam, Shape4, check,
+                   sub_scope)
 
 
 def _seed_from_key(key) -> jnp.ndarray:
@@ -663,6 +664,17 @@ class ConvolutionLayer(Layer):
                            groups=g, layout=layout)
             y = manual_tp_gather(y, blocks, mp,
                                  axis=3 if ctx.channels_last else 1)
+        elif (p.kernel_height == p.kernel_width == p.stride == 1 and g == 1
+              and p.pad_y == p.pad_x == 0
+              and inputs[0].shape[1 if ctx.channels_last else 2] == 1):
+            # a 1x1 conv over a sequence node (b, C, 1, L) is a product per
+            # position (the transformer stacks' FFN and vocabulary head):
+            # as a dot. XLA's convolution with one row of 8,192 positions
+            # ran the head at an eighth of the dot's rate on the chip
+            # (PERF.md section 6, PR 29); feature maps (h > 1) keep conv2d
+            w2 = w.reshape(p.num_channel, p.num_input_channel)
+            y = jnp.dot(inputs[0], w2.T) if ctx.channels_last else \
+                jnp.einsum("bchl,oc->bohl", inputs[0], w2)
         else:
             y = ops.conv2d(inputs[0], w, stride=p.stride,
                            pad=(p.pad_y, p.pad_x),
@@ -1068,13 +1080,25 @@ class SoftmaxLayer(LossLayerBase):
         tgt = jnp.take_along_axis(logp, idx[:, None], axis=1)[:, 0]
         return jnp.sum(self._ce(logp, tgt)) * self._scale()
 
+    @property
+    def layout_support(self):
+        # the per-position loss reads (b, L, vocab): channels-last is its
+        # own layout, and a transpose of the logits (1.2 GB in float32 at
+        # 8,192 x 37,984) is a pass over them each way. The flat form
+        # flattens in NCHW order and stays there.
+        return "nhwc" if self.seq else "nchw"
+
     def apply(self, params, inputs, ctx):
         if not self.seq:
             return super().apply(params, inputs, ctx)
         x = inputs[0]
-        b, v, h, L = x.shape
+        if ctx.channels_last:
+            b, h, L, v = x.shape
+            logits = x.reshape(b, L, v)
+        else:
+            b, v, h, L = x.shape
+            logits = x.reshape(b, v, L).transpose(0, 2, 1)  # (b, L, v)
         check(h == 1, "softmax seq=1 needs a (batch, vocab, 1, seq) node")
-        logits = x.reshape(b, v, L).transpose(0, 2, 1)     # (b, L, v)
         out = jax.nn.softmax(logits, axis=-1)
         if ctx.labels is not None:
             label = ctx.labels.field(self.target)          # (b, L)
@@ -1086,6 +1110,8 @@ class SoftmaxLayer(LossLayerBase):
             tgt = jnp.take_along_axis(logp, idx, axis=2)[..., 0]
             ce = self._ce(logp, tgt)
             ctx.losses.append(jnp.sum(ce) / L * self._scale())
+        if ctx.channels_last:
+            return [out.reshape(b, 1, L, v)]
         return [out.transpose(0, 2, 1).reshape(b, v, 1, L)]
 
 
@@ -1147,6 +1173,10 @@ class AttentionLayer(Layer):
         # only nkvhead heads, broadcast to the query heads at dispatch
         # (0 -> = nhead, classic MHA)
         self.nkvhead = 0
+        # head_dim > 0: the heads' own size, where nhead * head_dim is not
+        # d_model (wqkv is d x (nhead + 2 nkvhead) head_dim, wo
+        # (nhead head_dim) x d); 0 -> d_model / nhead
+        self.head_dim = 0
         # attn_window > 0 (causal only): sliding-window attention — each
         # query sees only the last attn_window keys; flash kernels skip
         # out-of-window tiles wholesale
@@ -1170,6 +1200,8 @@ class AttentionLayer(Layer):
             self.rope_base = float(val)
         if name == "nkvhead":
             self.nkvhead = int(val)
+        if name == "head_dim":
+            self.head_dim = int(val)
         if name == "attn_window":
             self.attn_window = int(val)
         if name == "decode_chunk":
@@ -1183,18 +1215,30 @@ class AttentionLayer(Layer):
         check(len(in_shapes) == 1, "AttentionLayer only support 1-1 connection")
         b, d, h, L = in_shapes[0]
         check(h == 1, "attention input must be (batch, d_model, 1, seq)")
-        check(d % self.nhead == 0, "nhead must divide d_model")
+        if self.head_dim:
+            check(self.head_dim % 8 == 0,
+                  "attention: head_dim = %d is not a multiple of 8, the "
+                  "sublane tile of the flash kernels (ops/flash_attn.py)"
+                  % self.head_dim)
+        else:
+            check(d % self.nhead == 0,
+                  "nhead must divide d_model (or set head_dim)")
+        self.param.num_input_channel = d
         if self.rope:
-            check((d // self.nhead) % 2 == 0,
-                  "rope needs an even head dim")
+            check(self._dh() % 2 == 0,
+                  "attention: rope rotates pairs of features and needs an "
+                  "even head size, got %d (key head_dim, else d_model / "
+                  "nhead)" % self._dh())
         if self.nkvhead:
             check(self.nhead % self.nkvhead == 0,
                   "nkvhead must divide nhead")
         if self.attn_window:
             check(self.attn_window > 0, "attn_window must be positive")
             check(self.causal, "attn_window requires causal = 1")
-        self.param.num_input_channel = d
         return [in_shapes[0]]
+
+    def _dh(self):
+        return self.head_dim or self.param.num_input_channel // self.nhead
 
     def _apply_rope(self, x, offset=0):
         """Rotary embedding on (b, nh, L, dh): rotate the (first-half,
@@ -1207,23 +1251,25 @@ class AttentionLayer(Layer):
         inv = jnp.power(self.rope_base,
                         -jnp.arange(half, dtype=jnp.float32) / half)
         ang = pos * inv                                     # (L, half)
-        cos = jnp.cos(ang).astype(x.dtype)
-        sin = jnp.sin(ang).astype(x.dtype)
-        x1, x2 = x[..., :half], x[..., half:]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        # the rotation itself in float32 whatever the compute type: a
+        # bf16 cosine keeps 8 bits of an angle that runs to thousands
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:].astype(jnp.float32)
         return jnp.concatenate([x1 * cos - x2 * sin,
-                                x1 * sin + x2 * cos], axis=-1)
+                                x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
-    def _kv_width(self, d):
-        nkv = self.nkvhead or self.nhead
-        return nkv * (d // self.nhead)
+    def _kv_width(self):
+        return (self.nkvhead or self.nhead) * self._dh()
 
     def init_params(self, rng):
         d = self.param.num_input_channel
-        w = d + 2 * self._kv_width(d)    # [q | k | v] columns; 3d for MHA
+        qw = self.nhead * self._dh()     # = d unless head_dim is set
+        w = qw + 2 * self._kv_width()    # [q | k | v] columns; 3d for MHA
         return {"wqkv": self.param.rand_init_weight(
                     rng, (d, w), in_num=d, out_num=w),
                 "wo": self.param.rand_init_weight(
-                    rng, (d, d), in_num=d, out_num=d)}
+                    rng, (qw, d), in_num=qw, out_num=d)}
 
     def save_model(self, w, params):
         self.param.save(w)
@@ -1242,8 +1288,6 @@ class AttentionLayer(Layer):
     layout_support = "nhwc"
 
     def apply(self, params, inputs, ctx):
-        from ..parallel import (attention_reference, ring_attention,
-                                ulysses_attention)
         x = inputs[0]
         if ctx.channels_last:
             # physical (b, 1, L, d) for logical (b, d, 1, L): (b, L, d) is
@@ -1255,21 +1299,41 @@ class AttentionLayer(Layer):
         else:
             b, d, _, L = x.shape
             seq = x.reshape(b, d, L).transpose(0, 2, 1)      # (b, L, d)
-        nh, dh = self.nhead, d // self.nhead
+        nh, dh = self.nhead, self._dh()
         nkv = self.nkvhead or nh
-        kvw = self._kv_width(d)
-        qkv = jnp.dot(seq, params["wqkv"])            # (b, L, d + 2*kvw)
-        q = qkv[..., :d]
-        k = qkv[..., d:d + kvw]
-        v = qkv[..., d + kvw:]
+        qw, kvw = nh * dh, self._kv_width()
 
         def heads(t, n):  # (b, L, n*dh) -> (b, n, L, dh)
             return t.reshape(b, L, n, dh).transpose(0, 2, 1, 3)
 
-        q, k, v = heads(q, nh), heads(k, nkv), heads(v, nkv)
-        if self.rope:
-            off = ctx.decode_pos if ctx.decode_pos is not None else 0
-            q, k = self._apply_rope(q, off), self._apply_rope(k, off)
+        # sub-scopes qkv / core / out: tools/trace_layers.py splits the
+        # layer's device time by them
+        with sub_scope("qkv"):
+            qkv = jnp.dot(seq, params["wqkv"])        # (b, L, qw + 2*kvw)
+            q = heads(qkv[..., :qw], nh)
+            k = heads(qkv[..., qw:qw + kvw], nkv)
+            v = heads(qkv[..., qw + kvw:], nkv)
+            if self.rope:
+                off = ctx.decode_pos if ctx.decode_pos is not None else 0
+                q, k = self._apply_rope(q, off), self._apply_rope(k, off)
+        with sub_scope("core"):
+            out = self._core(q, k, v, ctx)
+        with sub_scope("out"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, L, qw)  # merge heads
+            out = jnp.dot(out, params["wo"])
+        if ctx.channels_last:
+            return [out.reshape(b, 1, L, d)]
+        return [out.transpose(0, 2, 1).reshape(b, d, 1, L)]
+
+    def _core(self, q, k, v, ctx):
+        """softmax(q k^T / sqrt(dh) + mask) v on (b, heads, L, dh), by the
+        path the context asks for. Counts ``attn.flash`` / ``attn.dense``
+        once per traced layer (telemetry's path account)."""
+        from ..parallel import (attention_reference, ring_attention,
+                                ulysses_attention)
+        from ..utils import telemetry
+        b, nh, L, dh = q.shape
+        nkv = k.shape[1]
         mesh = ctx.mesh
         if ctx.decode_pos is not None:
             # KV-cached decode step: write this input's k/v into the cache
@@ -1295,9 +1359,11 @@ class AttentionLayer(Layer):
                 # O(L)-memory flash kernel for long prompts, instead of
                 # (L, l_max) dense scores against the cache
                 if ops.use_pallas() and ops.flash_supported(L, dh):
+                    telemetry.count_path("attn.flash")
                     out = ops.flash_attention(q, k, v, causal=True,
                                               window=self.attn_window)
                 else:
+                    telemetry.count_path("attn.dense")
                     out = attention_reference(
                         q, k, v, causal=True, scale=dh ** -0.5,
                         window=self.attn_window)
@@ -1384,6 +1450,7 @@ class AttentionLayer(Layer):
             # pallas_call has no GSPMD partitioning rule of its own.
             # GQA: the kernel reads grouped k/v natively (BlockSpec row
             # map) — K/V HBM traffic stays nkvhead-sized
+            telemetry.count_path("attn.flash")
             causal = bool(self.causal)
             if mesh is None or ctx.manual_tp:
                 # inside a pipeline stage body the code is ALREADY
@@ -1404,13 +1471,10 @@ class AttentionLayer(Layer):
                     mesh=mesh, in_specs=(spec, spec, spec),
                     out_specs=spec)(q, k, v)
         else:
+            telemetry.count_path("attn.dense")
             out = attention_reference(q, k, v, causal=bool(self.causal),
                                       window=self.attn_window)
-        out = out.transpose(0, 2, 1, 3).reshape(b, L, d)      # merge heads
-        out = jnp.dot(out, params["wo"])
-        if ctx.channels_last:
-            return [out.reshape(b, 1, L, d)]
-        return [out.transpose(0, 2, 1).reshape(b, d, 1, L)]
+        return out
 
 
 class EmbedLayer(Layer):
@@ -1537,31 +1601,154 @@ class AddLayer(Layer):
         return [out]
 
 
+class RMSNormLayer(Layer):
+    """Root-mean-square norm over the channels of every position (beyond
+    the reference; Zhang & Sennrich 2019): ``x * rsqrt(mean_c(x^2) + eps)
+    * gain``, no mean taken off and no bias, the statistics in float32
+    whatever the compute type. Sequence nodes (b, D, 1, L) and feature maps
+    normalise over D; flat (b, 1, 1, w) nodes over w. ``gain`` (tag
+    ``gain``) starts at one. Channels-last is its native layout (the sum
+    runs over the lane axis)."""
+
+    type_name = "rmsnorm"
+    layout_support = "nhwc"
+
+    def __init__(self):
+        super().__init__()
+        self.eps = 1e-6
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "eps":
+            self.eps = float(val)
+
+    def infer_shape(self, in_shapes):
+        check(len(in_shapes) == 1, "RMSNormLayer only support 1-1 connection")
+        b, c, h, w = in_shapes[0]
+        self._flat = c == 1 and h == 1
+        self.param.num_input_channel = w if self._flat else c
+        return [in_shapes[0]]
+
+    def init_params(self, rng):
+        return {"gain": np.ones((self.param.num_input_channel,), np.float32)}
+
+    def visit_order(self):
+        return [("gain", "gain")]
+
+    def save_model(self, w, params):
+        self.param.save(w)
+        w.write_tensor(params["gain"])
+
+    def load_model(self, r):
+        self.param.load(r)
+        return {"gain": r.read_tensor()}
+
+    def apply(self, params, inputs, ctx):
+        x = inputs[0]
+        axis = 3 if (ctx.channels_last or self._flat) else 1
+        xf = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis,
+                                     keepdims=True) + self.eps)
+        shape = [1, 1, 1, 1]
+        shape[axis] = -1
+        gain = params["gain"].astype(jnp.float32).reshape(shape)
+        return [(xf * inv * gain).astype(x.dtype)]
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """``x[perm]`` for a permutation whose inverse is known: the backward
+    is the gather ``g[inv]``, not the scatter-add jax would transpose the
+    forward's gather into."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return x[perm], (perm, inv)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inv = res
+    return g[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, perm, inv):
+    """Row p of the result is token ``perm[p] % T`` of ``x`` (T, d): the
+    sorted order of the (choice, token) pairs, ``k`` choices a token, with
+    no (k T, d) copy of ``x`` made first. Backward: the gather ``g[inv]``
+    summed over the choices."""
+    return x[perm % x.shape[0]]
+
+
+def _dispatch_rows_fwd(x, perm, inv):
+    return x[perm % x.shape[0]], (inv, x.shape[0])
+
+
+def _dispatch_rows_bwd(res, g):
+    inv, T = res
+    return jnp.sum(g[inv].reshape(-1, T, g.shape[-1]), axis=0), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
 class MoELayer(Layer):
     """Mixture-of-experts FFN (beyond the reference — the scale-out sibling
-    of fullc): input (b, 1, 1, d_in) -> (b, 1, 1, nhidden) through nexpert
-    gated expert FFNs (relu inside, reference fullc+relu semantics per
-    expert).
+    of fullc): a router over ``nexpert`` experts gives every token
+    ``top_k`` of them and their weights, and the layer returns the
+    weighted sum of what those experts make of the token.
 
-    Gating is dense-dispatch: every expert processes every token and the
-    softmax gate weights the combine — static shapes, MXU-sized matmuls,
-    the XLA-friendly form. ``top_k > 0`` keeps only the top-k gate
-    probabilities (renormalized); the dispatch stays dense so there is no
-    dynamic-shape routing, which is the right trade below thousands of
-    experts on TPU.
+    Keys: ``nexpert``; ``top_k`` (0 = all): the weights are the softmax
+    over the token's top k float32 logits (which is the softmax over all
+    experts renormalised over those k);
+    ``expert_act`` = ``relu`` (one matrix an expert, ``nhidden`` outputs)
+    or ``reglu`` (three: ``relu(x Wg) * (x Wu)`` of width ``nhidden``, then
+    ``Wd`` back to the input's width); ``nexpert_held`` / ``expert_offset``:
+    this layer HOLDS only experts ``[offset, offset + held)`` of the
+    ``nexpert`` the router scores — one chip's share of an expert-parallel
+    layer. What the absent experts would add is left out of the result;
+    nothing here stands in for the chips that hold them.
+
+    Input: a flat node (b, 1, 1, d) or a sequence node (b, d, 1, L); a
+    second input node, if given, is what the router reads (a block whose
+    router sees its un-normed input while the experts see the normed one).
+
+    Two lowerings. **dense** (flat input): every expert held processes
+    every token and the routing weights mask the sum — static shapes, no
+    sort; its cost is held / top_k times the useful work. **sparse**
+    (sequence input, the only lowering taken there): the token-expert
+    pairs are sorted by expert, rows gathered, one grouped matrix product
+    a matrix over the experts held (``ops.grouped_matmul``), and the rows
+    gathered back and summed per token — no capacity, no dropped token at
+    any load; cost follows the pairs held (PERF.md section 5 has what the
+    chip read of the products and of the gathers either side of them).
+    Counts ``moe.sparse`` / ``moe.dense`` once per traced layer.
 
     With a mesh carrying an "ep" axis (trainer key ``expert_parallel = k``)
-    the expert dimension shards over the mesh
+    the dense form's expert dimension shards over the mesh
     (parallel.expert_parallel_ffn): each device runs its local experts and
-    one psum combines — composes with the "data" axis for dp x ep.
+    one psum combines — composes with the "data" axis for dp x ep. The
+    sparse form raises under an ep mesh: its token exchange between chips
+    is not written.
     """
 
     type_name = "moe"
+    layout_support = "nhwc"
+    # what the sparse lowering leaves in ctx.layer_stats, in this order:
+    # the pairs routed to experts held and the fullest expert's load
+    stat_names = ("moe.pairs_held", "moe.load_max")
 
     def __init__(self):
         super().__init__()
         self.n_expert = 0
         self.top_k = 0
+        self.n_held = 0
+        self.expert_offset = 0
+        self.expert_act = "relu"
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -1569,91 +1756,200 @@ class MoELayer(Layer):
             self.n_expert = int(val)
         if name == "top_k":
             self.top_k = int(val)
+        if name == "nexpert_held":
+            self.n_held = int(val)
+        if name == "expert_offset":
+            self.expert_offset = int(val)
+        if name == "expert_act":
+            check(val in ("relu", "reglu"), "expert_act must be relu or reglu")
+            self.expert_act = val
+
+    def _held(self):
+        return self.n_held or self.n_expert
 
     def infer_shape(self, in_shapes):
-        check(len(in_shapes) == 1, "MoELayer only support 1-1 connection")
+        check(1 <= len(in_shapes) <= 2,
+              "MoELayer takes one input, or two: the experts' and the "
+              "router's")
+        check(len(in_shapes) == 1 or in_shapes[1] == in_shapes[0],
+              "moe: the router's input must have the experts' input's shape")
         b, c, h, w = in_shapes[0]
-        check(c == 1 and h == 1,
-              "moe input must be flattened (batch, 1, 1, d); add a flatten "
-              "layer first")
+        check(h == 1,
+              "moe input must be flat (batch, 1, 1, d) or a sequence "
+              "(batch, d, 1, seq); add a flatten layer first")
+        self._seq = c > 1
         check(self.n_expert > 0, "must set nexpert")
         check(self.param.num_hidden > 0, "must set nhidden")
         check(self.top_k <= self.n_expert, "top_k cannot exceed nexpert")
-        self.param.num_input_node = w
-        return [(b, 1, 1, self.param.num_hidden)]
+        check(0 <= self.expert_offset
+              and self.expert_offset + self._held() <= self.n_expert,
+              "moe: experts [expert_offset, expert_offset + nexpert_held) "
+              "must lie within nexpert")
+        din = c if self._seq else w
+        self.param.num_input_node = din
+        dout = din if self.expert_act == "reglu" else self.param.num_hidden
+        return [(b, dout, 1, w) if self._seq else (b, 1, 1, dout)]
 
     def init_params(self, rng):
-        din, dout = self.param.num_input_node, self.param.num_hidden
-        e = self.n_expert
-        return {
+        din, f = self.param.num_input_node, self.param.num_hidden
+        e, held = self.n_expert, self._held()
+        out = {
             "gate": self.param.rand_init_weight(
                 rng, (e, din), in_num=din, out_num=e),
             "experts": self.param.rand_init_weight(
-                rng, (e, din, dout), in_num=din, out_num=dout),
+                rng, (held, din, f), in_num=din, out_num=f),
         }
+        if self.expert_act == "reglu":
+            out["up"] = self.param.rand_init_weight(
+                rng, (held, din, f), in_num=din, out_num=f)
+            out["down"] = self.param.rand_init_weight(
+                rng, (held, f, din), in_num=f, out_num=din)
+        return out
+
+    def _keys(self):
+        return ("gate", "experts") + (
+            ("up", "down") if self.expert_act == "reglu" else ())
 
     def save_model(self, w, params):
         self.param.save(w)
         import struct
         w.write_raw(struct.pack("<ii", self.n_expert, self.top_k))
-        w.write_tensor(params["gate"])
-        w.write_tensor(params["experts"])
+        for key in self._keys():
+            w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
         import struct
         self.n_expert, self.top_k = struct.unpack("<ii", r.read_raw(8))
-        return {"gate": r.read_tensor(), "experts": r.read_tensor()}
+        return {key: r.read_tensor() for key in self._keys()}
 
     def visit_order(self):
-        return [("wmat", "experts"), ("gate", "gate")]
+        # ``experts`` is the one matrix of a relu expert and the gate
+        # matrix Wg of a reglu one; ``gate`` is the router
+        return [("wmat", "experts"), ("gate", "gate")] + (
+            [("up", "up"), ("down", "down")]
+            if self.expert_act == "reglu" else [])
+
+    def _top(self, logits):
+        """(T, k) expert indices and their weights, float32."""
+        k = self.top_k if 0 < self.top_k < self.n_expert else self.n_expert
+        # exact-k from top_k indices (a >=kth-value threshold would keep
+        # every tied expert — common in bf16)
+        vals, idx = jax.lax.top_k(logits, k)
+        return idx, jax.nn.softmax(vals, axis=-1)
 
     def _gate_probs(self, x2, gate):
-        logits = x2 @ gate.T                                # (b, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        if self.top_k and self.top_k < self.n_expert:
-            # exact-k mask from top_k indices (a >=kth-value threshold
-            # would keep every tied expert — common in bf16)
-            _, idx = jax.lax.top_k(probs, self.top_k)       # (b, k)
-            mask = jnp.sum(jax.nn.one_hot(idx, self.n_expert,
-                                          dtype=probs.dtype), axis=1)
-            probs = probs * mask
-            probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
-        return probs
+        """(T, nexpert) routing weights, nought outside each token's top k."""
+        logits = jnp.dot(x2, gate.T, preferred_element_type=jnp.float32)
+        idx, w = self._top(logits)
+        return jnp.sum(jax.nn.one_hot(idx, self.n_expert, dtype=w.dtype)
+                       * w[..., None], axis=1)
+
+    def _experts(self, mm, x, params):
+        """One expert's function of its rows, by the product ``mm(rows,
+        stack of matrices)`` the lowering gives."""
+        a = mm(x, params["experts"])
+        if self.expert_act == "relu":
+            return jnp.maximum(a, 0.0)
+        return mm(jnp.maximum(a, 0.0) * mm(x, params["up"]), params["down"])
+
+    def _dense(self, x2, probs, params):
+        lo = self.expert_offset
+        p_held = probs[:, lo: lo + self._held()].astype(x2.dtype)
+        mm = lambda a, w: jnp.einsum(                       # noqa: E731
+            "ti,eio->eto" if a.ndim == 2 else "eti,eio->eto", a, w)
+        return jnp.einsum("eto,te->to", self._experts(mm, x2, params), p_held)
+
+    def _sparse(self, x2, xr, params, ctx):
+        T = x2.shape[0]
+        held, lo = self._held(), self.expert_offset
+        with sub_scope("route"):
+            logits = jnp.dot(xr, params["gate"].T,
+                             preferred_element_type=jnp.float32)
+            idx, w = self._top(logits)                      # (T, k)
+            k = idx.shape[1]
+            # pair p = j T + t is token t's j-th choice: choice-major, so
+            # that (k T, d) <-> (k, T, d) splits a leading axis (k = 6 on
+            # the sublanes would be a padded copy each time)
+            local = idx.T.reshape(-1) - lo
+            mine = (local >= 0) & (local < held)
+            # pairs of experts held first, by expert; the others last
+            key = jnp.where(mine, local, held).astype(jnp.int32)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(T * k, dtype=jnp.int32))
+            sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32),
+                            axis=0)
+            ctx.layer_stats[ctx.conn_index] = jnp.stack(
+                [jnp.sum(sizes), jnp.max(sizes)]).astype(jnp.float32)
+        with sub_scope("dispatch"):
+            rows = _dispatch_rows(x2, order, inv)
+        with sub_scope("experts"):
+            ys = self._experts(
+                lambda a, m: ops.grouped_matmul(a, m, sizes), rows, params)
+        with sub_scope("combine"):
+            # back to (choice, token) order; a pair not held is a zero row
+            ys = _permute_rows(ys, inv, order).reshape(k, T, -1)
+            return jnp.einsum("kto,tk->to", ys, w.astype(ys.dtype))
 
     def apply(self, params, inputs, ctx):
         from ..parallel import expert_parallel_ffn
+        from ..utils import telemetry
         x = inputs[0]
+        xr = inputs[1] if len(inputs) > 1 else x
         b = x.shape[0]
-        x2 = x.reshape(b, -1)
-        probs = self._gate_probs(x2, params["gate"])
+        if self._seq and not ctx.channels_last:
+            x, xr = (jnp.transpose(v, (0, 2, 3, 1)) for v in (x, xr))
+        d = self.param.num_input_node
+        x2, xr2 = x.reshape(-1, d), xr.reshape(-1, d)
         mesh = ctx.mesh
         n_ep = manual_axis_size(ctx, "ep")
-        if n_ep > 1:
-            # same contract as expert_parallel_ffn (parallel/tensor.py):
-            # an indivisible expert count fails loudly, not silently dense
-            check(self.n_expert % n_ep == 0,
-                  "expert_parallel_ffn: n_experts %d not divisible by "
-                  "mesh axis 'ep' size %d" % (self.n_expert, n_ep))
-            # expert parallelism inside a pipeline stage body (manual
-            # shard_map): each ep rank runs its slice of the expert stack
-            # through the SAME per-device body expert_parallel_ffn wraps
-            # in shard_map (which cannot nest here) — dense local experts,
-            # group-local psum combine
-            from ..parallel.tensor import _ep_local
-            loc = self.n_expert // n_ep
-            eidx = jax.lax.axis_index("ep")
-            w_l = jax.lax.dynamic_slice_in_dim(params["experts"],
-                                               eidx * loc, loc, 0)
-            p_l = jax.lax.dynamic_slice_in_dim(probs, eidx * loc, loc, 1)
-            out = _ep_local(x2, w_l, p_l, axis_name="ep")
-        elif (not ctx.manual_tp and mesh is not None
-                and "ep" in getattr(mesh, "axis_names", ())):
-            batch_axis = "data" if "data" in mesh.axis_names else None
-            out = expert_parallel_ffn(x2, params["experts"], probs,
-                                      mesh, batch_axis=batch_axis)
+        on_ep = n_ep > 1 or (not ctx.manual_tp and mesh is not None
+                             and "ep" in getattr(mesh, "axis_names", ()))
+        if on_ep:
+            check(not self._seq,
+                  "moe: a sequence input takes the sparse lowering, whose "
+                  "token exchange over the mesh's 'ep' axis is not written; "
+                  "one chip's share runs with nexpert_held / expert_offset "
+                  "and no ep axis")
+            check(self.expert_act == "relu" and self._held() == self.n_expert,
+                  "moe: the 'ep' mesh paths take relu experts, all held")
+        if self._seq:
+            telemetry.count_path("moe.sparse")
+            out = self._sparse(x2, xr2, params, ctx)
         else:
-            y = jnp.einsum("bi,eio->ebo", x2, params["experts"])
-            y = jnp.maximum(y, 0.0)
-            out = jnp.einsum("ebo,be->bo", y, probs)
-        return [out.reshape(b, 1, 1, self.param.num_hidden)]
+            telemetry.count_path("moe.dense")
+            probs = self._gate_probs(xr2, params["gate"])
+            if n_ep > 1:
+                # same contract as expert_parallel_ffn (parallel/tensor.py):
+                # an indivisible expert count fails loudly, not silently
+                # dense
+                check(self.n_expert % n_ep == 0,
+                      "expert_parallel_ffn: n_experts %d not divisible by "
+                      "mesh axis 'ep' size %d" % (self.n_expert, n_ep))
+                # expert parallelism inside a pipeline stage body (manual
+                # shard_map): each ep rank runs its slice of the expert
+                # stack through the SAME per-device body
+                # expert_parallel_ffn wraps in shard_map (which cannot nest
+                # here) — dense local experts, group-local psum combine
+                from ..parallel.tensor import _ep_local
+                loc = self.n_expert // n_ep
+                eidx = jax.lax.axis_index("ep")
+                w_l = jax.lax.dynamic_slice_in_dim(params["experts"],
+                                                   eidx * loc, loc, 0)
+                p_l = jax.lax.dynamic_slice_in_dim(probs, eidx * loc, loc, 1)
+                out = _ep_local(x2, w_l, p_l.astype(x2.dtype),
+                                axis_name="ep")
+            elif on_ep:
+                batch_axis = "data" if "data" in mesh.axis_names else None
+                out = expert_parallel_ffn(x2, params["experts"],
+                                          probs.astype(x2.dtype),
+                                          mesh, batch_axis=batch_axis)
+            else:
+                out = self._dense(x2, probs, params)
+        out = out.astype(x.dtype)
+        if not self._seq:
+            return [out.reshape(b, 1, 1, -1)]
+        out = out.reshape(x.shape[:3] + (-1,))              # (b, 1, L, dout)
+        return [out if ctx.channels_last
+                else jnp.transpose(out, (0, 3, 1, 2))]

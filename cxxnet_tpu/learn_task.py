@@ -1092,7 +1092,8 @@ class LearnTask:
         if self.health_monitor:
             self._health = health.HealthMonitor(
                 spike_factor=self.loss_spike_factor,
-                spike_warmup=self.loss_spike_warmup)
+                spike_warmup=self.loss_spike_warmup,
+                gauge_names=lambda: self.net_trainer.health_gauge_names)
             self._recovery = health.RecoveryPolicy(
                 action=self.nonfinite_action,
                 backoff=self.rollback_backoff,

@@ -8,7 +8,7 @@ tiny variants compile fast in tests and multi-chip dry runs.
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 from .nnet.trainer import Trainer
 from .utils.config import parse_config_string
@@ -345,42 +345,63 @@ def googlenet_trainer(batch_size: int = 128, input_hw: int = 224,
 
 def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
                        ffn: int, attn_keys: str = "",
-                       norm: bool = False) -> Tuple[str, str]:
-    """One transformer block in the DSL, shared by the LM and ViT
-    builders so the block shape lives in one place. Residuals connect the
-    BLOCK INPUT (pre-norm form): out = x + att(norm(x)), then
-    + ffn(norm(.)). norm=True inserts batch_norm (moving_average) before
-    each sub-block; attn_keys are extra per-attention config lines
-    (causal/rope/GQA/window)."""
+                       norm=False, ffn_kind: str = "conv",
+                       ffn_keys: str = "", norm_keys: str = "",
+                       init_sigma: Optional[float] = 0.05
+                       ) -> Tuple[str, str]:
+    """One transformer block in the DSL, shared by the LM, ViT and
+    mixture-of-experts builders so the block shape lives in one place.
+    Residuals connect the BLOCK INPUT (pre-norm form): out = x +
+    att(norm(x)), then + ffn(norm(.)). ``norm``: False, ``"batch_norm"``
+    (True; moving_average) or ``"rmsnorm"`` before each sub-block;
+    attn_keys are extra per-attention config lines (causal/rope/GQA/
+    window/head_dim). ``ffn_kind``: ``"conv"`` (two 1x1 convs of width
+    ``ffn`` around a relu) or ``"moe"`` (one ``moe`` layer of expert width
+    ``ffn`` whose router reads the block's input, before the norm and the
+    attention; ``ffn_keys`` are its lines). ``norm_keys``: lines of both
+    norm layers. ``init_sigma`` None leaves the
+    weights' spread to the conf's global key."""
+    def keys(text):
+        return "".join("  %s\n" % ln.strip()
+                       for ln in text.splitlines() if ln.strip())
+    norm = "batch_norm" if norm is True else norm
+    norm_keys = keys(("moving_average = 1\n" if norm == "batch_norm"
+                      else "") + norm_keys)
+    short = {"batch_norm": "bn", "rmsnorm": "rn", False: ""}[norm]
     txt = ""
     att_in = node_in
     if norm:
-        txt += ("layer[%(in)s->%(p)sn1] = batch_norm:%(p)s_bn1\n"
-                "  moving_average = 1\n" % {"in": node_in, "p": p})
+        txt += ("layer[%(in)s->%(p)sn1] = %(norm)s:%(p)s_%(s)s1\n%(nk)s"
+                % {"in": node_in, "p": p, "norm": norm, "s": short,
+                   "nk": norm_keys})
         att_in = p + "n1"
+    sigma = "" if init_sigma is None else \
+        "  init_sigma = %.10g\n" % init_sigma
     txt += """layer[%(ai)s->%(p)satt] = attention:%(p)s_att
   nhead = %(nh)d
-  init_sigma = 0.05
-%(ak)slayer[%(in)s,%(p)satt->%(p)sres1] = add
-""" % {"ai": att_in, "in": node_in, "p": p, "nh": nhead,
-       "ak": "".join("  %s\n" % l.strip()
-                     for l in attn_keys.splitlines() if l.strip())}
+%(sg)s%(ak)slayer[%(in)s,%(p)satt->%(p)sres1] = add
+""" % {"ai": att_in, "in": node_in, "p": p, "nh": nhead, "sg": sigma,
+       "ak": keys(attn_keys)}
     ffn_in = p + "res1"
     if norm:
-        txt += ("layer[%(p)sres1->%(p)sn2] = batch_norm:%(p)s_bn2\n"
-                "  moving_average = 1\n" % {"p": p})
+        txt += ("layer[%(p)sres1->%(p)sn2] = %(norm)s:%(p)s_%(s)s2\n%(nk)s"
+                % {"p": p, "norm": norm, "s": short, "nk": norm_keys})
         ffn_in = p + "n2"
-    txt += """layer[%(fi)s->%(p)sf1] = conv:%(p)s_ffn1
+    if ffn_kind == "moe":
+        txt += """layer[%(fi)s,%(in)s->%(p)sf2] = moe:%(p)s_moe
+  nhidden = %(ffn)d
+%(fk)s""" % {"fi": ffn_in, "in": node_in, "p": p, "ffn": ffn,
+            "fk": keys(ffn_keys)}
+    else:
+        txt += """layer[%(fi)s->%(p)sf1] = conv:%(p)s_ffn1
   kernel_size = 1
   nchannel = %(ffn)d
-  init_sigma = 0.05
-layer[%(p)sf1->%(p)sr] = relu
+%(sg)slayer[%(p)sf1->%(p)sr] = relu
 layer[%(p)sr->%(p)sf2] = conv:%(p)s_ffn2
   kernel_size = 1
   nchannel = %(dim)d
-  init_sigma = 0.05
-layer[%(p)sres1,%(p)sf2->%(p)sout] = add
-""" % {"fi": ffn_in, "p": p, "ffn": ffn, "dim": dim}
+%(sg)s""" % {"fi": ffn_in, "p": p, "ffn": ffn, "dim": dim, "sg": sigma}
+    txt += "layer[%(p)sres1,%(p)sf2->%(p)sout] = add\n" % {"p": p}
     return txt, p + "out"
 
 
@@ -415,11 +436,98 @@ layer[%s->logits] = conv:head
 layer[+0] = softmax
   seq = 1
 netconfig = end
-metric = seq
 """ % (node, vocab)
-    # `metric = seq` is not a metric — strip it; kept minimal
-    txt = txt.replace("metric = seq\n", "")
     return txt
+
+
+def smallthinker_netconfig(vocab: int = 151936, dim: int = 2560,
+                           nhead: int = 28, nkvhead: int = 4,
+                           head_dim: int = 128, nlayer: int = 52,
+                           n_expert: int = 64, top_k: int = 6,
+                           expert_width: int = 768, n_held: int = 0,
+                           expert_offset: int = 0, window: int = 4096,
+                           period=(0, 1, 1, 1), rope_theta: float = 1.5e6,
+                           eps: float = 1e-6, remat: str = "moe") -> str:
+    """SmallThinker-21BA3B (PowerInfer, 2025; the defaults are its
+    published config.json) from the netconfig DSL: embed -> nlayer x [
+    rmsnorm, attention (grouped-query, a head size of its own; layer l is
+    global with no position signal where ``period[l % len] == 0`` and
+    rotary inside a causal window of ``window`` keys where it is 1) +
+    residual, rmsnorm, sparse ReGLU experts top_k of n_expert whose router
+    reads the block's input + residual ] -> rmsnorm -> untied vocab head
+    -> per-position softmax. No bias anywhere. Matrices start at
+    normal(0, 0.02) and the embedding at normal(0, 1): the stream the
+    routers read (un-normed) is then each token's own vector and not what
+    attention averages into every position, so random weights route
+    token by token as a trained router does.
+
+    ``n_held`` / ``expert_offset`` / ``vocab`` / ``nlayer`` cut one chip's
+    share of an expert-parallel deployment (the experts and the vocabulary
+    rows this chip holds, the layers of this pipeline stage); the router
+    keeps its ``n_expert`` outputs. ``remat`` names the layer kinds whose
+    activations are recomputed in the backward pass ("moe", "attention",
+    both with a blank between, or "")."""
+    txt = """
+netconfig = start
+layer[0->emb] = embed:emb
+  vocab_size = %d
+  nhidden = %d
+  init_sigma = 1
+""" % (vocab, dim)
+    node = "emb"
+    for i in range(nlayer):
+        local = period[i % len(period)]
+        attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\nrope = %d\n"
+                "rope_base = %.10g\nattn_window = %d\nremat = %d\n"
+                % (nkvhead, head_dim, local, rope_theta,
+                   window if local else 0, "attention" in remat))
+        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
+               "expert_offset = %d\nexpert_act = reglu\nremat = %d\n"
+               % (n_expert, top_k, n_held or n_expert, expert_offset,
+                  "moe" in remat))
+        blk, node = _transformer_block(
+            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
+            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
+            norm_keys="eps = %.10g\n" % eps, init_sigma=None)
+        txt += "\n" + blk
+    txt += """
+layer[%s->nf] = rmsnorm:norm_f
+  eps = %.10g
+layer[nf->logits] = conv:head
+  kernel_size = 1
+  nchannel = %d
+  no_bias = 1
+layer[+0] = softmax
+  seq = 1
+netconfig = end
+random_type = gaussian
+init_sigma = 0.02
+""" % (node, eps, vocab)
+    return txt
+
+
+SMALLTHINKER_ADAMW = ("updater = adamw\neta = 0.0000003\nbeta1 = 0.9\n"
+                      "beta2 = 0.95\nadam_eps = 1e-08\nwd = 0.1\n"
+                      "gain:wd = 0.0\n")
+
+
+def smallthinker_conf(seq: int = 8192, batch_size: int = 1,
+                      dev: str = "tpu", extra_cfg: str = "", **kw) -> str:
+    """The whole training conf of the SmallThinker recipe: the netconfig,
+    the shapes, and AdamW as large mixture-of-experts models are trained
+    (beta 0.9 / 0.95, decay 0.1 on the matrices and none on the norms'
+    gains) at eta 3e-7, a thousandth of a 3e-4 peak: the first steps of a
+    linear warm-up, held constant. All assumed, the model's card gives
+    none. (At 3e-4 from the first step the loss rises from 10.9 to 14.7
+    and every router collapses onto one expert within three steps; at
+    3e-6 the routers' loads still drift by a fifth within 45 steps; at
+    3e-7 the bfloat16 copies of the weights hardly change and the routing
+    is nearly at rest: PERF.md section 6, PR 29.)"""
+    return (smallthinker_netconfig(**kw) + SMALLTHINKER_ADAMW +
+            "input_shape = 1,1,%d\n" % seq +
+            "batch_size = %d\n" % batch_size +
+            "label_vec[0,%d) = label\n" % seq +
+            "dev = %s\n" % dev + extra_cfg)
 
 
 def transformer_lm_conf(vocab: int = 50, seq: int = 16,

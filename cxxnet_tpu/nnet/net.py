@@ -539,9 +539,11 @@ class NeuralNet:
                               manual_tp=ctx.manual_tp)
             c2.rng = rng
             c2.layer_index = getattr(ctx, "layer_index", pidx)
-            return tuple(lay.apply(pp, list(xs), c2))
-        return list(jax.checkpoint(pure)(
-            p, tuple(ins), ctx.rng, ctx.epoch))
+            c2.conn_index = ctx.conn_index
+            return tuple(lay.apply(pp, list(xs), c2)), c2.layer_stats
+        outs, stats = jax.checkpoint(pure)(p, tuple(ins), ctx.rng, ctx.epoch)
+        ctx.layer_stats.update(stats)
+        return list(outs)
 
     def _apply_layer_range(self, params, values, ctx, base_rng,
                            lo: int, hi: int, layouts=None):
@@ -685,6 +687,7 @@ class NeuralNet:
         layouts = self._apply_layer_range(params, values, ctx, base_rng,
                                           0, len(cfg.layers))
         self._last_cache_updates = ctx.cache_updates
+        self._last_layer_stats = ctx.layer_stats
         # every escaping node value is reference-NCHW; the transposes of
         # values the caller never reads are dead code XLA eliminates
         for n, lo_ in enumerate(layouts):

@@ -127,6 +127,9 @@ class Trainer:
         self.health_monitor = 0
         self.nonfinite_action = "rollback"
         self.last_health = None     # device array of the LAST step's vector
+        # names of what follows the four in that vector (set when the step
+        # is traced): gauges the host monitor keeps at each check
+        self.health_gauge_names = []
         self.metric = MetricSet()
         self.train_metric = MetricSet()
         self.eval_node_names: List[Optional[str]] = []  # None -> last node
@@ -939,7 +942,8 @@ class Trainer:
                 for n in self.eval_nodes]
             stats = self.train_metric.device_stats(eval_outs, labels)
         state_ups = getattr(self.net, "_last_state_updates", {})
-        return loss, (stats, state_ups)
+        layer_stats = dict(getattr(self.net, "_last_layer_stats", {}))
+        return loss, (stats, state_ups, layer_stats)
 
     def _apply_updates(self, params, grads, opt_state, epoch):
         new_params = [dict(p) for p in params]
@@ -1043,7 +1047,8 @@ class Trainer:
 
         def step(params, opt_state, grad_accum, metric_accum,
                  data, label, epoch, rng):
-            (loss, (stats, state_ups)), grads = jax.value_and_grad(
+            (loss, (stats, state_ups, layer_stats)), grads = \
+                jax.value_and_grad(
                 self._loss_fn, has_aux=True)(params, data, label, rng,
                                              epoch, with_stats)
             health = None
@@ -1064,6 +1069,17 @@ class Trainer:
                     health = jnp.stack([lossf, gn_sq,
                                         nan_elems.astype(jnp.float32),
                                         ok.astype(jnp.float32)])
+                    # behind the four: the layers' own readings of this
+                    # step (moe: pairs held, fullest expert), named by
+                    # health_gauge_names in the same order
+                    names = []
+                    for i in sorted(layer_stats):
+                        names += ["%s/%s" % (n, self.net.layer_scope(i))
+                                  for n in self.net.layers[i].stat_names]
+                    self.health_gauge_names = names
+                    health = jnp.concatenate(
+                        [health] + [layer_stats[i]
+                                    for i in sorted(layer_stats)])
             if guard:
                 prev = (params, opt_state, grad_accum, metric_accum)
             if accumulate:
@@ -1723,11 +1739,10 @@ class Trainer:
         keys, shapes = [], []
         for i in att_idx:
             lay = net2.layers[i]
-            d_in = net2.node_shapes[net2.cfg.layers[i].nindex_in[0]][1]
             for nm in ("k", "v"):
                 keys.append((i, nm))
                 shapes.append((b, lay.nkvhead or lay.nhead, l_max,
-                               d_in // lay.nhead))
+                               lay._dh()))
         return att_idx, keys, shapes, net2.compute_dtype or jnp.float32
 
     def beam_generate(self, prompts, n_new: int,
@@ -2142,9 +2157,14 @@ class Trainer:
         return ret
 
     # ------------------------------------------------------------------
+    # every tag a layer's visit_order gives: fullc/conv (wmat, bias),
+    # attention (wo), moe (gate = the router, up, down), rmsnorm (gain)
+    _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain")
+
     def set_weight(self, value: np.ndarray, layer_name: str, tag: str) -> None:
-        check(tag in ("wmat", "bias", "wo"),
-              "SetWeight: weight tag can only be bias, wmat, or wo")
+        check(tag in self._WEIGHT_TAGS,
+              "SetWeight: weight tag can only be one of %s"
+              % ", ".join(self._WEIGHT_TAGS))
         # params mutate in place below; the decode cache keys on list
         # identity and would otherwise serve stale weights to generate()
         self._decode_params = None
@@ -2156,8 +2176,9 @@ class Trainer:
         self.net.set_weight(self.params, value, layer_name, tag)
 
     def get_weight(self, layer_name: str, tag: str):
-        check(tag in ("wmat", "bias", "wo"),
-              "GetWeight: weight tag can only be bias, wmat, or wo")
+        check(tag in self._WEIGHT_TAGS,
+              "GetWeight: weight tag can only be one of %s"
+              % ", ".join(self._WEIGHT_TAGS))
         return self.net.get_weight(self.canonical_params(), layer_name, tag)
 
 

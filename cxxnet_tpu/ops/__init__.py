@@ -337,6 +337,82 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                                window)
 
 
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Tiles of the grouped product's kernel: rows in 512s (the callers pad
+    to it), the contraction and the output columns whole up to 1024 and
+    else in the largest lane-aligned divisor — few grid steps, a few MiB
+    of VMEM."""
+    def tile(x):
+        if x <= 1024:
+            return x
+        return next((t for t in (1024, 768, 640, 512, 384, 256, 128)
+                     if x % t == 0), 512)
+    return (min(m, 512), tile(k), tile(n))
+
+
+def _megablox():
+    # the package's own ``gmm`` attribute is its custom_vjp function and
+    # shadows the module of that name
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@jax.custom_vjp
+def _gmm(lhs, rhs, sizes):
+    """megablox ``gmm`` with tiles chosen per product. Its own VJP
+    (megablox.ops) hands the forward's tiles to the two backward products,
+    whose contraction and column sizes are the forward's swapped."""
+    _mb = _megablox()
+    m, (_, k, n) = lhs.shape[0], rhs.shape
+    return _mb.gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiling(m, k, n),
+                   interpret=pallas_interpret())
+
+
+def _gmm_fwd(lhs, rhs, sizes):
+    return _gmm(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _gmm_bwd(res, g):
+    _mb = _megablox()
+    lhs, rhs, sizes = res
+    m, (ng, k, n) = lhs.shape[0], rhs.shape
+    interpret = pallas_interpret()
+    d_lhs = _mb.gmm(g, rhs, sizes, lhs.dtype, _gmm_tiling(m, n, k),
+                    transpose_rhs=True, interpret=interpret)
+    d_rhs = _mb.tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype,
+                     _gmm_tiling(m, k, n), num_actual_groups=ng,
+                     interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """Rows of ``lhs`` (m, k), sorted by group, each times its group's
+    matrix of ``rhs`` (g, k, n): rows ``[sum(sizes[:i]), sum(sizes[:i+1]))``
+    take ``rhs[i]``. The sizes may add up to less than m; the rows beyond
+    come out as exact zeros, and cost nothing on a TPU. There it is the
+    megablox kernel (a grid over the row tiles that hold work: the cost
+    follows the rows, not m x g), elsewhere ``lax.ragged_dot`` (XLA's own
+    lowering, which the CPU backend expands densely)."""
+    m = lhs.shape[0]
+    if not use_pallas():
+        return lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+    tm = min(512, -(-m // 128) * 128)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    # one group more than rhs has matrices, holding the rows with no work:
+    # the kernel then zeroes them itself, forward and backward
+    rest = (m + pad - jnp.sum(group_sizes)).astype(jnp.int32)
+    sizes = jnp.concatenate([group_sizes.astype(jnp.int32), rest[None]])
+    out = _gmm(lhs, rhs, sizes)
+    return out[:m] if pad else out
+
+
 def softmax(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     return jax.nn.softmax(x, axis=axis)
 

@@ -66,6 +66,9 @@ REPORT_ROWS = 30              # layer x phase rows the text shows
 REPORT_OPS_PER_ROW = 4
 # scopes of the train step that are no layer's and no phase of their own
 STEP_SCOPES = ("clip", "accum", "guard")
+# the mark of a scope a layer opens inside its own (layer.base.sub_scope):
+# a layer's rows are split by them
+SUB_SCOPE_MARK = "~"
 # the program's host spans (telemetry.span / telemetry.phase)
 SPAN_PREFIXES = ("train.", "init.", "jit.", "io.")
 UNNAMED = "(no scope)"
@@ -278,7 +281,10 @@ def scope_of(tf_op: str) -> Tuple[str, str]:
     operation with no ``tf_op`` at all as ``NO_TF_OP``. Under an empty
     ``jvp()`` a leading ``shard_map`` and its control flow are skipped
     (``jvp()/shard_map/while/body/.../conv1/...`` is conv1's); what the
-    pipeline runs between the layers stands under ``shard_map`` itself."""
+    pipeline runs between the layers stands under ``shard_map`` itself.
+    A layer's own sub-scope (a component that starts with
+    ``SUB_SCOPE_MARK``) is kept: ``jvp(b0_att)/~core/...`` is
+    ``b0_att/core``."""
     if not tf_op:
         return "other", NO_TF_OP
     parts = _JIT.sub("", tf_op.rsplit(":", 1)[0]).split("/")
@@ -293,6 +299,11 @@ def scope_of(tf_op: str) -> Tuple[str, str]:
     if not layer and parts[1:2] == [SHARD_MAP]:
         inner = [c for c in parts[1:-1] if not _CONTROL.match(c)]
         layer = inner[0] if inner and "(" not in inner[0] else SHARD_MAP
+    if layer:
+        sub = next((c for c in parts[1:-1]
+                    if c.startswith(SUB_SCOPE_MARK)), None)
+        if sub:
+            layer = "%s/%s" % (layer, sub[len(SUB_SCOPE_MARK):])
     if "transpose(" in head:
         return "backward", layer or UNNAMED
     if "jvp(" in head:

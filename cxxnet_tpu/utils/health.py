@@ -125,7 +125,11 @@ class HealthMonitor:
     """
 
     def __init__(self, spike_factor: float = 0.0, spike_warmup: int = 20,
-                 spike_decay: float = 0.98):
+                 spike_decay: float = 0.98, gauge_names=None):
+        # gauge_names(): names of what the step put behind the four health
+        # values (Trainer.health_gauge_names: moe.pairs_held/<layer>,
+        # moe.load_max/<layer>); each check keeps them as gauges
+        self.gauge_names = gauge_names
         self.spike_factor = float(spike_factor)
         self.spike_warmup = int(spike_warmup)
         self.spike_decay = float(spike_decay)
@@ -160,6 +164,9 @@ class HealthMonitor:
         loss = float(h[H_LOSS])
         gn_sq = float(h[H_GNORM_SQ])
         nan_grads = int(h[H_NAN_GRADS])
+        if self.gauge_names is not None:
+            for name, v in zip(self.gauge_names(), h[H_OK + 1:]):
+                telemetry.gauge(name, float(v))
         if nan_grads > 0:
             # the elements updater _clip_nan silently zeroes (with
             # clip_gradient set) — or that reach the optimizer raw —

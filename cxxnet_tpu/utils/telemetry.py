@@ -22,6 +22,12 @@ arxiv 1802.04799). This module is that measurement substrate:
   of each name and outlives ``reset()``: it says what this process's
   set-up cost. ``summary()``, ``/metrics`` (``cxxnet_phase_seconds``) and
   ``/statusz`` show it.
+* **paths** — ``telemetry.count_path("moe.sparse")`` is a counter that
+  also writes a small always-on account (``paths()``), for which lowering
+  a layer took when its step was traced (``moe.sparse`` / ``moe.dense``,
+  ``attn.flash`` / ``attn.dense``, once per traced layer): like the phase
+  account it is the process's and outlives ``reset()``, so that a run that
+  enabled nothing can still say what it timed.
 * **counters / gauges** — ``telemetry.count("train.images", n)`` accumulates
   monotonically; ``telemetry.gauge("device.bytes_in_use", v)`` records the
   latest value of a level. ``sample_device_memory()`` snapshots the
@@ -98,7 +104,7 @@ from . import lockrank
 
 __all__ = [
     "enable", "disable", "enabled", "reset", "span", "phase", "phases",
-    "count", "gauge",
+    "count", "count_path", "paths", "gauge",
     "hist", "event", "record_compile", "jit_watch",
     "sample_device_memory",
     "flush", "finish", "summary", "brief_summary", "events",
@@ -453,6 +459,9 @@ class _Registry:
         # reset()/enable() as compile_hook does: it is the process's
         # set-up, and a JitWatch's first call does not come again.
         self.phase_s: Dict[str, float] = {}
+        # the always-on account of paths: name -> times a traced layer
+        # took that lowering in this process
+        self.path_n: Dict[str, int] = {}
         self.reset()
 
     # -- lifecycle -----------------------------------------------------
@@ -611,6 +620,15 @@ class _Registry:
     def phases(self) -> Dict[str, float]:
         with self._lock:
             return dict(self.phase_s)
+
+    def count_path(self, name: str) -> None:
+        with self._lock:
+            self.path_n[name] = self.path_n.get(name, 0) + 1
+        self.count(name)
+
+    def paths(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self.path_n)
 
     def span_event(self, name: str, start_perf: float, dur: float,
                    **attrs) -> None:
@@ -829,6 +847,7 @@ class _Registry:
                 },
                 "phases": {k: round(v, 6)
                            for k, v in self.phase_s.items()},
+                "paths": dict(self.path_n),
             }
 
     def brief_summary(self, top: int = 8,
@@ -1402,6 +1421,17 @@ def span_event(name: str, start_perf: float, dur: float, **attrs) -> None:
 
 def count(name: str, n=1) -> None:
     _REG.count(name, n)
+
+
+def count_path(name: str) -> None:
+    """A counter that also writes the always-on path account."""
+    _REG.count_path(name)
+
+
+def paths() -> Dict[str, int]:
+    """The path account: name -> times a traced layer took that lowering
+    in this process, recorded whether telemetry is enabled or not."""
+    return _REG.paths()
 
 
 def gauge(name: str, value) -> None:

@@ -84,7 +84,7 @@ def main(argv=None) -> int:
     program.sync()
     first_step_s = time.perf_counter() - t0
     d = jax.devices()[0]
-    line = {"workload": args.workload, "input_shape": cfg["input_shape"],
+    line = {"workload": args.workload, "input_shape": cfg.get("input_shape"),
             "rows_per_chip": cfg["batch_per_chip"],
             "first_step_s": first_step_s, "phases": telemetry.phases(),
             "device": {"platform": d.platform, "kind": d.device_kind,
@@ -116,6 +116,12 @@ def main(argv=None) -> int:
     kept = os.path.join(args.out, args.workload + ".xplane.pb")
     shutil.copy(path, kept)
     shutil.rmtree(trace_dir, ignore_errors=True)
+    health = getattr(getattr(program, "trainer", None), "last_health", None)
+    if health is not None:
+        # the traced stretch's last step: loss, gradient norm, and whatever
+        # the layers put behind them (a sparse moe layer's pairs held and
+        # fullest expert)
+        line["last_health"] = [float(v) for v in health]
     line.update(steps=args.steps,
                 group_s={"before": groups[0], "traced": groups[1],
                          "after": groups[2]},

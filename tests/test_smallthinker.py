@@ -1,0 +1,387 @@
+"""SmallThinker's block through the normal path (conf text ->
+``Trainer.update``), at a small size on the CPU: d 64, 4 query / 2 key-value
+heads of 16, 8 experts top-2 of width 32, vocabulary 96, L 32, window 8,
+pattern [0, 1, 1, 1]. Against the benchmark's plain reference
+(``benchmark/references/moe_lm.py``) on seeded weights, float32."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import compare, lm_inputs, netconf  # noqa: E402
+from benchmark.inputs import seed_key  # noqa: E402
+from benchmark.programs import cxxnet_lm_trainer  # noqa: E402
+from benchmark.references import moe_lm  # noqa: E402
+from benchmark.windows import resident as window  # noqa: E402
+from cxxnet_tpu import models, ops  # noqa: E402
+from cxxnet_tpu.layer.base import ApplyContext  # noqa: E402
+from cxxnet_tpu.layer.layers import AttentionLayer, MoELayer  # noqa: E402
+from cxxnet_tpu.utils import telemetry  # noqa: E402
+
+D, L, VOCAB, NEXP, WIDTH = 64, 32, 96, 8, 32
+SMALL = dict(vocab=VOCAB, dim=D, nhead=4, nkvhead=2, head_dim=16, nlayer=4,
+             n_expert=NEXP, top_k=2, expert_width=WIDTH, window=8)
+CFG = {"seq_len": L, "batch_per_chip": 2 * L,
+       "extra_cfg": "eval_train = 0\nhealth_monitor = 1\n"}
+SEED = 2**31 + 77
+
+
+def _conf(**over):
+    return models.smallthinker_netconfig(**dict(SMALL, **over)) \
+        + models.SMALLTHINKER_ADAMW
+
+
+_PATHS_BEFORE = {}
+
+
+@pytest.fixture(scope="module")
+def trained():
+    """The uncut small model, three steps of the program and of the
+    reference from the same seed."""
+    conf = _conf()
+    ref = moe_lm.for_config(conf, CFG, 2 * L)
+    # the path account is the process's: count from what tests before left
+    _PATHS_BEFORE.update(telemetry.paths())
+    program = cxxnet_lm_trainer.Program(conf, CFG, 1, SEED, {})
+    before = _probabilities(program)
+    got = window.first_steps(program, ref.hyper, 3)
+    return conf, ref, program, before, got, ref.run(SEED, 3)
+
+
+def _probabilities(program):
+    tr = program.trainer
+    values, _ = tr.net.forward(tr.params, program.batches[0].data)
+    return np.asarray(values[-1])            # (rows, vocab, 1, L)
+
+
+def test_logits_agree_with_the_reference(trained):
+    conf, ref, program, before, _, _ = trained
+    layers, _ = netconf.parse(conf)
+    params = jax.jit(ref._weights)(seed_key(SEED))
+    ids = program.batches[0].data.reshape(2, L).astype(jnp.int32)
+    want = jax.vmap(lambda row: moe_lm.logits_of(layers, "highest", params,
+                                                 row))(ids)
+    want = jax.nn.softmax(want, axis=-1)     # the loss node holds softmax
+    # float32 on both sides, the same weights and tokens: what is left is
+    # the order of float32 sums, on probabilities of about 1 / 96
+    np.testing.assert_allclose(before[:, :, 0, :].transpose(0, 2, 1), want,
+                               rtol=2e-5, atol=1e-8)
+
+
+def test_loss_gradients_and_three_adamw_steps_agree(trained):
+    _, _, _, _, got, want = trained
+    nums = compare.numbers(got, want)
+    # the mean of 64 float32 cross-entropies, summed in another order
+    assert max(nums[k]["value"] for k in ("loss1", "loss2", "loss3")) < 1e-6
+    # every leaf's first gradient, read out of AdamW's m1 = (1 - beta1) g:
+    # float32 sums in another order, and the division by (1 - beta1)
+    assert set(got["grad_norm"]) == set(want["grad_norm"])
+    for leaf, w in want["grad_norm"].items():
+        assert got["grad_norm"][leaf] == pytest.approx(w, rel=2e-5), leaf
+    # three steps of AdamW: m / sqrt(v) is about +-1 at the first step
+    # whatever the gradient's size, so a gradient element of 1e-9 whose
+    # sign float32 rounding flips moves by 2 eta; norms over a leaf of
+    # thousands of elements agree far closer than any one element
+    for leaf, w in want["change_norm"].items():
+        assert got["change_norm"][leaf] == pytest.approx(w, rel=1e-4), leaf
+    assert nums["grad_worst"]["value"] < 2e-5
+    assert nums["change_worst"]["value"] < 1e-4
+
+
+def test_the_step_counts_its_paths_and_returns_the_routing(trained):
+    _, _, program, _, _, _ = trained
+    paths = {k: n - _PATHS_BEFORE.get(k, 0)
+             for k, n in telemetry.paths().items()}
+    assert paths.get("moe.sparse", 0) >= 4 and not paths.get("moe.dense")
+    assert paths.get("attn.dense", 0) >= 4       # no flash kernel on a CPU
+    tr = program.trainer
+    health = np.asarray(tr.last_health)
+    names = tr.health_gauge_names
+    assert names == [n + "/b%d_moe" % i for i in range(4)
+                     for n in ("moe.pairs_held", "moe.load_max")]
+    extra = dict(zip(names, health[4:]))
+    # every expert is held: all 2 * 64 pairs, and some expert above the mean
+    assert all(extra["moe.pairs_held/b%d_moe" % i] == 2 * 2 * L
+               for i in range(4))
+    assert all(2 * 2 * L / NEXP <= extra["moe.load_max/b%d_moe" % i]
+               <= 2 * L for i in range(4))
+
+
+def test_the_health_monitor_keeps_the_routing_as_gauges(trained):
+    from cxxnet_tpu.utils import health
+    _, _, program, _, _, _ = trained
+    tr = program.trainer
+    telemetry.enable()
+    try:
+        mon = health.HealthMonitor(
+            gauge_names=lambda: tr.health_gauge_names)
+        # the check runs one step late: the vector before is judged when
+        # the next arrives
+        assert mon.observe(0, 0, tr.last_health) is None
+        assert mon.observe(0, 1, tr.last_health) is None
+        gauges = telemetry.summary()["gauges"]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert gauges["moe.pairs_held/b0_moe"] == 2 * 2 * L
+    assert gauges["moe.load_max/b3_moe"] == float(
+        np.asarray(tr.last_health)[-1])
+
+
+# ------------------------------------------------ the moe layer by itself
+def _moe_layer(held=NEXP, offset=0, **keys):
+    lay = MoELayer()
+    for k, v in dict({"nexpert": NEXP, "top_k": 2, "nhidden": WIDTH,
+                      "expert_act": "reglu",
+                      "nexpert_held": held, "expert_offset": offset},
+                     **keys).items():
+        lay.set_param(k, str(v))
+    lay.infer_shape([(2, D, 1, L)] * 2)
+    return lay
+
+
+def _moe_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    return {"gate": rng.normal(0, 0.5, (NEXP, D)).astype(np.float32),
+            "experts": rng.normal(0, 0.2, (NEXP, D, WIDTH)).astype(np.float32),
+            "up": rng.normal(0, 0.2, (NEXP, D, WIDTH)).astype(np.float32),
+            "down": rng.normal(0, 0.2, (NEXP, WIDTH, D)).astype(np.float32)}
+
+
+def _share(w, lo, n):
+    return dict(w, **{k: w[k][lo:lo + n] for k in ("experts", "up", "down")})
+
+
+def _apply_moe(lay, w, u, x):
+    ctx = ApplyContext(train=True)
+    ctx.conn_index = 0
+    y, = lay.apply(w, [jnp.asarray(u), jnp.asarray(x)], ctx)
+    return np.asarray(y), np.asarray(ctx.layer_stats[0])
+
+
+def _reference_moe(w, u, x, held=NEXP, offset=0):
+    lay = netconf.Layer("moe", "m", ["u", "x"], ["y"], {
+        "nexpert": str(NEXP), "top_k": "2", "expert_act": "reglu",
+        "expert_offset": str(offset)})
+    ww = _share(w, offset, held)
+    ww = {"wmat": ww["experts"], "gate": ww["gate"], "up": ww["up"],
+          "down": ww["down"]}
+    rows = [moe_lm._moe(lay, "highest", ww, u[i].reshape(D, L).T,
+                        x[i].reshape(D, L).T) for i in range(u.shape[0])]
+    return np.stack([np.asarray(r).T.reshape(D, 1, L) for r in rows])
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """What ties one chip's share to the model: experts 0-1, 2-3, 4-5, 6-7
+    each give their part of y, and the parts add up to the layer's y."""
+    rng = np.random.RandomState(1)
+    u, x = rng.randn(2, 2, D, 1, L).astype(np.float32)
+    w = _moe_weights()
+    whole, stats = _apply_moe(_moe_layer(), w, u, x)
+    np.testing.assert_allclose(whole, _reference_moe(w, u, x), rtol=1e-4,
+                               atol=1e-5)
+    parts, pairs = [], 0
+    for lo in range(0, NEXP, 2):
+        y, st = _apply_moe(_moe_layer(held=2, offset=lo), _share(w, lo, 2),
+                           u, x)
+        np.testing.assert_allclose(
+            y, _reference_moe(w, u, x, held=2, offset=lo), rtol=1e-4,
+            atol=1e-5)
+        parts.append(y)
+        pairs += st[0]
+    # float32 sums in another order
+    np.testing.assert_allclose(sum(parts), whole, rtol=1e-4, atol=1e-5)
+    assert pairs == stats[0] == 2 * 2 * L        # every pair is held once
+
+
+def test_all_tokens_to_one_pair_of_experts_and_none_is_dropped():
+    rng = np.random.RandomState(2)
+    u = rng.randn(2, D, 1, L).astype(np.float32)
+    x = np.abs(rng.randn(2, D, 1, L)).astype(np.float32) + 1.0
+    w = _moe_weights()
+    w["gate"] = np.zeros((NEXP, D), np.float32)
+    w["gate"][3], w["gate"][5] = 0.2, 0.1        # every token: experts 3, 5
+    y, stats = _apply_moe(_moe_layer(), w, u, x)
+    assert stats.tolist() == [2 * 2 * L, 2 * L]  # all pairs; one expert: all
+    np.testing.assert_allclose(y, _reference_moe(w, u, x), rtol=1e-4,
+                               atol=1e-5)
+    # the share that holds neither expert adds nothing, and says so
+    y0, stats0 = _apply_moe(_moe_layer(held=2, offset=0), _share(w, 0, 2),
+                            u, x)
+    assert stats0.tolist() == [0, 0] and not y0.any()
+
+
+def test_the_kernel_path_agrees_with_the_plain_one():
+    """The grouped product through the megablox kernel (interpreted here)
+    against lax.ragged_dot, forward and both gradients, with rows left
+    over that no group takes."""
+    rng = np.random.RandomState(3)
+    lhs = jnp.asarray(rng.randn(200, D), jnp.float32)
+    rhs = jnp.asarray(rng.randn(NEXP, D, WIDTH), jnp.float32)
+    sizes = jnp.array([5, 0, 70, 3, 0, 0, 10, 2], jnp.int32)
+
+    def both(a, b):
+        out = ops.grouped_matmul(a, b, sizes)
+        return jnp.sum(jnp.sin(out)), out
+    plain = jax.value_and_grad(both, argnums=(0, 1), has_aux=True)(lhs, rhs)
+    ops.set_use_pallas(True)
+    try:
+        kernel = jax.value_and_grad(both, argnums=(0, 1), has_aux=True)(
+            lhs, rhs)
+    finally:
+        ops.set_use_pallas(None)
+    assert not np.asarray(kernel[0][1][90:]).any()      # exact zeros
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(kernel)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_sequence_input_on_an_expert_mesh_raises():
+    import jax.sharding as shd
+    lay = _moe_layer()
+    mesh = shd.Mesh(np.array(jax.devices()[:2]), ("ep",))
+    ctx = ApplyContext(train=True, mesh=mesh)
+    with pytest.raises(ValueError, match="token exchange"):
+        lay.apply(_moe_weights(), [jnp.zeros((2, D, 1, L))] * 2, ctx)
+
+
+# ------------------------------------------ the attention layer by itself
+def _attention(**keys):
+    lay = AttentionLayer()
+    for k, v in dict({"nhead": 4, "nkvhead": 2, "head_dim": 16,
+                      "causal": 1}, **keys).items():
+        lay.set_param(k, str(v))
+    lay.infer_shape([(2, D, 1, L)])
+    return lay
+
+
+def _attention_weights(lay, seed=4):
+    return {k: jnp.asarray(v) * 10
+            for k, v in lay.init_params(np.random.RandomState(seed)).items()}
+
+
+def _apply_attention(lay, w, x):
+    y, = lay.apply(w, [jnp.asarray(x)], ApplyContext(train=True))
+    return np.asarray(y)
+
+
+def _reference_attention(w, x, **keys):
+    lay = netconf.Layer("attention", "a", ["x"], ["y"], {
+        k: str(v) for k, v in dict({"nhead": 4, "nkvhead": 2,
+                                    "head_dim": 16, "causal": 1},
+                                   **keys).items()})
+    ww = {"wmat": w["wqkv"], "wo": w["wo"]}
+    return np.stack([np.asarray(moe_lm._attention(
+        lay, "highest", ww, x[i].reshape(D, L).T)).T.reshape(D, 1, L)
+        for i in range(x.shape[0])])
+
+
+@pytest.mark.parametrize("keys", [
+    {}, {"rope": 1, "rope_base": 1500000}, {"attn_window": 8},
+    {"rope": 1, "rope_base": 1500000, "attn_window": 8}],
+    ids=["global_nope", "global_rope", "window_nope", "window_rope"])
+def test_attention_with_a_head_size_of_its_own_agrees(keys):
+    x = np.random.RandomState(5).randn(2, D, 1, L).astype(np.float32)
+    lay = _attention(**keys)
+    w = _attention_weights(lay)
+    assert w["wqkv"].shape == (D, (4 + 2 * 2) * 16) and \
+        w["wo"].shape == (4 * 16, D)
+    np.testing.assert_allclose(_apply_attention(lay, w, x),
+                               _reference_attention(w, x, **keys),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_a_window_of_the_whole_sequence_is_global_attention():
+    x = np.random.RandomState(6).randn(2, D, 1, L).astype(np.float32)
+    lay = _attention()
+    w = _attention_weights(lay)
+    want = _apply_attention(lay, w, x)
+    for win in (L, 4 * L):
+        np.testing.assert_array_equal(
+            _apply_attention(_attention(attn_window=win), w, x), want)
+    assert np.abs(_apply_attention(_attention(attn_window=8), w, x)
+                  - want).max() > 1e-3
+
+
+def test_rope_off_is_no_rotation_and_rope_on_is_one():
+    x = np.random.RandomState(7).randn(2, D, 1, L).astype(np.float32)
+    lay = _attention(rope=0)
+    w = _attention_weights(lay)
+    plain = _apply_attention(lay, w, x)
+    # rope = 0 is the layer with its rotation taken out
+    rot = _attention(rope=1)
+    rot._apply_rope = lambda t, offset=0: t
+    np.testing.assert_array_equal(_apply_attention(rot, w, x), plain)
+    assert np.abs(_apply_attention(_attention(rope=1), w, x)
+                  - plain).max() > 1e-3
+
+
+def test_query_head_j_reads_key_value_head_j_over_group():
+    """4 query heads on 2 key-value heads equal 4 on 4 whose key and value
+    columns are those of head j // 2."""
+    x = np.random.RandomState(8).randn(2, D, 1, L).astype(np.float32)
+    lay = _attention()
+    w = _attention_weights(lay)
+    q, k, v = np.split(np.asarray(w["wqkv"]), [64, 96], axis=1)
+    wide = lambda m: np.repeat(m.reshape(D, 2, 16), 2, axis=1) \
+        .reshape(D, 64)                                     # noqa: E731
+    mha = _attention(nkvhead=4)
+    w4 = {"wqkv": jnp.asarray(np.concatenate([q, wide(k), wide(v)], 1)),
+          "wo": w["wo"]}
+    np.testing.assert_allclose(_apply_attention(mha, w4, x),
+                               _apply_attention(lay, w, x), rtol=1e-5,
+                               atol=1e-6)
+
+
+def test_head_dim_is_checked_with_a_message_that_names_the_key():
+    with pytest.raises(ValueError, match="head_dim"):
+        _attention(head_dim=12)
+    with pytest.raises(ValueError, match="head_dim"):
+        lay = AttentionLayer()
+        lay.set_param("nhead", "4")
+        lay.set_param("rope", "1")
+        lay.infer_shape([(2, 12, 1, L)])        # head size 3: odd
+
+
+# ------------------------------------------------------------- the recipe
+def test_the_builder_writes_the_published_model():
+    layers, glob = netconf.parse(models.smallthinker_conf())
+    kinds = [lay.type for lay in layers]
+    assert kinds.count("attention") == kinds.count("moe") == 52
+    att = [lay for lay in layers if lay.type == "attention"]
+    assert [a.geti("rope") for a in att[:8]] == [0, 1, 1, 1, 0, 1, 1, 1]
+    assert [a.geti("attn_window") for a in att[:4]] == [0, 4096, 4096, 4096]
+    assert (att[0].geti("nhead"), att[0].geti("nkvhead"),
+            att[0].geti("head_dim")) == (28, 4, 128)
+    moe = next(lay for lay in layers if lay.type == "moe")
+    assert (moe.geti("nexpert"), moe.geti("top_k"), moe.geti("nhidden"),
+            moe.geti("nexpert_held")) == (64, 6, 768, 64)
+    assert moe.ins[1] == "emb"            # the router reads the block's input
+    n = sum(int(np.prod(s)) for tags in
+            lm_inputs.weight_shapes(layers).values() for s in tags.values())
+    assert 21.4e9 < n < 21.6e9            # "21B"
+    assert glob["updater"] == "adamw" and "metric" not in glob
+
+
+@pytest.mark.parametrize("path, kw", [
+    ("smallthinker_21b.conf", {}),
+    ("smallthinker_21b_ep4_l4.conf",
+     dict(nlayer=4, n_held=16, vocab=37984,
+          extra_cfg="compute_dtype = bfloat16\n"))])
+def test_the_example_confs_are_what_the_builder_writes(path, kw):
+    """The recipe lives in the builder; ``example/`` carries its text (and
+    ``benchmark/configs/`` the measured copy, held by
+    ``tests/benchmark/test_moe_lm.py``)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "example", "transformer", path)) as f:
+        body = "\n".join(ln for ln in f.read().splitlines()
+                         if not ln.startswith("#"))
+    assert body.strip() == models.smallthinker_conf(**kw).strip()
+

@@ -59,3 +59,52 @@ def test_channels_last_lrn_compiles_without_a_copy(one_chip, shape, dtype):
     # the kernel's (H*W, C, N) view is a bitcast of that layout: a copy
     # or a transpose beside it would cost a pass over the tensor each
     assert not re.search(r"= \S+ (copy|transpose)\(", text), text
+
+
+# the sparse expert product of the language-model cell, at the published
+# widths and a quarter of its tokens: the grouped-product kernel has to take
+# Mosaic's tiling at d 2560 / width 768, and the layer's cost has to follow
+# the pairs, not pairs x experts
+def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(one_chip):
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import lm_flops
+    from cxxnet_tpu import ops
+    from cxxnet_tpu.layer.base import ApplyContext
+    from cxxnet_tpu.layer.layers import MoELayer
+    d, width, seq, held, nexp, k = 2560, 768, 2048, 16, 16, 4
+    lay = MoELayer()
+    for key, val in {"nexpert": nexp, "top_k": k, "nhidden": width,
+                     "expert_act": "reglu", "nexpert_held": held}.items():
+        lay.set_param(key, str(val))
+    lay.infer_shape([(1, d, 1, seq)] * 2)
+    shapes = {"gate": (nexp, d), "experts": (held, d, width),
+              "up": (held, d, width), "down": (held, width, d)}
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def layer(params, u, x):
+        ctx = ApplyContext(train=True, channels_last=True)
+        return lay.apply(params, [u, x], ctx)[0]
+    ops.set_use_pallas(True)
+    interpret, ops.pallas_interpret = ops.pallas_interpret, lambda: False
+    try:
+        # Mosaic refuses the process default ("highest") for bf16 operands
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(layer).lower(
+                {key: arg(s) for key, s in shapes.items()},
+                arg((1, 1, seq, d)), arg((1, 1, seq, d))).compile()
+    finally:
+        ops.set_use_pallas(None)
+        ops.pallas_interpret = interpret
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    want = lm_flops.expert_product(seq * k, d, width, held)["flops"] \
+        + 2 * seq * d * nexp
+    got = compiled.cost_analysis()["flops"]
+    # every expert on every token would read held / k = 4 times the pairs
+    assert want <= got < 2 * want, (got, want)

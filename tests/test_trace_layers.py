@@ -132,6 +132,36 @@ def test_a_pipelined_steps_layers_are_found_behind_shard_map():
                for n in re.findall(r'op_name="([^"]*)"', txt))
 
 
+def test_attention_and_moe_rows_split_by_their_sub_scopes():
+    """The compiled step of a small SmallThinker block names, on both
+    passes, the seven sub-scopes ``trace_layers.py`` splits those layers'
+    rows by (the moe layers run under remat, as in the benchmark's cell)."""
+    from cxxnet_tpu import models
+    from cxxnet_tpu.utils.config import parse_config_string
+    conf = models.smallthinker_conf(
+        seq=16, batch_size=2, vocab=40, dim=32, nhead=4, nkvhead=2,
+        head_dim=8, nlayer=1, n_expert=4, top_k=2, expert_width=16,
+        window=8, dev="cpu")
+    tr = Trainer()
+    for k, v in parse_config_string(conf):
+        tr.set_param(k, v)
+    tr.init_model()
+    b = DataBatch()
+    b.data = np.zeros((2, 1, 1, 16), np.float32)
+    b.label = np.zeros((2, 16), np.float32)
+    b.batch_size = 2
+    txt = tr.lower_update(b).as_text(debug_info=True)
+    found = {devtrace.scope_of(n + ":") for n in
+             re.findall(r'loc\("(jit\(step\)/[^"]*)"', txt)}
+    for phase in ("forward", "backward"):
+        for row in ("b0_att/qkv", "b0_att/core", "b0_att/out",
+                    "b0_moe/route", "b0_moe/dispatch", "b0_moe/experts",
+                    "b0_moe/combine", "b0_rn1", "head"):
+            if (phase, row) == ("backward", "b0_moe/route"):
+                continue      # top-k and the sort carry no gradient
+            assert (phase, row) in found, (phase, row)
+
+
 def test_what_is_no_layer_has_a_scope_of_its_own():
     tr = _trainer(SMALL_CONF, "compute_dtype = bfloat16\nchannels_last = 1\n"
                   "input_divideby = 255\nhealth_monitor = 1\n"
@@ -378,6 +408,20 @@ def _op(name, start, dur, tf_op="", flops=0.0, nbytes=0.0):
      ("forward", "shard_map")),
     ("jit(step)/jvp()/shard_map:", ("forward", "shard_map")),
     ("jit(step)/update/conv1/mul:", ("update", "conv1")),
+    # a layer's own sub-scopes split its rows (attention, moe)
+    ("jit(step)/jvp(b0_att)/~core/pallas_call:", ("forward", "b0_att/core")),
+    ("jit(step)/transpose(jvp(b0_att))/~qkv/dot_general:",
+     ("backward", "b0_att/qkv")),
+    ("jit(step)/transpose(jvp(b1_moe))/jvp(b1_moe)/checkpoint/"
+     "rematted_computation/~experts/pallas_call:",
+     ("backward", "b1_moe/experts")),
+    ("jit(step)/jvp(b1_moe)/checkpoint/~route/sort:",
+     ("forward", "b1_moe/route")),
+    # only the mark makes a sub-scope: a layer named as attention's is
+    # stays a layer, behind a pipeline's control flow too
+    ("jit(step)/jvp(out)/dot_general:", ("forward", "out")),
+    ("jit(step)/jvp()/shard_map/while/body/out/dot_general:",
+     ("forward", "out")),
     ("jit(step)/update/packed/select_n:", ("update", "packed")),
     ("jit(step)/health/reduce_sum:", ("health", "health")),
     ("jit(step)/clip/mul:", ("other", "clip")),
