@@ -1325,6 +1325,19 @@ class AttentionLayer(Layer):
             return [out.reshape(b, 1, L, d)]
         return [out.transpose(0, 2, 1).reshape(b, d, 1, L)]
 
+    def _count_flash(self, q, k, causal):
+        """The path account's ``attn.flash`` and, beside it, the static
+        tile schedule the kernels will walk for these shapes: the forward
+        tile as gauges, and the (block_q, block_k) score tiles of one
+        head's grid that take no mask, take one, and are never visited."""
+        from ..utils import telemetry
+        sched = ops.flash_schedule(q, k, causal, self.attn_window)
+        telemetry.count_path("attn.flash")
+        telemetry.gauge("flash.block_q", sched["block_q"])
+        telemetry.gauge("flash.block_k", sched["block_k"])
+        for kind in ("full", "edge", "skipped"):
+            telemetry.count_path("flash.tiles." + kind, sched[kind])
+
     def _core(self, q, k, v, ctx):
         """softmax(q k^T / sqrt(dh) + mask) v on (b, heads, L, dh), by the
         path the context asks for. Counts ``attn.flash`` / ``attn.dense``
@@ -1359,7 +1372,7 @@ class AttentionLayer(Layer):
                 # O(L)-memory flash kernel for long prompts, instead of
                 # (L, l_max) dense scores against the cache
                 if ops.use_pallas() and ops.flash_supported(L, dh):
-                    telemetry.count_path("attn.flash")
+                    self._count_flash(q, k, True)
                     out = ops.flash_attention(q, k, v, causal=True,
                                               window=self.attn_window)
                 else:
@@ -1450,8 +1463,8 @@ class AttentionLayer(Layer):
             # pallas_call has no GSPMD partitioning rule of its own.
             # GQA: the kernel reads grouped k/v natively (BlockSpec row
             # map) — K/V HBM traffic stays nkvhead-sized
-            telemetry.count_path("attn.flash")
             causal = bool(self.causal)
+            self._count_flash(q, k, causal)
             if mesh is None or ctx.manual_tp:
                 # inside a pipeline stage body the code is ALREADY
                 # per-device (the stage shard_map sliced the microbatch);

@@ -337,6 +337,14 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                                window)
 
 
+def flash_schedule(q, k, causal: bool, window: int = 0) -> dict:
+    """The static tile schedule ``flash_attention`` walks for these
+    operands (ops/flash_attn.schedule): what telemetry's ``flash.*``
+    gauges and counters report."""
+    from . import flash_attn as _fa
+    return _fa.schedule(q, k, causal, window)
+
+
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """Tiles of the grouped product's kernel: rows in 512s (the callers pad
     to it), the contraction and the output columns whole up to 1024 and

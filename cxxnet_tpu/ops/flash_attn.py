@@ -7,20 +7,41 @@ the (L, L) score matrix — Q tiles stay resident while K/V tiles stream
 through VMEM and the softmax is accumulated online (running max + sum, the
 same log-sum-exp carry ring attention uses across devices).
 
-Forward: grid (batch*heads, Lq/block_q, Lk/block_k), K/V innermost so the
-(m, l, acc) carry lives in VMEM scratch across the sequential kv steps;
-the MXU sees (block_q, d) x (d, block_k) and (block_q, block_k) x
-(block_k, d) matmuls. Saves the per-row logsumexp for backward.
+The tile schedule (PR 31; ``_Geom`` holds it, ``_tiling`` sizes it).
+Each kernel's grid is (rows, resident tiles, steps): a resident tile
+meets only the streamed tiles that hold a score it keeps — on or under
+the causal diagonal, inside the sliding window, before the padded tail —
+so ``steps`` is the longest such run, the streamed block's index is the
+run's start plus the step, and a step past the run's end names the block
+already held (nothing moves) and computes nothing. A step's body walks
+its streamed block in ``sub`` columns; each (resident, sub) score tile is
+skipped, taken whole (no iota, compare or select), or masked, by one
+static-shape predicate each: only tiles that straddle the diagonal, the
+window's trailing edge or the tail take ``_mask``. Tiles are sized from
+(L, d, dtype, window) for the chip's VMEM, not a constant.
+
+Forward: grid (batch*heads, q tiles, kv steps); the (m, l, acc) carry
+lives in VMEM scratch across the kv steps, m and l replicated along the
+lanes; the MXU sees (block_q, d) x (d, sub) and (block_q, sub) x (sub, d)
+matmuls. Saves the per-row logsumexp for backward, lane-dense:
+(batch*heads, 1, L).
 
 Backward (FlashAttention-2 factorization): with P = exp(S - lse) the
 gradients are
     dV = Pᵀ dO
     dS = P ∘ (dO Vᵀ - D),  D = rowsum(dO ∘ O)
-    dQ = scale · dS K      (kernel: grid over q tiles, kv streams)
-    dK = scale · dSᵀ Q     (kernel: grid over kv tiles, q streams)
-computed by two kernels that recompute S blockwise from the saved lse;
-D is computed once (fused XLA reduce) and streamed in as (bh, L, 1)
-tiles — O(L) memory end to end.
+    dQ = scale · dS K      (kernel: q tiles resident, kv streams)
+    dK = scale · dSᵀ Q     (kernel: kv tiles resident, q streams)
+computed by two kernels that recompute S blockwise from the saved lse; D
+is computed once (fused XLA reduce) and, like lse, travels as a lane-dense
+(batch*heads, 1, L) row. The dQ kernel turns its rows of lse and D into
+lane-replicated columns once per q tile. The dK/dV kernel works on the
+transposed scores (kv rows, q lanes), so lse and D broadcast along
+sublanes and every product is a plain matmul; its grid rows are the
+key-value heads and its steps run over the group's query heads x the q
+tiles that reach the kv tile, so dK and dV are summed over the group in
+float32 scratch and written once at key-value resolution — O(L) memory
+end to end.
 
 Numerics are golden-tested against the dense reference on CPU
 (interpret=True) in tests/test_flash_attention.py and on the chip by
@@ -32,10 +53,12 @@ reference is the hand-written insanity pooling plan
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 try:
@@ -44,170 +67,351 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()/max() NaN-free
+_LANES = 128
+# what one kernel's blocks, scratch and score tiles may add up to by
+# _vmem_bytes; the compiler is given twice that (a v5e core has 128 MiB)
+VMEM_BUDGET = 32 << 20
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def _mask(s, q_blk, kv_blk, block_q, block_k, causal, kv_len, window=0):
-    """Causal / sliding-window / padded-tail masking of a score tile.
-    kv_len is the true (pre-padding) sequence length — static, so the
-    where() folds away entirely for tile-aligned inputs. window > 0 keeps
-    only the last ``window`` keys per query (requires causal)."""
-    kpos = kv_blk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    keep = kpos < kv_len
-    if causal:
-        qpos = q_blk * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        keep = jnp.logical_and(keep, qpos >= kpos)
-        if window > 0:
-            keep = jnp.logical_and(keep, qpos - kpos < window)
-    return jnp.where(keep, s, NEG_INF)
+def _dot(a, b, dims=_NN):
+    """A product on the MXU: operands in their own dtype (bf16 on the fast
+    path), float32 accumulation. The precision is spelled out: Mosaic
+    refuses a process-wide ``highest`` (tests/conftest.py) for bf16."""
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
 
 
-def _block_needed(causal, q_blk, kv_blk, block_q, block_k, window=0):
-    """False for kv tiles strictly above the causal diagonal, and (with a
-    sliding window) for tiles entirely older than the window — both are
-    skipped wholesale (the flash causal/local speedup). q_blk/kv_blk are
-    traced program ids; window is static."""
-    if not causal:
-        return True
-    need = kv_blk * block_k <= q_blk * block_q + (block_q - 1)
-    if window > 0:
-        # newest key of this tile vs oldest query of the q tile
-        need = jnp.logical_and(
-            need,
-            (q_blk * block_q) - (kv_blk * block_k + block_k - 1) < window)
-    return need
+class _Geom(NamedTuple):
+    """The static geometry of one kernel's tile walk: block sizes, tile
+    counts, and what decides whether a score is kept. Every method takes
+    python / numpy integers (the tests, the tile counts) or traced ones
+    (index maps, kernel bodies): ``xp`` is numpy or jax.numpy."""
+    bq: int
+    bk: int
+    sub: int        # columns of the streamed block one pass of a body takes
+    n_q: int
+    n_k: int
+    causal: bool
+    window: int
+    kv_len: int
+
+    @property
+    def masks(self) -> bool:
+        return self.causal or self.n_k * self.bk > self.kv_len
+
+    def kv_range(self, i, xp=jnp):
+        """First and last kv tile holding a kept score of q tile ``i``."""
+        if not self.causal:
+            return 0 * i, 0 * i + (self.n_k - 1)
+        hi = xp.minimum(((i + 1) * self.bq - 1) // self.bk, self.n_k - 1)
+        lo = 0 * i
+        if self.window > 0:
+            lo = xp.maximum(i * self.bq - self.window + 1, 0) // self.bk
+        return lo, hi
+
+    def q_range(self, j, xp=jnp):
+        """First and last q tile holding a kept score of kv tile ``j``."""
+        if not self.causal:
+            return 0 * j, 0 * j + (self.n_q - 1)
+        lo = (j * self.bk) // self.bq
+        hi = 0 * j + (self.n_q - 1)
+        if self.window > 0:
+            hi = xp.minimum(
+                (self.window + (j + 1) * self.bk - 2) // self.bq, hi)
+        return lo, hi
+
+    def kv_block(self, i, step):
+        """The kv tile q tile ``i`` holds at a grid step: its run's start
+        plus the step, and past the run's end the last one again."""
+        lo, hi = self.kv_range(i)
+        return jnp.minimum(lo + step, hi)
+
+    def q_block(self, j, step):
+        lo, hi = self.q_range(j)
+        return jnp.minimum(lo + step, hi)
+
+    def kv_steps(self) -> int:
+        lo, hi = self.kv_range(np.arange(self.n_q), np)
+        return int(np.max(hi - lo)) + 1
+
+    def q_steps(self) -> int:
+        lo, hi = self.q_range(np.arange(self.n_k), np)
+        return int(np.max(hi - lo)) + 1
+
+    def kind(self, q0, nq, k0, nk, xp=jnp):
+        """(needed, full) of the score tile of queries [q0, q0 + nq) and
+        keys [k0, k0 + nk): it holds a kept score; it holds no other."""
+        needed = k0 < self.kv_len
+        full = k0 + nk <= self.kv_len
+        if self.causal:
+            needed = xp.logical_and(needed, k0 <= q0 + (nq - 1))
+            full = xp.logical_and(full, k0 + (nk - 1) <= q0)
+            if self.window > 0:
+                needed = xp.logical_and(
+                    needed, q0 - (k0 + (nk - 1)) < self.window)
+                full = xp.logical_and(
+                    full, q0 + (nq - 1) - k0 < self.window)
+        return needed, full
+
+    def mask(self, s, q0, k0, k_axis):
+        """NEG_INF on the scores the contract drops; keys run along
+        ``k_axis`` of ``s``. Only edge tiles pay for this."""
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+        keep = kpos < self.kv_len
+        if self.causal:
+            qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                 1 - k_axis)
+            keep = jnp.logical_and(keep, qpos >= kpos)
+            if self.window > 0:
+                keep = jnp.logical_and(keep, qpos - kpos < self.window)
+        return jnp.where(keep, s, NEG_INF)
+
+
+def tile_counts(g: _Geom) -> Tuple[int, int, int]:
+    """(full, edge, skipped) score tiles of (bq, sub) one head's forward
+    grid holds: what telemetry's ``flash.tiles.*`` count."""
+    q0, k0 = np.meshgrid(np.arange(g.n_q) * g.bq,
+                         np.arange(g.n_k * g.bk // g.sub) * g.sub,
+                         indexing="ij")
+    needed, full = g.kind(q0, g.bq, k0, g.sub, np)
+    n_full = int(np.sum(needed & full))
+    n_edge = int(np.sum(needed & ~full))
+    return n_full, n_edge, q0.size - n_full - n_edge
+
+
+def _lanes(x, n: int):
+    """A lane-replicated (rows, 128) statistic at width ``n``."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _to_row(x):
+    """Lane-replicated (n, 128) -> the lane-dense (1, n) row."""
+    return x.T[:1, :]
+
+
+def _to_col(row):
+    """Lane-dense (1, n) row -> lane-replicated (n, 128)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _visit(g: _Geom, q0, nq, k0, nk, live, body):
+    """Run ``body(masked)`` for the score tile at (q0, k0) if the grid
+    step is live and the tile holds a kept score: the plain body where no
+    score is dropped, the masking one where the tile straddles an edge."""
+    if not g.masks:
+        pl.when(live)(functools.partial(body, False))
+        return
+    needed, full = g.kind(q0, nq, k0, nk)
+    needed = jnp.logical_and(live, needed)
+    pl.when(jnp.logical_and(needed, full))(functools.partial(body, False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
+        functools.partial(body, True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                kv_len, padded, window=0):
-    kv_i = pl.program_id(2)
-    n_kv = pl.num_programs(2)
-    q_blk = pl.program_id(1)
+                m_scr, l_scr, acc_scr, *, scale, g):
+    i, t = pl.program_id(1), pl.program_id(2)
+    lo, hi = g.kv_range(i)
+    j = lo + t
+    sub, d = g.sub, q_ref.shape[-1]
 
-    @pl.when(kv_i == 0)
+    @pl.when(t == 0)
     def _():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_block_needed(causal, q_blk, kv_i, block_q, block_k, window))
-    def _():
-        # operands stay in their input dtype (bf16 on the fast MXU path);
-        # every accumulation is f32 via preferred_element_type
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # (bq, bk) f32
-        if causal or padded:
-            s = _mask(s, q_blk, kv_i, block_q, block_k, causal, kv_len,
-                      window)
-        m_prev = m_scr[...]
+    def body(c, masked):
+        q = q_ref[0]
+        k = k_ref[0, c * sub:(c + 1) * sub, :]
+        v = v_ref[0, c * sub:(c + 1) * sub, :]
+        s = _dot(q, k, _NT) * scale                         # (bq, sub) f32
+        if masked:
+            s = g.mask(s, i * g.bq, j * g.bk + c * sub, 1)
+        m_prev = m_scr[...]                                 # (bq, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                              # (bq, bk) f32
+        p = jnp.exp(s - _lanes(m_new, sub))                 # (bq, sub) f32
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + _dot(
+            p.astype(v.dtype), v)
 
-    @pl.when(kv_i == n_kv - 1)
+    for c in range(g.bk // sub):
+        _visit(g, i * g.bq, g.bq, j * g.bk + c * sub, sub, j <= hi,
+               functools.partial(body, c))
+
+    @pl.when(t == pl.num_programs(2) - 1)
     def _():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / _lanes(l, d)).astype(o_ref.dtype)
         # lse = m + log(l): per-row logsumexp for the backward recompute
-        lse_ref[0] = m_scr[...] + jnp.log(l)
+        lse_ref[0] = _to_row(m_scr[...] + jnp.log(l))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, block_q, block_k, kv_len, padded,
-               window=0):
-    kv_i = pl.program_id(2)
-    n_kv = pl.num_programs(2)
-    q_blk = pl.program_id(1)
+               lse_scr, delta_scr, dq_scr, *, scale, g):
+    i, t = pl.program_id(1), pl.program_id(2)
+    lo, hi = g.kv_range(i)
+    j = lo + t
+    sub = g.sub
 
-    @pl.when(kv_i == 0)
+    @pl.when(t == 0)
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        lse_scr[...] = _to_col(lse_ref[0])
+        delta_scr[...] = _to_col(delta_ref[0])
 
-    @pl.when(_block_needed(causal, q_blk, kv_i, block_q, block_k, window))
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal or padded:
-            s = _mask(s, q_blk, kv_i, block_q, block_k, causal, kv_len,
-                      window)
-        p = jnp.exp(s - lse_ref[0])                         # (bq, bk) f32
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - delta_ref[0]) * scale
-        dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
-                               preferred_element_type=jnp.float32)
+    def body(c, masked):
+        q, do = q_ref[0], do_ref[0]
+        k = k_ref[0, c * sub:(c + 1) * sub, :]
+        v = v_ref[0, c * sub:(c + 1) * sub, :]
+        s = _dot(q, k, _NT) * scale
+        if masked:
+            s = g.mask(s, i * g.bq, j * g.bk + c * sub, 1)
+        p = jnp.exp(s - _lanes(lse_scr[...], sub))          # (bq, sub) f32
+        dp = _dot(do, v, _NT)
+        # dS without its scale: dq takes it once, in float32, at the end
+        ds = p * (dp - _lanes(delta_scr[...], sub))
+        dq_scr[...] += _dot(ds.astype(k.dtype), k)
 
-    @pl.when(kv_i == n_kv - 1)
+    for c in range(g.bk // sub):
+        _visit(g, i * g.bq, g.bq, j * g.bk + c * sub, sub, j <= hi,
+               functools.partial(body, c))
+
+    @pl.when(t == pl.num_programs(2) - 1)
     def _():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, block_q, block_k, kv_len, padded,
-                window=0):
-    q_i = pl.program_id(2)
-    n_q = pl.num_programs(2)
-    kv_blk = pl.program_id(1)
+                *, scale, g, steps):
+    j, t = pl.program_id(1), pl.program_id(2)
+    lo, hi = g.q_range(j)
+    i = lo + t % steps      # t runs over the group's heads x the q steps
+    sub = g.sub
 
-    @pl.when(q_i == 0)
+    @pl.when(t == 0)
     def _():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_block_needed(causal, q_i, kv_blk, block_q, block_k, window))
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        delta = delta_ref[0]                                # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # (bq, bk)
-        if causal or padded:
-            s = _mask(s, q_i, kv_blk, block_q, block_k, causal, kv_len,
-                      window)
-        p = jnp.exp(s - lse_ref[0])
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, d)
+    def body(c, masked):
+        # transposed scores: keys on the sublanes, queries on the lanes,
+        # so lse and delta are rows and no product transposes an operand
+        k, v = k_ref[0], v_ref[0]
+        q = q_ref[0, c * sub:(c + 1) * sub, :]
+        do = do_ref[0, c * sub:(c + 1) * sub, :]
+        st = _dot(k, q, _NT) * scale                        # (bk, sub)
+        if masked:
+            st = g.mask(st, i * g.bq + c * sub, j * g.bk, 0)
+        pt = jnp.exp(st - lse_ref[0, :, c * sub:(c + 1) * sub])
+        dv_scr[...] += _dot(pt.astype(do.dtype), do)
+        dpt = _dot(v, do, _NT)
+        dst = pt * (dpt - delta_ref[0, :, c * sub:(c + 1) * sub])
+        dk_scr[...] += _dot(dst.astype(q.dtype), q)
 
-    @pl.when(q_i == n_q - 1)
+    for c in range(g.bq // sub):
+        _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
+               functools.partial(body, c))
+
+    @pl.when(t == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _pick_block(L: int, target: int = 256) -> int:
-    """Sequence tile: lane-aligned (multiple of 128) so the (bq, bk) score
-    tile maps onto the MXU cleanly. Exact divisors are preferred (zero
-    padding); otherwise L is padded up to a multiple of the tile."""
-    for b in (target, 128):
-        if L % b == 0:
-            return b
-    return target if L >= target else 128
+class Tiles(NamedTuple):
+    """One kernel's tiling: q and kv rows a grid step holds, and the
+    columns of the streamed block (kv; q in the dK/dV kernel) one pass of
+    the body takes."""
+    bq: int
+    bk: int
+    sub: int
 
 
-def _padded_len(L: int, block: int) -> int:
-    return -(-L // block) * block
+def _vmem_bytes(kernel: str, t: Tiles, d: int, itemsize: int) -> int:
+    """What the ``fwd``, ``dq`` or ``dkv`` kernel keeps in VMEM with
+    these tiles, by arithmetic: its double-buffered blocks and rows of
+    statistics, its float32 scratch, and the float32 score tiles of one
+    pass (s, p and a mask's select going forward; s, p, dp, ds and their
+    bf16 copies going back)."""
+    lanes = max(d, _LANES)
+    n_q, n_k = {"fwd": (2, 2), "dq": (3, 2), "dkv": (2, 4)}[kernel]
+    blocks = itemsize * lanes * (n_q * t.bq + n_k * t.bk)
+    rows = 0 if kernel == "fwd" else 2 * 8 * 4 * t.bq
+    resident = t.bk if kernel == "dkv" else t.bq
+    scratch = 4 * resident * (
+        2 * lanes if kernel == "dkv" else 2 * _LANES + lanes)
+    scores = 4 * resident * t.sub * (3 if kernel == "fwd" else 5)
+    return 2 * (blocks + rows) + scratch + scores
+
+
+def _fit(L: int, target: int) -> int:
+    """The largest lane-aligned block up to ``target`` that pads L by at
+    most a sixteenth; 128 (and whatever padding that takes) otherwise."""
+    b = target
+    while b > _LANES and -(-L // b) * b - L > L // 16:
+        b //= 2
+    return b
+
+
+def _pow2(x: float, lo: int, hi: int) -> int:
+    """The power of two nearest ``x`` (in ratio) within [lo, hi]."""
+    return min(hi, max(lo, 1 << max(0, round(math.log2(max(x, 1.0))))))
+
+
+def _tiling(L: int, d: int, itemsize: int, window: int
+            ) -> Tuple[Tiles, Tiles, Tiles]:
+    """Tiles of the forward, dQ and dK/dV kernels for one shape, by what
+    the chip read (PERF.md section 5, PR 31: a v5e at L 512, 2,048 and
+    8,192, windows 0 to 4,096). The streamed block is long — the keys a
+    late query sees, up to 1,024 — so that a grid step's products outweigh
+    its fixed cost (2,048 read 2-3% faster still, and doubled the bodies
+    to trace and lower); the resident tile is about half the keys a query
+    sees on average, up to 1,024: larger, and the tiles on the diagonal
+    and the window's edge compute mostly masked scores; the body takes
+    the streamed block in ``sub`` columns, 512 going forward and up to
+    1,024 going back, so that the resident accumulators are revisited
+    seldom and the float32 score tiles stay inside VMEM_BUDGET. Short
+    sequences get blocks that divide them or pad them by under a lane
+    tile. The query group does not enter: the dK/dV kernel's steps grow
+    with it, its tiles do not."""
+    span = min(L, window) if window else L
+    mean = span * (1.0 - span / (2.0 * L))
+    res = _fit(L, _pow2(mean / 2, _LANES, 1024))
+    stream = _fit(L, min(1024, max(_LANES, 1 << (span.bit_length() - 1))))
+    sub_f, sub_b = min(stream, 512), min(stream, max(res, 512))
+
+    def tiles():
+        return (Tiles(res, stream, sub_f), Tiles(res, stream, sub_b),
+                Tiles(stream, res, sub_b))
+
+    def fits():
+        return all(_vmem_bytes(kernel, t, d, itemsize) <= VMEM_BUDGET
+                   for kernel, t in zip(("fwd", "dq", "dkv"), tiles()))
+    # a head size the budget was not read at: the backward's wide pass
+    # goes first, then the streamed block, then the resident tile
+    while not fits() and max(res, stream) > _LANES:
+        if sub_b > sub_f:
+            sub_b //= 2
+        elif stream > _LANES:
+            stream //= 2
+            sub_f, sub_b = min(sub_f, stream), min(sub_b, stream)
+        else:
+            res //= 2
+    return tiles()
 
 
 def supports(L: int, d: int) -> bool:
@@ -216,18 +420,19 @@ def supports(L: int, d: int) -> bool:
     return pltpu is not None and L >= 128 and d % 8 == 0
 
 
-def _dims():
+def _dims(vmem_bytes: Optional[int] = None):
     # the innermost stream dim carries the scratch accumulator across steps:
     # must be sequential ("arbitrary"); batch*heads and the tile dim are
     # parallel (Mosaic may split them over the two TensorCores)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_bytes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, interpret: bool = False,
-                    window: int = 0):
+                    window: int = 0, tiles=None):
     """Memory-O(L) attention. q: (b, h, L, d) -> (b, h, L, d); k/v may
     carry FEWER heads (grouped-query attention, nkv | h): the kernels read
     the shared kv head per query group through the BlockSpec index map, so
@@ -236,9 +441,11 @@ def flash_attention(q, k, v, causal: bool = False,
     Same contract as parallel.attention_reference (incl. sliding
     ``window``, causal-only); the caller gates on supports().
     `interpret=True` runs the kernels in the Pallas interpreter so CPU
-    tests cover the exact kernel code.
+    tests cover the exact kernel code. ``tiles`` is the tests' way to run
+    several tiles at a small L: three ``Tiles`` (forward, dQ, dK/dV) in
+    place of ``_tiling``'s choice.
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, interpret, window)
+    out, _ = _flash_fwd(q, k, v, causal, scale, interpret, window, tiles)
     return out
 
 
@@ -247,11 +454,17 @@ def _merge_bh(x):
     return x.reshape(b * h, L, d)
 
 
-def _pad_seq(x, Lp):
-    L = x.shape[1]
+def _pad_seq(x, Lp, axis=1):
+    L = x.shape[axis]
     if L == Lp:
         return x
-    return jnp.pad(x, ((0, 0), (0, Lp - L), (0, 0)))
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, Lp - L)
+    return jnp.pad(x, pad)
+
+
+def _padded_len(L: int, block: int) -> int:
+    return -(-L // block) * block
 
 
 def _kv_row_map(nh: int, nkv: int):
@@ -265,125 +478,172 @@ def _kv_row_map(nh: int, nkv: int):
     return to_kv
 
 
-def _flash_fwd(q, k, v, causal, scale, interpret, window=0):
+def _geom(t: Tiles, L: int, causal: bool, window: int) -> _Geom:
+    return _Geom(t.bq, t.bk, t.sub, -(-L // t.bq), -(-L // t.bk),
+                 bool(causal), int(window), L)
+
+
+def _params(kernel, t: Tiles, d, dtype, interpret):
+    if interpret:
+        return None
+    return _dims(2 * _vmem_bytes(kernel, t, d, jnp.dtype(dtype).itemsize))
+
+
+def _fwd_call(qf, kf, vf, L, to_kv, t: Tiles, causal, scale, window,
+              interpret):
+    """Forward kernel on merged, padded (rows, Lp, d) arrays: the output
+    and the lane-dense logsumexp (rows, 1, Lpq)."""
+    bh, Lpq, d = qf.shape
+    g = _geom(t, L, causal, window)
+    kv_spec = pl.BlockSpec(
+        (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, g=g),
+        grid=(bh, g.n_q, g.kv_steps()),
+        in_specs=[
+            pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0)),
+            kv_spec, kv_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0)),
+            pl.BlockSpec((1, 1, t.bq), lambda r, i, s: (r, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, Lpq, d), qf.dtype),
+            jax.ShapeDtypeStruct((bh, 1, Lpq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((t.bq, _LANES), jnp.float32),
+            pltpu.VMEM((t.bq, _LANES), jnp.float32),
+            pltpu.VMEM((t.bq, d), jnp.float32),
+        ],
+        compiler_params=_params("fwd", t, d, qf.dtype, interpret),
+        interpret=interpret,
+    )(qf, kf, vf)
+
+
+def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
+             scale, window, interpret):
+    bh, Lpq, d = qf.shape
+    g = _geom(t, L, causal, window)
+    q_spec = pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
+    row_spec = pl.BlockSpec((1, 1, t.bq), lambda r, i, s: (r, 0, i))
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, g=g),
+        grid=(bh, g.n_q, g.kv_steps()),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, Lpq, d), qf.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((t.bq, _LANES), jnp.float32),
+            pltpu.VMEM((t.bq, _LANES), jnp.float32),
+            pltpu.VMEM((t.bq, d), jnp.float32),
+        ],
+        compiler_params=_params("dq", t, d, qf.dtype, interpret),
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, delta)
+
+
+def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
+              scale, window, interpret):
+    """dK and dV at key-value resolution: grid rows are the kv heads, the
+    streamed dimension runs over the group's query heads x the q tiles
+    that reach the resident kv tile."""
+    bkv, Lpk, d = kf.shape
+    g = _geom(t, L, causal, window)
+    steps = g.q_steps()
+
+    # step s: query head s // steps of kv head r's group, its q tile
+    q_spec = pl.BlockSpec((1, t.bq, d), lambda r, j, s: (
+        r * grp + s // steps, g.q_block(j, s % steps), 0))
+    row_spec = pl.BlockSpec((1, 1, t.bq), lambda r, j, s: (
+        r * grp + s // steps, 0, g.q_block(j, s % steps)))
+    kv_spec = pl.BlockSpec((1, t.bk, d), lambda r, j, s: (r, j, 0))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, g=g, steps=steps),
+        grid=(bkv, g.n_k, grp * steps),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((bkv, Lpk, d), kf.dtype),
+            jax.ShapeDtypeStruct((bkv, Lpk, d), vf.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((t.bk, d), jnp.float32),
+            pltpu.VMEM((t.bk, d), jnp.float32),
+        ],
+        compiler_params=_params("dkv", t, d, qf.dtype, interpret),
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, delta)
+
+
+def _shape_tiles(q, k, window, tiles):
     b, h, L, d = q.shape
-    nkv = k.shape[1]
-    assert h % nkv == 0, "query heads must be a multiple of kv heads"
+    assert h % k.shape[1] == 0, "query heads must be a multiple of kv heads"
+    if tiles is None:
+        tiles = _tiling(L, d, jnp.dtype(q.dtype).itemsize, window)
+    return tiles
+
+
+def schedule(q, k, causal: bool, window: int = 0) -> dict:
+    """What ``flash_attention`` will do with these (b, heads, L, d)
+    operands, from their shapes alone: the forward kernel's q tile and the
+    kv columns one pass of its body takes (``block_q``, ``block_k``), and
+    how many such score tiles of one head's grid hold no masked score,
+    straddle an edge, or are never visited (``full``, ``edge``,
+    ``skipped``)."""
+    fwd = _shape_tiles(q, k, window, None)[0]
+    full, edge, skipped = tile_counts(_geom(fwd, q.shape[2], causal, window))
+    return {"block_q": fwd.bq, "block_k": fwd.sub, "full": full,
+            "edge": edge, "skipped": skipped}
+
+
+def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None):
+    b, h, L, d = q.shape
     if scale is None:
         scale = d ** -0.5
     assert window == 0 or causal, "window attention requires causal"
-    bq = bk = _pick_block(L)
-    Lp = _padded_len(L, bq)
-    qf = _pad_seq(_merge_bh(q), Lp)
-    kf, vf = (_pad_seq(_merge_bh(t), Lp) for t in (k, v))
-    bh = b * h
-    to_kv = _kv_row_map(h, nkv)
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk, kv_len=L,
-                             padded=Lp > L, window=window)
-    out, lse = pl.pallas_call(
-        kern,
-        grid=(bh, Lp // bq, Lp // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda g, i, j: (to_kv(g), j, 0)),
-            pl.BlockSpec((1, bk, d), lambda g, i, j: (to_kv(g), j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda g, i, j: (g, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, Lp, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, Lp, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ] if pltpu is not None else [],
-        compiler_params=None if interpret else _dims(),
-        interpret=interpret,
-    )(qf, kf, vf)
+    t = _shape_tiles(q, k, window, tiles)[0]
+    qf = _pad_seq(_merge_bh(q), _padded_len(L, t.bq))
+    kf, vf = (_pad_seq(_merge_bh(x), _padded_len(L, t.bk)) for x in (k, v))
+    out, lse = _fwd_call(qf, kf, vf, L, _kv_row_map(h, k.shape[1]), t,
+                         causal, scale, window, interpret)
     out = out[:, :L].reshape(b, h, L, d)
-    return out, (q, k, v, out, lse)
+    # the residual is trimmed to L: the backward pads to its own tiles
+    return out, (q, k, v, out, lse[:, :, :L])
 
 
-def _flash_bwd(causal, scale, interpret, window, res, g):
+def _flash_bwd(causal, scale, interpret, window, tiles, res, g):
     q, k, v, out, lse = res
     b, h, L, d = q.shape
     nkv = k.shape[1]
-    grp = h // nkv
     if scale is None:
         scale = d ** -0.5
-    bq = bk = _pick_block(L)
-    Lp = _padded_len(L, bq)
-    qf = _pad_seq(_merge_bh(q), Lp)
-    kf, vf = (_pad_seq(_merge_bh(t), Lp) for t in (k, v))
-    dof, of = (_pad_seq(_merge_bh(t), Lp) for t in (g, out))
-    bh = b * h
-    to_kv = _kv_row_map(h, nkv)
+    _, t_dq, t_dkv = _shape_tiles(q, k, window, tiles)
     # D = rowsum(dO ∘ O), computed once here (cheap elementwise + reduce,
-    # XLA fuses it) and streamed to both kernels as a (bh, Lp, 1) tile
-    # input; padded rows have dO = 0 so their D is 0 and every padded-row
+    # XLA fuses it) and given to both kernels as a lane-dense row like
+    # lse; padded rows have dO = 0 so their D is 0 and every padded-row
     # contribution to dk/dv vanishes
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    # the saved lse residual is already padded: (bh, Lp, 1)
+    delta = jnp.sum(_merge_bh(g).astype(jnp.float32)
+                    * _merge_bh(out).astype(jnp.float32),
+                    axis=-1)[:, None, :]
 
-    q_spec_i = pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, i, 0))
-    kv_spec_j = pl.BlockSpec((1, bk, d), lambda g_, i, j: (to_kv(g_), j, 0))
-    lse_spec_i = pl.BlockSpec((1, bq, 1), lambda g_, i, j: (g_, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, kv_len=L, padded=Lp > L,
-                          window=window),
-        grid=(bh, Lp // bq, Lp // bk),
-        in_specs=[q_spec_i, kv_spec_j, kv_spec_j, q_spec_i,
-                  lse_spec_i, lse_spec_i],
-        out_specs=q_spec_i,
-        out_shape=jax.ShapeDtypeStruct((bh, Lp, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-        ] if pltpu is not None else [],
-        compiler_params=None if interpret else _dims(),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+    def padded(t):
+        Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
+        qf, dof = (_pad_seq(_merge_bh(x), Lq) for x in (q, g))
+        kf, vf = (_pad_seq(_merge_bh(x), Lk) for x in (k, v))
+        return (qf, kf, vf, dof, _pad_seq(lse, Lq, 2),
+                _pad_seq(delta, Lq, 2))
 
-    # dkv: kv tiles are the resident (parallel) dim, q tiles stream. With
-    # GQA the kernel reads k/v via the grouped row map but WRITES dk/dv at
-    # query-head resolution (each grid row owns its output row — no race
-    # across the parallel dim); the group-sum to kv resolution happens
-    # outside as one XLA reduce
-    q_spec_s = pl.BlockSpec((1, bq, d), lambda g_, j, i: (g_, i, 0))
-    kv_spec_in = pl.BlockSpec((1, bk, d),
-                              lambda g_, j, i: (to_kv(g_), j, 0))
-    kv_spec_r = pl.BlockSpec((1, bk, d), lambda g_, j, i: (g_, j, 0))
-    lse_spec_s = pl.BlockSpec((1, bq, 1), lambda g_, j, i: (g_, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, kv_len=L, padded=Lp > L,
-                          window=window),
-        grid=(bh, Lp // bk, Lp // bq),
-        in_specs=[q_spec_s, kv_spec_in, kv_spec_in, q_spec_s,
-                  lse_spec_s, lse_spec_s],
-        out_specs=[kv_spec_r, kv_spec_r],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, Lp, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, Lp, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ] if pltpu is not None else [],
-        compiler_params=None if interpret else _dims(),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
-
-    dq = dq[:, :L].reshape(b, h, L, d)
-    dk = dk[:, :L].reshape(b, nkv, grp, L, d).sum(axis=2)
-    dv = dv[:, :L].reshape(b, nkv, grp, L, d).sum(axis=2)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    dq = _dq_call(*padded(t_dq), L, _kv_row_map(h, nkv), t_dq, causal,
+                  scale, window, interpret)
+    dk, dv = _dkv_call(*padded(t_dkv), L, h // nkv, t_dkv, causal, scale,
+                       window, interpret)
+    return (dq[:, :L].reshape(b, h, L, d),
+            dk[:, :L].reshape(b, nkv, L, d),
+            dv[:, :L].reshape(b, nkv, L, d))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

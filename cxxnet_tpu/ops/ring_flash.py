@@ -42,7 +42,17 @@ try:
 except ImportError:  # pragma: no cover
     pltpu = None
 
-from .flash_attn import NEG_INF, _dims, _pick_block
+from .flash_attn import NEG_INF, _dims
+
+
+def _pick_block(L: int, target: int = 256) -> int:
+    """Sequence tile: lane-aligned (multiple of 128) so the (bq, bk) score
+    tile maps onto the MXU cleanly; ring shards are uniform, so an exact
+    divisor always exists (supports())."""
+    for b in (target, 128):
+        if L % b == 0:
+            return b
+    return target if L >= target else 128
 
 
 def supports(sq: int, skv: int, d: int) -> bool:

@@ -25,7 +25,9 @@ arxiv 1802.04799). This module is that measurement substrate:
 * **paths** — ``telemetry.count_path("moe.sparse")`` is a counter that
   also writes a small always-on account (``paths()``), for which lowering
   a layer took when its step was traced (``moe.sparse`` / ``moe.dense``,
-  ``attn.flash`` / ``attn.dense``, once per traced layer): like the phase
+  ``attn.flash`` / ``attn.dense``, once per traced layer; beside
+  ``attn.flash`` the tiles its static schedule holds, ``flash.tiles.full``
+  / ``.edge`` / ``.skipped``): like the phase
   account it is the process's and outlives ``reset()``, so that a run that
   enabled nothing can still say what it timed.
 * **counters / gauges** — ``telemetry.count("train.images", n)`` accumulates
@@ -621,10 +623,10 @@ class _Registry:
         with self._lock:
             return dict(self.phase_s)
 
-    def count_path(self, name: str) -> None:
+    def count_path(self, name: str, n: int = 1) -> None:
         with self._lock:
-            self.path_n[name] = self.path_n.get(name, 0) + 1
-        self.count(name)
+            self.path_n[name] = self.path_n.get(name, 0) + n
+        self.count(name, n)
 
     def paths(self) -> Dict[str, int]:
         with self._lock:
@@ -1423,9 +1425,9 @@ def count(name: str, n=1) -> None:
     _REG.count(name, n)
 
 
-def count_path(name: str) -> None:
+def count_path(name: str, n: int = 1) -> None:
     """A counter that also writes the always-on path account."""
-    _REG.count_path(name)
+    _REG.count_path(name, n)
 
 
 def paths() -> Dict[str, int]:

@@ -13,7 +13,9 @@ profiler; the file lands at ``<out>/<workload>.xplane.pb`` and the last line
 printed gives each group's seconds (what tracing costs while it is on) and
 ``phases``, the program's phase account (``telemetry.phases()``): what
 ``init_model`` and the step's first call took, the latter with jax's own
-trace / lower / compile / cache_load beside it. With ``--no-trace`` nothing
+trace / lower / compile / cache_load beside it, and ``paths``, its path
+account (``telemetry.paths()``: which lowering each traced layer took and,
+for the flash kernels, the tiles their schedule holds). With ``--no-trace`` nothing
 is traced and the compile cache is the process's usual one, as the
 benchmark's set-up finds it: run it twice, and the second line is the split
 of ``step_build_s`` on a warm cache.
@@ -87,6 +89,7 @@ def main(argv=None) -> int:
     line = {"workload": args.workload, "input_shape": cfg.get("input_shape"),
             "rows_per_chip": cfg["batch_per_chip"],
             "first_step_s": first_step_s, "phases": telemetry.phases(),
+            "paths": telemetry.paths(),
             "device": {"platform": d.platform, "kind": d.device_kind,
                        "count": chips}}
     if args.no_trace:

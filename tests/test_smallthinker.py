@@ -350,6 +350,52 @@ def test_head_dim_is_checked_with_a_message_that_names_the_key():
         lay.infer_shape([(2, 12, 1, L)])        # head size 3: odd
 
 
+@pytest.mark.parametrize("window", [0, 160])
+def test_the_flash_path_counts_its_tile_schedule_once_a_layer(window):
+    """Forced through the interpreter at L 256: ``attn.flash`` once, the
+    forward tile as gauges, one head's tiles by kind in the path account;
+    the dense path leaves all of it alone."""
+    L2 = 256
+    lay = AttentionLayer()
+    for k, v in {"nhead": 4, "nkvhead": 2, "head_dim": 16, "causal": 1,
+                 "attn_window": window}.items():
+        lay.set_param(k, str(v))
+    lay.infer_shape([(1, D, 1, L2)])
+    w = _attention_weights(lay)
+    x = jnp.asarray(np.random.RandomState(6).randn(1, D, 1, L2), jnp.float32)
+
+    def delta(force):
+        before = telemetry.paths()
+        telemetry.enable()
+        ops.set_use_pallas(force)
+        try:
+            y, = lay.apply(w, [x], ApplyContext(train=True))
+            gauges = telemetry.summary()["gauges"]
+        finally:
+            ops.set_use_pallas(None)
+            telemetry.disable()
+            telemetry.reset()
+        return np.asarray(y), gauges, {
+            k: n - before.get(k, 0) for k, n in telemetry.paths().items()
+            if n != before.get(k, 0)}
+
+    y_flash, gauges, paths = delta(True)
+    sched = ops.flash_schedule(jnp.zeros((1, 4, L2, 16)),
+                               jnp.zeros((1, 2, L2, 16)), True, window)
+    bq, bk = sched["block_q"], sched["block_k"]
+    assert L2 % bq == 0 and L2 % bk == 0
+    assert gauges["flash.block_q"] == bq and gauges["flash.block_k"] == bk
+    kinds = {k: sched[k] for k in ("full", "edge", "skipped")}
+    assert sum(kinds.values()) == (L2 // bq) * (L2 // bk)
+    assert kinds["edge"] >= L2 // max(bq, bk)      # the diagonal's tiles
+    assert paths == dict({"attn.flash": 1}, **{
+        "flash.tiles." + k: n for k, n in kinds.items() if n})
+    y_dense, gauges, paths = delta(False)
+    assert paths == {"attn.dense": 1}
+    assert not any(k.startswith("flash.") for k in gauges)
+    np.testing.assert_allclose(y_flash, y_dense, rtol=2e-4, atol=2e-5)
+
+
 # ------------------------------------------------------------- the recipe
 def test_the_builder_writes_the_published_model():
     layers, glob = netconf.parse(models.smallthinker_conf())

@@ -74,6 +74,22 @@ def test_counter_and_gauge_aggregation():
     assert s["gauges"]["hbm"] == 7
 
 
+def test_path_account_adds_a_count_on_or_off():
+    """``count_path(name, n)``: the always-on account takes the count
+    whether telemetry is enabled or not (the flash kernels' tile counts
+    ride on it beside ``attn.flash``); the counter only when enabled."""
+    before = telemetry.paths()
+    telemetry.count_path("test.tiles", 56)
+    telemetry.enable()
+    telemetry.count_path("test.tiles", 16)
+    telemetry.count_path("test.path")
+    paths = telemetry.paths()
+    assert paths["test.tiles"] - before.get("test.tiles", 0) == 72
+    assert paths["test.path"] - before.get("test.path", 0) == 1
+    s = telemetry.summary()
+    assert s["counters"]["test.tiles"] == 16 and s["paths"] == paths
+
+
 def test_summary_span_stats():
     telemetry.enable()
     for _ in range(5):

@@ -108,3 +108,36 @@ def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(one_chip):
     got = compiled.cost_analysis()["flops"]
     # every expert on every token would read held / k = 4 times the pairs
     assert want <= got < 2 * want, (got, want)
+
+
+# the language-model cell's attention core (PR 31): 28 query heads on 4
+# key-value heads of 128 over 8,192 tokens, bf16, the global layer and a
+# 4,096 window. Mosaic has to take the tiles `_tiling` chooses (blocks of
+# (1, 1, bq) statistics rows, their transposes, score tiles of up to
+# 1,024 x 1,024 float32 under the VMEM limit the kernels ask for), and the
+# backward has to be the three kernels alone: dK / dV leave the kernel at
+# key-value resolution, so no sum over the group stands beside it
+@pytest.mark.parametrize("window", [0, 4096])
+def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
+    from cxxnet_tpu.ops import flash_attn
+    q = jax.ShapeDtypeStruct((1, 28, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    for kernel, t in zip(("fwd", "dq", "dkv"),
+                         flash_attn._tiling(8192, 128, 2, window)):
+        assert flash_attn._vmem_bytes(kernel, t, 128, 2) \
+            <= flash_attn.VMEM_BUDGET
+
+    def both(q_, k_, v_, do_):
+        out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
+            a, b, c, True, None, False, window), q_, k_, v_)
+        return (out,) + vjp(do_)
+    compiled = jax.jit(both).lower(q, kv, kv, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert [o.shape for o in compiled.out_info] == [
+        q.shape, q.shape, kv.shape, kv.shape]
+    # nothing of query-head size is summed after the kernels
+    assert not re.search(r"bf16\[\d+,7,8192,128\]", text), text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

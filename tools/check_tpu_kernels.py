@@ -12,6 +12,7 @@ of the benchmark's cells).
 Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
 """
 
+import functools
 import os
 import sys
 
@@ -219,6 +220,7 @@ def main():
                     np.asarray(a, np.float32), np.asarray(b, np.float32),
                     rtol=1e-1, atol=1e-1)
         print("flash attention L=%d bf16 fwd + dq + dk/dv: OK" % L)
+    _check_flash_at_the_cells_shape(rs)
 
     # --- ring-step flash kernels, compiled ---
     # a 1-device sp mesh exercises the full kernel set (SMEM offsets,
@@ -395,6 +397,62 @@ dev = tpu
     print("depthwise (ngroup=C) conv train step on-chip: OK")
 
     print("ALL TPU KERNEL CHECKS PASSED")
+
+
+def _check_flash_at_the_cells_shape(rs):
+    """The language-model cell's attention (`smallthinker-ep4-train-8k`:
+    28 query heads on 4 key-value heads of 128, 8,192 tokens, bf16, the
+    global layer and a 4,096 window): forward and the three gradients
+    against the dense reference, one key-value head's group at a time
+    (its float32 scores are 1.9 GB), then the kernels' time and their
+    rate by the mask's FLOPs, so that a reader has both without a trace."""
+    import time
+    from benchmark import lm_flops
+    from cxxnet_tpu.ops import flash_attn
+    from cxxnet_tpu.parallel.ring import attention_reference
+    nh, nkv, L, d = 28, 4, 8192, 128
+    grp = nh // nkv
+    q, do = (jnp.asarray(rs.randn(1, nh, L, d), jnp.bfloat16)
+             for _ in range(2))
+    k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.bfloat16)
+            for _ in range(2))
+
+    def ms(fn, *args, n=5):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    for window in (0, 4096):
+        def both(attend, q_, k_, v_, do_):
+            out, vjp = jax.vjp(attend, q_, k_, v_)
+            return (out,) + vjp(do_)
+        flash = jax.jit(functools.partial(both, lambda q_, k_, v_: (
+            flash_attn.flash_attention(q_, k_, v_, True, None, False,
+                                       window))))
+        dense = jax.jit(functools.partial(both, lambda q_, k_, v_: (
+            attention_reference(q_, k_, v_, causal=True, window=window))))
+        got = flash(q, k, v, do)
+        for h in range(nkv):
+            rows = slice(h * grp, (h + 1) * grp)
+            want = dense(q[:, rows], k[:, h:h + 1], v[:, h:h + 1],
+                         do[:, rows])
+            for a, b in zip(got, want):
+                a = a[:, rows] if a.shape[1] == nh else a[:, h:h + 1]
+                np.testing.assert_allclose(
+                    np.asarray(a, np.float32), np.asarray(b, np.float32),
+                    rtol=1e-1, atol=1e-1)
+        fwd = jax.jit(lambda q_, k_, v_: flash_attn.flash_attention(
+            q_, k_, v_, True, None, False, window))
+        flops = lm_flops.flash_attention(L, nh, nkv, d, window)["flops"]
+        t_f, t_fb = ms(fwd, q, k, v), ms(flash, q, k, v, do)
+        print("flash attention at the cell's shape, window=%d: OK; forward "
+              "%.2f ms = %.1f TFLOP/s, forward + backward %.2f ms = %.1f "
+              "TFLOP/s (by the mask's FLOPs, x1 and x3.5)"
+              % (window, t_f, flops / t_f / 1e9, t_fb,
+                 3.5 * flops / t_fb / 1e9))
 
 
 if __name__ == "__main__":
