@@ -23,8 +23,7 @@ import jax.numpy as jnp
 from .. import ops
 from ..layer import factory
 from ..layer.base import ApplyContext, LabelInfo, Layer, check
-from ..layer.layers import (AvgPoolingLayer, ConvolutionLayer,
-                            MaxPoolingLayer, SplitLayer, SumPoolingLayer)
+from ..layer.layers import ConvolutionLayer, SplitLayer
 from ..utils import serializer
 from .config import NetConfig
 
@@ -47,7 +46,6 @@ class NeuralNet:
                  input_scale: float = 1.0,
                  input_mean=None,
                  fuse_siblings: bool = True,
-                 fuse_cross_1x1: bool = False,
                  channels_last: bool = False):
         """infer_shapes=False skips shape inference entirely — used for the
         weight-copy (finetune) path, which only deserializes params and never
@@ -67,7 +65,7 @@ class NeuralNet:
         channels_last=True runs the conv stack's activations in the
         TPU-preferred (N, H, W, C) layout on device (trainer key
         ``channels_last``; measured +24% raw-jax on the inception topology,
-        tools/layout_experiment.py). Logical node shapes, params, model
+        doc/performance.md). Logical node shapes, params, model
         files, and every user-visible tensor stay reference-NCHW: the
         forward loop tracks a per-node physical layout, feeds channels-last
         to layers declaring layout_support "nhwc"/"any", auto-converts
@@ -77,10 +75,8 @@ class NeuralNet:
         self.max_batch = batch_size
         self.compute_dtype = compute_dtype
         self.fuse_siblings = fuse_siblings
-        self.fuse_cross_1x1 = bool(fuse_cross_1x1)
         self.channels_last = bool(channels_last)
         self._fuse_plan: Optional[Dict[int, List[int]]] = None
-        self._cross_plan: Optional[Dict[int, Tuple[int, int]]] = None
         self.input_scale = float(input_scale)
         self.input_mean = None if input_mean is None else \
             np.asarray(input_mean, np.float32)
@@ -242,9 +238,8 @@ class NeuralNet:
         return groups
 
     def _fusion_graph_tools(self):
-        """(immutable, chain) closures shared by the fusion planners —
-        ONE definition of the value-safety rules both plans rest on
-        (pinned by tests/test_fusion.py MUTATED_CONF):
+        """(immutable, chain): the value-safety rules the sibling plan
+        rests on (pinned by tests/test_fusion.py MUTATED_CONF):
 
         immutable(n): the node's value never changes after its first
         definition — at most one writer (a second writer is a self-loop
@@ -284,82 +279,6 @@ class NeuralNet:
                 n = alias[n]
 
         return immutable, chain
-
-    def _cross_1x1_plan(self) -> Dict[int, Tuple[List[int], int, int]]:
-        """Cross-INPUT 1x1 batching (opt-in, config ``fuse_cross_1x1``):
-        pair a (possibly sibling-fused) group of 1x1 convs reading node
-        n0 with an inception pool-projection — a shape-preserving pool of
-        n0 followed by its own 1x1 conv. The two matmuls have different
-        INPUTS (x vs pool(x)) so concat-fusion cannot merge them, but
-        stacked as one batched matmul they hit the MXU in a single call
-        (the round-3/4 "cross-geometry fusion" lever for the inception
-        towers' ~23% MFU). Keyed by the 1x1 group leader; value =
-        (group_members, pool_layer, proj_layer)."""
-        if self._cross_plan is not None:
-            return self._cross_plan
-        plan: Dict[int, Tuple[List[int], int, int]] = {}
-        cfg = self.cfg
-        if self.fuse_cross_1x1:
-            sib = self._sibling_conv_plan()
-            immutable, chain = self._fusion_graph_tools()
-
-            def is_1x1(j):
-                lay = self.layers[j]
-                info = cfg.layers[j]
-                if (self.is_shared[j] or type(lay) is not ConvolutionLayer
-                        or len(info.nindex_in) != 1
-                        or len(info.nindex_out) != 1):
-                    return False
-                p = lay.param
-                return (p.kernel_height == 1 and p.kernel_width == 1
-                        and p.stride == 1 and p.pad_y == 0 and p.pad_x == 0
-                        and p.num_group == 1)
-
-            # leaders: sibling groups of 1x1s, or lone 1x1s
-            leaders: Dict[int, List[int]] = {}
-            grouped = {j for g in sib.values() for j in g}
-            for lead, g in sib.items():
-                if all(is_1x1(j) for j in g):
-                    leaders[lead] = g
-            for i in range(len(self.layers)):
-                if i not in grouped and is_1x1(i):
-                    leaders[i] = [i]
-
-            for lead, g in leaders.items():
-                root = chain(cfg.layers[lead].nindex_in[0])
-                if root is None:
-                    continue
-                p0 = self.layers[lead].param
-                for pl in range(lead + 1, len(self.layers)):
-                    lay_p = self.layers[pl]
-                    info_p = cfg.layers[pl]
-                    if (type(lay_p) not in (MaxPoolingLayer,
-                                            AvgPoolingLayer,
-                                            SumPoolingLayer)
-                            or self.is_shared[pl]
-                            or len(info_p.nindex_in) != 1
-                            or len(info_p.nindex_out) != 1):
-                        continue
-                    if chain(info_p.nindex_in[0]) != root:
-                        continue
-                    if (self.node_shapes[info_p.nindex_out[0]][1:]
-                            != self.node_shapes[info_p.nindex_in[0]][1:]):
-                        continue   # pool must preserve (c, h, w)
-                    if not immutable(info_p.nindex_out[0]):
-                        continue
-                    pj = next(
-                        (j for j in range(pl + 1, len(self.layers))
-                         if is_1x1(j) and cfg.layers[j].nindex_in[0]
-                         == info_p.nindex_out[0]
-                         and immutable(cfg.layers[j].nindex_out[0])
-                         and self.layers[j].param.no_bias == p0.no_bias),
-                        None)
-                    if pj is None:
-                        continue
-                    plan[lead] = (g, pl, pj)
-                    break
-        self._cross_plan = plan
-        return plan
 
     # --- channels-last layout tracking ---
     def _image_like(self, n: int) -> bool:
@@ -437,93 +356,6 @@ class NeuralNet:
                 layouts[out_n] = want
                 off += n
 
-    def _apply_fused_cross(self, g: List[int], pl: int, pj: int,
-                           params, values, layouts, ctx,
-                           base_rng) -> None:
-        """Stacked batched matmul over two DIFFERENT inputs: the 1x1
-        group's input x and the shape-preserving pool(x) feeding the
-        pool-projection 1x1 (see _cross_1x1_plan). The pool layer runs
-        first (its own apply, rng-folded at its own index, exactly as the
-        unfused loop would), then ONE einsum('gmc,gnc->gmn') computes the
-        group concat and the projection together — each batch slice is an
-        independent contraction over C, so per-member numerics are the
-        separate matmuls'. Outputs are sliced to every member's node; the
-        pool's node value is published for any other consumers."""
-        cfg = self.cfg
-        n_in = cfg.layers[g[0]].nindex_in[0]
-        want = ("NHWC" if (self.channels_last and self._image_like(n_in))
-                else "NCHW")
-        x = values[n_in]
-        if layouts[n_in] != want:
-            x = self._relayout(x, layouts[n_in], want)
-            values[n_in] = x
-            layouts[n_in] = want
-        # the pool, applied early (input aliases the group's root, so it
-        # is ready); numerics identical to its in-order application
-        pool_lay = self.layers[pl]
-        pool_info = cfg.layers[pl]
-        ctx.rng = jax.random.fold_in(base_rng, pl)
-        ctx.layer_index = pl
-        ctx.conn_index = pl
-        ctx.channels_last = (want == "NHWC")
-        pool_in = values[pool_info.nindex_in[0]]
-        if layouts[pool_info.nindex_in[0]] != want:
-            pool_in = self._relayout(
-                pool_in, layouts[pool_info.nindex_in[0]], want)
-            values[pool_info.nindex_in[0]] = pool_in
-            layouts[pool_info.nindex_in[0]] = want
-        with jax.named_scope(self.layer_scope(pl)):
-            (pooled,) = pool_lay.apply(params[pl], [pool_in], ctx)
-        values[pool_info.nindex_out[0]] = pooled
-        layouts[pool_info.nindex_out[0]] = want
-
-        members = list(g) + [pj]
-        p0 = self.layers[g[0]].param
-
-        def fused(xv, pv, member_params):
-            c_in = xv.shape[3] if want == "NHWC" else xv.shape[1]
-            wg = jnp.concatenate(
-                [self.layers[j]._kernel_oihw(member_params[k]["wmat"])
-                 .reshape(-1, c_in) for k, j in enumerate(g)], axis=0)
-            wp = self.layers[pj]._kernel_oihw(
-                member_params[-1]["wmat"]).reshape(-1, c_in)
-            n_max = max(wg.shape[0], wp.shape[0])
-            ws = jnp.stack([
-                jnp.pad(wg, ((0, n_max - wg.shape[0]), (0, 0))),
-                jnp.pad(wp, ((0, n_max - wp.shape[0]), (0, 0)))])
-            def flat(v):
-                if want == "NCHW":
-                    v = jnp.transpose(v, (0, 2, 3, 1))
-                return v.reshape(-1, c_in)
-            xs = jnp.stack([flat(xv), flat(pv)])
-            return jnp.einsum("gmc,gnc->gmn", xs, ws)
-
-        if all(self.layers[j].remat for j in members):
-            fused = jax.checkpoint(fused)
-        b, _, h, w = self.node_shapes[cfg.layers[g[0]].nindex_out[0]]
-        bsz = x.shape[0]
-
-        def publish(j, ym, off):
-            n = self.layers[j].param.num_channel
-            out = ym[:, off:off + n].reshape(bsz, h, w, n)
-            if p0.no_bias == 0:
-                out = out + params[j]["bias"].reshape(1, 1, 1, -1)
-            if want == "NCHW":
-                out = jnp.transpose(out, (0, 3, 1, 2))
-            out_n = cfg.layers[j].nindex_out[0]
-            values[out_n] = out
-            layouts[out_n] = want
-            return off + n
-
-        # one scope for the stacked matmul and its slices, named by all
-        # its members (the pool keeps its own above)
-        with jax.named_scope(self.group_scope(members)):
-            y = fused(x, pooled, [params[j] for j in members])
-            off = 0
-            for j in g:
-                off = publish(j, y[0], off)
-            publish(pj, y[1], 0)
-
     def _apply_remat(self, lay, pidx, p, ins, ctx):
         """jax.checkpoint around a pure layer apply (config key ``remat``):
         the layer's activations are recomputed during the backward pass
@@ -561,20 +393,9 @@ class NeuralNet:
         if layouts is None:
             layouts = ["NCHW"] * cfg.param.num_nodes
         fuse_groups = self._sibling_conv_plan()
-        cross_groups = self._cross_1x1_plan()
         fused_done: set = set()
         for i in range(lo, hi):
             if i in fused_done:
-                continue
-            cp = cross_groups.get(i)
-            if (cp is not None and max(cp[0][-1], cp[2]) < hi
-                    and not getattr(ctx, "manual_tp", False)
-                    and ctx.decode_pos is None):
-                g, pl, pj = cp
-                self._apply_fused_cross(g, pl, pj, params, values,
-                                        layouts, ctx, base_rng)
-                fused_done.update(g)
-                fused_done.update((pl, pj))
                 continue
             g = fuse_groups.get(i)
             if g is not None and g[-1] < hi:

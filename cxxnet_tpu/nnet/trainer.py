@@ -109,8 +109,6 @@ class Trainer:
         self.input_scale = 1.0      # device-side input normalization
         self.input_mean = None
         self.fuse_sibling_convs = 1  # sibling-conv fusion pass (net.py)
-        self.fuse_cross_1x1 = 0      # cross-input 1x1 batching (opt-in
-                                     # until the on-chip A/B settles it)
         self.channels_last = -1     # NHWC conv-stack layout: -1 auto
         #                             (on for TPU backends), 0/1 force
         self.fsdp = 0               # ZeRO-3 param sharding over data
@@ -180,8 +178,6 @@ class Trainer:
             self.test_on_server = int(val)
         if name == "fuse_sibling_convs":
             self.fuse_sibling_convs = int(val)
-        if name == "fuse_cross_1x1":
-            self.fuse_cross_1x1 = int(val)
         if name == "channels_last":
             self.channels_last = int(val)
         if name == "fsdp":
@@ -359,7 +355,7 @@ class Trainer:
     def _resolve_channels_last(self) -> bool:
         """channels_last = -1 (auto) turns the NHWC conv-stack layout on
         exactly where it pays: TPU backends (the MXU/VPU want C minor;
-        measured +24% on inception, tools/layout_experiment.py). CPU/GPU
+        measured +24% on inception, doc/performance.md). CPU/GPU
         keep reference NCHW. 0/1 force either way (the ablation knob)."""
         if self.channels_last >= 0:
             return bool(self.channels_last)
@@ -376,7 +372,6 @@ class Trainer:
                              input_scale=self.input_scale,
                              input_mean=self.input_mean,
                              fuse_siblings=bool(self.fuse_sibling_convs),
-                             fuse_cross_1x1=bool(self.fuse_cross_1x1),
                              channels_last=self._resolve_channels_last())
         self._setup_mesh()
         # resolve eval nodes (metric[label,node] -> node id; default last)
@@ -826,7 +821,6 @@ class Trainer:
                              input_scale=self.input_scale,
                              input_mean=self.input_mean,
                              fuse_siblings=bool(self.fuse_sibling_convs),
-                             fuse_cross_1x1=bool(self.fuse_cross_1x1),
                              channels_last=self._resolve_channels_last())
         self._setup_mesh()
         self.eval_nodes = [self.net_cfg.param.num_nodes - 1 if nm is None

@@ -19,7 +19,6 @@ Design notes (TPU):
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Sequence, Tuple
 
 import jax
@@ -43,7 +42,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, stride: int = 1,
            layout: str = "NCHW") -> jnp.ndarray:
     """2-D convolution. x: (N, C, H, W) — or (N, H, W, C) with
     layout="NHWC", the TPU-preferred channels-last activation layout
-    (measured +24% on the inception topology, tools/layout_experiment.py).
+    (measured +24% on the inception topology, doc/performance.md).
     w is always (O, C/groups, KH, KW) OIHW — the reference's storage layout
     — so params, checkpoints, and TP shardings are layout-independent; XLA
     folds the (small) kernel transpose into its conv emitter.
@@ -78,70 +77,6 @@ def _pool_padding(h: int, w: int, k: Tuple[int, int], s: int):
     return (oh, ow), (ph, pw)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _max_pool(x, kernel, stride, padding):
-    """Max pooling whose BACKWARD is a k*k shift-accumulate of equality
-    masks instead of XLA's select-and-scatter. Measured SLOWER on TPU
-    v5lite (GoogLeNet b256 bf16: 2.3k img/s vs 4.6k with
-    select-and-scatter — the 9 input-sized compare/select passes cost
-    more than they save), so this is OPT-IN via CXXNET_POOL=mask; kept
-    because it reproduces the reference's unpool tie semantics exactly —
-    EVERY input equal to the window max receives the full output gradient
-    (mshadow unpool, reference src/layer/pooling_layer-inl.hpp Backprop)
-    — where select-and-scatter picks a single winner per window."""
-    window = (1, 1, kernel[0], kernel[1])
-    strides = (1, 1, stride, stride)
-    return lax.reduce_window(x, -jnp.inf, lax.max, window, strides,
-                             [(0, 0), (0, 0)] + list(padding))
-
-
-def _max_pool_fwd(x, kernel, stride, padding):
-    y = _max_pool(x, kernel, stride, padding)
-    return y, (x, y)
-
-
-def _max_pool_bwd(kernel, stride, padding, res, g):
-    x, y = res
-    n, c, h, w = x.shape
-    (ylo, yhi), (xlo, xhi) = padding
-    s = stride
-    oh, ow = y.shape[2], y.shape[3]
-    xp = jnp.pad(x, ((0, 0), (0, 0), (ylo, yhi), (xlo, xhi)),
-                 constant_values=-jnp.inf)
-    # upsample y/g to the stride lattice (interior zeros never contribute:
-    # their g is zero, so a spurious equality adds zero)
-    interior = ((0, 0, 0), (0, 0, 0), (0, 0, s - 1), (0, 0, s - 1))
-    yu = lax.pad(y, jnp.asarray(-jnp.inf, y.dtype), interior)
-    gu = lax.pad(g, jnp.asarray(0, g.dtype), interior)
-    uh, uw = (oh - 1) * s + 1, (ow - 1) * s + 1
-    hp, wp = xp.shape[2], xp.shape[3]
-    dxp = None
-    for a in range(kernel[0]):
-        for b in range(kernel[1]):
-            xs = xp[:, :, a: a + uh, b: b + uw]
-            contrib = jnp.where(xs == yu, gu, jnp.asarray(0, g.dtype))
-            # pad-and-sum (not .at[].add: overlapping in-place updates
-            # serialize with full-array copies and wreck fusion)
-            part = jnp.pad(contrib, ((0, 0), (0, 0),
-                                     (a, hp - uh - a), (b, wp - uw - b)))
-            dxp = part if dxp is None else dxp + part
-    return (dxp[:, :, ylo: ylo + h, xlo: xlo + w],)
-
-
-_max_pool.defvjp(_max_pool_fwd, _max_pool_bwd)
-
-
-# A fused Pallas max-pool BACKWARD (reference tie semantics in one VMEM
-# pass, replacing select-and-scatter) lived here through r4 and was
-# deleted after its on-chip A/B: GoogLeNet b128 bf16 measured 2,435
-# img/s vs 4,707 with select-and-scatter (onchip_logs/poolab.log, r5) —
-# and it needed three fixes against a moving Mosaic target just to
-# compile (f32-only vector compares, no interior-pad lowering, 16M
-# VMEM stack limits). XLA's select-and-scatter is the fast path on
-# v5lite; CXXNET_POOL=mask below keeps the reference-exact tie
-# semantics available in plain HLO.
-
-
 def pool2d(x: jnp.ndarray, mode: str, kernel: Tuple[int, int], stride: int,
            pad: Tuple[int, int] = (0, 0),
            layout: str = "NCHW") -> jnp.ndarray:
@@ -154,12 +89,10 @@ def pool2d(x: jnp.ndarray, mode: str, kernel: Tuple[int, int], stride: int,
     padding never wins the max. layout="NHWC" pools a channels-last input
     (window over axes 1,2).
 
-    CXXNET_POOL=mask selects the equality-mask custom VJP (_max_pool:
-    reference unpool tie semantics, but measured slower on TPU — see its
-    docstring); the default is XLA's reduce_window autodiff
-    (select-and-scatter backward).
+    The max mode's backward is XLA's select-and-scatter: on a tie one
+    input of the window gets the gradient, where the reference's unpool
+    gives it to every tied input; the gradient's sum is the same.
     """
-    import os
     if layout == "NHWC":
         n, h, w, c = x.shape
     else:
@@ -175,15 +108,6 @@ def pool2d(x: jnp.ndarray, mode: str, kernel: Tuple[int, int], stride: int,
         strides = (1, 1, stride, stride)
         padding = [(0, 0), (0, 0), (py, py + ph), (px, px + pw)]
     if mode == "max":
-        pool_knob = os.environ.get("CXXNET_POOL")
-        if pool_knob == "mask":
-            # the mask VJP kernel is written for NCHW; wrap for NHWC
-            # (opt-in knob — the transposes are acceptable there)
-            if layout == "NHWC":
-                return to_nhwc(_max_pool(to_nchw(x), kernel, stride,
-                                         ((py, py + ph), (px, px + pw))))
-            return _max_pool(x, kernel, stride,
-                             ((py, py + ph), (px, px + pw)))
         return lax.reduce_window(x, -jnp.inf, lax.max, window,
                                  strides, padding)
     elif mode in ("sum", "avg"):
@@ -267,8 +191,8 @@ def lrn_nhwc(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
              knorm: float) -> jnp.ndarray:
     """Channels-last LRN in plain HLO: the cross-channel window sum is a
     reduce_window over the last axis and the backward is jax's autodiff of
-    it. The non-TPU path, the golden model of the fused kernel
-    (pallas_kernels.lrn_nhwc) and what CXXNET_LRN=xla selects."""
+    it. The non-TPU path and the golden model of the fused kernel
+    (pallas_kernels.lrn_nhwc)."""
     salpha = alpha / nsize
     norm = chpool_sum(jnp.square(x), nsize, axis=3) * salpha + knorm
     return x * jnp.power(norm, -beta)
@@ -276,12 +200,10 @@ def lrn_nhwc(x: jnp.ndarray, nsize: int, alpha: float, beta: float,
 
 def lrn_fused(shape, dtype, layout: str = "NCHW") -> bool:
     """Whether ``lrn`` takes a fused Pallas kernel for this input: on a TPU
-    (use_pallas), not CXXNET_LRN=xla, and channels-last only a shape the
-    kernel tiles. LRNLayer asks with the per-device shape: on a mesh the
-    kernel has to run inside shard_map (pallas_call has no partitioning
-    rule)."""
-    import os
-    if not use_pallas() or os.environ.get("CXXNET_LRN") == "xla":
+    (use_pallas), and channels-last only a shape the kernel tiles.
+    LRNLayer asks with the per-device shape: on a mesh the kernel has to
+    run inside shard_map (pallas_call has no partitioning rule)."""
+    if not use_pallas():
         return False
     if layout == "NHWC":
         from . import pallas_kernels
@@ -307,8 +229,7 @@ def lrn(x: jnp.ndarray, nsize: int, alpha: float, beta: float, knorm: float,
     take a fused Pallas kernel (one HBM pass each way, analytic backward,
     the window sum a band product on the MXU): NCHW always, NHWC where
     the shape tiles (batch a multiple of 128, channels of the sublane
-    tile); elsewhere the reduce_window path, which CXXNET_LRN=xla also
-    forces on a TPU (the A/B control). Counts ``lrn.fused`` /
+    tile); elsewhere the reduce_window path. Counts ``lrn.fused`` /
     ``lrn.fallback`` once per traced layer."""
     if not lrn_fused(x.shape, x.dtype, layout):
         return lrn_reduce_window(x, nsize, alpha, beta, knorm, layout)

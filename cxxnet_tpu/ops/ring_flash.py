@@ -22,11 +22,8 @@ K/V block so each block arrives home with every device's contribution.
 
 Validated in interpret mode on CPU against the dense reference
 (tests/test_ring_flash.py) and compiled on the chip by
-tools/check_tpu_kernels.py. Default ON wherever the kernels run (the
-on-chip pass blessed it); CXXNET_RING=dense is the opt-out and
-CXXNET_RING=flash forces the kernel path even off-TPU (Pallas
-interpreter) — see parallel/ring.py _ring_flash_enabled and
-doc/performance.md's knob table.
+tools/check_tpu_kernels.py. Taken wherever Pallas runs and the shape
+tiles: parallel/ring.py _ring_flash_enabled.
 """
 
 from __future__ import annotations

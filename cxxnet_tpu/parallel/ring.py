@@ -252,7 +252,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
 
 
 # ---------------------------------------------------------------------------
-# flash-kernel ring step (opt-in: CXXNET_RING=flash) — ops/ring_flash.py
+# flash-kernel ring step (_ring_flash_enabled) — ops/ring_flash.py
 # runs each ring step's online-softmax update fully in VMEM; backward is a
 # second ring pass (dq accumulates locally, dk/dv travel with their block)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -385,23 +385,13 @@ _ring_flash_local.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def _ring_flash_enabled(sq: int, skv: int, d: int) -> bool:
-    """Default ON wherever the kernels run (validated on-chip by
-    tools/check_tpu_kernels.py); CXXNET_RING=dense is the opt-out.
-    CXXNET_RING=flash still forces the kernel path off-TPU (Pallas
-    interpreter — how the CPU tests execute the exact kernel code)."""
-    import os
-    mode = os.environ.get("CXXNET_RING", "")
-    if mode in ("dense", "off", "0", "xla"):
-        return False
+    """The ring step takes the flash kernels wherever Pallas runs (a TPU,
+    or a test's ops.set_use_pallas(True), which runs them in the
+    interpreter) and the per-device shape tiles; elsewhere the dense
+    step."""
     from .. import ops as _ops
-    if getattr(_ops, "_use_pallas", None) is False:
-        return False   # explicit global kill-switch always wins
-    if not _ops.use_pallas() and mode != "flash":
-        # auto mode follows the global Pallas dispatch (TPU backend, or
-        # tests forcing set_use_pallas(True))
-        return False
     from ..ops import ring_flash as rf
-    return rf.supports(sq, skv, d)
+    return _ops.use_pallas() and rf.supports(sq, skv, d)
 
 
 def ring_attention(q, k, v, mesh: Mesh, *, axis_name: str = "sp",

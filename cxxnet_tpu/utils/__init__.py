@@ -35,8 +35,8 @@ def enable_compile_cache() -> str:
     uses that directory and the program sets no other; where it is not,
     the cache lives at the fixed ``<repo>/.jax_cache`` (the path is part
     of the cache key, so a directory that moves never hits). Called once
-    by every entry point (bin/cxxnet, chip_smoke.py, bench.py, the
-    tools) before the first compile; also starts counting the cache's
+    by every entry point (bin/cxxnet, chip_smoke.py, the tools) before
+    the first compile; also starts counting the cache's
     hits and misses for ``compile_cache_stats``."""
     import os
     import jax

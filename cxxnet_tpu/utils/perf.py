@@ -2,23 +2,21 @@
 cards, MFU & roofline-efficiency gauges, HBM headroom accounting, and an
 on-demand profiler capture guard.
 
-The offline tools already knew how to compute "is it fast / does it
-fit": ``tools/roofline.py`` counts analytic FLOPs against the chip peak,
-``tools/memory_report.py`` compiles a step and reads XLA's
-``memory_analysis()``, ``tools/profile_bench.py`` captures an xprof
-trace. None of that fed the *running* system — production ML infra
-treats cost models as first-class runtime objects (TF's system paper,
-arXiv:1605.08695) and compile-time cost metadata as the optimization
-currency (TVM, arXiv:1802.04799). This module is that runtime spine:
+An offline tool can say "does it fit" (``tools/memory_report.py``
+compiles a step and reads XLA's ``memory_analysis()``) and a profiler
+trace where the time went (``tools/trace_layers.py``). Neither feeds
+the *running* system — production ML infra treats cost models as
+first-class runtime objects (TF's system paper, arXiv:1605.08695) and
+compile-time cost metadata as the optimization currency (TVM,
+arXiv:1802.04799). This module is that runtime spine:
 
 * **DeviceSpec** — the peak-FLOP/s + HBM-bandwidth + HBM-capacity table
   (``DEVICE_SPECS``), keyed by the ``device_kind`` string jax reports
-  for the chip, each entry with the source of its figures. The offline
-  tools consume the SAME table (``tools/roofline.py`` delegates
-  ``peak_flops()`` / ``peak_hbm_bytes()`` here), so live and offline
-  numbers can never disagree. A device that is not in the table is an
-  error, not a default; a CPU backend has no entry, so a CPU run
-  reports no MFU, roofline or headroom figure at all.
+  for the chip, each entry with the source of its figures;
+  ``tools/memory_report.py`` reads the same table. A device that is
+  not in the table is an error, not a default; a CPU backend has no
+  entry, so a CPU run reports no MFU, roofline or headroom figure at
+  all.
 
 * **ProgramCard** — one card per (program name, input-shapes signature)
   the trainer compiles. The recompile detector
@@ -403,8 +401,8 @@ class Ledger:
         """One compile into the flight ring + the warm-grid account,
         with trigger attribution: the active trace context (a serving
         request paying the cliff at prefill) and/or the active compile
-        window (the dispatcher's session-creation / batch-step bracket,
-        a bench phase). Emits the transition-style ``program_compile``
+        window (the dispatcher's session-creation / batch-step bracket).
+        Emits the transition-style ``program_compile``
         JSONL event OUTSIDE the ring lock."""
         reg = self._reg()
         tc = reg.current_trace()
@@ -627,8 +625,8 @@ class Ledger:
             return dict(card)
 
     def drain(self, timeout: float = 10.0) -> bool:
-        """Wait for queued analysis jobs to finish (bench rows and the
-        end-of-run flush want complete cards). True when idle."""
+        """Wait for queued analysis jobs to finish (the end-of-run flush
+        wants complete cards). True when idle."""
         deadline = time.monotonic() + timeout
         with self._cond:
             while self._jobs or self._busy:

@@ -44,7 +44,7 @@ arxiv 1802.04799). This module is that measurement substrate:
   a multihost run is exact bucket-count addition — never re-binning.
   Every span duration additionally feeds the histogram of its span name,
   which is what /metrics serves as Prometheus ``_bucket`` series
-  (utils/statusd.py) and what bench.py's p50/p90/p99 come from.
+  (utils/statusd.py).
 
 Sinks:
 
@@ -54,8 +54,7 @@ Sinks:
   (``write_chrome_trace`` or ``chrome_trace``), loadable in
   chrome://tracing or https://ui.perfetto.dev;
 * an aggregate ``summary()`` dict (per-span totals, counters, compiles,
-  step-time percentiles) — printed by learn_task at end of run and
-  attached to bench.py's emitted JSON.
+  step-time percentiles) — printed by learn_task at end of run.
 
 Disabled (the default) the module is near-zero overhead: ``span()`` returns
 a shared no-op context manager (no allocation), counters are one
@@ -109,7 +108,7 @@ __all__ = [
     "count", "count_path", "paths", "gauge",
     "hist", "event", "record_compile", "jit_watch",
     "sample_device_memory",
-    "flush", "finish", "summary", "brief_summary", "events",
+    "flush", "finish", "summary", "events",
     "recent_events", "last_event", "wall_epoch", "span_event",
     "percentile", "count_by",
     "chrome_trace", "events_to_chrome", "write_chrome_trace",
@@ -852,27 +851,6 @@ class _Registry:
                 "paths": dict(self.path_n),
             }
 
-    def brief_summary(self, top: int = 8,
-                      summary: Optional[dict] = None) -> dict:
-        """Compact per-phase breakdown for embedding in one-line JSON
-        (the bench.py contract): top spans by total time + compile cost.
-        Pass a precomputed ``summary()`` to avoid re-sorting every span's
-        duration history."""
-        s = summary if summary is not None else self.summary()
-        ranked = sorted(s["spans"].items(),
-                        key=lambda kv: -kv[1]["total_s"])[:top]
-        out = {"spans": {name: {"count": a["count"],
-                                "total_s": a["total_s"],
-                                "p50_ms": a["p50_ms"],
-                                "p90_ms": a["p90_ms"],
-                                "p99_ms": a["p99_ms"]}
-                         for name, a in ranked},
-               "compiles": s["compiles"]["count"],
-               "compile_s": s["compiles"]["total_s"]}
-        if s["counters"]:
-            out["counters"] = s["counters"]
-        return out
-
     def finish(self, close: bool = False) -> Optional[dict]:
         """Record the end-of-run summary event, flush the log, and (with a
         log path) write the Chrome-trace export next to it. Returns the
@@ -1253,7 +1231,7 @@ class BooksAuditor:
     "blocks total = free + live + retained", "tenant charges sum to the
     door books", "fleet sums = Σ replica feeds" — checked on a daemon
     sweep and at every /metrics scrape, so every number the request
-    autopsy and the bench rows cite is provably reconciled.
+    autopsy cites is provably reconciled.
 
     A law is a callable ``fn() -> Optional[str]``: ``None`` means the
     books reconcile (or the law could not take a consistent snapshot —
@@ -1334,7 +1312,7 @@ class BooksAuditor:
         return results
 
     def snapshot(self) -> dict:
-        """Point-in-time view for /metrics and bench rows."""
+        """Point-in-time view for /metrics."""
         with self._lock:
             return {"laws": sorted(self._laws),
                     "broken": dict(self._broken),
@@ -1345,7 +1323,7 @@ class BooksAuditor:
     def reset(self) -> None:
         """Clear every latch, emitting the ``broken: 0`` transition for
         each — the operator's acknowledge. ``violations`` stays
-        cumulative (the bench-row feed)."""
+        cumulative."""
         with self._lock:
             cleared = sorted(self._broken)
             self._broken.clear()
@@ -1494,10 +1472,6 @@ def finish(close: bool = False) -> Optional[dict]:
 
 def summary() -> dict:
     return _REG.summary()
-
-
-def brief_summary(top: int = 8, summary: Optional[dict] = None) -> dict:
-    return _REG.brief_summary(top=top, summary=summary)
 
 
 def events() -> List[dict]:

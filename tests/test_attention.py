@@ -381,7 +381,6 @@ class TestGQAParallelPaths:
         # mode on CPU); nkv=2 < nh=4
         q, k, v = self._qkv(b=1, nh=4, nkv=2, L=512, d=16)
         from cxxnet_tpu import ops
-        os.environ["CXXNET_RING"] = "flash"
         ops.set_use_pallas(True)
         try:
             def loss(q_, k_, v_):
@@ -393,7 +392,6 @@ class TestGQAParallelPaths:
             gq, gk, gv = jax.grad(loss, argnums=(0, 1, 2))(
                 jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
         finally:
-            del os.environ["CXXNET_RING"]
             ops.set_use_pallas(None)
         np.testing.assert_allclose(out, self._expanded_ref(q, k, v, True),
                                    rtol=2e-4, atol=2e-4)

@@ -25,16 +25,11 @@ The headline guarantees:
 """
 
 import json
-import os
-import subprocess
-import sys
 import time
 from urllib.error import HTTPError
 from urllib.request import urlopen
 
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from cxxnet_tpu.utils import perf, routerd, servd, statusd, telemetry
 
@@ -354,47 +349,6 @@ def test_router_marks_warming_replica_and_keeps_refreshing(ledger):
         assert rep["warm_pct"] == 100.0
     finally:
         _drain_all(router, fe, ss)
-
-
-# ----------------------------------------------------------------------
-# bench_compare directions for the cold-start family
-def test_bench_compare_cold_start_directions(tmp_path):
-    """Both-directions subprocess pin: the cold-start rows and their
-    sub-fields gate worse-when-HIGHER (seconds-to-useful, capacity
-    dip) while ready_programs_pct gates worse-when-LOWER."""
-    bench = tmp_path / "BENCH_r01.json"
-    base = tmp_path / "BASELINE.json"
-    base.write_text(json.dumps({"published": {
-        "serve_cold_start_to_ready_s": 5.0,
-        "serve_cold_start_to_ready_s.ready_programs_pct": 100.0,
-        "serve_scale_up_to_first_token_s": 1.0,
-        "serve_reload_capacity_dip": 0.2,
-        "serve_reload_capacity_dip.reload_stall_s": 1.0}}))
-
-    def run(rows):
-        bench.write_text("".join(json.dumps(r) + "\n" for r in rows))
-        return subprocess.run(
-            [sys.executable, "tools/bench_compare.py", "--bench",
-             str(bench), "--baseline", str(base)],
-            capture_output=True, text=True, cwd=REPO)
-
-    worse = run([
-        {"metric": "serve_cold_start_to_ready_s", "value": 20.0,
-         "unit": "s", "ready_programs_pct": 50.0},
-        {"metric": "serve_scale_up_to_first_token_s", "value": 4.0,
-         "unit": "s"},
-        {"metric": "serve_reload_capacity_dip", "value": 0.9,
-         "unit": "ratio", "reload_stall_s": 5.0}])
-    assert worse.returncode == 2, worse.stdout
-    assert worse.stdout.count("REGRESSION") == 5, worse.stdout
-    better = run([
-        {"metric": "serve_cold_start_to_ready_s", "value": 2.0,
-         "unit": "s", "ready_programs_pct": 100.0},
-        {"metric": "serve_scale_up_to_first_token_s", "value": 0.5,
-         "unit": "s"},
-        {"metric": "serve_reload_capacity_dip", "value": 0.05,
-         "unit": "ratio", "reload_stall_s": 0.2}])
-    assert better.returncode == 0, better.stdout
 
 
 # ----------------------------------------------------------------------

@@ -110,3 +110,24 @@ def test_dist_worker_corpus_sharding(tmp_path):
     it.set_param("dist_worker_rank", "4")
     with pytest.raises(AssertionError):
         it._parse_image_conf()
+
+
+def test_no_environment_variable_chooses_a_lowering():
+    """The package reads five ``CXXNET_*`` variables, all operational
+    (lock-order checks, the multi-host launch, the native library): which
+    lowering an op takes is decided from the platform, the layout and the
+    shape, and an A/B is two checkouts, never a switch."""
+    import glob
+    import os
+    import re
+    allowed = {"CXXNET_LOCKRANK", "CXXNET_NUM_WORKER", "CXXNET_WORKER_RANK",
+               "CXXNET_TPU_NATIVE", "CXXNET_TPU_NATIVE_LIB"}
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "cxxnet_tpu")
+    found = {}
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path, encoding="utf-8") as f:
+            for name in re.findall(r"\bCXXNET_[A-Z0-9_]+\b", f.read()):
+                found.setdefault(name, os.path.relpath(path, root))
+    assert set(found) == allowed, {k: v for k, v in found.items()
+                                   if k not in allowed}

@@ -276,79 +276,3 @@ def test_fusion_on_data_parallel_mesh():
         _, loss = tr.net.forward(tr.params, x, labels=li, train=False)
         losses.append(float(loss))
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-4)
-
-
-# --- cross-input 1x1 batching (fuse_cross_1x1, net.py _cross_1x1_plan) --
-
-
-def test_cross_plan_pairs_pool_projection():
-    tr = _trainer(MODULE_CONF, "fuse_cross_1x1 = 1\n")
-    plan = tr.net._cross_1x1_plan()
-    assert len(plan) == 1
-    ((lead, (g, pl, pj)),) = plan.items()
-    assert g == _conv_indices(tr, ["b1", "b3r", "c5r"]) and lead == g[0]
-    assert pj == _conv_indices(tr, ["dproj"])[0]
-    assert tr.net.layers[pl].type_name in ("max_pooling",)
-    # off by default
-    assert _trainer(MODULE_CONF).net._cross_1x1_plan() == {}
-
-
-def test_cross_fused_matches_unfused():
-    """Forward loss and every grad leaf match the unfused net (and the
-    sibling-only net) — each batched-matmul slice is an independent
-    contraction, so numerics are the separate convs'."""
-    tr_x = _trainer(MODULE_CONF, "fuse_cross_1x1 = 1\n")
-    tr_s = _trainer(MODULE_CONF)
-    tr_0 = _trainer(MODULE_CONF, "fuse_sibling_convs = 0\n")
-    assert len(tr_x.net._cross_1x1_plan()) == 1
-    rs = np.random.RandomState(5)
-    x = rs.rand(4, 3, 8, 8).astype(np.float32)
-    y = rs.randint(0, 5, (4, 1)).astype(np.float32)
-    lx, gx = _loss_and_grads(tr_x, x, y)
-    ls, gs = _loss_and_grads(tr_s, x, y)
-    l0, g0 = _loss_and_grads(tr_0, x, y)
-    np.testing.assert_allclose(float(lx), float(l0), rtol=1e-6)
-    np.testing.assert_allclose(float(lx), float(ls), rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(gx),
-                    jax.tree_util.tree_leaves(g0)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_cross_fused_matches_channels_last():
-    """The batched path under the TPU layout (NHWC feature maps)."""
-    tr_x = _trainer(MODULE_CONF,
-                    "fuse_cross_1x1 = 1\nchannels_last = 1\n")
-    tr_0 = _trainer(MODULE_CONF, "fuse_sibling_convs = 0\n")
-    rs = np.random.RandomState(6)
-    x = rs.rand(4, 3, 8, 8).astype(np.float32)
-    y = rs.randint(0, 5, (4, 1)).astype(np.float32)
-    lx, gx = _loss_and_grads(tr_x, x, y)
-    l0, g0 = _loss_and_grads(tr_0, x, y)
-    np.testing.assert_allclose(float(lx), float(l0), rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(gx),
-                    jax.tree_util.tree_leaves(g0)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_cross_fused_trains_and_predicts():
-    """Full trainer path: update + predict run the batched-matmul module
-    and track the unfused trainer step for step."""
-    from cxxnet_tpu.io.data import DataBatch
-    tr_x = _trainer(MODULE_CONF, "fuse_cross_1x1 = 1\n")
-    tr_0 = _trainer(MODULE_CONF, "fuse_sibling_convs = 0\n")
-    rs = np.random.RandomState(9)
-    for _ in range(3):
-        b = DataBatch()
-        b.data = rs.rand(4, 3, 8, 8).astype(np.float32)
-        b.label = rs.randint(0, 5, (4, 1)).astype(np.float32)
-        b.batch_size = 4
-        tr_x.update(b)
-        tr_0.update(b)
-    for p_x, p_0 in zip(tr_x.params, tr_0.params):
-        for key in p_0:
-            np.testing.assert_allclose(
-                np.asarray(p_x[key]), np.asarray(p_0[key]),
-                rtol=2e-5, atol=2e-6, err_msg=key)
-    np.testing.assert_allclose(tr_x.predict(b), tr_0.predict(b))

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from cxxnet_tpu import ops
 from cxxnet_tpu.layer import ApplyContext, LabelInfo, factory
 from cxxnet_tpu.layer import layers as L
 
@@ -157,40 +158,61 @@ def test_pooling_matches_numpy(mode, cls, hw, k, s):
     np.testing.assert_allclose(np.asarray(y), expect, rtol=1e-5, atol=1e-6)
 
 
-def test_max_pool_mask_backward():
-    """CXXNET_POOL=mask: the equality-mask custom VJP matches XLA autodiff
-    when there are no ties, and gives the reference's unpool semantics
-    (all tied positions receive the full gradient) when there are."""
-    import os
-    from cxxnet_tpu import ops
+def _np_unpool(x, g_of_y, k, s, p):
+    """Gradient of a ceil-mode max pool over (n, c, h, w) by a plain
+    unpool: pad by p with -inf, and each window's upstream gradient
+    ``g_of_y(max)`` goes to its largest input (the input has no ties)."""
+    n, c, h, w = x.shape
+    oh = ops.pool_out_dim(h + 2 * p, k, s)
+    ow = ops.pool_out_dim(w + 2 * p, k, s)
+    xp = np.full((n, c, max((oh - 1) * s + k, h + 2 * p),
+                  max((ow - 1) * s + k, w + 2 * p)), -np.inf, np.float64)
+    xp[:, :, p:p + h, p:p + w] = x
+    dxp = np.zeros_like(xp)
+    ni, ci = np.indices((n, c))
+    for i in range(oh):
+        for j in range(ow):
+            win = xp[:, :, i * s:i * s + k, j * s:j * s + k].reshape(n, c, -1)
+            a = win.argmax(axis=2)
+            np.add.at(dxp, (ni, ci, i * s + a // k, j * s + a % k),
+                      g_of_y(win.max(axis=2)))
+    return dxp[:, :, p:p + h, p:p + w]
 
-    def grad_of(f, x):
-        return jax.grad(lambda x_: jnp.sum(jnp.sin(f(x_)) * 1.7))(x)
 
-    for (h, w, k, s, p) in [(13, 13, 3, 2, 0), (8, 8, 2, 2, 0),
-                            (14, 14, 3, 1, 1), (7, 9, 3, 3, 0)]:
-        x = rand((2, 3, h, w), seed=7)
-        f = lambda x_: ops.pool2d(x_, "max", (k, k), s, (p, p))
-        ref = grad_of(f, jnp.asarray(x))          # select-and-scatter
-        fwd_ref = np.asarray(f(jnp.asarray(x)))   # default (XLA) path
-        os.environ["CXXNET_POOL"] = "mask"
-        try:
-            got = grad_of(f, jnp.asarray(x))
-            np.testing.assert_array_equal(np.asarray(f(jnp.asarray(x))),
-                                          fwd_ref)
-        finally:
-            del os.environ["CXXNET_POOL"]
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-6)
-    # tie semantics (reference unpool): every max-equal input gets the grad
+@pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
+@pytest.mark.parametrize("h,w,k,s,p", [(13, 13, 3, 2, 0), (8, 8, 2, 2, 0),
+                                       (14, 14, 3, 1, 1), (7, 9, 3, 3, 0)])
+def test_max_pool_backward_matches_numpy_unpool(h, w, k, s, p, layout):
+    """pool2d's max backward (XLA's select-and-scatter) against a plain
+    numpy unpool, on an input without ties."""
+    x = rand((2, 3, h, w), seed=7)
+
+    def loss(x_):
+        return jnp.sum(jnp.sin(ops.pool2d(x_, "max", (k, k), s, (p, p),
+                                          layout=layout)) * 1.7)
+
+    xin = jnp.asarray(x)
+    if layout == "NHWC":
+        xin = ops.to_nhwc(xin)
+    dx = jax.grad(loss)(xin)
+    if layout == "NHWC":
+        dx = ops.to_nchw(dx)
+    want = _np_unpool(x, lambda y: 1.7 * np.cos(y), k, s, p)
+    np.testing.assert_allclose(np.asarray(dx), want, rtol=1e-5, atol=1e-6)
+
+
+def test_max_pool_backward_on_ties_picks_one_winner():
+    """The stated deviation from the reference's unpool (PARITY.md): on a
+    tie select-and-scatter gives the window's gradient to one input, not
+    to every tied one; the gradient's sum is the same."""
     ones = jnp.ones((1, 1, 4, 4), jnp.float32)
-    os.environ["CXXNET_POOL"] = "mask"
-    try:
-        dx = jax.grad(lambda x_: jnp.sum(
-            ops.pool2d(x_, "max", (2, 2), 2)))(ones)
-    finally:
-        del os.environ["CXXNET_POOL"]
-    np.testing.assert_array_equal(np.asarray(dx), np.ones((1, 1, 4, 4)))
+    dx = np.asarray(jax.grad(lambda x_: jnp.sum(
+        ops.pool2d(x_, "max", (2, 2), 2)))(ones))
+    assert dx.sum() == 4.0
+    for i in (0, 2):
+        for j in (0, 2):
+            win = dx[0, 0, i:i + 2, j:j + 2]
+            assert sorted(win.ravel()) == [0.0, 0.0, 0.0, 1.0]
 
 
 def test_relu_max_pooling_fused():

@@ -1,5 +1,6 @@
 """Pallas kernel numerics vs the pure-XLA goldens, run in interpreter mode
-on CPU (the same kernels compile for TPU; bench.py exercises them there)."""
+on CPU (the same kernels compile for TPU; tools/check_tpu_kernels.py
+exercises them there)."""
 
 import numpy as np
 import jax
@@ -155,8 +156,8 @@ class TestLRNChannelsLast:
 
 
 class TestLRNDispatch:
-    """ops.lrn picks by what it can see: platform, layout, shape, and
-    CXXNET_LRN=xla; the counters say which."""
+    """ops.lrn picks by what it can see: platform, layout, shape; the
+    counters say which."""
 
     @pytest.fixture(autouse=True)
     def forced_on(self):
@@ -191,8 +192,8 @@ class TestLRNDispatch:
             np.asarray(out), np.asarray(ops.lrn_nhwc(x, 5, *LRN_ARGS)))
 
     @pytest.mark.parametrize("layout", ["NHWC", "NCHW"])
-    def test_env_xla_takes_reduce_window(self, monkeypatch, layout):
-        monkeypatch.setenv("CXXNET_LRN", "xla")
+    def test_pallas_off_takes_reduce_window(self, layout):
+        ops.set_use_pallas(False)
         x = _nhwc(2, 64, jnp.float32)
         f = lambda v: ops.lrn(v, 5, *LRN_ARGS, layout=layout)  # noqa: E731
         out, counts = self._counts(f, x)

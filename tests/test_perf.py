@@ -1,8 +1,7 @@
 """The live program performance ledger (utils/perf.py): DeviceSpec
 resolution, ProgramCard math from faked XLA analyses, MFU/headroom
 joins, /programz + /metrics rendering, the /profilez capture guard,
-the report's program-ledger section, and the bench/roofline null-row
-accounting — all jax-free except ONE cheap real-jit CPU test pinning
+and the report's program-ledger section — all jax-free except ONE cheap real-jit CPU test pinning
 that a compiled train step actually produces a card."""
 
 import json
@@ -80,15 +79,6 @@ def test_cpu_backend_has_no_spec_and_no_mfu():
     assert lg.decode_pool_cap_bytes(0.5) is None
     assert "device spec: none" in statusd.programz_html(snap)
     lg.disable()
-
-
-def test_roofline_peaks_come_from_the_shared_table():
-    """Satellite: tools/roofline.py must read perf.DEVICE_SPECS — the
-    offline and live numbers can never disagree."""
-    import roofline
-    spec = perf.DEVICE_SPECS[perf.TARGET_DEVICE_KIND]
-    assert roofline.peak_flops() == spec.peak_flops
-    assert roofline.peak_hbm_bytes() == spec.hbm_bw
 
 
 # ----------------------------------------------------------------------
@@ -439,59 +429,6 @@ def test_report_program_ledger_section():
     assert tr.aggregate(events[:1] + events[2:])["programs"] is None
 
 
-def test_roofline_counts_null_bench_rows(tmp_path):
-    import roofline
-    wrapper = {"parsed": {"metric": "alexnet_imagenet", "value": None,
-                          "error": "backend unreachable"},
-               "tail": '{"metric": "alexnet_imagenet", "value": null}\n'
-                       '{"metric": "googlenet_imagenet", "value": 123.0}'
-                       '\n'}
-    p = tmp_path / "BENCH_rX.json"
-    p.write_text(json.dumps(wrapper))
-    rates, n_null = roofline.rates_from_bench([str(p)])
-    assert n_null == 1                       # one METRIC, all-null
-    assert rates == {"googlenet": 123.0}
-    # raw JSONL: repeated rounds keep the BEST rate per model, and a
-    # metric that measured anywhere is not counted as skipped even if
-    # an earlier round was null
-    p2 = tmp_path / "raw.log"
-    p2.write_text('{"metric": "resnet18_imagenet", "value": 50.0}\n'
-                  '{"metric": "resnet18_imagenet", "value": 80.0}\n'
-                  '{"metric": "resnet18_imagenet", "value": 60.0}\n'
-                  '{"metric": "mobilenet_imagenet", "value": null}\n'
-                  '{"metric": "mobilenet_imagenet", "value": 40.0}\n'
-                  '{"metric": "vgg16_imagenet", "value": null}\n')
-    rates, n_null = roofline.rates_from_bench([str(p2)])
-    assert rates == {"resnet18": 80.0, "mobilenet": 40.0}
-    assert n_null == 1                       # only vgg16 never measured
-
-
-def test_bench_compare_prints_null_skip_count(tmp_path, capsys):
-    import bench_compare
-    bench = tmp_path / "BENCH_r09.json"
-    bench.write_text(json.dumps({"parsed": {
-        "metric": "alexnet_imagenet_images_per_sec_per_chip",
-        "value": None, "unit": "images/sec/chip",
-        "error": "backend unreachable"}}))
-    baseline = tmp_path / "BASELINE.json"
-    baseline.write_text(json.dumps({"published": {
-        "alexnet_imagenet_images_per_sec_per_chip": 15047.0}}))
-    rc = bench_compare.main(["--bench", str(bench),
-                             "--baseline", str(baseline)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "1 row(s) skipped: backend unreachable" in out
-    # a measured round with a baseline gates normally, no skip banner
-    bench2 = tmp_path / "BENCH_r10.json"
-    bench2.write_text(json.dumps({"parsed": {
-        "metric": "alexnet_imagenet_images_per_sec_per_chip",
-        "value": 15100.0, "unit": "images/sec/chip"}}))
-    rc = bench_compare.main(["--bench", str(bench2),
-                             "--baseline", str(baseline)])
-    out = capsys.readouterr().out
-    assert rc == 0 and "backend unreachable" not in out
-
-
 # ----------------------------------------------------------------------
 # the ONE real-jit CPU test (everything above is jax-free)
 # ----------------------------------------------------------------------
@@ -551,13 +488,6 @@ def test_real_train_step_produces_a_program_card():
         # the measured join fired (3 train.step spans recorded)
         assert c["measured_n"] >= 3
         assert c["mfu_pct"] is None and c["roofline_eff_pct"] is None
-        # bench.py's row attachment rides the same ledger
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        import bench
-        row = bench._attach_perf({})
-        assert row["hbm_peak_bytes"] and row["hbm_peak_bytes"] > 0
-        assert row["predicted_step_ms"] is None
-        assert row["mfu_pct"] is None
     finally:
         perf.disable()
         perf.reset()

@@ -1,12 +1,10 @@
-"""Flash-kernel ring attention (CXXNET_RING=flash, ops/ring_flash.py).
+"""Flash-kernel ring attention (ops/ring_flash.py).
 
 Runs the exact kernel code on the virtual CPU mesh via the Pallas
 interpreter and goldens it against the dense reference — forward and
 gradients, causal and not. The compiled path is validated on the chip by
 tools/check_tpu_kernels.py.
 """
-
-import os
 
 import numpy as np
 import jax
@@ -32,11 +30,9 @@ def _qkv(b=1, h=2, s=512, d=16, seed=0):
 @pytest.fixture
 def flash_ring_env():
     from cxxnet_tpu import ops
-    os.environ["CXXNET_RING"] = "flash"
     ops.set_use_pallas(True)        # kernels run interpreted on CPU
     yield
     ops.set_use_pallas(None)
-    os.environ.pop("CXXNET_RING", None)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -70,30 +66,26 @@ def test_grads_match_dense(flash_ring_env, causal):
                                    rtol=3e-4, atol=3e-4)
 
 
-def test_disabled_without_env():
-    # without CXXNET_RING=flash the XLA path runs (still correct)
-    os.environ.pop("CXXNET_RING", None)
-    q, k, v = _qkv(seed=3)
-    mesh = _mesh()
-    out = ring.ring_attention(q, k, v, mesh, causal=True)
-    ref = ring.attention_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_pallas_kill_switch_disables(flash_ring_env):
+@pytest.mark.parametrize("seq, tiles", [(512, True), (32, False)],
+                         ids=["shape_tiles", "shape_too_small"])
+@pytest.mark.parametrize("pallas", [True, False],
+                         ids=["pallas_on", "pallas_off"])
+def test_ring_step_choice(pallas, seq, tiles):
+    """The ring step is the flash kernels where Pallas runs AND the
+    per-device block tiles (s / 4 of 128 rows; 8 is under the 128-lane
+    tile), the dense step elsewhere; either way the result is right."""
     from cxxnet_tpu import ops
-    ops.set_use_pallas(False)       # global kernel off-switch wins
-    assert not ring._ring_flash_enabled(128, 128, 16)
-    ops.set_use_pallas(True)
-    assert ring._ring_flash_enabled(128, 128, 16)
-
-
-def test_unsupported_shape_falls_back(flash_ring_env):
-    # s/n = 8 per device: below the 128-lane tile -> XLA path silently
-    q, k, v = _qkv(s=32, seed=4)
+    q, k, v = _qkv(s=seq, seed=3)
     mesh = _mesh()
-    out = ring.ring_attention(q, k, v, mesh, causal=True)
+    ops.set_use_pallas(pallas)
+    try:
+        assert ring._ring_flash_enabled(seq // 4, seq // 4, 16) \
+            == (pallas and tiles)
+        out = ring.ring_attention(q, k, v, mesh, causal=True)
+    finally:
+        ops.set_use_pallas(None)
+    # auto, off the TPU: dense
+    assert not ring._ring_flash_enabled(seq // 4, seq // 4, 16)
     ref = ring.attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -132,25 +124,3 @@ def test_bf16_forward_close_to_f32(flash_ring_env):
     ref = ring.attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref), rtol=0.05, atol=0.05)
-
-
-def test_default_on_when_pallas_active():
-    """The flash ring step is the DEFAULT wherever the kernels run
-    (CXXNET_RING=dense is the opt-out; =flash still forces interpret)."""
-    from cxxnet_tpu import ops
-    os.environ.pop("CXXNET_RING", None)
-    ops.set_use_pallas(True)
-    try:
-        assert ring._ring_flash_enabled(512, 512, 16)
-        assert not ring._ring_flash_enabled(100, 100, 16)  # unsupported shape
-    finally:
-        ops.set_use_pallas(None)
-    os.environ["CXXNET_RING"] = "dense"
-    ops.set_use_pallas(True)
-    try:
-        assert not ring._ring_flash_enabled(512, 512, 16)
-    finally:
-        ops.set_use_pallas(None)
-        os.environ.pop("CXXNET_RING", None)
-    # auto mode off-TPU without forcing: dense
-    assert not ring._ring_flash_enabled(512, 512, 16)

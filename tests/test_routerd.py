@@ -849,9 +849,19 @@ def test_tenant_flood_chaos_headline(make_router):
     requests all serve, its p99 and per-tenant SLO burn hold), the
     autoscaler admits the standby mid-flood, the fleet scales back
     down after the flood — zero silent losses, and the books reconcile
-    per tenant on the router AND fleet-wide."""
+    per tenant on the router AND fleet-wide.
+
+    The fleet is saturated by construction, not by how fast this host
+    runs the clients: the replicas hold their work behind a gate until
+    all six noisy clients have a request in them, ONE probe reads both
+    backlogs, and nothing probes again until the flood is over. (A
+    timed flood against 3 ms of work raced the host: under six xdist
+    workers a client's round trip took 50-90 ms, the replicas never
+    queued, and nothing was shed.)"""
+    gate = threading.Event()
 
     def work(toks, seq):
+        gate.wait(30.0)
         time.sleep(0.003)
         return [t + 1 for t in toks]
 
@@ -860,6 +870,7 @@ def test_tenant_flood_chaos_headline(make_router):
     sb = _inproc_replica(work, queue_size=4, slo=True)
     telemetry.enable()
     stop = threading.Event()
+    flood_over = threading.Event()
     try:
         router = make_router(
             [("127.0.0.1", fe.port, ss.port) for fe, ss in reps],
@@ -876,6 +887,40 @@ def test_tenant_flood_chaos_headline(make_router):
                                                window_s=60.0)
                          for t in ("noisy", "victim")})
         router.probe_now()
+        results = {}
+
+        def flood(name, **kw):
+            # the duration is a cap: flood_over ends the flood
+            results[name] = faultinject.tenant_flood(
+                router.port, name, duration_s=60.0, stop=flood_over,
+                **kw)
+
+        noisy_th = threading.Thread(target=flood, args=("noisy",),
+                                    kwargs={"nclients": 6})
+        victim_th = threading.Thread(target=flood, args=("victim",),
+                                     kwargs={"nclients": 1})
+        noisy_th.start()
+        # every noisy client has one request inside a replica (one in
+        # the gated work, the rest queued behind it): both replicas
+        # hold a backlog, and this probe is the one that reads it
+        wait_until(lambda: sum(fe.stats()["accepted"]
+                               for fe, _ in reps) == 6, timeout=20.0,
+                   msg="six noisy requests inside the replicas")
+        router.probe_now()
+        gate.set()
+        victim_th.start()
+        # the flood runs until both tenants have shown what the test
+        # is about (counters, not a duration)
+        wait_until(lambda: router.tenant_stats().get(
+                       "noisy", {}).get("shed", 0) >= 20
+                   and router.tenant_stats().get(
+                       "victim", {}).get("served", 0) >= 20,
+                   timeout=30.0, msg="noisy shed and victim served")
+        # mid-flood: queued work with zero free slots admits the standby
+        assert router.autoscale_now() == "up"
+        flood_over.set()
+        noisy_th.join()
+        victim_th.join()
 
         def pace():
             # the prober loop, off the clock: probe + federate + one
@@ -889,20 +934,6 @@ def test_tenant_flood_chaos_headline(make_router):
 
         pacer = threading.Thread(target=pace, daemon=True)
         pacer.start()
-        results = {}
-
-        def flood(name, **kw):
-            results[name] = faultinject.tenant_flood(
-                router.port, name, duration_s=1.2, **kw)
-
-        ths = [threading.Thread(target=flood, args=("noisy",),
-                                kwargs={"nclients": 6}),
-               threading.Thread(target=flood, args=("victim",),
-                                kwargs={"nclients": 1})]
-        for t in ths:
-            t.start()
-        for t in ths:
-            t.join()
         noisy, victim = results["noisy"], results["victim"]
         # zero silent losses: every request of BOTH tenants got its
         # one response line
@@ -924,8 +955,8 @@ def test_tenant_flood_chaos_headline(make_router):
         snap = router.scale_snapshot()
         assert snap["events"] >= 1
         assert snap["recent"][0]["action"] == "up", snap["recent"]
-        # ... and retires it once the flood is gone (the pacer keeps
-        # running the loop)
+        # ... and retires it once the flood is gone (the pacer runs
+        # the loop)
         wait_until(lambda: router._replicas[2].standby, timeout=6.0,
                    msg="scale-down after the flood")
         # per-tenant SLO: the noisy tenant burned its own fleet-wide
@@ -953,6 +984,8 @@ def test_tenant_flood_chaos_headline(make_router):
             (rt["noisy"], noisy)
     finally:
         stop.set()
+        flood_over.set()
+        gate.set()
         telemetry.disable()
         for fe, ss in reps + [sb]:
             fe.drain(timeout_ms=2000)

@@ -2139,7 +2139,7 @@ def test_paged_kv_exhaustion_deterministic_queue_wait(make_frontend):
     assert stats["accepted"] == stats["served"] == 8
 
 
-def test_paged_kv_exhaustion_requeue_path(make_frontend):
+def test_paged_kv_exhaustion_requeue_path():
     """With the gather-budget hooks disarmed (a backend that cannot
     predict demand), admission reaches the allocator and raises
     KVPoolExhausted — the dispatcher must REQUEUE to the head (a
@@ -2150,13 +2150,29 @@ def test_paged_kv_exhaustion_requeue_path(make_frontend):
                                   per_token_s=0.002,
                                   kv_pool_blocks=4, kv_block_tokens=4,
                                   kv_gate=False)
-    fe = make_frontend(None, slot_backend=sb, batch_max=4,
-                       batch_window_ms=0.0, drain_ms=15000.0)
+    # queue BEFORE start(): the first gather then holds four requests
+    # against a pool of two sequences (2 blocks each of 4), so the
+    # allocator refuses DETERMINISTICALLY. A TCP flood raced arrival: on
+    # a loaded host the six trickled in one at a time, each retired
+    # before the next came, and nothing was ever refused
+    fe = servd.ServeFrontend(None, slot_backend=sb, batch_max=4,
+                             batch_window_ms=0.0, drain_ms=15000.0)
+    replies = {}
+
+    def mkreply(i):
+        def reply(text):
+            replies.setdefault(i, []).append(text)
+        return reply
+
     lines = ["%d %d %d %d" % (10 * i, 10 * i + 1, 10 * i + 2,
                               10 * i + 3) for i in range(1, 7)]
-    resps = faultinject.serve_flood(fe.port, lines, timeout=30.0)
-    for i, r in enumerate(resps):
-        assert r == _expect_line(10 * (i + 1), 4), (i, r)
+    events = [fe.submit(line, mkreply(i)) for i, line in enumerate(lines)]
+    fe.start()
+    for ev in events:
+        assert ev.wait(30.0), "request never answered"
+    for i in range(6):
+        assert replies[i] == [_expect_line(10 * (i + 1), 4)], \
+            (i, replies[i])
     # the allocator DID refuse some admissions (the path under test)…
     assert sb.alloc.alloc_failures > 0
     # …and every refusal became a requeue: no error class, no breaker

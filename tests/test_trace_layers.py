@@ -89,10 +89,7 @@ def _weighted_scopes(tr):
     (SMALL_CONF, "remat = 1\n", ["c1", "fc1"]),
     # sibling fusion: one scope for the group, named by its members
     (MODULE_CONF, "", ["stem", "b1+b3r+c5r", "b3", "c5", "dproj", "head"]),
-    # cross fusion: the pool projection joins the group's stacked matmul
-    (MODULE_CONF, "fuse_cross_1x1 = 1\n",
-     ["stem", "b1+b3r+c5r+dproj", "b3", "c5", "head"]),
-], ids=["plain", "remat", "fused_siblings", "fused_cross"])
+], ids=["plain", "remat", "fused_siblings"])
 def test_lowered_step_names_every_layer_on_both_passes(conf, extra, scopes):
     tr = _trainer(conf, extra)
     names, matmuls, module = _lowered_names(tr)
@@ -308,7 +305,7 @@ def test_phase_account_holds_init_and_the_first_update_only(fresh_account):
     assert telemetry.phases() == first        # a warm call adds nothing
     assert telemetry.events() == []
     # the first occurrence stands: a second model and its step's build in
-    # the same process (the benchmark's reference, bench.py's next row) are
+    # the same process (the benchmark's reference after the window) are
     # not added in, and reset() / enable() do not lose what cannot recur
     _trainer(SMALL_CONF).update(b)
     telemetry.reset()

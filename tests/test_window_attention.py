@@ -3,7 +3,6 @@
 XLA ring, flash ring, ulysses). Causal-only by contract.
 """
 
-import os
 
 import numpy as np
 import jax
@@ -75,14 +74,12 @@ def test_ring_xla_window():
 
 
 def test_ring_flash_window():
-    os.environ["CXXNET_RING"] = "flash"
     ops.set_use_pallas(True)
     try:
         q, k, v = _qkv(seed=5)
         out = ring.ring_attention(q, k, v, _mesh(), causal=True, window=W)
     finally:
         ops.set_use_pallas(None)
-        os.environ.pop("CXXNET_RING", None)
     ref = ring.attention_reference(q, k, v, causal=True, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -157,7 +154,6 @@ def test_ring_flash_window_with_skipped_blocks():
     """8-device ring at L=1024, window=96: most ring steps hold blocks
     entirely out of window (skipped by the traced tile predicate) and the
     result must still match the dense reference, incl. grads."""
-    os.environ["CXXNET_RING"] = "flash"
     ops.set_use_pallas(True)
     try:
         q, k, v = _qkv(s=1024, seed=9)
@@ -171,7 +167,6 @@ def test_ring_flash_window_with_skipped_blocks():
             q_, k, v, mesh, causal=True, window=W) * w))(jnp.asarray(q))
     finally:
         ops.set_use_pallas(None)
-        os.environ.pop("CXXNET_RING", None)
     gr = jax.grad(lambda q_: jnp.sum(ring.attention_reference(
         q_, k, v, causal=True, window=W) * w))(jnp.asarray(q))
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),

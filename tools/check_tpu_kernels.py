@@ -228,21 +228,17 @@ def main():
     # ring semantics are goldened on the CPU mesh (tests/test_ring_flash.py)
     from cxxnet_tpu.parallel import ring as ring_mod
     from jax.sharding import Mesh
-    os.environ["CXXNET_RING"] = "flash"
-    try:
-        mesh1 = Mesh(np.array(jax.devices()[:1]), ("sp",))
-        q3 = jnp.asarray(rs.randn(1, 2, 512, 64), jnp.float32)
-        for causal in (False, True):
-            out = np.asarray(ring_mod.ring_attention(
-                q3, q3, q3, mesh1, causal=causal))
-            ref = np.asarray(attention_reference(q3, q3, q3, causal=causal))
-            np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
-        g = jax.jit(jax.grad(lambda q: jnp.sum(ring_mod.ring_attention(
-            q, q3, q3, mesh1, causal=True))))(q3)
-        assert np.isfinite(float(jnp.sum(g)))
-        print("ring-flash step kernels compiled (n=1 ring): OK")
-    finally:
-        os.environ.pop("CXXNET_RING", None)
+    mesh1 = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    q3 = jnp.asarray(rs.randn(1, 2, 512, 64), jnp.float32)
+    for causal in (False, True):
+        out = np.asarray(ring_mod.ring_attention(
+            q3, q3, q3, mesh1, causal=causal))
+        ref = np.asarray(attention_reference(q3, q3, q3, causal=causal))
+        np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
+    g = jax.jit(jax.grad(lambda q: jnp.sum(ring_mod.ring_attention(
+        q, q3, q3, mesh1, causal=True))))(q3)
+    assert np.isfinite(float(jnp.sum(g)))
+    print("ring-flash step kernels compiled (n=1 ring): OK")
 
     # --- channels_last conv-stack layout, compiled on-chip -------------
     # one bf16 train step of a conv->relu->lrn->bn->relu_max_pooling net
@@ -301,85 +297,8 @@ dev = tpu
     np.testing.assert_allclose(weights[0], weights[1], rtol=2e-2, atol=2e-4)
     print("channels_last train-step parity on-chip: OK")
 
-    # --- mask-VJP max-pool backward (CXXNET_POOL=mask), compiled --------
-    # tie-forcing quantized input; the reference-tie-semantics HLO path
-    # must compile and differ from select-and-scatter exactly on ties
-    # (the fused Pallas variant was deleted after losing its on-chip A/B
-    # 2:1 — onchip_logs/poolab.log)
-    from cxxnet_tpu import ops as _ops
-    xq = jnp.asarray(np.round(rs.rand(4, 192, 28, 28) * 4) / 4,
-                     jnp.bfloat16)
-    (_, _), (ph2, pw2) = _ops._pool_padding(30, 30, (3, 3), 1)
-    padq = ((1, 1 + ph2), (1, 1 + pw2))
-    g_msk = jax.jit(jax.grad(lambda x: jnp.sum(jnp.square(
-        _ops._max_pool(x, (3, 3), 1, padq)
-    ).astype(jnp.float32))))(xq)
-    assert np.isfinite(np.asarray(g_msk, np.float32)).all()
-    print("mask-VJP max-pool backward (ties, bf16) compiles on-chip: OK")
-
-    # --- cross-input 1x1 batching parity on-chip ------------------------
-    # the opt-in fuse_cross_1x1 path (batched-matmul inception module,
-    # net.py _apply_fused_cross) must match the default path through the
-    # REAL TPU compiler before tools/cross1x1_ab.py may flip the default
-    inc_conf = """
-netconfig = start
-layer[0->s] = conv:xs
-  kernel_size = 3
-  pad = 1
-  nchannel = 16
-  random_type = xavier
-layer[s->sa,sb,sc] = split
-layer[sa->a1] = conv:xa
-  kernel_size = 1
-  nchannel = 8
-layer[sb->b1] = conv:xb
-  kernel_size = 1
-  nchannel = 12
-layer[sc->c1] = max_pooling
-  kernel_size = 3
-  stride = 1
-  pad = 1
-layer[c1->c2] = conv:xp
-  kernel_size = 1
-  nchannel = 8
-layer[a1,b1,c2->cc] = ch_concat
-layer[cc->gp] = avg_pooling
-  kernel_size = 8
-  stride = 8
-layer[gp->fl] = flatten
-layer[fl->out] = fullc:xh
-  nhidden = 5
-  init_sigma = 0.05
-layer[+0] = softmax
-netconfig = end
-input_shape = 3,16,16
-batch_size = 8
-eta = 0.05
-eval_train = 0
-compute_dtype = bfloat16
-dev = tpu
-"""
-    db2 = DataBatch()
-    db2.data = rs.rand(8, 3, 16, 16).astype(np.float32)
-    db2.label = rs.randint(0, 5, (8, 1)).astype(np.float32)
-    db2.batch_size = 8
-    xw = []
-    for knob in (0, 1):
-        t3 = Trainer()
-        for k, v in parse_config_string(
-                inc_conf + "fuse_cross_1x1 = %d\n" % knob):
-            t3.set_param(k, v)
-        t3.init_model()
-        if knob:
-            assert len(t3.net._cross_1x1_plan()) == 1
-        t3.update(db2)
-        xw.append(np.asarray(
-            jax.device_get(t3.params[0]["wmat"]), np.float32))
-    np.testing.assert_allclose(xw[0], xw[1], rtol=2e-2, atol=2e-4)
-    print("cross-input 1x1 batching parity on-chip: OK")
-
     # --- depthwise conv (feature_group_count = C) compiles + steps ------
-    # the mobilenet bench row's distinct XLA-TPU path: grouped conv at
+    # mobilenet's distinct XLA-TPU path: grouped conv at
     # the one-channel-per-group extreme, under bf16 + channels_last
     from cxxnet_tpu.models import mobilenet_trainer
     mnt = mobilenet_trainer(batch_size=8, input_hw=32, dev="tpu",

@@ -14,7 +14,8 @@ arithmetic is cxxnet_tpu/utils/devtrace.py; doc/observability.md says where
 the names come from. Needs neither jax nor a chip.
 
 A trace is written by ``profile_dir = <dir>`` (the second round of a
-training run), statusd's ``/profilez?secs=N`` or ``tools/profile_bench.py``.
+training run), statusd's ``/profilez?secs=N`` or
+``tests/fixtures/record_layers_trace.py``.
 """
 
 import argparse
