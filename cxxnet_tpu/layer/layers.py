@@ -9,6 +9,8 @@ forward functions, which our golden tests verify (tests/test_layers.py).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -1668,16 +1670,27 @@ class RMSNormLayer(Layer):
         return [(xf * inv * gain).astype(x.dtype)]
 
 
+def _rows_or_zero(x, at):
+    """Row ``at[i]`` of ``x``, and a zero row where ``at[i]`` lies beyond
+    ``x``: ``x`` is the head of a longer array whose other rows nobody
+    made, because none of them holds anything."""
+    if x.shape[0] == at.shape[0]:       # a whole permutation: none beyond
+        return x[at]
+    return x.at[at].get(mode="fill", fill_value=0)
+
+
 @jax.custom_vjp
 def _permute_rows(x, perm, inv):
     """``x[perm]`` for a permutation whose inverse is known: the backward
     is the gather ``g[inv]``, not the scatter-add jax would transpose the
-    forward's gather into."""
-    return x[perm]
+    forward's gather into. ``x`` may be the first m rows of the permuted
+    array only, ``inv`` then the first m of the inverse: the rows beyond
+    read as zeros, and the backward gathers m rows."""
+    return _rows_or_zero(x, perm)
 
 
 def _permute_rows_fwd(x, perm, inv):
-    return x[perm], (perm, inv)
+    return _rows_or_zero(x, perm), (perm, inv)
 
 
 def _permute_rows_bwd(res, g):
@@ -1692,8 +1705,9 @@ _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 def _dispatch_rows(x, perm, inv):
     """Row p of the result is token ``perm[p] % T`` of ``x`` (T, d): the
     sorted order of the (choice, token) pairs, ``k`` choices a token, with
-    no (k T, d) copy of ``x`` made first. Backward: the gather ``g[inv]``
-    summed over the choices."""
+    no (k T, d) copy of ``x`` made first; ``perm`` may be the sorted
+    order's first m pairs only. Backward: the gather ``g[inv]`` (a zero
+    for a pair beyond those m) summed over the choices."""
     return x[perm % x.shape[0]]
 
 
@@ -1703,10 +1717,71 @@ def _dispatch_rows_fwd(x, perm, inv):
 
 def _dispatch_rows_bwd(res, g):
     inv, T = res
-    return jnp.sum(g[inv].reshape(-1, T, g.shape[-1]), axis=0), None, None
+    return (jnp.sum(_rows_or_zero(g, inv).reshape(-1, T, g.shape[-1]),
+                    axis=0), None, None)
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+def _expert_rows(act, mm, x, mats):
+    """One expert's function of its rows, by the product ``mm(rows, stack
+    of matrices)`` the lowering gives: ``act`` is ``expert_act``."""
+    a = mm(x, mats["experts"])
+    if act == "relu":
+        return jnp.maximum(a, 0.0)
+    return mm(jnp.maximum(a, 0.0) * mm(x, mats["up"]), mats["down"])
+
+
+def _sorted_side(act, m, x2, w, mats, order, inv, sizes):
+    """A sparse ``moe`` layer's output (T, dout) from the first ``m`` pairs
+    of the sorted order: exact where ``sum(sizes) <= m``, since the pairs
+    of experts held come first."""
+    T, k = w.shape
+    head = order[:m]
+    with sub_scope("dispatch"):
+        rows = _dispatch_rows(x2, head, inv)
+    with sub_scope("experts"):
+        ys = _expert_rows(
+            act, lambda a, mat: ops.grouped_matmul(a, mat, sizes), rows, mats)
+    with sub_scope("combine"):
+        # back to (choice, token) order; a pair not held is a zero row
+        ys = _permute_rows(ys, inv, head).reshape(k, T, -1)
+        return jnp.einsum("kto,tk->to", ys, w.astype(ys.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _either_side(act, m, x2, w, mats, order, inv, sizes):
+    """``_sorted_side`` on the sorted order's first m rows where the pairs
+    held fit them, and on all of them where they do not: the same result
+    either way, no pair dropped. The backward chooses again and makes the
+    chosen side's forward anew, so that nothing whose shape depends on the
+    choice has to pass from one ``cond`` to the other (jax would hand on
+    both sides' residuals, the side not taken as zeros)."""
+    return jax.lax.cond(
+        jnp.sum(sizes) <= m,
+        functools.partial(_sorted_side, act, m),
+        functools.partial(_sorted_side, act, order.shape[0]),
+        x2, w, mats, order, inv, sizes)
+
+
+def _either_side_fwd(act, m, *args):
+    return _either_side(act, m, *args), args
+
+
+def _either_side_bwd(act, m, res, g):
+    x2, w, mats, order, inv, sizes = res
+
+    def back(n, g, x2, w, mats):
+        return jax.vjp(lambda *a: _sorted_side(
+            act, n, *a, order, inv, sizes), x2, w, mats)[1](g)
+    return jax.lax.cond(
+        jnp.sum(sizes) <= m, functools.partial(back, m),
+        functools.partial(back, order.shape[0]),
+        g, x2, w, mats) + (None, None, None)
+
+
+_either_side.defvjp(_either_side_fwd, _either_side_bwd)
 
 
 class MoELayer(Layer):
@@ -1737,9 +1812,22 @@ class MoELayer(Layer):
     pairs are sorted by expert, rows gathered, one grouped matrix product
     a matrix over the experts held (``ops.grouped_matmul``), and the rows
     gathered back and summed per token — no capacity, no dropped token at
-    any load; cost follows the pairs held (PERF.md section 5 has what the
-    chip read of the products and of the gathers either side of them).
-    Counts ``moe.sparse`` / ``moe.dense`` once per traced layer.
+    any load. The sorted side's cost follows the pairs held: the pairs of
+    experts held sort first, and a share of the experts
+    (``nexpert_held < nexpert``) gathers, multiplies and differentiates
+    only the first ``M = min(k T, round_up(ceil(3/2 k T held / nexpert),
+    512))`` rows of the sorted order, one and a half times its part at even
+    routing in whole row tiles of the grouped product, a number the shape
+    alone gives. A step whose pairs held exceed M takes all k T rows
+    instead (a ``cond`` on ``sum(sizes) <= M``, forward and again
+    backward: the same result, nothing dropped; the bounded form makes its
+    forward anew in its backward, as ``remat = 1`` would, so that the two
+    sides pass no residuals between the ``cond``s). With every expert held
+    M = k T and there is no ``cond``. The token side keeps its k T rows
+    (PERF.md section 5 has what the chip read of the products and of the
+    gathers either side of them). Counts ``moe.sparse`` / ``moe.dense``
+    once per traced layer and ``moe.bounded`` beside the first where M < k T
+    (gauges ``moe.rows``, ``moe.rows_full``).
 
     With a mesh carrying an "ep" axis (trainer key ``expert_parallel = k``)
     the dense form's expert dimension shards over the mesh
@@ -1754,6 +1842,10 @@ class MoELayer(Layer):
     # what the sparse lowering leaves in ctx.layer_stats, in this order:
     # the pairs routed to experts held and the fullest expert's load
     stat_names = ("moe.pairs_held", "moe.load_max")
+    # {one of them: (gauge, bound)}, set when the sparse lowering is traced:
+    # the pairs held against the rows its sorted side has, as
+    # utils/health.HealthMonitor keeps it (moe.overflow/<layer>)
+    stat_limits = {}
 
     def __init__(self):
         super().__init__()
@@ -1858,22 +1950,17 @@ class MoELayer(Layer):
         return jnp.sum(jax.nn.one_hot(idx, self.n_expert, dtype=w.dtype)
                        * w[..., None], axis=1)
 
-    def _experts(self, mm, x, params):
-        """One expert's function of its rows, by the product ``mm(rows,
-        stack of matrices)`` the lowering gives."""
-        a = mm(x, params["experts"])
-        if self.expert_act == "relu":
-            return jnp.maximum(a, 0.0)
-        return mm(jnp.maximum(a, 0.0) * mm(x, params["up"]), params["down"])
-
     def _dense(self, x2, probs, params):
         lo = self.expert_offset
         p_held = probs[:, lo: lo + self._held()].astype(x2.dtype)
         mm = lambda a, w: jnp.einsum(                       # noqa: E731
             "ti,eio->eto" if a.ndim == 2 else "eti,eio->eto", a, w)
-        return jnp.einsum("eto,te->to", self._experts(mm, x2, params), p_held)
+        return jnp.einsum(
+            "eto,te->to", _expert_rows(self.expert_act, mm, x2, params),
+            p_held)
 
     def _sparse(self, x2, xr, params, ctx):
+        from ..utils import telemetry
         T = x2.shape[0]
         held, lo = self._held(), self.expert_offset
         with sub_scope("route"):
@@ -1895,15 +1982,29 @@ class MoELayer(Layer):
                             axis=0)
             ctx.layer_stats[ctx.conn_index] = jnp.stack(
                 [jnp.sum(sizes), jnp.max(sizes)]).astype(jnp.float32)
-        with sub_scope("dispatch"):
-            rows = _dispatch_rows(x2, order, inv)
-        with sub_scope("experts"):
-            ys = self._experts(
-                lambda a, m: ops.grouped_matmul(a, m, sizes), rows, params)
-        with sub_scope("combine"):
-            # back to (choice, token) order; a pair not held is a zero row
-            ys = _permute_rows(ys, inv, order).reshape(k, T, -1)
-            return jnp.einsum("kto,tk->to", ys, w.astype(ys.dtype))
+        pairs = T * k
+        rows = self._sorted_rows(pairs)
+        if rows < pairs:
+            telemetry.count_path("moe.bounded")
+        telemetry.gauge("moe.rows", rows)
+        telemetry.gauge("moe.rows_full", pairs)
+        self.stat_limits = {"moe.pairs_held": ("moe.overflow", rows)}
+        mats = {key: params[key] for key in self._keys()[1:]}
+        args = (self.expert_act, rows, x2, w, mats, order, inv, sizes)
+        if rows == pairs:
+            return _sorted_side(*args)
+        return _either_side(*args)
+
+    def _sorted_rows(self, pairs):
+        """The static row count of the sorted side for ``pairs`` (choice,
+        token) pairs: one and a half times this share's part of them at
+        even routing, in whole row tiles of the grouped product; all of
+        them where every expert is held."""
+        held = self._held()
+        if held == self.n_expert:
+            return pairs
+        even = -(-3 * pairs * held // (2 * self.n_expert))
+        return min(pairs, -(-even // 512) * 512)
 
     def apply(self, params, inputs, ctx):
         from ..parallel import expert_parallel_ffn

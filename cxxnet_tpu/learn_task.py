@@ -1093,7 +1093,8 @@ class LearnTask:
             self._health = health.HealthMonitor(
                 spike_factor=self.loss_spike_factor,
                 spike_warmup=self.loss_spike_warmup,
-                gauge_names=lambda: self.net_trainer.health_gauge_names)
+                gauge_names=lambda: self.net_trainer.health_gauge_names,
+                gauge_limits=lambda: self.net_trainer.health_gauge_limits)
             self._recovery = health.RecoveryPolicy(
                 action=self.nonfinite_action,
                 backoff=self.rollback_backoff,

@@ -128,6 +128,10 @@ class Trainer:
         # names of what follows the four in that vector (set when the step
         # is traced): gauges the host monitor keeps at each check
         self.health_gauge_names = []
+        # {one of those names: (gauge that says the reading passed its
+        # static bound, the bound)}: a moe layer's pairs held against the
+        # rows its sorted side has
+        self.health_gauge_limits = {}
         self.metric = MetricSet()
         self.train_metric = MetricSet()
         self.eval_node_names: List[Optional[str]] = []  # None -> last node
@@ -1066,11 +1070,17 @@ class Trainer:
                     # behind the four: the layers' own readings of this
                     # step (moe: pairs held, fullest expert), named by
                     # health_gauge_names in the same order
-                    names = []
+                    names, limits = [], {}
                     for i in sorted(layer_stats):
-                        names += ["%s/%s" % (n, self.net.layer_scope(i))
-                                  for n in self.net.layers[i].stat_names]
+                        lay = self.net.layers[i]
+                        scope = self.net.layer_scope(i)
+                        names += ["%s/%s" % (n, scope)
+                                  for n in lay.stat_names]
+                        for n, (over, bound) in lay.stat_limits.items():
+                            limits["%s/%s" % (n, scope)] = (
+                                "%s/%s" % (over, scope), bound)
                     self.health_gauge_names = names
+                    self.health_gauge_limits = limits
                     health = jnp.concatenate(
                         [health] + [layer_stats[i]
                                     for i in sorted(layer_stats)])

@@ -283,8 +283,8 @@ def scope_of(tf_op: str) -> Tuple[str, str]:
     (``jvp()/shard_map/while/body/.../conv1/...`` is conv1's); what the
     pipeline runs between the layers stands under ``shard_map`` itself.
     A layer's own sub-scope (a component that starts with
-    ``SUB_SCOPE_MARK``) is kept: ``jvp(b0_att)/~core/...`` is
-    ``b0_att/core``."""
+    ``SUB_SCOPE_MARK``, bare or inside ``jvp(...)`` / ``transpose(...)``)
+    is kept: ``jvp(b0_att)/~core/...`` is ``b0_att/core``."""
     if not tf_op:
         return "other", NO_TF_OP
     parts = _JIT.sub("", tf_op.rsplit(":", 1)[0]).split("/")
@@ -300,7 +300,11 @@ def scope_of(tf_op: str) -> Tuple[str, str]:
         inner = [c for c in parts[1:-1] if not _CONTROL.match(c)]
         layer = inner[0] if inner and "(" not in inner[0] else SHARD_MAP
     if layer:
-        sub = next((c for c in parts[1:-1]
+        # a sub-scope opened under a vjp of the layer's own (a sparse moe
+        # layer's backward makes its forward anew) stands wrapped:
+        # ``transpose(jvp(~experts))``
+        sub = next((c for c in (_WRAP.sub("", c).rstrip(")")
+                                for c in parts[1:-1])
                     if c.startswith(SUB_SCOPE_MARK)), None)
         if sub:
             layer = "%s/%s" % (layer, sub[len(SUB_SCOPE_MARK):])

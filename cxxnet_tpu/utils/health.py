@@ -125,11 +125,18 @@ class HealthMonitor:
     """
 
     def __init__(self, spike_factor: float = 0.0, spike_warmup: int = 20,
-                 spike_decay: float = 0.98, gauge_names=None):
+                 spike_decay: float = 0.98, gauge_names=None,
+                 gauge_limits=None):
         # gauge_names(): names of what the step put behind the four health
         # values (Trainer.health_gauge_names: moe.pairs_held/<layer>,
-        # moe.load_max/<layer>); each check keeps them as gauges
+        # moe.load_max/<layer>); each check keeps them as gauges.
+        # gauge_limits(): {one of those names: (name, bound)}
+        # (Trainer.health_gauge_limits); each check also keeps gauge
+        # ``name`` = 1 where the reading passed the bound, else 0
+        # (moe.overflow/<layer>: the pairs held did not fit the sorted
+        # side's rows and the layer took its whole-order fallback)
         self.gauge_names = gauge_names
+        self.gauge_limits = gauge_limits
         self.spike_factor = float(spike_factor)
         self.spike_warmup = int(spike_warmup)
         self.spike_decay = float(spike_decay)
@@ -165,8 +172,12 @@ class HealthMonitor:
         gn_sq = float(h[H_GNORM_SQ])
         nan_grads = int(h[H_NAN_GRADS])
         if self.gauge_names is not None:
+            limits = self.gauge_limits() if self.gauge_limits else {}
             for name, v in zip(self.gauge_names(), h[H_OK + 1:]):
                 telemetry.gauge(name, float(v))
+                if name in limits:
+                    over, bound = limits[name]
+                    telemetry.gauge(over, float(v > bound))
         if nan_grads > 0:
             # the elements updater _clip_nan silently zeroes (with
             # clip_gradient set) — or that reach the optimizer raw —

@@ -8,7 +8,9 @@ locations stripped, so that two trees can be compared without a chip:
 Conf text, ``extra_cfg``, keys, layers and dtype are the configuration's
 own; the batch (and the sequence) are cut to what a CPU lowers in seconds.
 ``alexnet-b2048`` is also lowered on a four-device mesh (``dev = cpu:0-3``),
-as ``alexnet-dp4`` runs it. Each is lowered twice: ``Trainer.lower_update``
+as ``alexnet-dp4`` runs it; the language model also with every expert held
+(``nexpert_held = 64``: the sparse ``moe`` lowering's whole sorted side, no
+bound and no ``cond``, PR 33). Each is lowered twice: ``Trainer.lower_update``
 and the step the cells really run (``health_monitor = 1``).
 
 ``--tpu-like`` follows the branches the chip takes as far as a CPU lowering
@@ -21,9 +23,10 @@ import os
 import re
 import sys
 
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-sys.path.insert(0, os.getcwd())
+if __name__ == "__main__":      # as a script: before jax is imported
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    sys.path.insert(0, os.getcwd())
 
 import jax                                               # noqa: E402
 import jax.numpy as jnp                                  # noqa: E402
@@ -34,10 +37,12 @@ from cxxnet_tpu.nnet.trainer import Trainer              # noqa: E402
 from cxxnet_tpu.utils.config import parse_config_string  # noqa: E402
 
 
-def build(name, chips, batch, seq, tpu_like):
+def build(name, chips, batch, seq, tpu_like, all_held=False):
     cfg = json.load(open("benchmark/configs/%s.json" % name))
     conf = open("benchmark/" + cfg["conf"]).read() + "\n" \
         + cfg.get("extra_cfg", "")
+    if all_held:
+        conf = conf.replace("nexpert_held = 16", "nexpert_held = 64")
     dev = "cpu" if chips == 1 else "cpu:0-%d" % (chips - 1)
     n = batch * chips
     b = DataBatch()
@@ -81,16 +86,18 @@ def main():
         ops.set_use_pallas(True)
     os.makedirs(out, exist_ok=True)
     batch = 128 if tpu_like else 8
-    for name, chips, rows, seq in (
-            ("alexnet-b2048", 1, batch, None),
-            ("alexnet-b2048", 4, batch, None),
-            ("googlenet-b512", 1, batch, None),
-            ("smallthinker-21b-ep4-l4", 1, 1, 512)):
-        tr, b = build(name, chips, rows, seq, tpu_like)
+    for name, chips, rows, seq, all_held in (
+            ("alexnet-b2048", 1, batch, None, False),
+            ("alexnet-b2048", 4, batch, None, False),
+            ("googlenet-b512", 1, batch, None, False),
+            ("smallthinker-21b-ep4-l4", 1, 1, 512, False),
+            ("smallthinker-21b-ep4-l4", 1, 1, 512, True)):
+        tr, b = build(name, chips, rows, seq, tpu_like, all_held)
         for kind, low in (("lower_update", tr.lower_update(b)),
                           ("health_step", health_step(tr, b))):
             txt = stripped(low)
-            tag = "%s.chips%d.%s" % (name, chips, kind)
+            tag = "%s.chips%d.%s%s" % (name, chips, kind,
+                                       ".all_held" if all_held else "")
             with open(os.path.join(out, tag + ".mlir"), "w") as f:
                 f.write(txt)
             print(tag, len(txt.splitlines()), "lines",

@@ -2,10 +2,13 @@
 ``Trainer.update``), at a small size on the CPU: d 64, 4 query / 2 key-value
 heads of 16, 8 experts top-2 of width 32, vocabulary 96, L 32, window 8,
 pattern [0, 1, 1, 1]. Against the benchmark's plain reference
-(``benchmark/references/moe_lm.py``) on seeded weights, float32."""
+(``benchmark/references/moe_lm.py``) on seeded weights, float32. A share of
+the experts bounds its sorted side only from 512 rows up (the grouped
+product's row tile), so what reads the bound runs at L 512 (``LONG``)."""
 
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +35,9 @@ SMALL = dict(vocab=VOCAB, dim=D, nhead=4, nkvhead=2, head_dim=16, nlayer=4,
 CFG = {"seq_len": L, "batch_per_chip": 2 * L,
        "extra_cfg": "eval_train = 0\nhealth_monitor = 1\n"}
 SEED = 2**31 + 77
+# two sequences of LONG tokens, top 2: 2,048 pairs; a share of 2 of the 8
+# experts gets 3/2 * 2,048 * 2/8 = 768 rows, in whole tiles of 512: 1,024
+LONG, LONG_PAIRS, LONG_ROWS = 512, 2048, 1024
 
 
 def _conf(**over):
@@ -39,7 +45,9 @@ def _conf(**over):
         + models.SMALLTHINKER_ADAMW
 
 
-_PATHS_BEFORE = {}
+def _paths_since(before):
+    return {k: n - before.get(k, 0) for k, n in telemetry.paths().items()
+            if n != before.get(k, 0)}
 
 
 @pytest.fixture(scope="module")
@@ -49,11 +57,34 @@ def trained():
     conf = _conf()
     ref = moe_lm.for_config(conf, CFG, 2 * L)
     # the path account is the process's: count from what tests before left
-    _PATHS_BEFORE.update(telemetry.paths())
+    before = telemetry.paths()
     program = cxxnet_lm_trainer.Program(conf, CFG, 1, SEED, {})
-    before = _probabilities(program)
+    probs = _probabilities(program)
     got = window.first_steps(program, ref.hyper, 3)
-    return conf, ref, program, before, got, ref.run(SEED, 3)
+    return types.SimpleNamespace(
+        conf=conf, ref=ref, program=program, probs=probs, got=got,
+        want=ref.run(SEED, 3), paths=_paths_since(before), gauges={})
+
+
+@pytest.fixture(scope="module")
+def share():
+    """One chip's share of the same model (experts 2-3 of 8 in each layer)
+    at L ``LONG``, where its sorted side is bounded; one step, traced with
+    telemetry on so that the gauges written at trace time are kept."""
+    conf = _conf(n_held=2, expert_offset=2)
+    cfg = dict(CFG, seq_len=LONG, batch_per_chip=2 * LONG)
+    before = telemetry.paths()
+    telemetry.enable()
+    try:
+        program = cxxnet_lm_trainer.Program(conf, cfg, 1, SEED, {})
+        program.step()
+        program.sync()
+        gauges = telemetry.summary()["gauges"]
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    return types.SimpleNamespace(program=program, gauges=gauges,
+                                 paths=_paths_since(before))
 
 
 def _probabilities(program):
@@ -63,7 +94,8 @@ def _probabilities(program):
 
 
 def test_logits_agree_with_the_reference(trained):
-    conf, ref, program, before, _, _ = trained
+    conf, ref, program, before = (trained.conf, trained.ref,
+                                  trained.program, trained.probs)
     layers, _ = netconf.parse(conf)
     params = jax.jit(ref._weights)(seed_key(SEED))
     ids = program.batches[0].data.reshape(2, L).astype(jnp.int32)
@@ -77,7 +109,7 @@ def test_logits_agree_with_the_reference(trained):
 
 
 def test_loss_gradients_and_three_adamw_steps_agree(trained):
-    _, _, _, _, got, want = trained
+    got, want = trained.got, trained.want
     nums = compare.numbers(got, want)
     # the mean of 64 float32 cross-entropies, summed in another order
     assert max(nums[k]["value"] for k in ("loss1", "loss2", "loss3")) < 1e-6
@@ -96,10 +128,10 @@ def test_loss_gradients_and_three_adamw_steps_agree(trained):
     assert nums["change_worst"]["value"] < 1e-4
 
 
-def test_the_step_counts_its_paths_and_returns_the_routing(trained):
-    _, _, program, _, _, _ = trained
-    paths = {k: n - _PATHS_BEFORE.get(k, 0)
-             for k, n in telemetry.paths().items()}
+@pytest.mark.parametrize("which", ["trained", "share"])
+def test_the_step_counts_its_paths_and_returns_the_routing(request, which):
+    fx = request.getfixturevalue(which)
+    program, paths, gauges = fx.program, fx.paths, fx.gauges
     assert paths.get("moe.sparse", 0) >= 4 and not paths.get("moe.dense")
     assert paths.get("attn.dense", 0) >= 4       # no flash kernel on a CPU
     tr = program.trainer
@@ -108,43 +140,96 @@ def test_the_step_counts_its_paths_and_returns_the_routing(trained):
     assert names == [n + "/b%d_moe" % i for i in range(4)
                      for n in ("moe.pairs_held", "moe.load_max")]
     extra = dict(zip(names, health[4:]))
-    # every expert is held: all 2 * 64 pairs, and some expert above the mean
-    assert all(extra["moe.pairs_held/b%d_moe" % i] == 2 * 2 * L
-               for i in range(4))
-    assert all(2 * 2 * L / NEXP <= extra["moe.load_max/b%d_moe" % i]
-               <= 2 * L for i in range(4))
+    held = [extra["moe.pairs_held/b%d_moe" % i] for i in range(4)]
+    if which == "trained":
+        # every expert is held: all 2 * 64 pairs, some expert above the mean,
+        # the sorted side whole and no second branch
+        pairs = 2 * 2 * L
+        assert held == [pairs] * 4 and "moe.bounded" not in paths
+        assert all(pairs / NEXP <= extra["moe.load_max/b%d_moe" % i]
+                   <= 2 * L for i in range(4))
+    else:
+        # 2 of 8 experts: about a quarter of the pairs, on a sorted side of
+        # half the rows in each of the four layers, traced once each
+        pairs = LONG_PAIRS
+        assert paths["moe.bounded"] == paths["moe.sparse"] == 4
+        assert gauges["moe.rows"] == LONG_ROWS
+        assert gauges["moe.rows_full"] == pairs
+        assert all(pairs / 8 < n < LONG_ROWS for n in held), held
+    assert tr.health_gauge_limits == {
+        "moe.pairs_held/b%d_moe" % i: ("moe.overflow/b%d_moe" % i,
+                                       min(pairs, LONG_ROWS))
+        for i in range(4)}
 
 
-def test_the_health_monitor_keeps_the_routing_as_gauges(trained):
+@pytest.mark.parametrize("which", ["trained", "share"])
+def test_the_health_monitor_keeps_the_routing_as_gauges(request, which):
     from cxxnet_tpu.utils import health
-    _, _, program, _, _, _ = trained
-    tr = program.trainer
-    telemetry.enable()
-    try:
-        mon = health.HealthMonitor(
-            gauge_names=lambda: tr.health_gauge_names)
-        # the check runs one step late: the vector before is judged when
-        # the next arrives
-        assert mon.observe(0, 0, tr.last_health) is None
-        assert mon.observe(0, 1, tr.last_health) is None
-        gauges = telemetry.summary()["gauges"]
-    finally:
-        telemetry.disable()
-        telemetry.reset()
-    assert gauges["moe.pairs_held/b0_moe"] == 2 * 2 * L
-    assert gauges["moe.load_max/b3_moe"] == float(
-        np.asarray(tr.last_health)[-1])
+    tr = request.getfixturevalue(which).program.trainer
+
+    def kept(vectors):
+        telemetry.enable()
+        try:
+            mon = health.HealthMonitor(
+                gauge_names=lambda: tr.health_gauge_names,
+                gauge_limits=lambda: tr.health_gauge_limits)
+            # the check runs one step late: the vector before is judged
+            # when the next arrives
+            for i, vec in enumerate(vectors):
+                assert mon.observe(0, i, vec) is None
+            return telemetry.summary()["gauges"]
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+    last = np.asarray(tr.last_health)
+    gauges = kept([last, last])
+    assert gauges["moe.pairs_held/b0_moe"] == last[4]
+    assert gauges["moe.load_max/b3_moe"] == float(last[-1])
+    # at rest the pairs held fit the rows the sorted side has
+    assert [gauges["moe.overflow/b%d_moe" % i] for i in range(4)] == [0.0] * 4
+    # a step whose layer 1 held one pair more than its rows says so (the
+    # layer itself then takes all rows: the tests of the layer below)
+    full = last.copy()
+    full[4 + 2 * 1] = tr.health_gauge_limits["moe.pairs_held/b1_moe"][1] + 1
+    gauges = kept([full, last])
+    assert [gauges["moe.overflow/b%d_moe" % i] for i in range(4)] \
+        == [0.0, 1.0, 0.0, 0.0]
+
+
+def test_with_every_expert_held_the_step_lowers_as_before_the_bound(share):
+    """The uncut model at ``LONG``: the sparse lowering has all its rows,
+    no ``cond``, and the step's lowered text is the one this tree had before
+    a share's sorted side was bounded (PR 33; the digest is of the parent
+    commit's text, by ``tests/fixtures/lowered_step_text.stripped``). Who
+    changes what the uncut layer lowers to on purpose records a new one."""
+    import hashlib
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "lowered_step_text",
+        os.path.join(ROOT, "tests", "fixtures", "lowered_step_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    cfg = dict(CFG, seq_len=LONG, batch_per_chip=2 * LONG)
+    program = cxxnet_lm_trainer.Program(_conf(), cfg, 1, SEED, {})
+    text = tool.stripped(program.trainer.lower_update(program.batches[0]))
+    assert "stablehlo.case" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "f4ba3e2cc645d5bf"
+    # and a share of it does branch: once forward, once backward, a layer
+    program = share.program
+    text = tool.stripped(program.trainer.lower_update(program.batches[0]))
+    assert text.count("stablehlo.case") == 2 * 4
 
 
 # ------------------------------------------------ the moe layer by itself
-def _moe_layer(held=NEXP, offset=0, **keys):
+def _moe_layer(held=NEXP, offset=0, seq=L, **keys):
     lay = MoELayer()
     for k, v in dict({"nexpert": NEXP, "top_k": 2, "nhidden": WIDTH,
                       "expert_act": "reglu",
                       "nexpert_held": held, "expert_offset": offset},
                      **keys).items():
         lay.set_param(k, str(v))
-    lay.infer_shape([(2, D, 1, L)] * 2)
+    lay.infer_shape([(2, D, 1, seq)] * 2)
     return lay
 
 
@@ -174,32 +259,102 @@ def _reference_moe(w, u, x, held=NEXP, offset=0):
     ww = _share(w, offset, held)
     ww = {"wmat": ww["experts"], "gate": ww["gate"], "up": ww["up"],
           "down": ww["down"]}
-    rows = [moe_lm._moe(lay, "highest", ww, u[i].reshape(D, L).T,
-                        x[i].reshape(D, L).T) for i in range(u.shape[0])]
-    return np.stack([np.asarray(r).T.reshape(D, 1, L) for r in rows])
+    seq = u.shape[-1]
+    rows = [moe_lm._moe(lay, "highest", ww, u[i].reshape(D, seq).T,
+                        x[i].reshape(D, seq).T) for i in range(u.shape[0])]
+    return jnp.stack([r.T.reshape(D, 1, seq) for r in rows])
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("seq, bounded", [(L, 0), (LONG, 4)])
+def test_the_four_shares_add_up_to_the_uncut_layer(seq, bounded):
     """What ties one chip's share to the model: experts 0-1, 2-3, 4-5, 6-7
-    each give their part of y, and the parts add up to the layer's y."""
+    each give their part of y, and the parts add up to the layer's y; at
+    ``LONG`` each share does so from a sorted side of half the rows."""
     rng = np.random.RandomState(1)
-    u, x = rng.randn(2, 2, D, 1, L).astype(np.float32)
+    u, x = rng.randn(2, 2, D, 1, seq).astype(np.float32)
     w = _moe_weights()
-    whole, stats = _apply_moe(_moe_layer(), w, u, x)
+    before = telemetry.paths()
+    whole, stats = _apply_moe(_moe_layer(seq=seq), w, u, x)
     np.testing.assert_allclose(whole, _reference_moe(w, u, x), rtol=1e-4,
                                atol=1e-5)
     parts, pairs = [], 0
     for lo in range(0, NEXP, 2):
-        y, st = _apply_moe(_moe_layer(held=2, offset=lo), _share(w, lo, 2),
-                           u, x)
+        y, st = _apply_moe(_moe_layer(held=2, offset=lo, seq=seq),
+                           _share(w, lo, 2), u, x)
         np.testing.assert_allclose(
             y, _reference_moe(w, u, x, held=2, offset=lo), rtol=1e-4,
             atol=1e-5)
         parts.append(y)
         pairs += st[0]
+    assert _paths_since(before).get("moe.bounded", 0) == bounded
     # float32 sums in another order
     np.testing.assert_allclose(sum(parts), whole, rtol=1e-4, atol=1e-5)
-    assert pairs == stats[0] == 2 * 2 * L        # every pair is held once
+    assert pairs == stats[0] == 2 * 2 * seq      # every pair is held once
+
+
+def _value_and_gradients(lay, w, u, x):
+    """sum(sin(y)), y, the layer's two readings, and the gradients to the
+    router, the three expert matrices and both inputs."""
+    def f(w, u, x):
+        ctx = ApplyContext(train=True)
+        ctx.conn_index = 0
+        y, = lay.apply(w, [u, x], ctx)
+        return jnp.sum(jnp.sin(y)), (y, ctx.layer_stats[0])
+    return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+        w, jnp.asarray(u), jnp.asarray(x))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "gmm"])
+@pytest.mark.parametrize("load", ["at_rest", "all_pairs_here"])
+def test_a_share_under_its_bound_is_its_whole_sorted_side(load, kernel):
+    """Experts 2-3 of 8 at ``LONG``: the sorted side has 1,024 of the 2,048
+    rows. At rest about a quarter of the pairs are held and the first 1,024
+    rows give what all rows give; with every token routed to experts 2 and 3
+    all 2,048 pairs are held, twice the bound, and the layer takes all rows:
+    none dropped, clipped or re-weighted, forward and every gradient."""
+    rng = np.random.RandomState(5)
+    u = rng.randn(2, D, 1, LONG).astype(np.float32)
+    x = rng.randn(2, D, 1, LONG).astype(np.float32)
+    w = _moe_weights()
+    if load == "all_pairs_here":
+        x = np.abs(x) + 1.0
+        w["gate"] = np.zeros((NEXP, D), np.float32)
+        w["gate"][2], w["gate"][3] = 0.2, 0.1
+    mine = _share(w, 2, 2)
+    bounded, whole = (_moe_layer(held=2, offset=2, seq=LONG)
+                      for _ in range(2))
+    whole._sorted_rows = lambda pairs: pairs     # the lowering of before
+    before = telemetry.paths()
+    ops.set_use_pallas(True if kernel else None)
+    try:
+        got = _value_and_gradients(bounded, mine, u, x)
+        assert _paths_since(before) == {"moe.sparse": 1, "moe.bounded": 1}
+        want = _value_and_gradients(whole, mine, u, x)
+    finally:
+        ops.set_use_pallas(None)
+    assert _paths_since(before) == {"moe.sparse": 2, "moe.bounded": 1}
+    stats = np.asarray(got[0][1][1]).tolist()
+    if load == "at_rest":
+        assert LONG_PAIRS / 8 < stats[0] < LONG_ROWS
+    else:
+        assert stats == [LONG_PAIRS, LONG_PAIRS // 2] and stats[0] > LONG_ROWS
+    # the same rows through the same products: float32 sums in another
+    # order at most (lax.ragged_dot's weight gradients on 1,024 and on
+    # 2,048 rows)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+    def reference(w, u, x):
+        return jnp.sum(jnp.sin(_reference_moe(w, u, x, held=2, offset=2)))
+    ref = jax.grad(reference, argnums=(0, 1, 2))(w, jnp.asarray(u),
+                                                 jnp.asarray(x))
+    np.testing.assert_allclose(got[0][1][0], _reference_moe(
+        w, u, x, held=2, offset=2), rtol=1e-4, atol=1e-5)
+    for key in ("gate", "experts", "up", "down"):
+        r = ref[0][key] if key == "gate" else ref[0][key][2:4]
+        np.testing.assert_allclose(got[1][0][key], r, rtol=1e-4, atol=1e-4)
+    for a, b in zip(got[1][1:], ref[1:]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
 def test_all_tokens_to_one_pair_of_experts_and_none_is_dropped():

@@ -64,8 +64,13 @@ def test_channels_last_lrn_compiles_without_a_copy(one_chip, shape, dtype):
 # the sparse expert product of the language-model cell, at the published
 # widths and a quarter of its tokens: the grouped-product kernel has to take
 # Mosaic's tiling at d 2560 / width 768, and the layer's cost has to follow
-# the pairs, not pairs x experts
-def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(one_chip):
+# the pairs, not pairs x experts. With every expert held the sorted side has
+# all k T rows and there is no branch; a share of 16 of 64 (the cell's) gets
+# 4,608 of its 12,288 rows and a second branch, today's, for a step whose
+# pairs held do not fit them (PR 33)
+@pytest.mark.parametrize("nexp, k", [(16, 4), (64, 6)])
+def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(
+        one_chip, nexp, k):
     import os
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -75,7 +80,7 @@ def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(one_chip):
     from cxxnet_tpu import ops
     from cxxnet_tpu.layer.base import ApplyContext
     from cxxnet_tpu.layer.layers import MoELayer
-    d, width, seq, held, nexp, k = 2560, 768, 2048, 16, 16, 4
+    d, width, seq, held = 2560, 768, 2048, 16
     lay = MoELayer()
     for key, val in {"nexpert": nexp, "top_k": k, "nhidden": width,
                      "expert_act": "reglu", "nexpert_held": held}.items():
@@ -102,12 +107,37 @@ def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(one_chip):
         ops.set_use_pallas(None)
         ops.pallas_interpret = interpret
     text = compiled.as_text()
-    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
-    want = lm_flops.expert_product(seq * k, d, width, held)["flops"] \
-        + 2 * seq * d * nexp
-    got = compiled.cost_analysis()["flops"]
-    # every expert on every token would read held / k = 4 times the pairs
-    assert want <= got < 2 * want, (got, want)
+    kernels = 'custom_call_target="tpu_custom_call"'
+    pairs, rows = seq * k, lay._sorted_rows(seq * k)
+    if nexp == held:
+        assert rows == pairs and " conditional(" not in text
+        assert len(re.findall(kernels, text)) == 3
+        want = lm_flops.expert_product(pairs, d, width, held)["flops"] \
+            + 2 * seq * d * nexp
+        got = compiled.cost_analysis()["flops"]
+        # every expert on every token would read held / k = 4 times the pairs
+        assert want <= got < 2 * want, (got, want)
+        return
+    assert rows == 4608 < pairs == 12288
+    # branch 0 is the whole sorted side, branch 1 its first `rows` rows
+    (names,) = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    bodies = [re.search(r"^%s \(.*?^\}" % re.escape(n.strip()), text,
+                        re.M | re.S).group(0) for n in names.split(",")]
+    for body, m in zip(bodies, (pairs, rows)):
+        calls = [ln for ln in body.splitlines() if kernels in ln]
+        assert len(calls) == 3, body
+        # the three products read and write m rows: rows x 2560 in and out
+        # of the layer's width, rows x 768 between them
+        for ln in calls:
+            assert set(re.findall(r"bf16\[(\d+),(?:768|2560)\]", ln)) \
+                == {str(m)}, ln
+    # and beside them: the bounded branch makes one k T-row array, the token
+    # side's gather of the combine; everything of the sorted side has `rows`
+    def made(body, m, width):
+        return len(re.findall(r"= bf16\[%d,%d\]" % (m, width), body))
+    assert made(bodies[1], pairs, 768) == 0
+    assert made(bodies[1], pairs, d) == 1 < made(bodies[0], pairs, d)
+    assert made(bodies[1], rows, 768) and made(bodies[1], rows, d)
 
 
 # the language-model cell's attention core (PR 31): 28 query heads on 4

@@ -414,6 +414,15 @@ def _op(name, start, dur, tf_op="", flops=0.0, nbytes=0.0):
      ("backward", "b1_moe/experts")),
     ("jit(step)/jvp(b1_moe)/checkpoint/~route/sort:",
      ("forward", "b1_moe/route")),
+    # inside the branches of a bounded moe layer's cond, and in its
+    # backward, which runs a vjp of its own (PR 33)
+    ("jit(step)/jvp(b2_moe)/cond/branch_1_fun/~dispatch/gather:",
+     ("forward", "b2_moe/dispatch")),
+    ("jit(step)/transpose(jvp(b2_moe))/jvp(b2_moe)/checkpoint/cond/"
+     "branch_1_fun/transpose(jvp(~experts))/pallas_call:",
+     ("backward", "b2_moe/experts")),
+    ("jit(step)/transpose(jvp(b2_moe))/jvp(b2_moe)/checkpoint/cond/"
+     "branch_0_fun/jvp(~combine)/gather:", ("backward", "b2_moe/combine")),
     # only the mark makes a sub-scope: a layer named as attention's is
     # stays a layer, behind a pipeline's control flow too
     ("jit(step)/jvp(out)/dot_general:", ("forward", "out")),
