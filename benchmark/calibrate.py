@@ -10,8 +10,11 @@ below the configuration's (``fp8``; ``bf16`` beside it, which should read
 what the program reads), and the reference with each fault a training cell
 can have planted in it (half of the batch left out; on four chips the
 exchange left out, which leaves each chip a quarter), each put in the
-program's place against the same reference (the upper readings). What it
-read goes to ``chiprun_out/calibrate/<workload>.json``. A benchmark run never
+program's place against the same reference (the upper readings). Where
+both count something of the run (a language model's pairs held), the
+program's own count after its last step stands beside the reference's,
+step by step. What it read goes to
+``chiprun_out/calibrate/<workload>.json``. A benchmark run never
 calls this; PERF.md records what it printed.
 """
 
@@ -51,7 +54,8 @@ def main(argv=None) -> int:
     def make_ref(**kw):
         return reference.for_config(spec["conf_text"], cfg, batch, **kw)
     ref = make_ref()
-    wants, out = {}, {"workload": args.workload, "program": {}, "control": {}}
+    wants, out = {}, {"workload": args.workload, "program": {}, "control": {},
+                      "counted": {}}
 
     def want(seed):
         if seed not in wants:
@@ -60,6 +64,11 @@ def main(argv=None) -> int:
             print("reference", seed, "%.2f s" % (time.perf_counter() - t),
                   flush=True)
         return wants[seed]
+
+    def save():
+        os.makedirs(OUT, exist_ok=True)
+        with open(os.path.join(OUT, args.workload + ".json"), "w") as f:
+            json.dump(out, f, indent=1)
 
     def show(tag, seed, nums):
         row = {k: v["value"] for k, v in nums.items()}
@@ -71,10 +80,18 @@ def main(argv=None) -> int:
         program = program_of.Program(spec["conf_text"], cfg, chips, seed,
                                      traffic)
         got = kind.first_steps(program, ref.hyper, steps)
+        said = program.gauges() if hasattr(program, "gauges") else {}
         program.release()
         del program
         out["program"][seed] = show("program", seed,
                                     compare.numbers(got, want(seed)))
+        if said or want(seed).get("pairs_held"):
+            out["counted"][seed] = {
+                "program_last_step": said,
+                "reference": want(seed).get("pairs_held")}
+            print("counted", seed, json.dumps(out["counted"][seed]),
+                  flush=True)
+        save()
     controls = {"fp8": {"precision": "fp8"}, "bf16": {"precision": "bf16"},
                 "half_batch": {"rows_used": batch // 2}}
     if chips > 1:
@@ -87,9 +104,7 @@ def main(argv=None) -> int:
                 name, seed, compare.numbers(other.run(seed, steps),
                                             want(seed)))
         del other
-    os.makedirs(OUT, exist_ok=True)
-    with open(os.path.join(OUT, args.workload + ".json"), "w") as f:
-        json.dump(out, f, indent=1)
+        save()
     return 0
 
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from . import netconf
+from .inputs import seed_key
 
 
 def vocab_of(layers) -> int:
@@ -51,6 +52,18 @@ def weight_shapes(layers: List[netconf.Layer]) -> Dict[str, Dict[str, tuple]]:
         elif lay.type == "conv":
             out[lay.name] = {"wmat": (lay.geti("nchannel"), d)}
     return out
+
+
+def model_axes(layers: List[netconf.Layer]) -> Dict[str, Dict[str, int]]:
+    """layer name -> {tag: the axis of that leaf that counts the model's
+    hidden units}, the width of the residual stream: ``weight_shapes``'
+    shapes, the axis that is ``d`` there."""
+    by_type = {"embed": {"wmat": 1}, "rmsnorm": {"gain": 0},
+               "attention": {"wmat": 0, "wo": 1},
+               "moe": {"wmat": 1, "gate": 1, "up": 1, "down": 2},
+               "conv": {"wmat": 1}}
+    return {lay.name: by_type[lay.type] for lay in layers
+            if lay.type in by_type}
 
 
 def make_leaf(key, index: int, shape, sigma: float):
@@ -92,11 +105,39 @@ def make_tokens(key, batch_id: int, rows: int, seq_len: int, vocab: int):
     return ids[:, :-1].reshape(rows, 1, 1, seq_len), ids[:, 1:]
 
 
-def make_params(leaves, sigmas: Dict[str, float], key):
+def make_params(leaves, sigmas: Dict[str, float], key, base_key,
+                axes: Dict[str, Dict[str, int]]):
     """layer name -> {tag: array} of all the weights (``sigmas``: what
-    ``sigmas_of`` gives). Call it under one jit."""
+    ``sigmas_of`` gives; ``axes``: ``model_axes``'). Call it under one jit.
+
+    Every seed gives the SAME model with its hidden units in another
+    order: the leaves are drawn from ``base_key`` (the configuration's
+    ``weights_base_seed``), and ``key`` (the run's ``--seed``) draws one
+    permutation of the model's width that every leaf's model axis is
+    reordered by (embedding columns, the norms' gains, the rows of what
+    reads the stream, the columns of what writes it). That is an exact
+    symmetry of the model: every token meets the same experts under every
+    seed, so a step is the same work, and only the order of the sums, and
+    with it the rounding, differs."""
     params = {}
     for i, name, tag, shape in leaves:
-        params.setdefault(name, {})[tag] = make_leaf(key, i, shape,
+        params.setdefault(name, {})[tag] = make_leaf(base_key, i, shape,
                                                      sigmas[name])
+    width = next(shape[axes[name][tag]] for _, name, tag, shape in leaves)
+    order = jax.random.permutation(jax.random.fold_in(key, 99), width)
+    for _, name, tag, _ in leaves:
+        params[name][tag] = jnp.take(params[name][tag], order,
+                                     axis=axes[name][tag])
     return params
+
+
+def params_from_seed(layers, glob: Dict[str, str], cfg: dict):
+    """``key -> params``: what a run of a configuration starts from, for
+    the program's adapter and the reference alike. The model is the one of
+    the configuration's ``weights_base_seed`` (``make_params`` says what
+    the run's seed then does); a ``cfg`` that states none, as the program's
+    own tests make them, gets the model of seed 0."""
+    leaves, sigmas = leaves_of(layers), sigmas_of(layers, glob)
+    base_key = seed_key(cfg.get("weights_base_seed", 0))
+    axes = model_axes(layers)
+    return lambda key: make_params(leaves, sigmas, key, base_key, axes)

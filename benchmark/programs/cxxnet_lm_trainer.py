@@ -4,7 +4,8 @@ model, as a configuration's ``"program"`` key names it.
 Like ``cxxnet_trainer`` it touches the program only through what
 ``bin/cxxnet`` itself uses for ``task = train`` (``Trainer()``,
 ``set_param`` per conf pair, ``init_model``, ``set_weight``, ``update``) and
-three attributes read, never written: ``last_health`` (the step's own loss),
+four attributes read, never written: ``last_health`` (the step's own loss
+and, behind the four health values, what ``health_gauge_names`` names),
 ``opt_state`` (AdamW's first moment after the first step) and ``params``.
 
 The configuration's ``batch_per_chip`` counts TOKENS a step (the window's
@@ -52,13 +53,11 @@ class Program:
 
         layers, glob = netconf.parse(conf_text)
         self.leaves = lm_inputs.leaves_of(layers)
-        sigmas = lm_inputs.sigmas_of(layers, glob)
         self.key = inputs.seed_key(seed)
         # the weights the run starts from: made here from the seed, not
         # taken from the program, so that the reference can make the same
-        make_w = jax.jit(lambda k: lm_inputs.make_params(self.leaves, sigmas,
-                                                         k))
-        start = jax.block_until_ready(make_w(self.key))
+        make_w = lm_inputs.params_from_seed(layers, glob, cfg)
+        start = jax.block_until_ready(jax.jit(make_w)(self.key))
         mark("build.weights_s")
         for _, name, tag, _ in self.leaves:
             self.trainer.set_weight(start[name].pop(tag), name, tag)
@@ -82,7 +81,7 @@ class Program:
                 lambda v: jnp.sqrt(jnp.sum(jnp.square(v))), tree)
 
         def change_norms(now, key):
-            start = lm_inputs.make_params(self.leaves, sigmas, key)
+            start = make_w(key)
             return norms(jax.tree.map(
                 lambda w, w0: w.reshape(w0.shape) - w0, now, start))
         # one program each, not an operation a leaf
@@ -97,6 +96,16 @@ class Program:
     def sync(self) -> float:
         """Wait for the last step by fetching its loss."""
         return float(self.trainer.last_health[0])
+
+    def gauges(self) -> Dict[str, float]:
+        """What the last step's layers counted: the values the step returns
+        behind its four health values, by the trainer's names for them
+        (``moe.pairs_held/<layer>``, ``moe.load_max/<layer>``). Call it
+        after a ``sync``: it fetches the vector the sync waited for."""
+        import jax
+        names = getattr(self.trainer, "health_gauge_names", None) or []
+        values = jax.device_get(self.trainer.last_health)[4:]
+        return {n: float(v) for n, v in zip(names, values)}
 
     def _leaves(self, tree_of) -> dict:
         idx = self.trainer.net.cfg.get_layer_index

@@ -124,24 +124,35 @@ def route(lay, logits):
 
 
 def _moe(lay, precision, w, u, x_router):
-    """One sequence through the experts held: all of them on all tokens."""
+    """One sequence through the experts held: all of them on all tokens
+    (``tests/test_smallthinker.py`` holds the program's layer to it)."""
+    return _moe_counted(lay, precision, w, u, x_router)[0]
+
+
+def _moe_counted(lay, precision, w, u, x_router):
+    """``_moe``'s output and, beside it, the number of (token, expert)
+    pairs whose expert is held here: the rows a sparse lowering of this
+    layer has work on."""
     T, d = u.shape
     held, _, f = w["wmat"].shape
     lo = lay.geti("expert_offset")
-    probs = route(lay, _mm(precision, x_router, w["gate"].T))
-    mask = jnp.repeat(probs[:, lo:lo + held], f, axis=1)    # (T, held * f)
+    probs = route(lay, _mm(precision, x_router, w["gate"].T))[:, lo:lo + held]
+    pairs = jnp.sum(probs > 0)
+    mask = jnp.repeat(probs, f, axis=1)                     # (T, held * f)
     wide = lambda m: m.transpose(1, 0, 2).reshape(d, held * f)  # noqa: E731
     a = jnp.maximum(_mm(precision, u, wide(w["wmat"])), 0.0)
     if lay.params.get("expert_act", "relu") == "relu":
         # one matrix an expert: the weighted sum of the experts' outputs
-        return jnp.sum((a * mask).reshape(T, held, f), axis=1)
+        return jnp.sum((a * mask).reshape(T, held, f), axis=1), pairs
     a = a * _mm(precision, u, wide(w["up"]))
-    return _mm(precision, a * mask, w["down"].reshape(held * f, d))
+    return _mm(precision, a * mask, w["down"].reshape(held * f, d)), pairs
 
 
-def apply_layers(layers, precision, params, vals):
+def apply_layers(layers, precision, params, vals, pairs=None):
     """Apply ``layers`` in order to the node values ``vals`` of one
-    sequence (name -> array; node "0" holds the token ids), in place."""
+    sequence (name -> array; node "0" holds the token ids), in place.
+    ``pairs`` (a dict, where given) gets each ``moe`` layer's count of the
+    pairs its experts here held, by the layer's name."""
     for lay in layers:
         w = params.get(lay.name)
         a = vals[lay.ins[0]]
@@ -154,7 +165,10 @@ def apply_layers(layers, precision, params, vals):
         elif lay.type == "add":
             out = sum(vals[n] for n in lay.ins)
         elif lay.type == "moe":
-            out = _moe(lay, precision, w, a, vals[lay.ins[-1]])
+            out, held = _moe_counted(lay, precision, w, a,
+                                     vals[lay.ins[-1]])
+            if pairs is not None:
+                pairs[lay.name] = held
         elif lay.type == "conv":
             out = _mm(precision, a, w["wmat"].T)
         elif lay.type == "softmax":
@@ -178,36 +192,48 @@ def _pieces(layers):
     return pieces + [layers[start:]]
 
 
-def logits_of(layers, precision, params, ids):
-    """One sequence of token ids (T,) -> logits (T, vocab). Each piece runs
-    under ``jax.checkpoint``: what stays alive across a cut is the residual
+def forward(layers, precision, params, ids):
+    """One sequence of token ids (T,) -> logits (T, vocab) and {``moe``
+    layer: the pairs its experts here held}. Each piece runs under
+    ``jax.checkpoint``: what stays alive across a cut is the residual
     stream, and the backward pass holds one block's intermediates."""
-    vals = {"0": ids}
+    vals, pairs = {"0": ids}, {}
     for piece in _pieces(layers):
         need = {n: vals[n] for lay in piece for n in lay.ins if n in vals}
         last = piece[-1].outs[0]
 
         def run(p, xs, piece=piece, last=last):
-            return apply_layers(piece, precision, p, dict(xs))[last]
-        vals = {last: jax.checkpoint(run)(params, need)}
-    return vals[layers[-1].outs[0]]
+            held = {}
+            out = apply_layers(piece, precision, p, dict(xs), held)[last]
+            return out, held
+        out, held = jax.checkpoint(run)(params, need)
+        vals = {last: out}
+        pairs.update(held)
+    return vals[layers[-1].outs[0]], pairs
+
+
+def logits_of(layers, precision, params, ids):
+    """``forward``'s logits alone."""
+    return forward(layers, precision, params, ids)[0]
 
 
 def loss_sum(layers, precision, params, data, label, rows_used):
     """Sum of the next-token cross-entropy over the first ``rows_used``
-    tokens of the batch (rows one after another). ``data`` (rows, 1, 1, L)
-    and ``label`` (rows, L) as the program gets them."""
+    tokens of the batch (rows one after another), and ``forward``'s pairs
+    held summed over the batch's rows. ``data`` (rows, 1, 1, L) and
+    ``label`` (rows, L) as the program gets them."""
     rows, L = label.shape
 
     def one(ids, lab, used):
-        logp = jax.nn.log_softmax(
-            logits_of(layers, precision, params, ids), axis=-1)
+        logits, pairs = forward(layers, precision, params, ids)
+        logp = jax.nn.log_softmax(logits, axis=-1)
         ce = -jnp.take_along_axis(logp, lab[:, None].astype(jnp.int32),
                                   axis=1)[:, 0]
-        return jnp.sum(jnp.where(used, ce, 0.0))
+        return jnp.sum(jnp.where(used, ce, 0.0)), pairs
     ids = data.reshape(rows, L).astype(jnp.int32)
     used = (jnp.arange(rows * L) < rows_used).reshape(rows, L)
-    return jnp.sum(jax.vmap(one)(ids, label, used))
+    ce, pairs = jax.vmap(one)(ids, label, used)
+    return jnp.sum(ce), jax.tree.map(jnp.sum, pairs)
 
 
 def _norms(tree) -> Dict[str, jnp.ndarray]:
@@ -218,13 +244,16 @@ def _norms(tree) -> Dict[str, jnp.ndarray]:
 class Reference:
     """Three steps of training from a seed; ``run`` returns what the
     comparison reads: each step's loss, the norm of every leaf's first
-    gradient and of its change over the steps."""
+    gradient and of its change over the steps. Beside them, read by no
+    comparison, ``pairs_held``: each ``moe`` layer's pairs held in each of
+    the steps, which ``kernel_work`` counts the experts' products by."""
 
-    def __init__(self, conf_text: str, seq_len: int, batch: int,
+    def __init__(self, conf_text: str, cfg: dict, batch: int,
                  precision: str = "highest", rows_used: int = 0):
         if precision not in PRECISIONS:
             raise ValueError("precision %r" % precision)
         self.layers, self.glob = netconf.parse(conf_text)
+        seq_len = cfg["seq_len"]
         if batch % seq_len:
             raise ValueError("batch of %d tokens is no whole number of "
                              "sequences of %d" % (batch, seq_len))
@@ -232,8 +261,9 @@ class Reference:
         self.batch = batch                         # tokens a step
         self.rows_used = rows_used or batch
         self.vocab = lm_inputs.vocab_of(self.layers)
-        self.sigmas = lm_inputs.sigmas_of(self.layers, self.glob)
         self.leaves = lm_inputs.leaves_of(self.layers)
+        self._weights = lm_inputs.params_from_seed(self.layers, self.glob,
+                                                   cfg)
         by_name = {lay.name: lay for lay in self.layers}
         adam = {"beta1": float(self.glob.get("beta1", 0.9)),
                 "beta2": float(self.glob.get("beta2", 0.999)),
@@ -251,7 +281,7 @@ class Reference:
                             self.rows_used)
         # few programs, each whole: every one is a load from the compile
         # cache in every run of every later check
-        self._grad = jax.jit(jax.value_and_grad(loss))
+        self._grad = jax.jit(jax.value_and_grad(loss, has_aux=True))
         self._init = jax.jit(self._start)
         self._tokens = jax.jit(lambda key, batch_id: lm_inputs.make_tokens(
             key, batch_id, self.rows, self.seq_len, self.vocab))
@@ -259,9 +289,6 @@ class Reference:
         self._norms_of = jax.jit(_norms)
         self._change = jax.jit(lambda new, key: _norms(
             jax.tree.map(jnp.subtract, new, self._weights(key))))
-
-    def _weights(self, key):
-        return lm_inputs.make_params(self.leaves, self.sigmas, key)
 
     def _start(self, key):
         params = self._weights(key)
@@ -290,17 +317,19 @@ class Reference:
     def for_config(cls, conf_text: str, cfg: dict, batch: int, **kw):
         """The reference of one configuration file at one global batch
         (``batch`` counts tokens, as ``batch_per_chip`` does)."""
-        return cls(conf_text, cfg["seq_len"], batch, **kw)
+        return cls(conf_text, cfg, batch, **kw)
 
     def run(self, seed: int, n_steps: int = 3) -> dict:
         key = seed_key(seed)
         params, m1, m2 = self._init(key)
-        losses, grad_norms = [], None
+        losses, grad_norms, pairs_held = [], None, {}
         with jax.default_matmul_precision("highest"):
             for step in range(n_steps):
                 data, label = self._tokens(key, step % 2)
-                total, grads = self._grad(params, data, label)
+                (total, pairs), grads = self._grad(params, data, label)
                 losses.append(float(total) / self.rows_used)
+                for name, n in jax.device_get(pairs).items():
+                    pairs_held.setdefault(name, []).append(int(n))
                 if step == 0:
                     grad_norms = {
                         n: float(v) / self.rows_used for n, v in
@@ -309,7 +338,8 @@ class Reference:
                 del grads
             change = jax.device_get(self._change(params, key))
         return {"loss": losses, "grad_norm": grad_norms,
-                "change_norm": {n: float(v) for n, v in change.items()}}
+                "change_norm": {n: float(v) for n, v in change.items()},
+                "pairs_held": pairs_held}
 
 
 # what a window kind asks of a reference's file
@@ -320,3 +350,47 @@ def train_flops_per_item(conf_text: str, cfg: dict) -> float:
     """Model FLOPs of one trained token of this configuration."""
     return lm_flops.train_flops_per_item(conf_text, cfg["seq_len"])
 
+
+def kernel_work(conf_text: str, cfg: dict, name: str, ctx: dict):
+    """FLOPs and bytes one training step of this configuration needs of the
+    named kernel, all layers that run it summed: the model's operations and
+    the bytes it cannot avoid, the forward pass three times over, nothing
+    made again counted. ``flash_attention``: every attention layer's core by
+    its mask. ``expert_product``: every ``moe`` layer's grouped products
+    over the pairs its experts here hold on the run's two resident batches,
+    as this reference counted them in its own first two steps
+    (``ctx["want"]["pairs_held"]``, the mean of the two: the window
+    alternates them; a recipe under which the routing drifts through a run
+    makes this the count of the run's start, PERF.md section 6), and over
+    even routing's where no reference has run; ``pairs_a_step`` says how
+    many that was. Nothing the program says of itself is counted. Nothing
+    for a name not known here."""
+    layers, _ = netconf.parse(conf_text)
+    seq = cfg["seq_len"]
+    rows = cfg["batch_per_chip"] // seq
+    d = next(lay.geti("nhidden") for lay in layers if lay.type == "embed")
+    said = {}
+    if name == "flash_attention":
+        each = rows                     # a sequence at a time
+        works = [lm_flops.flash_attention(seq, a["nh"], a["nkv"], a["dh"],
+                                          a["window"])
+                 for a in (lm_flops._dims(lay, d) for lay in layers
+                           if lay.type == "attention")]
+    elif name == "expert_product":
+        each = 1                        # the pairs are the whole step's
+        counted = (ctx.get("want") or {}).get("pairs_held") or {}
+        works, said["pairs_a_step"] = [], 0.0
+        for lay in (lay for lay in layers if lay.type == "moe"):
+            e, k = lay.geti("nexpert"), lay.geti("top_k")
+            held = lay.geti("nexpert_held") or e
+            by_step = counted.get(lay.name, [])[:2]
+            pairs = sum(by_step) / len(by_step) if by_step \
+                else rows * seq * (k or e) * held / e
+            mats = 3 if lay.params.get("expert_act") == "reglu" else 1
+            works.append(lm_flops.expert_product(
+                pairs, d, lay.geti("nhidden"), held, mats))
+            said["pairs_a_step"] += pairs
+    else:
+        return None
+    return dict(said, **{key: 3.0 * each * sum(w[key] for w in works)
+                         for key in ("flops", "bytes")})

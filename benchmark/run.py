@@ -297,9 +297,10 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     limits = dict(spec["limits"], window_compiles=0.0, **out.get("limits", {}))
     rows = compare.judge(nums, limits)
     reduced = h.traced.reduced if h.traced else None
+    said = {}                 # what a reader says beside its number
     if trace:
         ctx = dict(out["ctx"], trace=reduced, chips=cell["chips"],
-                   peak=dev["peak"],
+                   peak=dev["peak"], said=said,
                    counters={"compile_cache_misses": h.misses_at_setup})
         metrics = per_layer_metrics(spec, ctx)
     else:
@@ -320,6 +321,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
                          setup_s=h.setup_s, setup_phases=phases)
     if reduced:
         result["run"]["class_s"] = reduced["class_s"]
+        result["run"]["scope_s"] = reduced["scope_s"]
+    if said:
+        result["run"]["readers"] = said
     result["compared"] = {r["name"]: [r["value"], r["limit"]] for r in rows}
     for r in rows:
         print("compared %-20s %-12.6g limit %-10s %s %s"

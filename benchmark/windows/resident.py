@@ -57,6 +57,10 @@ def run(spec: dict, seed: int, seconds: float, h) -> dict:
         trace_start=tracer.start if tracer else None,
         trace_stop=tracer.stop if tracer else None)
     h.window_closed()
+    # what the program counted in the window's last step, where its adapter
+    # can say (after the window's last sync: nothing waits for it): for the
+    # reader of the line, no metric is made of it
+    gauges = program.gauges() if hasattr(program, "gauges") else {}
 
     # ---- the comparison, once the window has closed and the peak is read
     program.release()
@@ -73,9 +77,12 @@ def run(spec: dict, seed: int, seconds: float, h) -> dict:
         "end_to_end": {"train_items_per_s_per_chip": rate},
         "numbers": nums, "limits": {"window_failed_steps": 0.0},
         "ctx": {"window": win, "flops_per_item":
-                reference.train_flops_per_item(spec["conf_text"], cfg)},
+                reference.train_flops_per_item(spec["conf_text"], cfg),
+                "reference": reference, "conf_text": spec["conf_text"],
+                "cfg": cfg, "want": want},
         "run": {"window_s": win["elapsed_s"], "groups": win["groups"],
-                "reference_s": ref_s, "items_per_s_per_chip": rate},
+                "reference_s": ref_s, "items_per_s_per_chip": rate,
+                **({"gauges": gauges} if gauges else {})},
     }
 
 

@@ -24,6 +24,8 @@ from benchmark.windows import resident as window  # noqa: E402
 
 BENCH = os.path.join(ROOT, "benchmark")
 FIXTURES = os.path.join(BENCH, "fixtures")
+ACCEPTED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "accepted.json")
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -38,10 +40,59 @@ def _conf(name):
         return f.read()
 
 
+def _accepted():
+    with open(ACCEPTED) as f:
+        return json.load(f)
+
+
 # --------------------------------------------------------------- (a) lint
-def lint(manifest, root):
-    """The rules of the manifest that a file can be checked against."""
+def append_only(manifest, root, accepted):
+    """The one rule on where things stand: what ``accepted.json`` records
+    (a ``benchmark`` PR brings it up to date; no other PR touches it) is
+    still there and still first, in its order. A later PR puts a
+    configuration, a cell, a per-layer metric and a cell on a metric's
+    ``workloads`` list at the END of the list it joins: the driver reads an
+    entry put before an accepted one as a change to that one. Nothing is
+    said about what stands last."""
     errs = []
+
+    def prefix(what, was, now):
+        if now[:len(was)] != was:
+            errs.append("append-only: %s has %s where accepted.json records "
+                        "%s first: add at the end, move and remove nothing"
+                        % (what, now[:len(was)], was))
+    cells = [w["name"] for w in manifest["workloads"]]
+    metrics = {m["name"]: m for m in manifest["per_layer"]}
+    prefix("configs", accepted["configs"],
+           [c["name"] for c in manifest["configs"]])
+    prefix("workloads", accepted["workloads"], cells)
+    prefix("per_layer", accepted["per_layer"], list(metrics))
+    for name, was in accepted["per_layer_workloads"].items():
+        if name in metrics:
+            prefix("per_layer %s: workloads" % name, was,
+                   metrics[name].get("workloads", cells))
+    for m in manifest["per_layer"]:
+        listed = [c for c in m.get("workloads", cells) if c in cells]
+        if listed != [c for c in cells if c in listed]:
+            errs.append("append-only: per_layer %s lists its cells in "
+                        "another order than workloads has them" % m["name"])
+    for name in accepted["lists_every_cell"]:
+        if name in metrics and metrics[name].get("workloads", cells) != cells:
+            errs.append("append-only: per_layer %s lists every cell, a new "
+                        "one too" % name)
+    for directory, files in accepted["files"].items():
+        for f in files:
+            if not os.path.exists(os.path.join(root, "benchmark", directory,
+                                               f)):
+                errs.append("append-only: benchmark/%s/%s is recorded in "
+                            "accepted.json and is not there"
+                            % (directory, f))
+    return errs
+
+
+def lint(manifest, root, accepted=None):
+    """The rules of the manifest that a file can be checked against."""
+    errs = append_only(manifest, root, accepted or _accepted())
     if set(manifest) != {"command", "paths", "run_seconds", "configs",
                          "workloads", "end_to_end", "per_layer"}:
         errs.append("keys")
@@ -121,6 +172,30 @@ def test_lint_catches_a_bad_manifest():
     assert len(lint(m, ROOT)) == 3
 
 
+def limits_errors(limits_file):
+    """A limits file of a training cell: the five numbers, each with a
+    limit in (0, 1). A loss (never a norm) may hold ``null``, read and
+    shown but not compared, only with its reason beside it under
+    ``"not_compared": {"<name>": "<why>"}`` (PERF.md section 2 has the
+    readings)."""
+    limits = limits_file["limits"]
+    why = limits_file.get("not_compared", {})
+    errs = []
+    if set(limits) != {"loss1", "loss2", "loss3", "grad_worst",
+                       "change_worst"}:
+        errs.append("names %s" % sorted(limits))
+    for name, v in limits.items():
+        if v is None:
+            if not name.startswith("loss") or not why.get(name):
+                errs.append("%s is not compared and not_compared does not "
+                            "say why, or it is no loss" % name)
+        elif not 0 < v < 1:
+            errs.append("%s: limit %r" % (name, v))
+    if set(why) - {k for k, v in limits.items() if v is None}:
+        errs.append("not_compared names a number that has a limit")
+    return errs
+
+
 def test_every_metric_and_limit_file_is_there():
     m = _manifest()
     for metric in m["per_layer"]:
@@ -128,17 +203,37 @@ def test_every_metric_and_limit_file_is_there():
                                            metric["name"] + ".json")))
         assert callable(bench_run.load_reader(BENCH, desc["reader"]))
     for w in m["workloads"]:
-        limits = bench_run.resolve(w["name"])["limits"]
-        assert set(limits) == {"loss1", "loss2", "loss3", "grad_worst",
-                               "change_worst"}
-        # None: read and shown, not compared (PERF.md section 2 says why)
-        assert all(v is None or 0 < v < 1 for v in limits.values())
-        assert limits["grad_worst"] and limits["change_worst"]
-        assert [k for k, v in limits.items() if v is None] == (
-            ["loss2"] if w["name"] == "googlenet-resident" else [])
+        spec = bench_run.resolve(w["name"])
+        assert spec["limits"], w["name"]
+        if spec["traffic"]["kind"] == "resident":      # a training cell
+            with open(os.path.join(BENCH, "limits",
+                                   w["name"] + ".json")) as f:
+                assert limits_errors(json.load(f)) == [], w["name"]
+    # what the cells hold: the two losses with no upper reading are shown,
+    # each with its reason; the language-model cell's limits were set anew
+    # (PR 34) from the cell as committed, one model under every seed
+    held = {w["name"]: bench_run.resolve(w["name"])["limits"]
+            for w in m["workloads"][:4]}
+    assert [k for k, v in held["googlenet-resident"].items()
+            if v is None] == ["loss2"]
+    assert held["smallthinker-ep4-train-8k"] == {
+        "loss1": None, "loss2": 6e-5, "loss3": 3e-4, "grad_worst": 0.06,
+        "change_worst": 0.03}
+    assert limits_errors({"limits": dict(held["alexnet-resident"],
+                                         loss2=None)}) != []
+    assert limits_errors({"limits": dict(held["alexnet-resident"],
+                                         grad_worst=None),
+                          "not_compared": {"grad_worst": "why"}}) != []
 
 
 # ------------------------------------------- (b) the harness is driven by data
+def _join_every_cell_lists(manifest, cell):
+    """A new cell goes to the end of the lists that hold every cell."""
+    for m in manifest["per_layer"]:
+        if m["name"] in _accepted()["lists_every_cell"]:
+            m["workloads"].append(cell)
+
+
 def test_new_cell_metric_and_reader_are_found_by_name(tmp_path):
     before = {}
     for d, _, files in os.walk(BENCH):
@@ -173,6 +268,7 @@ def test_new_cell_metric_and_reader_are_found_by_name(tmp_path):
     m["workloads"].append({"name": "other-cell", "config": "other-net",
                            "traffic": "short-groups", "chips": 1,
                            "why": "a test"})
+    _join_every_cell_lists(m, "other-cell")
     m["per_layer"].append({"name": "steps_twice", "unit": "count",
                            "better": "higher", "source": "program_counter",
                            "layer": "train step, whole",
@@ -185,7 +281,9 @@ def test_new_cell_metric_and_reader_are_found_by_name(tmp_path):
     spec = bench_run.resolve("other-cell", bench, manifest_path)
     assert spec["cfg"]["batch_per_chip"] == 64
     assert spec["traffic"]["sync_every"] == 4
-    assert [x["name"] for x in spec["per_layer"]] == ["steps_twice"]
+    assert [x["name"] for x in spec["per_layer"]] == [
+        "init_model_s", "step_build_s", "steps_twice"]
+    spec["per_layer"] = spec["per_layer"][2:]
     got = bench_run.per_layer_metrics(spec, {"window": {"steps": 21}})
     assert got == {"steps_twice": {"value": 42.0, "unit": "count"}}
     # an old cell does not report the new metric, and no file was edited
@@ -193,6 +291,147 @@ def test_new_cell_metric_and_reader_are_found_by_name(tmp_path):
     assert "steps_twice" not in [x["name"] for x in old["per_layer"]]
     for rel, data in before.items():
         assert open(os.path.join(bench, rel), "rb").read() == data
+
+
+def _next_model_config_pr(bench, where):
+    """What the next ``model_config`` PR brings, as files in the copy
+    ``bench`` and as entries of the manifest that comes back: one
+    configuration, one one-chip cell on the mix ``resident``, two per-layer
+    metrics (a kernel's roofline share as a data file over a
+    ``kernel_work`` name of its own new reference, and a reader of its
+    own), a program and a limits file. ``where``: ``"end"`` appends
+    everything, as the rule asks; ``"middle"`` puts each entry before the
+    last accepted one of its list."""
+    def write(rel, text):
+        with open(os.path.join(bench, rel), "w") as f:
+            f.write(text)
+    write("configs/next-lm.json", json.dumps(
+        {"name": "next-lm", "program": "next_trainer",
+         "reference": "next_lm", "seq_len": 64, "batch_per_chip": 128}))
+    write("programs/next_trainer.py", "class Program:\n    pass\n")
+    write("references/next_lm.py",
+          "def kernel_work(conf_text, cfg, name, ctx):\n"
+          "    if name == 'delta_rule':\n"
+          "        return {'flops': 2e9 * cfg['batch_per_chip'],\n"
+          "                'bytes': 1e6}\n")
+    write("limits/next-cell.json", json.dumps(
+        {"limits": {"loss1": 1e-4, "loss2": None, "loss3": 1e-4,
+                    "grad_worst": 0.05, "change_worst": 0.05},
+         "not_compared": {"loss2": "no upper reading"}}))
+    write("metrics/delta_rule_roofline.json", json.dumps(
+        {"reader": "scope_roofline_share",
+         "args": {"scopes": ["*_gdn/core"], "work": "delta_rule"}}))
+    write("metrics/gdn_state_rows.json", json.dumps(
+        {"reader": "counted_sum", "args": {"key": "state_rows"}}))
+    write("readers/counted_sum.py",
+          "def read(ctx, key):\n"
+          "    got = (ctx.get('want') or {}).get(key)\n"
+          "    return sum(got.values()) if got else None\n")
+    m = _manifest()
+    at = (lambda seq: len(seq)) if where == "end" else (
+        lambda seq: len(seq) - 1)
+    m["configs"].insert(at(m["configs"]), {
+        "name": "next-lm", "source": "a model card",
+        "file": "benchmark/configs/next-lm.json",
+        "reduced": ["num_hidden_layers"], "why": "a rehearsal"})
+    m["workloads"].insert(at(m["workloads"]), {
+        "name": "next-cell", "config": "next-lm", "traffic": "resident",
+        "chips": 1, "why": "a rehearsal"})
+    joined = [p for p in m["per_layer"]
+              if "smallthinker-ep4-train-8k" in p["workloads"]][:7]
+    for p in joined:
+        p["workloads"].insert(at(p["workloads"]), "next-cell")
+    for name, unit, source in (
+            ("delta_rule_roofline", "%", "device_trace"),
+            ("gdn_state_rows", "count", "program_counter")):
+        m["per_layer"].insert(at(m["per_layer"]), {
+            "name": name, "unit": unit, "better": "higher", "source": source,
+            "layer": "kernels", "moves": "train_items_per_s_per_chip",
+            "workloads": ["next-cell"]})
+    return m, [p["name"] for p in joined]
+
+
+def _removed(m):
+    del m["per_layer"][4]
+    return m
+
+
+def _reordered(m):
+    m["workloads"][0], m["workloads"][1] = m["workloads"][1], \
+        m["workloads"][0]
+    return m
+
+
+def _cell_taken_off_a_list(m):
+    m["per_layer"][0]["workloads"].remove("alexnet-dp4")
+    return m
+
+
+@pytest.mark.parametrize("where,then,fails_with", [
+    ("end", None, None),
+    ("middle", None, "add at the end, move and remove nothing"),
+    ("end", _removed, "per_layer has"),
+    ("end", _reordered, "workloads has"),
+    ("end", _cell_taken_off_a_list,
+     "per_layer compile_cache_misses: workloads has"),
+], ids=["appended", "in_the_middle", "a_metric_removed", "cells_reordered",
+        "a_cell_taken_off_a_list"])
+def test_the_next_model_config_pr_appends_and_edits_nothing(
+        tmp_path, where, then, fails_with):
+    """The rehearsal of what ISSUE 34 makes room for. Appended, it lints
+    clean, resolves, and its data-file metric reads the new reference's
+    ``kernel_work``; the same put before what is accepted, a removal and a
+    reorder each fail by the append-only rule's own message."""
+    bench = str(tmp_path / "benchmark")
+    shutil.copytree(BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {os.path.join(d, f): open(os.path.join(d, f), "rb").read()
+              for d, _, files in os.walk(bench) for f in files}
+    m, joined = _next_model_config_pr(bench, where)
+    if then:
+        m = then(m)
+    errs = lint(m, str(tmp_path))
+    if fails_with:
+        assert errs and all(e.startswith("append-only: ") for e in errs)
+        assert any(fails_with in e for e in errs), errs
+        return
+    assert errs == []
+    assert len(joined) == 7
+    manifest_path = str(tmp_path / "BENCHMARK.json")
+    json.dump(m, open(manifest_path, "w"))
+    spec = bench_run.resolve("next-cell", bench, manifest_path)
+    assert [x["name"] for x in spec["per_layer"]] == joined + [
+        "delta_rule_roofline", "gdn_state_rows"]
+    assert spec["limits"]["loss2"] is None
+    with open(os.path.join(bench, "limits", "next-cell.json")) as f:
+        assert limits_errors(json.load(f)) == []
+    for directory, name in (("programs", spec["cfg"]["program"]),
+                            ("references", spec["cfg"]["reference"])):
+        assert bench_run.load_part(bench, directory, name)
+    # the two new metrics through run.py as it stands: 256 GFLOP a step in
+    # 2.6 ms a step is half of the peak; the accepted cells do not gain them
+    spec["per_layer"] = [x for x in spec["per_layer"] if x["name"] in (
+        "delta_rule_roofline", "gdn_state_rows")]
+    ctx = {"window": {"steps": 40},
+           "want": {"state_rows": {"b0_gdn": 7.0, "b1_gdn": 5.0}},
+           "trace": {"steps": 10, "busy_s": 1.0, "scope_s": {
+               "forward": {"b0_gdn/core": 0.006, "b1_gdn/core": 0.004},
+               "backward": {"b0_gdn/core": 0.009, "b1_gdn/core": 0.007,
+                            "b0_gdn/conv": 0.5}}},
+           "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+           "reference": bench_run.load_part(bench, "references", "next_lm"),
+           "conf_text": None, "cfg": spec["cfg"], "said": {}}
+    got = bench_run.per_layer_metrics(spec, ctx)
+    assert got["gdn_state_rows"] == {"value": 12.0, "unit": "count"}
+    assert got["delta_rule_roofline"]["value"] == pytest.approx(
+        100 * (256e9 / 197e12) / 0.0026)
+    assert ctx["said"]["roofline/delta_rule"]["bound"] == "compute"
+    old = bench_run.resolve("smallthinker-ep4-train-8k", bench,
+                            manifest_path)
+    assert not {"delta_rule_roofline", "gdn_state_rows"} & {
+        x["name"] for x in old["per_layer"]}
+    for path, data in before.items():
+        assert open(path, "rb").read() == data
 
 
 def test_new_window_kind_program_and_reference_run_with_no_edit(tmp_path):
@@ -246,6 +485,7 @@ class Program:
                                                 "count-more")):
         m["workloads"].append({"name": name, "config": "counted",
                                "traffic": mix, "chips": 1, "why": "a test"})
+        _join_every_cell_lists(m, name)
     manifest_path = str(tmp_path / "BENCHMARK.json")
     json.dump(m, open(manifest_path, "w"))
     assert lint(m, str(tmp_path)) == []
@@ -355,6 +595,207 @@ def test_reduce_recorded_trace_matches_what_is_written_beside_it():
         [g[0] for g in want["idle_gaps"]]
     assert got["device_ops"][0][0] == want["device_ops"][0][0]
     assert 0 < got["busy_s"] <= got["window_s"]
+
+
+# ----------------------------------------------------- (c') scopes, readers
+# tf_ops as the cells' own traces have them (my chip runs, PR 34: the
+# language-model cell's and the conv cells' traced runs; PERF.md section 3
+# writes the rules down)
+_TF_OPS = [
+    ("jit(step)/jvp(conv1)/conv_general_dilated:", ("forward", "conv1")),
+    ("jit(step)/transpose(jvp(conv1))/conv_general_dilated:",
+     ("backward", "conv1")),
+    ("jit(step)/update/conv1/mul:", ("update", "conv1")),
+    ("jit(step)/jvp(i3a_1x1+i3a_3x3r+i3a_5x5r)/conv_general_dilated:",
+     ("forward", "i3a_1x1+i3a_3x3r+i3a_5x5r")),
+    ("jit(step)/jvp(b0_att)/~core/pallas_call:", ("forward", "b0_att/core")),
+    ("jit(step)/transpose(jvp(b0_att))/~qkv/dot_general:",
+     ("backward", "b0_att/qkv")),
+    ("jit(step)/transpose(jvp(b1_moe))/jvp(b1_moe)/checkpoint/"
+     "rematted_computation/~experts/pallas_call:",
+     ("backward", "b1_moe/experts")),
+    ("jit(step)/jvp(b2_moe)/checkpoint/cond/branch_1_fun/~dispatch/gather:",
+     ("forward", "b2_moe/dispatch")),
+    ("jit(step)/transpose(jvp(b2_moe))/jvp(b2_moe)/checkpoint/cond/"
+     "branch_1_fun/transpose(jvp(~experts))/pallas_call:",
+     ("backward", "b2_moe/experts")),
+    ("jit(step)/transpose(jvp(b2_moe))/jvp(b2_moe)/checkpoint/cond/"
+     "branch_0_fun/jvp(~combine)/gather:", ("backward", "b2_moe/combine")),
+    ("jit(step)/jvp(head)/dot_general:", ("forward", "head")),
+    ("jit(step)/update/b3_moe/add:", ("update", "b3_moe")),
+    ("jit(step)/health/reduce_sum:", ("other", "health")),
+    ("jit(step)/cast_params/convert_element_type:",
+     ("other", "cast_params")),
+    ("jit(step)/jvp()/add:", ("forward", "-")),
+    ("jit(step)/transpose(jvp())/convert_element_type:", ("backward", "-")),
+    ("jit(step)/reduce_sum:", ("other", "-")),
+    ("jit(step)/transpose(jvp())/mul;jit(step)/transpose(jvp())/"
+     "broadcast_in_dim", ("backward", "-")),
+    ("", ("other", "-")),
+]
+
+
+@pytest.mark.parametrize("tf_op,want", _TF_OPS,
+                         ids=[t[-48:] or "no_tf_op" for t, _ in _TF_OPS])
+def test_scope_of_puts_a_tf_op_to_its_phase_and_scope(tf_op, want):
+    assert trace_reduce.scope_of(tf_op) == want
+    # the program's own tool (tools/trace_layers.py) says the same; it
+    # keeps a phase for `health` and two names for a row in no scope
+    from cxxnet_tpu.utils import devtrace
+    phase, layer = devtrace.scope_of(tf_op)
+    if phase == "health":
+        phase = "other"
+    if layer in (devtrace.UNNAMED, devtrace.NO_TF_OP):
+        layer = trace_reduce.NO_SCOPE
+    assert (phase, layer) == want
+
+
+def test_reduce_events_sums_self_time_by_phase_and_scope():
+    def fusion(n, kind="kLoop"):
+        return "%%fusion.%d = f32[8]{0} fusion(f32[8]{0} %%p), kind=%s, " \
+            "calls=%%f" % (n, kind)
+    core = "%_core.1 = bf16[8]{0} custom-call(bf16[8]{0} %q), " \
+           "custom_call_target=\"tpu_custom_call\""
+    dev = {"modules": [_ev("jit_step(1)", 0, 500), _ev("jit_step(1)", 500, 500)],
+           "ops": [
+               (core, 0.0, 100.0, "jit(step)/jvp(b0_att)/~core/pallas_call:"),
+               (fusion(1), 100.0, 50.0, "jit(step)/jvp(b0_att)/~qkv/mul:"),
+               # a fusion with an operation nested in it: self time
+               (fusion(2, "kOutput"), 200.0, 200.0,
+                "jit(step)/transpose(jvp(b0_att))/~core/dot_general:"),
+               (fusion(3), 250.0, 50.0, ""),
+               (core, 500.0, 100.0,
+                "jit(step)/jvp(b0_att)/~core/pallas_call:"),
+               (fusion(4), 600.0, 25.0, "jit(step)/update/b0_att/sub:"),
+               _ev(fusion(5), 700, 10)]}           # a plain tuple: no tf_op
+    r = trace_reduce.reduce_events({"/device:TPU:0": dev}, [], "jit_step")
+    assert r["scope_s"] == {
+        "forward": {"b0_att/core": pytest.approx(200e-9),
+                    "b0_att/qkv": pytest.approx(50e-9)},
+        "backward": {"b0_att/core": pytest.approx(150e-9)},
+        "update": {"b0_att": pytest.approx(25e-9)},
+        "other": {"-": pytest.approx(60e-9)}}
+    total = sum(v for rows in r["scope_s"].values() for v in rows.values())
+    assert total == pytest.approx(r["busy_s"]) == pytest.approx(
+        sum(r["class_s"].values()))
+    assert trace_reduce.scope_seconds(r["scope_s"], ["*_att/core"]) == \
+        pytest.approx(350e-9)
+    assert trace_reduce.scope_seconds(r["scope_s"], ["*_att/*"],
+                                      ["forward"]) == pytest.approx(250e-9)
+    assert trace_reduce.scope_seconds(r["scope_s"], ["*_moe/experts"]) is None
+
+
+def test_the_scoped_trace_reduces_to_what_is_written_beside_it():
+    path = os.path.join(FIXTURES, "scoped.xplane.pb")
+    want = json.load(open(os.path.join(FIXTURES, "scoped.expected.json")))
+    got = trace_reduce.reduce_trace(path, want["step_module"])
+    assert got["steps"] == want["steps"] == 4
+    assert set(got["scope_s"]) == {"forward", "backward", "update", "other"}
+    for phase, rows in want["scope_s"].items():
+        assert got["scope_s"][phase] == pytest.approx(rows, rel=1e-9), phase
+    for key in ("window_s", "busy_s"):
+        assert got[key] == pytest.approx(want[key], rel=1e-9)
+    assert got["class_s"] == pytest.approx(want["class_s"], rel=1e-9)
+    # every kind of scope the rules name is in it, with time under it
+    s = got["scope_s"]
+    assert s["forward"]["l0"] > 0 and s["backward"]["l0"] > 0
+    assert s["forward"]["l1/core"] > 0 and s["backward"]["l1/core"] > 0
+    assert s["backward"]["l1/gate"] > 0          # transpose(jvp(~gate))
+    assert s["update"]["l0"] > 0 and s["update"]["l1"] > 0
+    total = sum(v for rows in s.values() for v in rows.values())
+    assert total == pytest.approx(got["busy_s"], rel=1e-9)
+
+
+def test_the_benchmarks_tf_op_reader_agrees_with_the_programs():
+    """``trace_reduce.tf_ops`` and ``cxxnet_tpu.utils.devtrace.read_xplane``
+    decode the same file on their own: the same operations in the same
+    order with the same ``tf_op``, on both fixtures."""
+    from cxxnet_tpu.utils import devtrace
+    for name in ("scoped", "tiny"):
+        path = os.path.join(FIXTURES, name + ".xplane.pb")
+        ours = trace_reduce.tf_ops(path)
+        theirs, _ = devtrace.read_xplane(path)
+        assert set(ours) == set(theirs) and ours
+        for plane, ops in theirs.items():
+            assert ours[plane] == [(o.name, o.tf_op) for o in ops["ops"]]
+        devices, _ = trace_reduce.read_xplane(path)
+        for plane, lines in devices.items():
+            assert [(e[0], e[3]) for e in lines["ops"]] == ours[plane]
+    assert any(tf for _, tf in ours[plane])
+
+
+_SCOPED = {"steps": 10, "busy_s": 2.0, "window_s": 2.5, "scope_s": {
+    "forward": {"b0_att/core": 0.04, "b1_att/core": 0.03,
+                "b0_moe/experts": 0.02, "b0_att/qkv": 0.05},
+    "backward": {"b0_att/core": 0.11, "b1_att/core": 0.09,
+                 "b0_moe/experts": 0.06}}}
+
+
+def test_scope_time_share_reads_the_matching_rows_and_nothing_without():
+    read = bench_run.load_reader(BENCH, "scope_time_share")
+    ctx = {"trace": _SCOPED}
+    assert read(ctx, scopes=["*_att/core"]) == pytest.approx(13.5)
+    assert read(ctx, scopes=["*_att/core"], phases=["forward"]) == \
+        pytest.approx(3.5)
+    assert read(ctx, scopes=["*_moe/experts", "b0_att/qkv"]) == \
+        pytest.approx(6.5)
+    # a program that opens no such scope, no trace, a reduction of before
+    assert read(ctx, scopes=["*_gdn/core"]) is None
+    assert read({"trace": None}, scopes=["*_att/core"]) is None
+    assert read({"trace": {"busy_s": 2.0}}, scopes=["*_att/core"]) is None
+
+
+def test_scope_roofline_share_asks_the_reference_and_says_what_bounds_it():
+    import types
+    read = bench_run.load_reader(BENCH, "scope_roofline_share")
+    asked = []
+
+    def kernel_work(conf_text, cfg, name, ctx):
+        asked.append((conf_text, cfg, name))
+        return {"flash": {"flops": 1.97e12, "bytes": 8.19e8},
+                "stream": {"flops": 1.97e9, "bytes": 8.19e9}}.get(name)
+    ctx = {"trace": _SCOPED, "peak": {"bf16_flops_per_s": 197e12,
+                                      "hbm_bytes_per_s": 819e9},
+           "reference": types.SimpleNamespace(kernel_work=kernel_work),
+           "conf_text": "conf", "cfg": {"seq_len": 8}}
+    # 0.27 s over 10 steps; 1.97 TFLOP need 10 ms of the peak
+    assert read(ctx, scopes=["*_att/core"], work="flash") == \
+        pytest.approx(100 * 0.010 / 0.027)
+    assert asked == [("conf", {"seq_len": 8}, "flash")]
+    assert ctx["said"]["roofline/flash"]["bound"] == "compute"
+    assert ctx["said"]["roofline/flash"]["kernel_s_a_step"] == \
+        pytest.approx(0.027)
+    assert read(ctx, scopes=["*_moe/experts"], work="stream") == \
+        pytest.approx(100 * 0.010 / 0.008)     # the reader clips nothing
+    assert ctx["said"]["roofline/stream"]["bound"] == "memory"
+    # nothing to read, never 0: no matching row (the parent's program, a
+    # stale executable), a name the reference does not know, a reference
+    # without kernel_work, no trace
+    assert read(ctx, scopes=["*_gdn/core"], work="flash") is None
+    assert read(ctx, scopes=["*_att/core"], work="unknown") is None
+    assert read(dict(ctx, reference=types.SimpleNamespace()),
+                scopes=["*_att/core"], work="flash") is None
+    assert read(dict(ctx, reference=reference), scopes=["*_att/core"],
+                work="flash") is None          # convnet has no kernel_work
+    assert read(dict(ctx, trace=None), scopes=["*_att/core"],
+                work="flash") is None
+
+
+@pytest.mark.parametrize("counted,want", [
+    ({"moe.sparse": 4, "attn.flash": 4}, 0),
+    ({"moe.sparse": 3, "moe.dense": 1}, 1),
+    ({"attn.flash": 4}, None),
+    (None, None)], ids=["all_sparse", "one_dense", "no_moe_layer",
+                        "no_account"])
+def test_program_count_reads_the_path_account(monkeypatch, counted, want):
+    import types
+    module = None if counted is None else types.SimpleNamespace(
+        paths=lambda: dict(counted))
+    monkeypatch.setitem(sys.modules, "cxxnet_tpu.utils.telemetry", module)
+    read = bench_run.load_reader(BENCH, "program_count")
+    args = {"name": "moe.dense", "of": ["moe.sparse", "moe.dense"]}
+    assert read({"window": {"steps": 10}}, **args) == want
+    assert read({"window": {}}, **args) is None        # no run was made
 
 
 # ------------------------------------------------------------ (d) FLOP count
