@@ -1055,6 +1055,10 @@ class SoftmaxLayer(LossLayerBase):
         # label_smooth = eps (beyond the reference): targets become
         # (1-eps) one-hot + eps/K uniform; grad = (p - smoothed) * scale
         self.label_smooth = 0.0
+        # weight_target = <label field> (seq = 1 only): each position's CE
+        # is multiplied by that field's value before the sum (0 leaves a
+        # position out; a masked-token loss weights the masked ones)
+        self.weight_target = ""
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -1064,6 +1068,8 @@ class SoftmaxLayer(LossLayerBase):
             self.label_smooth = float(val)
             check(0.0 <= self.label_smooth < 1.0,
                   "label_smooth must be in [0, 1)")
+        if name == "weight_target":
+            self.weight_target = val
 
     def transform(self, x2d):
         return jax.nn.softmax(x2d, axis=-1)
@@ -1092,6 +1098,8 @@ class SoftmaxLayer(LossLayerBase):
 
     def apply(self, params, inputs, ctx):
         if not self.seq:
+            check(not self.weight_target,
+                  "softmax: weight_target needs seq = 1")
             return super().apply(params, inputs, ctx)
         x = inputs[0]
         if ctx.channels_last:
@@ -1111,6 +1119,15 @@ class SoftmaxLayer(LossLayerBase):
             idx = label.astype(jnp.int32)[..., None]
             tgt = jnp.take_along_axis(logp, idx, axis=2)[..., 0]
             ce = self._ce(logp, tgt)
+            if self.weight_target:
+                from ..utils import telemetry
+                telemetry.count_path("loss.weighted")
+                weight = ctx.labels.field(self.weight_target)
+                check(weight.shape == ce.shape,
+                      "softmax seq=1: weight field %s of shape %s for %s "
+                      "positions" % (self.weight_target, weight.shape,
+                                     ce.shape))
+                ce = ce * weight.astype(ce.dtype)
             ctx.losses.append(jnp.sum(ce) / L * self._scale())
         if ctx.channels_last:
             return [out.reshape(b, 1, L, v)]
@@ -1189,11 +1206,30 @@ class AttentionLayer(Layer):
         # read per token is ~2x the useful traffic on average
         # (doc/performance.md decode roofline). Opt-in until measured.
         self.decode_chunk = 0
+        # qk_norm = 1: an rmsnorm over each head's features on q and on k,
+        # before the rotation (leaves qnorm / knorm of head_dim, shared by
+        # the heads; eps 1e-6)
+        self.qk_norm = 0
+        # attn_mask = blockdiff with block_len = B: block-diffusion
+        # training. The rows are [noised copy | clean copy] of one
+        # sequence, both at positions 0..rows/2-1 (the rotation wraps),
+        # under parallel.block_diffusion_keep's mask
+        self.attn_mask = "causal"
+        self.block_len = 0
 
     def set_param(self, name, val):
         super().set_param(name, val)
         if name == "nhead":
             self.nhead = int(val)
+        if name == "qk_norm":
+            self.qk_norm = int(val)
+        if name == "attn_mask":
+            check(val in ("causal", "blockdiff"),
+                  "attn_mask must be causal (what the key causal then "
+                  "decides) or blockdiff")
+            self.attn_mask = val
+        if name == "block_len":
+            self.block_len = int(val)
         if name == "causal":
             self.causal = int(val)
         if name == "rope":
@@ -1237,7 +1273,25 @@ class AttentionLayer(Layer):
         if self.attn_window:
             check(self.attn_window > 0, "attn_window must be positive")
             check(self.causal, "attn_window requires causal = 1")
+        if self.attn_mask == "blockdiff":
+            check(self.block_len > 0,
+                  "attn_mask = blockdiff needs block_len, the positions a "
+                  "block holds")
+            check(not self.causal and not self.attn_window,
+                  "attn_mask = blockdiff is its own mask: causal and "
+                  "attn_window must be 0")
+            check(L % 2 == 0 and (L // 2) % self.block_len == 0,
+                  "attn_mask = blockdiff: the %d rows must be two copies "
+                  "of whole blocks of %d" % (L, self.block_len))
+        else:
+            check(not self.block_len,
+                  "block_len is the block of attn_mask = blockdiff and "
+                  "means nothing under another mask")
         return [in_shapes[0]]
+
+    def _blockdiff(self):
+        """The block length where the mask is block diffusion's, else 0."""
+        return self.block_len if self.attn_mask == "blockdiff" else 0
 
     def _dh(self):
         return self.head_dim or self.param.num_input_channel // self.nhead
@@ -1246,10 +1300,14 @@ class AttentionLayer(Layer):
         """Rotary embedding on (b, nh, L, dh): rotate the (first-half,
         second-half) feature pairs by position-dependent angles (Su et al.
         2021) — relative offsets enter the q.k phase directly. ``offset``
-        is the global position of row 0 (KV-cached decode steps)."""
+        is the global position of row 0 (KV-cached decode steps). Under
+        the block-diffusion mask the rows are two copies of one sequence
+        and row r stands at position r mod L/2."""
         dh = x.shape[-1]
         half = dh // 2
         pos = offset + jnp.arange(x.shape[2], dtype=jnp.float32)[:, None]
+        if self._blockdiff():
+            pos = pos % (x.shape[2] // 2)
         inv = jnp.power(self.rope_base,
                         -jnp.arange(half, dtype=jnp.float32) / half)
         ang = pos * inv                                     # (L, half)
@@ -1268,24 +1326,33 @@ class AttentionLayer(Layer):
         d = self.param.num_input_channel
         qw = self.nhead * self._dh()     # = d unless head_dim is set
         w = qw + 2 * self._kv_width()    # [q | k | v] columns; 3d for MHA
-        return {"wqkv": self.param.rand_init_weight(
-                    rng, (d, w), in_num=d, out_num=w),
-                "wo": self.param.rand_init_weight(
-                    rng, (qw, d), in_num=qw, out_num=d)}
+        out = {"wqkv": self.param.rand_init_weight(
+                   rng, (d, w), in_num=d, out_num=w),
+               "wo": self.param.rand_init_weight(
+                   rng, (qw, d), in_num=qw, out_num=d)}
+        for key in self._norm_keys():
+            out[key] = np.ones((self._dh(),), np.float32)
+        return out
+
+    def _norm_keys(self):
+        return ("qnorm", "knorm") if self.qk_norm else ()
 
     def save_model(self, w, params):
         self.param.save(w)
-        w.write_tensor(params["wqkv"])
-        w.write_tensor(params["wo"])
+        for key in ("wqkv", "wo") + self._norm_keys():
+            w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
-        return {"wqkv": r.read_tensor(), "wo": r.read_tensor()}
+        return {key: r.read_tensor()
+                for key in ("wqkv", "wo") + self._norm_keys()}
 
     def visit_order(self):
         # wo gets its own tag: one array per tag so the GetWeight/SetWeight
         # ABI (and per-tag updater scoping, e.g. wo:lr) can reach both
-        return [("wmat", "wqkv"), ("wo", "wo")]
+        return [("wmat", "wqkv"), ("wo", "wo")] + [
+            (key, key) for key in self._norm_keys()]
+
 
     layout_support = "nhwc"
 
@@ -1315,6 +1382,10 @@ class AttentionLayer(Layer):
             q = heads(qkv[..., :qw], nh)
             k = heads(qkv[..., qw:qw + kvw], nkv)
             v = heads(qkv[..., qw + kvw:], nkv)
+            if self.qk_norm:
+                # over each head's features, (b, heads, L, dh)
+                q = _rms_norm(q, params["qnorm"], 1e-6, 3)
+                k = _rms_norm(k, params["knorm"], 1e-6, 3)
             if self.rope:
                 off = ctx.decode_pos if ctx.decode_pos is not None else 0
                 q, k = self._apply_rope(q, off), self._apply_rope(k, off)
@@ -1333,7 +1404,8 @@ class AttentionLayer(Layer):
         tile as gauges, and the (block_q, block_k) score tiles of one
         head's grid that take no mask, take one, and are never visited."""
         from ..utils import telemetry
-        sched = ops.flash_schedule(q, k, causal, self.attn_window)
+        sched = ops.flash_schedule(q, k, causal, self.attn_window,
+                                   self._blockdiff())
         telemetry.count_path("attn.flash")
         telemetry.gauge("flash.block_q", sched["block_q"])
         telemetry.gauge("flash.block_k", sched["block_k"])
@@ -1350,6 +1422,18 @@ class AttentionLayer(Layer):
         b, nh, L, dh = q.shape
         nkv = k.shape[1]
         mesh = ctx.mesh
+        bd = self._blockdiff()
+        if bd:
+            check(ctx.decode_pos is None,
+                  "attention: attn_mask = blockdiff is the training mask "
+                  "over a noised and a clean copy; decoding a block of "
+                  "tokens a step from a cache is not written")
+            check(manual_axis_size(ctx, "sp") <= 1
+                  and "sp" not in getattr(mesh, "axis_names", ()),
+                  "attention: attn_mask = blockdiff under sequence "
+                  "parallelism (ring / ulysses) is not written")
+            telemetry.count_path("attn.blockdiff")
+            telemetry.gauge("attn.block_len", bd)
         if ctx.decode_pos is not None:
             # KV-cached decode step: write this input's k/v into the cache
             # at [decode_pos, decode_pos + L) and attend the queries
@@ -1457,7 +1541,7 @@ class AttentionLayer(Layer):
             batch_axis = "data" if "data" in mesh.axis_names else None
             out = fn(q, k, v, mesh, causal=bool(self.causal),
                      batch_axis=batch_axis, window=self.attn_window)
-        elif ops.use_pallas() and ops.flash_supported(L, dh):
+        elif ops.use_pallas() and ops.flash_supported(L, dh, bd):
             # per-chip long-context path: blocked online-softmax Pallas
             # kernel, O(L) memory instead of the (L, L) score matrix. On a
             # mesh (no sp axis here) the kernel is batch-pointwise, so it
@@ -1472,7 +1556,8 @@ class AttentionLayer(Layer):
                 # per-device (the stage shard_map sliced the microbatch);
                 # opening another shard_map would nest and fail
                 out = ops.flash_attention(q, k, v, causal=causal,
-                                          window=self.attn_window)
+                                          window=self.attn_window,
+                                          block_len=bd)
             else:
                 from ..parallel._compat import shard_map
                 from jax.sharding import PartitionSpec as P
@@ -1482,13 +1567,15 @@ class AttentionLayer(Layer):
                 win = self.attn_window
                 out = shard_map(
                     lambda q_, k_, v_: ops.flash_attention(
-                        q_, k_, v_, causal=causal, window=win),
+                        q_, k_, v_, causal=causal, window=win,
+                        block_len=bd),
                     mesh=mesh, in_specs=(spec, spec, spec),
                     out_specs=spec)(q, k, v)
         else:
             telemetry.count_path("attn.dense")
             out = attention_reference(q, k, v, causal=bool(self.causal),
-                                      window=self.attn_window)
+                                      window=self.attn_window,
+                                      block_len=bd)
         return out
 
 
@@ -1616,6 +1703,18 @@ class AddLayer(Layer):
         return [out]
 
 
+def _rms_norm(x, gain, eps, axis):
+    """``x * rsqrt(mean(x^2) + eps) * gain`` along ``axis`` of a 4-D
+    ``x``, the statistics in float32 whatever the compute type."""
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
+                        + eps)
+    shape = [1, 1, 1, 1]
+    shape[axis] = -1
+    gain = gain.astype(jnp.float32).reshape(shape)
+    return (xf * inv * gain).astype(x.dtype)
+
+
 class RMSNormLayer(Layer):
     """Root-mean-square norm over the channels of every position (beyond
     the reference; Zhang & Sennrich 2019): ``x * rsqrt(mean_c(x^2) + eps)
@@ -1623,7 +1722,11 @@ class RMSNormLayer(Layer):
     whatever the compute type. Sequence nodes (b, D, 1, L) and feature maps
     normalise over D; flat (b, 1, 1, w) nodes over w. ``gain`` (tag
     ``gain``) starts at one. Channels-last is its native layout (the sum
-    runs over the lane axis)."""
+    runs over the lane axis). ``seq_rows = n`` on a sequence node reads
+    its first n positions only and gives a node of n (what follows a
+    stack that carried a second copy of the sequence beside the first):
+    the slice fuses into the norm's read, the other positions are not
+    copied."""
 
     type_name = "rmsnorm"
     layout_support = "nhwc"
@@ -1631,17 +1734,25 @@ class RMSNormLayer(Layer):
     def __init__(self):
         super().__init__()
         self.eps = 1e-6
+        self.seq_rows = 0
 
     def set_param(self, name, val):
         super().set_param(name, val)
         if name == "eps":
             self.eps = float(val)
+        if name == "seq_rows":
+            self.seq_rows = int(val)
 
     def infer_shape(self, in_shapes):
         check(len(in_shapes) == 1, "RMSNormLayer only support 1-1 connection")
         b, c, h, w = in_shapes[0]
         self._flat = c == 1 and h == 1
         self.param.num_input_channel = w if self._flat else c
+        if self.seq_rows:
+            check(not self._flat and h == 1 and 0 < self.seq_rows <= w,
+                  "rmsnorm: seq_rows = %d needs a sequence node (batch, d, "
+                  "1, seq) of at least as many positions" % self.seq_rows)
+            return [(b, c, h, self.seq_rows)]
         return [in_shapes[0]]
 
     def init_params(self, rng):
@@ -1661,13 +1772,10 @@ class RMSNormLayer(Layer):
     def apply(self, params, inputs, ctx):
         x = inputs[0]
         axis = 3 if (ctx.channels_last or self._flat) else 1
-        xf = x.astype(jnp.float32)
-        inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis,
-                                     keepdims=True) + self.eps)
-        shape = [1, 1, 1, 1]
-        shape[axis] = -1
-        gain = params["gain"].astype(jnp.float32).reshape(shape)
-        return [(xf * inv * gain).astype(x.dtype)]
+        if self.seq_rows:
+            x = x[:, :, :self.seq_rows] if ctx.channels_last \
+                else x[..., :self.seq_rows]
+        return [_rms_norm(x, params["gain"], self.eps, axis)]
 
 
 def _rows_or_zero(x, at):
@@ -1730,7 +1838,8 @@ def _expert_rows(act, mm, x, mats):
     a = mm(x, mats["experts"])
     if act == "relu":
         return jnp.maximum(a, 0.0)
-    return mm(jnp.maximum(a, 0.0) * mm(x, mats["up"]), mats["down"])
+    gate = jax.nn.silu(a) if act == "swiglu" else jnp.maximum(a, 0.0)
+    return mm(gate * mm(x, mats["up"]), mats["down"])
 
 
 def _sorted_side(act, m, x2, w, mats, order, inv, sizes):
@@ -1793,9 +1902,10 @@ class MoELayer(Layer):
     Keys: ``nexpert``; ``top_k`` (0 = all): the weights are the softmax
     over the token's top k float32 logits (which is the softmax over all
     experts renormalised over those k);
-    ``expert_act`` = ``relu`` (one matrix an expert, ``nhidden`` outputs)
-    or ``reglu`` (three: ``relu(x Wg) * (x Wu)`` of width ``nhidden``, then
-    ``Wd`` back to the input's width); ``nexpert_held`` / ``expert_offset``:
+    ``expert_act`` = ``relu`` (one matrix an expert, ``nhidden`` outputs),
+    ``reglu`` (three: ``relu(x Wg) * (x Wu)`` of width ``nhidden``, then
+    ``Wd`` back to the input's width) or ``swiglu`` (the same three with
+    ``silu`` for the relu); ``nexpert_held`` / ``expert_offset``:
     this layer HOLDS only experts ``[offset, offset + held)`` of the
     ``nexpert`` the router scores — one chip's share of an expert-parallel
     layer. What the absent experts would add is left out of the result;
@@ -1866,11 +1976,16 @@ class MoELayer(Layer):
         if name == "expert_offset":
             self.expert_offset = int(val)
         if name == "expert_act":
-            check(val in ("relu", "reglu"), "expert_act must be relu or reglu")
+            check(val in ("relu", "reglu", "swiglu"),
+                  "expert_act must be relu, reglu or swiglu")
             self.expert_act = val
 
     def _held(self):
         return self.n_held or self.n_expert
+
+    def _gated(self):
+        """Three matrices an expert (reglu, swiglu) and not one."""
+        return self.expert_act != "relu"
 
     def infer_shape(self, in_shapes):
         check(1 <= len(in_shapes) <= 2,
@@ -1892,7 +2007,7 @@ class MoELayer(Layer):
               "must lie within nexpert")
         din = c if self._seq else w
         self.param.num_input_node = din
-        dout = din if self.expert_act == "reglu" else self.param.num_hidden
+        dout = din if self._gated() else self.param.num_hidden
         return [(b, dout, 1, w) if self._seq else (b, 1, 1, dout)]
 
     def init_params(self, rng):
@@ -1904,7 +2019,7 @@ class MoELayer(Layer):
             "experts": self.param.rand_init_weight(
                 rng, (held, din, f), in_num=din, out_num=f),
         }
-        if self.expert_act == "reglu":
+        if self._gated():
             out["up"] = self.param.rand_init_weight(
                 rng, (held, din, f), in_num=din, out_num=f)
             out["down"] = self.param.rand_init_weight(
@@ -1913,7 +2028,7 @@ class MoELayer(Layer):
 
     def _keys(self):
         return ("gate", "experts") + (
-            ("up", "down") if self.expert_act == "reglu" else ())
+            ("up", "down") if self._gated() else ())
 
     def save_model(self, w, params):
         self.param.save(w)
@@ -1930,10 +2045,9 @@ class MoELayer(Layer):
 
     def visit_order(self):
         # ``experts`` is the one matrix of a relu expert and the gate
-        # matrix Wg of a reglu one; ``gate`` is the router
+        # matrix Wg of a reglu or swiglu one; ``gate`` is the router
         return [("wmat", "experts"), ("gate", "gate")] + (
-            [("up", "up"), ("down", "down")]
-            if self.expert_act == "reglu" else [])
+            [("up", "up"), ("down", "down")] if self._gated() else [])
 
     def _top(self, logits):
         """(T, k) expert indices and their weights, float32."""

@@ -347,7 +347,8 @@ def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
                        ffn: int, attn_keys: str = "",
                        norm=False, ffn_kind: str = "conv",
                        ffn_keys: str = "", norm_keys: str = "",
-                       init_sigma: Optional[float] = 0.05
+                       init_sigma: Optional[float] = 0.05,
+                       router_on_input: bool = True
                        ) -> Tuple[str, str]:
     """One transformer block in the DSL, shared by the LM, ViT and
     mixture-of-experts builders so the block shape lives in one place.
@@ -358,9 +359,10 @@ def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
     window/head_dim). ``ffn_kind``: ``"conv"`` (two 1x1 convs of width
     ``ffn`` around a relu) or ``"moe"`` (one ``moe`` layer of expert width
     ``ffn`` whose router reads the block's input, before the norm and the
-    attention; ``ffn_keys`` are its lines). ``norm_keys``: lines of both
-    norm layers. ``init_sigma`` None leaves the
-    weights' spread to the conf's global key."""
+    attention, or with ``router_on_input`` False the normed node its
+    experts read: the layer's one-input form; ``ffn_keys`` are its lines).
+    ``norm_keys``: lines of both norm layers. ``init_sigma`` None leaves
+    the weights' spread to the conf's global key."""
     def keys(text):
         return "".join("  %s\n" % ln.strip()
                        for ln in text.splitlines() if ln.strip())
@@ -388,10 +390,10 @@ def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
                 % {"p": p, "norm": norm, "s": short, "nk": norm_keys})
         ffn_in = p + "n2"
     if ffn_kind == "moe":
-        txt += """layer[%(fi)s,%(in)s->%(p)sf2] = moe:%(p)s_moe
+        txt += """layer[%(fi)s->%(p)sf2] = moe:%(p)s_moe
   nhidden = %(ffn)d
-%(fk)s""" % {"fi": ffn_in, "in": node_in, "p": p, "ffn": ffn,
-            "fk": keys(ffn_keys)}
+%(fk)s""" % {"fi": ffn_in + (("," + node_in) if router_on_input else ""),
+            "p": p, "ffn": ffn, "fk": keys(ffn_keys)}
     else:
         txt += """layer[%(fi)s->%(p)sf1] = conv:%(p)s_ffn1
   kernel_size = 1
@@ -527,6 +529,94 @@ def smallthinker_conf(seq: int = 8192, batch_size: int = 1,
             "input_shape = 1,1,%d\n" % seq +
             "batch_size = %d\n" % batch_size +
             "label_vec[0,%d) = label\n" % seq +
+            "dev = %s\n" % dev + extra_cfg)
+
+
+def sdar_moe_netconfig(vocab: int = 151936, dim: int = 2048,
+                       nhead: int = 32, nkvhead: int = 4,
+                       head_dim: int = 128, nlayer: int = 48,
+                       n_expert: int = 128, top_k: int = 8,
+                       expert_width: int = 768, n_held: int = 0,
+                       expert_offset: int = 0, seq: int = 8192,
+                       block_len: int = 4, rope_theta: float = 1e6,
+                       eps: float = 1e-6, remat: str = "moe") -> str:
+    """SDAR-30B-A3B (JetLM, 2025; the defaults are its published
+    config.json, ``model_type: sdar_moe``) as it is TRAINED, by diffusion
+    over blocks, from the netconfig DSL. A sequence of ``seq`` tokens runs
+    as 2 ``seq`` rows, [noised copy | clean copy]: embed -> nlayer x [
+    rmsnorm, attention (grouped-query, a head size of its own, an rmsnorm
+    over each head of q and k, rotary at the row's position in its copy,
+    the block-diffusion mask of ``block_len``) + residual, rmsnorm, sparse
+    SwiGLU experts top_k of n_expert whose router reads the normed stream
+    + residual ] -> rmsnorm of the noised copy's rows alone -> untied
+    vocab head -> per-position softmax weighted by the label field
+    ``loss_weight`` (0 on positions left unmasked, 1/t on masked ones;
+    ``io.blockdiff.noise_batch`` makes the rows and both fields). No bias
+    anywhere; matrices start at normal(0, 0.02) and the embedding at
+    normal(0, 1), for ``smallthinker_netconfig``'s reason: the stream the
+    routers read (normed, after the attention's residual) is then each
+    token's own vector and not what attention averages into a position.
+
+    ``n_held`` / ``expert_offset`` / ``vocab`` / ``nlayer`` cut one chip's
+    share of an expert-parallel deployment as ``smallthinker_netconfig``'s
+    do; ``remat`` names the layer kinds recomputed in the backward pass."""
+    txt = """
+netconfig = start
+layer[0->emb] = embed:emb
+  vocab_size = %d
+  nhidden = %d
+  init_sigma = 1
+""" % (vocab, dim)
+    node = "emb"
+    for i in range(nlayer):
+        attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 0\n"
+                "attn_mask = blockdiff\nblock_len = %d\nqk_norm = 1\n"
+                "rope = 1\nrope_base = %.10g\nremat = %d\n"
+                % (nkvhead, head_dim, block_len, rope_theta,
+                   "attention" in remat))
+        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
+               "expert_offset = %d\nexpert_act = swiglu\nremat = %d\n"
+               % (n_expert, top_k, n_held or n_expert, expert_offset,
+                  "moe" in remat))
+        blk, node = _transformer_block(
+            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
+            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
+            norm_keys="eps = %.10g\n" % eps, init_sigma=None,
+            router_on_input=False)
+        txt += "\n" + blk
+    txt += """
+layer[%s->nf] = rmsnorm:norm_f
+  eps = %.10g
+  seq_rows = %d
+layer[nf->logits] = conv:head
+  kernel_size = 1
+  nchannel = %d
+  no_bias = 1
+layer[+0] = softmax
+  seq = 1
+  weight_target = loss_weight
+netconfig = end
+random_type = gaussian
+init_sigma = 0.02
+""" % (node, eps, seq, vocab)
+    return txt
+
+
+SDAR_MOE_ADAMW = (SMALLTHINKER_ADAMW
+                  + "qnorm:wd = 0.0\nknorm:wd = 0.0\n")
+
+
+def sdar_moe_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
+                  extra_cfg: str = "", **kw) -> str:
+    """The whole training conf of the block-diffusion recipe: the
+    netconfig at ``seq`` tokens (2 ``seq`` rows), the shapes, the two
+    label fields, and ``smallthinker_conf``'s AdamW (assumed there as
+    here; the gains of the heads' norms take no decay either)."""
+    return (sdar_moe_netconfig(seq=seq, **kw) + SDAR_MOE_ADAMW +
+            "input_shape = 1,1,%d\n" % (2 * seq) +
+            "batch_size = %d\n" % batch_size +
+            "label_vec[0,%d) = label\n" % seq +
+            "label_vec[%d,%d) = loss_weight\n" % (seq, 2 * seq) +
             "dev = %s\n" % dev + extra_cfg)
 
 

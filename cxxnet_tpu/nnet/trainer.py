@@ -2162,8 +2162,10 @@ class Trainer:
 
     # ------------------------------------------------------------------
     # every tag a layer's visit_order gives: fullc/conv (wmat, bias),
-    # attention (wo), moe (gate = the router, up, down), rmsnorm (gain)
-    _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain")
+    # attention (wo; qnorm, knorm with qk_norm = 1), moe (gate = the
+    # router, up, down), rmsnorm (gain)
+    _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain",
+                    "qnorm", "knorm")
 
     def set_weight(self, value: np.ndarray, layer_name: str, tag: str) -> None:
         check(tag in self._WEIGHT_TAGS,

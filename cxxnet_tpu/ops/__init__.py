@@ -241,29 +241,32 @@ def lrn(x: jnp.ndarray, nsize: int, alpha: float, beta: float, knorm: float,
     return kernel(x, nsize, alpha, beta, knorm, pallas_interpret())
 
 
-def flash_supported(L: int, d: int) -> bool:
-    """True when (seq, head_dim) fits the Pallas flash-attention tiling."""
+def flash_supported(L: int, d: int, block_len: int = 0) -> bool:
+    """True when (seq, head_dim) fits the Pallas flash-attention tiling
+    (under the block-diffusion mask of ``block_len``, if given)."""
     from . import flash_attn as _fa
-    return _fa.supports(L, d)
+    return _fa.supports(L, d, block_len)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
-                    window: int = 0):
+                    window: int = 0, block_len: int = 0):
     """Memory-O(L) blocked attention (ops/flash_attn.py). window > 0
     (causal only) keeps the last ``window`` keys per query —
     sliding-window attention; out-of-window kv tiles are skipped
-    wholesale."""
+    wholesale. block_len > 0 (neither causal nor windowed) is the
+    block-diffusion training mask over a noised and a clean copy."""
     from . import flash_attn as _fa
     return _fa.flash_attention(q, k, v, causal, scale, pallas_interpret(),
-                               window)
+                               window, None, block_len)
 
 
-def flash_schedule(q, k, causal: bool, window: int = 0) -> dict:
+def flash_schedule(q, k, causal: bool, window: int = 0,
+                   block_len: int = 0) -> dict:
     """The static tile schedule ``flash_attention`` walks for these
     operands (ops/flash_attn.schedule): what telemetry's ``flash.*``
     gauges and counters report."""
     from . import flash_attn as _fa
-    return _fa.schedule(q, k, causal, window)
+    return _fa.schedule(q, k, causal, window, block_len)
 
 
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:

@@ -20,6 +20,17 @@ static-shape predicate each: only tiles that straddle the diagonal, the
 window's trailing edge or the tail take ``_mask``. Tiles are sized from
 (L, d, dtype, window) for the chip's VMEM, not a constant.
 
+A third mask beside causal and causal-with-window (PR 36): block
+diffusion's training mask (``block_len`` > 0). The rows are a noised and
+a clean copy of one sequence; a resident tile then meets TWO runs of
+streamed tiles (``kv_runs`` / ``q_runs``: a noised query tile its own
+blocks' noised keys and the clean keys before its block, a clean key tile
+the noised queries past its block and the clean ones from it on), which
+``_walk`` lays end to end as index arithmetic on the grid step: the same
+three kernels, the same ``kind`` / ``mask`` protocol comparing block
+indices, no table, no second kernel. The quadrant of clean queries and
+noised keys is never visited.
+
 Forward: grid (batch*heads, q tiles, kv steps); the (m, l, acc) carry
 lives in VMEM scratch across the kv steps, m and l replicated along the
 lanes; the MXU sees (block_q, d) x (d, sub) and (block_q, sub) x (sub, d)
@@ -98,10 +109,22 @@ class _Geom(NamedTuple):
     causal: bool
     window: int
     kv_len: int
+    # block diffusion (``block_len`` > 0): the rows are two copies of one
+    # sequence, [noised | clean], of ``kv_len // 2`` positions each, in
+    # blocks of ``block_len`` positions. A noised query keeps the noised
+    # keys of its own block and the clean keys of earlier blocks; a clean
+    # query keeps the clean keys of its own block and earlier ones. No
+    # tile straddles the two copies (``_geom`` holds the tiles to that).
+    block_len: int = 0
 
     @property
     def masks(self) -> bool:
-        return self.causal or self.n_k * self.bk > self.kv_len
+        return bool(self.causal or self.block_len
+                    or self.n_k * self.bk > self.kv_len)
+
+    @property
+    def half(self) -> int:
+        return self.kv_len // 2
 
     def kv_range(self, i, xp=jnp):
         """First and last kv tile holding a kept score of q tile ``i``."""
@@ -124,29 +147,129 @@ class _Geom(NamedTuple):
                 (self.window + (j + 1) * self.bk - 2) // self.bq, hi)
         return lo, hi
 
-    def kv_block(self, i, step):
-        """The kv tile q tile ``i`` holds at a grid step: its run's start
-        plus the step, and past the run's end the last one again."""
-        lo, hi = self.kv_range(i)
-        return jnp.minimum(lo + step, hi)
+    def kv_runs(self, i, xp=jnp):
+        """Block diffusion: the TWO runs of kv tiles that hold a kept score
+        of q tile ``i``, (first, last) of the run among the noised keys and
+        of the run among the clean ones; an empty run has last < first. A
+        noised tile: its own blocks' noised keys, and the clean keys below
+        its last row's block. A clean tile: the clean keys up to its last
+        row's block."""
+        B, H = self.block_len, self.half
+        first, last = i * self.bq, (i + 1) * self.bq - 1
+        noised = first < H
+        p0, p1 = first % H, last % H
+        nk = H // self.bk                   # kv tiles a copy
+        lo_a = xp.where(noised, (p0 // B * B) // self.bk, 0 * i)
+        hi_a = xp.where(
+            noised, (xp.minimum((p1 // B + 1) * B, H) - 1) // self.bk,
+            0 * i - 1)
+        hi_b = nk + xp.where(
+            noised, (p1 // B * B - 1) // self.bk,
+            (xp.minimum((p1 // B + 1) * B, H) - 1) // self.bk)
+        return lo_a, hi_a, 0 * i + nk, hi_b
 
-    def q_block(self, j, step):
-        lo, hi = self.q_range(j)
-        return jnp.minimum(lo + step, hi)
+    def q_runs(self, j, xp=jnp):
+        """Block diffusion: the two runs of q tiles that hold a kept score
+        of kv tile ``j``, among the noised queries and among the clean
+        ones. A noised key tile: the noised queries of its own blocks,
+        and no clean one. A clean key tile: the noised queries past its
+        first row's block, and the clean queries from that block on."""
+        B, H = self.block_len, self.half
+        first, last = j * self.bk, (j + 1) * self.bk - 1
+        noised = first < H
+        p0, p1 = first % H, last % H
+        nq = H // self.bq                   # q tiles a copy
+        lo_a = xp.where(noised, (p0 // B * B) // self.bq,
+                        xp.minimum(((p0 // B + 1) * B) // self.bq, nq))
+        hi_a = xp.where(
+            noised, (xp.minimum((p1 // B + 1) * B, H) - 1) // self.bq,
+            0 * j + (nq - 1))
+        lo_b = nq + xp.where(noised, 0 * j, (p0 // B * B) // self.bq)
+        hi_b = xp.where(noised, 0 * j + (nq - 1), 0 * j + (2 * nq - 1))
+        return lo_a, hi_a, lo_b, hi_b
+
+    @staticmethod
+    def _walk(runs, step, xp=jnp):
+        """Step ``step`` of a walk over two runs, the second wholly past
+        the first: the tile it names and the walk's last tile. A step past
+        the end names a tile past the last."""
+        lo_a, hi_a, lo_b, hi_b = runs
+        n_a = hi_a - lo_a + 1
+        tile = xp.where(step < n_a, lo_a + step, lo_b + (step - n_a))
+        return tile, xp.where(hi_b >= lo_b, hi_b, hi_a)
+
+    def kv_tile(self, i, step, xp=jnp):
+        """The kv tile q tile ``i`` meets at a grid step, and the last tile
+        of its walk: the step computes while the first is not past the
+        second."""
+        if self.block_len:
+            return self._walk(self.kv_runs(i, xp), step, xp)
+        lo, hi = self.kv_range(i, xp)
+        return lo + step, hi
+
+    def q_tile(self, j, step, steps=0, xp=jnp):
+        """``kv_tile`` for the dK/dV kernel; with ``steps``, ``step`` is a
+        grid step of that kernel, which run over the group's heads x
+        ``steps`` q steps."""
+        if self.block_len:
+            return self._walk(self.q_runs(j, xp),
+                              step % steps if steps else step, xp)
+        lo, hi = self.q_range(j, xp)
+        return lo + (step % steps if steps else step), hi
+
+    def kv_block(self, i, step, xp=jnp):
+        """The kv tile q tile ``i`` holds at a grid step: its walk's tile,
+        and past the walk's end the last one again (nothing moves)."""
+        tile, last = self.kv_tile(i, step, xp)
+        return xp.minimum(tile, last)
+
+    def q_block(self, j, step, xp=jnp):
+        tile, last = self.q_tile(j, step, xp=xp)
+        return xp.minimum(tile, last)
+
+    @staticmethod
+    def _longest(runs) -> int:
+        lo_a, hi_a, lo_b, hi_b = runs
+        return int(np.max(np.maximum(hi_a - lo_a + 1, 0)
+                          + np.maximum(hi_b - lo_b + 1, 0)))
 
     def kv_steps(self) -> int:
+        if self.block_len:
+            return self._longest(self.kv_runs(np.arange(self.n_q), np))
         lo, hi = self.kv_range(np.arange(self.n_q), np)
         return int(np.max(hi - lo)) + 1
 
     def q_steps(self) -> int:
+        if self.block_len:
+            return self._longest(self.q_runs(np.arange(self.n_k), np))
         lo, hi = self.q_range(np.arange(self.n_k), np)
         return int(np.max(hi - lo)) + 1
+
+    def _quadrant(self, q0, k0, xp=jnp):
+        """Block diffusion: the scores of a tile that starts at (q0, k0)
+        are kept where ``lo <= key's block - query's block <= hi``; the
+        clean queries keep no noised key (lo > hi). Also the two starts as
+        positions of their copies."""
+        H = self.half
+        qn, kn = q0 < H, k0 < H
+        far = 2 * H                     # more blocks than there are
+        hi = xp.where(xp.logical_and(qn, xp.logical_not(kn)), -1, 0)
+        lo = xp.where(kn, xp.where(qn, 0, far), -far)
+        return lo, hi, q0 % H, k0 % H
 
     def kind(self, q0, nq, k0, nk, xp=jnp):
         """(needed, full) of the score tile of queries [q0, q0 + nq) and
         keys [k0, k0 + nk): it holds a kept score; it holds no other."""
         needed = k0 < self.kv_len
         full = k0 + nk <= self.kv_len
+        if self.block_len:
+            B = self.block_len
+            lo, hi, pq, pk = self._quadrant(q0, k0, xp)
+            # the least and the greatest difference of blocks in the tile
+            least = pk // B - (pq + (nq - 1)) // B
+            most = (pk + (nk - 1)) // B - pq // B
+            needed = needed & (least <= hi) & (most >= lo)
+            full = full & (most <= hi) & (least >= lo)
         if self.causal:
             needed = xp.logical_and(needed, k0 <= q0 + (nq - 1))
             full = xp.logical_and(full, k0 + (nk - 1) <= q0)
@@ -162,6 +285,13 @@ class _Geom(NamedTuple):
         ``k_axis`` of ``s``. Only edge tiles pay for this."""
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
         keep = kpos < self.kv_len
+        if self.block_len:
+            lo, hi, pq, pk = self._quadrant(q0, k0)
+            # kpos as a position of its copy: k0 - pk is the copy's start
+            diff = self._block_of(kpos - (k0 - pk)) - self._block_of(
+                pq + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              1 - k_axis))
+            keep = keep & (diff <= hi) & (diff >= lo)
         if self.causal:
             qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                  1 - k_axis)
@@ -169,6 +299,13 @@ class _Geom(NamedTuple):
             if self.window > 0:
                 keep = jnp.logical_and(keep, qpos - kpos < self.window)
         return jnp.where(keep, s, NEG_INF)
+
+    def _block_of(self, pos):
+        """Block index of an array of positions (none negative)."""
+        B = self.block_len
+        if B & (B - 1) == 0:
+            return jax.lax.shift_right_logical(pos, B.bit_length() - 1)
+        return jax.lax.div(pos, B)
 
 
 def tile_counts(g: _Geom) -> Tuple[int, int, int]:
@@ -221,8 +358,7 @@ def _visit(g: _Geom, q0, nq, k0, nk, live, body):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, g):
     i, t = pl.program_id(1), pl.program_id(2)
-    lo, hi = g.kv_range(i)
-    j = lo + t
+    j, hi = g.kv_tile(i, t)
     sub, d = g.sub, q_ref.shape[-1]
 
     @pl.when(t == 0)
@@ -262,8 +398,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                lse_scr, delta_scr, dq_scr, *, scale, g):
     i, t = pl.program_id(1), pl.program_id(2)
-    lo, hi = g.kv_range(i)
-    j = lo + t
+    j, hi = g.kv_tile(i, t)
     sub = g.sub
 
     @pl.when(t == 0)
@@ -298,8 +433,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale, g, steps):
     j, t = pl.program_id(1), pl.program_id(2)
-    lo, hi = g.q_range(j)
-    i = lo + t % steps      # t runs over the group's heads x the q steps
+    # t runs over the group's heads x the q steps
+    i, hi = g.q_tile(j, t, steps)
     sub = g.sub
 
     @pl.when(t == 0)
@@ -372,8 +507,8 @@ def _pow2(x: float, lo: int, hi: int) -> int:
     return min(hi, max(lo, 1 << max(0, round(math.log2(max(x, 1.0))))))
 
 
-def _tiling(L: int, d: int, itemsize: int, window: int
-            ) -> Tuple[Tiles, Tiles, Tiles]:
+def _tiling(L: int, d: int, itemsize: int, window: int,
+            block_len: int = 0) -> Tuple[Tiles, Tiles, Tiles]:
     """Tiles of the forward, dQ and dK/dV kernels for one shape, by what
     the chip read (PERF.md section 5, PR 31: a v5e at L 512, 2,048 and
     8,192, windows 0 to 4,096). The streamed block is long — the keys a
@@ -387,11 +522,20 @@ def _tiling(L: int, d: int, itemsize: int, window: int
     seldom and the float32 score tiles stay inside VMEM_BUDGET. Short
     sequences get blocks that divide them or pad them by under a lane
     tile. The query group does not enter: the dK/dV kernel's steps grow
-    with it, its tiles do not."""
+    with it, its tiles do not. Under the block-diffusion mask the L rows
+    are two copies of L / 2 positions, a query sees the keys of about its
+    position as a causal one does, and the sizes are those of L / 2, cut
+    to divide it: no tile straddles the copies."""
+    half = L // 2 if block_len else 0
+    L = half or L
     span = min(L, window) if window else L
     mean = span * (1.0 - span / (2.0 * L))
     res = _fit(L, _pow2(mean / 2, _LANES, 1024))
     stream = _fit(L, min(1024, max(_LANES, 1 << (span.bit_length() - 1))))
+    while half % res:
+        res //= 2
+    while half % stream:
+        stream //= 2
     sub_f, sub_b = min(stream, 512), min(stream, max(res, 512))
 
     def tiles():
@@ -414,9 +558,12 @@ def _tiling(L: int, d: int, itemsize: int, window: int
     return tiles()
 
 
-def supports(L: int, d: int) -> bool:
+def supports(L: int, d: int, block_len: int = 0) -> bool:
     """Shapes the kernel path accepts: any L >= 128 (padded to a lane-
-    aligned tile, tail masked in-kernel) and a sublane-aligned head dim."""
+    aligned tile, tail masked in-kernel) and a sublane-aligned head dim;
+    under the block-diffusion mask two copies of whole lane tiles."""
+    if block_len and L % (2 * _LANES):
+        return False
     return pltpu is not None and L >= 128 and d % 8 == 0
 
 
@@ -429,23 +576,26 @@ def _dims(vmem_bytes: Optional[int] = None):
         vmem_limit_bytes=vmem_bytes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, interpret: bool = False,
-                    window: int = 0, tiles=None):
+                    window: int = 0, tiles=None, block_len: int = 0):
     """Memory-O(L) attention. q: (b, h, L, d) -> (b, h, L, d); k/v may
     carry FEWER heads (grouped-query attention, nkv | h): the kernels read
     the shared kv head per query group through the BlockSpec index map, so
     K/V HBM footprint and traffic stay nkv-sized.
 
     Same contract as parallel.attention_reference (incl. sliding
-    ``window``, causal-only); the caller gates on supports().
+    ``window``, causal-only, and ``block_len`` > 0: the block-diffusion
+    mask over rows that are a noised and a clean copy of one sequence,
+    neither causal nor windowed); the caller gates on supports().
     `interpret=True` runs the kernels in the Pallas interpreter so CPU
     tests cover the exact kernel code. ``tiles`` is the tests' way to run
     several tiles at a small L: three ``Tiles`` (forward, dQ, dK/dV) in
     place of ``_tiling``'s choice.
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, interpret, window, tiles)
+    out, _ = _flash_fwd(q, k, v, causal, scale, interpret, window, tiles,
+                        block_len)
     return out
 
 
@@ -478,9 +628,16 @@ def _kv_row_map(nh: int, nkv: int):
     return to_kv
 
 
-def _geom(t: Tiles, L: int, causal: bool, window: int) -> _Geom:
+def _geom(t: Tiles, L: int, causal: bool, window: int,
+          block_len: int = 0) -> _Geom:
+    if block_len:
+        assert not causal and not window, \
+            "the block-diffusion mask is neither causal nor windowed"
+        assert L % 2 == 0 and L // 2 % t.bq == 0 and L // 2 % t.bk == 0, \
+            "block diffusion: tiles (%d, %d) must divide each copy's %d " \
+            "rows" % (t.bq, t.bk, L // 2)
     return _Geom(t.bq, t.bk, t.sub, -(-L // t.bq), -(-L // t.bk),
-                 bool(causal), int(window), L)
+                 bool(causal), int(window), L, int(block_len))
 
 
 def _params(kernel, t: Tiles, d, dtype, interpret):
@@ -490,11 +647,11 @@ def _params(kernel, t: Tiles, d, dtype, interpret):
 
 
 def _fwd_call(qf, kf, vf, L, to_kv, t: Tiles, causal, scale, window,
-              interpret):
+              interpret, block_len=0):
     """Forward kernel on merged, padded (rows, Lp, d) arrays: the output
     and the lane-dense logsumexp (rows, 1, Lpq)."""
     bh, Lpq, d = qf.shape
-    g = _geom(t, L, causal, window)
+    g = _geom(t, L, causal, window, block_len)
     kv_spec = pl.BlockSpec(
         (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
     return pl.pallas_call(
@@ -523,9 +680,9 @@ def _fwd_call(qf, kf, vf, L, to_kv, t: Tiles, causal, scale, window,
 
 
 def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
-             scale, window, interpret):
+             scale, window, interpret, block_len=0):
     bh, Lpq, d = qf.shape
-    g = _geom(t, L, causal, window)
+    g = _geom(t, L, causal, window, block_len)
     q_spec = pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0))
     kv_spec = pl.BlockSpec(
         (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
@@ -547,12 +704,12 @@ def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
 
 
 def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
-              scale, window, interpret):
+              scale, window, interpret, block_len=0):
     """dK and dV at key-value resolution: grid rows are the kv heads, the
     streamed dimension runs over the group's query heads x the q tiles
     that reach the resident kv tile."""
     bkv, Lpk, d = kf.shape
-    g = _geom(t, L, causal, window)
+    g = _geom(t, L, causal, window, block_len)
     steps = g.q_steps()
 
     # step s: query head s // steps of kv head r's group, its q tile
@@ -579,49 +736,53 @@ def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
     )(qf, kf, vf, dof, lse, delta)
 
 
-def _shape_tiles(q, k, window, tiles):
+def _shape_tiles(q, k, window, tiles, block_len=0):
     b, h, L, d = q.shape
     assert h % k.shape[1] == 0, "query heads must be a multiple of kv heads"
     if tiles is None:
-        tiles = _tiling(L, d, jnp.dtype(q.dtype).itemsize, window)
+        tiles = _tiling(L, d, jnp.dtype(q.dtype).itemsize, window,
+                        block_len)
     return tiles
 
 
-def schedule(q, k, causal: bool, window: int = 0) -> dict:
+def schedule(q, k, causal: bool, window: int = 0,
+             block_len: int = 0) -> dict:
     """What ``flash_attention`` will do with these (b, heads, L, d)
     operands, from their shapes alone: the forward kernel's q tile and the
     kv columns one pass of its body takes (``block_q``, ``block_k``), and
     how many such score tiles of one head's grid hold no masked score,
     straddle an edge, or are never visited (``full``, ``edge``,
     ``skipped``)."""
-    fwd = _shape_tiles(q, k, window, None)[0]
-    full, edge, skipped = tile_counts(_geom(fwd, q.shape[2], causal, window))
+    fwd = _shape_tiles(q, k, window, None, block_len)[0]
+    full, edge, skipped = tile_counts(
+        _geom(fwd, q.shape[2], causal, window, block_len))
     return {"block_q": fwd.bq, "block_k": fwd.sub, "full": full,
             "edge": edge, "skipped": skipped}
 
 
-def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None):
+def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None,
+               block_len=0):
     b, h, L, d = q.shape
     if scale is None:
         scale = d ** -0.5
     assert window == 0 or causal, "window attention requires causal"
-    t = _shape_tiles(q, k, window, tiles)[0]
+    t = _shape_tiles(q, k, window, tiles, block_len)[0]
     qf = _pad_seq(_merge_bh(q), _padded_len(L, t.bq))
     kf, vf = (_pad_seq(_merge_bh(x), _padded_len(L, t.bk)) for x in (k, v))
     out, lse = _fwd_call(qf, kf, vf, L, _kv_row_map(h, k.shape[1]), t,
-                         causal, scale, window, interpret)
+                         causal, scale, window, interpret, block_len)
     out = out[:, :L].reshape(b, h, L, d)
     # the residual is trimmed to L: the backward pads to its own tiles
     return out, (q, k, v, out, lse[:, :, :L])
 
 
-def _flash_bwd(causal, scale, interpret, window, tiles, res, g):
+def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g):
     q, k, v, out, lse = res
     b, h, L, d = q.shape
     nkv = k.shape[1]
     if scale is None:
         scale = d ** -0.5
-    _, t_dq, t_dkv = _shape_tiles(q, k, window, tiles)
+    _, t_dq, t_dkv = _shape_tiles(q, k, window, tiles, block_len)
     # D = rowsum(dO ∘ O), computed once here (cheap elementwise + reduce,
     # XLA fuses it) and given to both kernels as a lane-dense row like
     # lse; padded rows have dO = 0 so their D is 0 and every padded-row
@@ -638,9 +799,9 @@ def _flash_bwd(causal, scale, interpret, window, tiles, res, g):
                 _pad_seq(delta, Lq, 2))
 
     dq = _dq_call(*padded(t_dq), L, _kv_row_map(h, nkv), t_dq, causal,
-                  scale, window, interpret)
+                  scale, window, interpret, block_len)
     dk, dv = _dkv_call(*padded(t_dkv), L, h // nkv, t_dkv, causal, scale,
-                       window, interpret)
+                       window, interpret, block_len)
     return (dq[:, :L].reshape(b, h, L, d),
             dk[:, :L].reshape(b, nkv, L, d),
             dv[:, :L].reshape(b, nkv, L, d))

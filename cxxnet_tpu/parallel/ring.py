@@ -35,7 +35,7 @@ from ._compat import shard_map
 
 def attention_reference(q, k, v, *, causal: bool = False,
                         scale: Optional[float] = None, window: int = 0,
-                        q_offset=0):
+                        q_offset=0, block_len: int = 0):
     """Plain single-device attention, the golden model for the parallel
     variants. q: (batch, heads, seq, head_dim); k/v may carry FEWER heads
     (grouped-query attention): nkv must divide nh and each group of
@@ -45,10 +45,14 @@ def attention_reference(q, k, v, *, causal: bool = False,
     attention). ``q_offset`` (static or traced) is the global position of
     q's first row when q is a chunk of a longer sequence (the in-pipeline
     sequence-parallel path computes each sp rank's query chunk against
-    the full k/v)."""
+    the full k/v). ``block_len`` > 0 (neither causal nor windowed) is the
+    block-diffusion training mask: the rows are a noised and a clean copy
+    of one sequence, ``block_diffusion_keep`` says which scores stay."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     assert window == 0 or causal, "window attention requires causal"
+    assert not block_len or not (causal or window), \
+        "the block-diffusion mask is neither causal nor windowed"
     b, nh, sq, d = q.shape
     nkv = k.shape[1]
     assert nh % nkv == 0, "query heads must be a multiple of kv heads"
@@ -63,8 +67,27 @@ def attention_reference(q, k, v, *, causal: bool = False,
         if window > 0:
             keep = jnp.logical_and(keep, qpos - kpos < window)
         s = jnp.where(keep, s, -jnp.inf)
+    if block_len:
+        s = jnp.where(block_diffusion_keep(sq, block_len), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bngqk,bnkd->bngqd", p, v).reshape(b, nh, sq, d)
+
+
+def block_diffusion_keep(rows: int, block_len: int):
+    """(rows, rows) bool: the scores block-diffusion training keeps
+    (Arriola et al. 2025, arXiv:2503.09573). Rows [0, rows / 2) are the
+    noised copy of a sequence and the rest its clean copy; a row's
+    position is its place in its copy and its block ``position //
+    block_len``. A noised query keeps the noised keys of its own block
+    and the clean keys of earlier blocks; a clean query keeps the clean
+    keys of its own block and earlier ones, and no noised key."""
+    half = rows // 2
+    r = jnp.arange(rows)
+    blk, noised = (r % half) // block_len, r < half
+    bq, bk = blk[:, None], blk[None, :]
+    nq, nk = noised[:, None], noised[None, :]
+    return jnp.where(nq & nk, bq == bk,
+                     jnp.where(nq, bk < bq, ~nk & (bk <= bq)))
 
 
 def decode_attention_chunked(q, k, v, *, pos, scale: Optional[float] = None,

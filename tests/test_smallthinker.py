@@ -32,7 +32,9 @@ from cxxnet_tpu.utils import telemetry  # noqa: E402
 D, L, VOCAB, NEXP, WIDTH = 64, 32, 96, 8, 32
 SMALL = dict(vocab=VOCAB, dim=D, nhead=4, nkvhead=2, head_dim=16, nlayer=4,
              n_expert=NEXP, top_k=2, expert_width=WIDTH, window=8)
-CFG = {"seq_len": L, "batch_per_chip": 2 * L,
+# the model of base seed 0, stated: ``lm_inputs.params_from_seed`` gives a
+# ``cfg`` without the key the same one (one path for the weights)
+CFG = {"seq_len": L, "batch_per_chip": 2 * L, "weights_base_seed": 0,
        "extra_cfg": "eval_train = 0\nhealth_monitor = 1\n"}
 SEED = 2**31 + 77
 # two sequences of LONG tokens, top 2: 2,048 pairs; a share of 2 of the 8

@@ -171,3 +171,32 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
     # nothing of query-head size is summed after the kernels
     assert not re.search(r"bf16\[\d+,7,8192,128\]", text), text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# the block-diffusion cell's attention (`sdar-ep8-train-8k`): 32 query heads
+# on 4 key-value heads of 128, 16,384 rows (a noised and a clean copy of
+# 8,192 tokens), block length 4. The two-run walk is scalar arithmetic in
+# the index maps and the kernels' bodies (floor divisions, selects), the mask
+# a shift and two compares on an edge tile: Mosaic has to take both
+def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
+    from cxxnet_tpu.ops import flash_attn
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    for kernel, t in zip(("fwd", "dq", "dkv"),
+                         flash_attn._tiling(16384, 128, 2, 0, 4)):
+        assert flash_attn._vmem_bytes(kernel, t, 128, 2) \
+            <= flash_attn.VMEM_BUDGET
+
+    def both(q_, k_, v_, do_):
+        out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
+            a, b, c, False, None, False, 0, None, 4), q_, k_, v_)
+        return (out,) + vjp(do_)
+    compiled = jax.jit(both).lower(q, kv, kv, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert [o.shape for o in compiled.out_info] == [
+        q.shape, q.shape, kv.shape, kv.shape]
+    assert not re.search(r"bf16\[\d+,8,16384,128\]", text), text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20

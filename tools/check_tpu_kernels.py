@@ -10,6 +10,8 @@ LRN numerics compiled for TPU (NCHW, and channels-last at the four shapes
 of the benchmark's cells).
 
 Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
+     python tools/check_tpu_kernels.py blockdiff   (the flash kernels under
+     the block-diffusion mask at its cell's shape alone, ~1 min)
 """
 
 import functools
@@ -30,6 +32,9 @@ def main():
     if jax.default_backend() != "tpu":
         sys.exit("check_tpu_kernels: needs a TPU backend, jax found %r"
                  % jax.default_backend())
+    if sys.argv[1:] == ["blockdiff"]:
+        _check_flash_under_the_block_diffusion_mask(np.random.RandomState(0))
+        return
     from cxxnet_tpu import ops
     from cxxnet_tpu.ops import pallas_kernels
     from cxxnet_tpu.layer import base, layers
@@ -221,6 +226,7 @@ def main():
                     rtol=1e-1, atol=1e-1)
         print("flash attention L=%d bf16 fwd + dq + dk/dv: OK" % L)
     _check_flash_at_the_cells_shape(rs)
+    _check_flash_under_the_block_diffusion_mask(rs)
 
     # --- ring-step flash kernels, compiled ---
     # a 1-device sp mesh exercises the full kernel set (SMEM offsets,
@@ -318,6 +324,23 @@ dev = tpu
     print("ALL TPU KERNEL CHECKS PASSED")
 
 
+def _ms(fn, *args, n=5):
+    """Wall-clock ms of one call of a jitted ``fn``, warm, mean of n."""
+    import time
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n * 1e3
+
+
+def _both(attend, q_, k_, v_, do_):
+    """An attention's output and its three gradients."""
+    out, vjp = jax.vjp(attend, q_, k_, v_)
+    return (out,) + vjp(do_)
+
+
 def _check_flash_at_the_cells_shape(rs):
     """The language-model cell's attention (`smallthinker-ep4-train-8k`:
     28 query heads on 4 key-value heads of 128, 8,192 tokens, bf16, the
@@ -325,7 +348,6 @@ def _check_flash_at_the_cells_shape(rs):
     against the dense reference, one key-value head's group at a time
     (its float32 scores are 1.9 GB), then the kernels' time and their
     rate by the mask's FLOPs, so that a reader has both without a trace."""
-    import time
     from benchmark import lm_flops
     from cxxnet_tpu.ops import flash_attn
     from cxxnet_tpu.parallel.ring import attention_reference
@@ -336,22 +358,11 @@ def _check_flash_at_the_cells_shape(rs):
     k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.bfloat16)
             for _ in range(2))
 
-    def ms(fn, *args, n=5):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(n):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / n * 1e3
-
     for window in (0, 4096):
-        def both(attend, q_, k_, v_, do_):
-            out, vjp = jax.vjp(attend, q_, k_, v_)
-            return (out,) + vjp(do_)
-        flash = jax.jit(functools.partial(both, lambda q_, k_, v_: (
+        flash = jax.jit(functools.partial(_both, lambda q_, k_, v_: (
             flash_attn.flash_attention(q_, k_, v_, True, None, False,
                                        window))))
-        dense = jax.jit(functools.partial(both, lambda q_, k_, v_: (
+        dense = jax.jit(functools.partial(_both, lambda q_, k_, v_: (
             attention_reference(q_, k_, v_, causal=True, window=window))))
         got = flash(q, k, v, do)
         for h in range(nkv):
@@ -366,12 +377,63 @@ def _check_flash_at_the_cells_shape(rs):
         fwd = jax.jit(lambda q_, k_, v_: flash_attn.flash_attention(
             q_, k_, v_, True, None, False, window))
         flops = lm_flops.flash_attention(L, nh, nkv, d, window)["flops"]
-        t_f, t_fb = ms(fwd, q, k, v), ms(flash, q, k, v, do)
+        t_f, t_fb = _ms(fwd, q, k, v), _ms(flash, q, k, v, do)
         print("flash attention at the cell's shape, window=%d: OK; forward "
               "%.2f ms = %.1f TFLOP/s, forward + backward %.2f ms = %.1f "
               "TFLOP/s (by the mask's FLOPs, x1 and x3.5)"
               % (window, t_f, flops / t_f / 1e9, t_fb,
                  3.5 * flops / t_fb / 1e9))
+
+
+def _check_flash_under_the_block_diffusion_mask(rs):
+    """The block-diffusion cell's attention (`sdar-ep8-train-8k`: 16,384
+    rows, the noised and the clean copy of 8,192 tokens, block length 4,
+    heads of 128, bf16): forward and the three gradients against the dense
+    masked softmax on one key-value head with a group of two (the tiles do
+    not follow the heads; a head's float32 scores are 1 GB), then, at the
+    cell's 32 query heads on 4, each kernel's time and its rate by the
+    scores the mask keeps (a gradient asked for alone leaves the other
+    backward kernel dead code)."""
+    from cxxnet_tpu.ops import flash_attn
+    from cxxnet_tpu.parallel.ring import attention_reference
+    L, d, B = 16384, 128, 4
+
+    def operands(nh, nkv):
+        q, do = (jnp.asarray(rs.randn(1, nh, L, d), jnp.bfloat16)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.bfloat16)
+                for _ in range(2))
+        return q, k, v, do
+
+    def flash(q_, k_, v_):
+        return flash_attn.flash_attention(q_, k_, v_, False, None, False,
+                                          0, None, B)
+    q, k, v, do = operands(2, 1)
+    got = jax.jit(functools.partial(_both, flash))(q, k, v, do)
+    want = jax.jit(functools.partial(_both, lambda q_, k_, v_: (
+        attention_reference(q_, k_, v_, block_len=B))))(q, k, v, do)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-1, atol=1e-1)
+    del got, want
+
+    nh, nkv = 32, 4
+    q, k, v, do = operands(nh, nkv)
+    half = L // 2
+    flops = 4.0 * nh * d * (half * half + half * B)     # forward, kept scores
+    t_f = _ms(jax.jit(flash), q, k, v)
+    t_q = _ms(jax.jit(lambda *a: _both(flash, *a)[1]), q, k, v, do) - t_f
+    t_kv = _ms(jax.jit(lambda *a: _both(flash, *a)[2:]), q, k, v, do) - t_f
+    t_fb = _ms(jax.jit(functools.partial(_both, flash)), q, k, v, do)
+    sched = flash_attn.schedule(q, k, False, 0, B)
+    print("flash attention under the block-diffusion mask at the cell's "
+          "shape: OK; tiles %s; forward %.2f ms = %.1f TFLOP/s, dQ %.2f ms "
+          "= %.1f TFLOP/s, dK/dV %.2f ms = %.1f TFLOP/s, forward + backward "
+          "%.2f ms = %.1f TFLOP/s (by the kept scores' FLOPs, x1, x1, x1.5 "
+          "and x3.5)" % (sched, t_f, flops / t_f / 1e9, t_q,
+                         flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9,
+                         t_fb, 3.5 * flops / t_fb / 1e9))
 
 
 if __name__ == "__main__":
