@@ -1296,6 +1296,18 @@ class AttentionLayer(Layer):
     def _dh(self):
         return self.head_dim or self.param.num_input_channel // self.nhead
 
+    def _rope_angles(self, L, half, offset=0):
+        """Float32 cosines and sines, (L, half), of the rotation's angles:
+        row r stands at position ``offset`` + r, under the block-diffusion
+        mask at r mod L/2."""
+        pos = offset + jnp.arange(L, dtype=jnp.float32)[:, None]
+        if self._blockdiff():
+            pos = pos % (L // 2)
+        inv = jnp.power(self.rope_base,
+                        -jnp.arange(half, dtype=jnp.float32) / half)
+        ang = pos * inv                                     # (L, half)
+        return jnp.cos(ang), jnp.sin(ang)
+
     def _apply_rope(self, x, offset=0):
         """Rotary embedding on (b, nh, L, dh): rotate the (first-half,
         second-half) feature pairs by position-dependent angles (Su et al.
@@ -1303,15 +1315,8 @@ class AttentionLayer(Layer):
         is the global position of row 0 (KV-cached decode steps). Under
         the block-diffusion mask the rows are two copies of one sequence
         and row r stands at position r mod L/2."""
-        dh = x.shape[-1]
-        half = dh // 2
-        pos = offset + jnp.arange(x.shape[2], dtype=jnp.float32)[:, None]
-        if self._blockdiff():
-            pos = pos % (x.shape[2] // 2)
-        inv = jnp.power(self.rope_base,
-                        -jnp.arange(half, dtype=jnp.float32) / half)
-        ang = pos * inv                                     # (L, half)
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        half = x.shape[-1] // 2
+        cos, sin = self._rope_angles(x.shape[2], half, offset)
         # the rotation itself in float32 whatever the compute type: a
         # bf16 cosine keeps 8 bits of an angle that runs to thousands
         x1 = x[..., :half].astype(jnp.float32)
@@ -1368,27 +1373,12 @@ class AttentionLayer(Layer):
         else:
             b, d, _, L = x.shape
             seq = x.reshape(b, d, L).transpose(0, 2, 1)      # (b, L, d)
-        nh, dh = self.nhead, self._dh()
-        nkv = self.nkvhead or nh
-        qw, kvw = nh * dh, self._kv_width()
-
-        def heads(t, n):  # (b, L, n*dh) -> (b, n, L, dh)
-            return t.reshape(b, L, n, dh).transpose(0, 2, 1, 3)
-
+        qw = self.nhead * self._dh()
         # sub-scopes qkv / core / out: tools/trace_layers.py splits the
         # layer's device time by them
         with sub_scope("qkv"):
             qkv = jnp.dot(seq, params["wqkv"])        # (b, L, qw + 2*kvw)
-            q = heads(qkv[..., :qw], nh)
-            k = heads(qkv[..., qw:qw + kvw], nkv)
-            v = heads(qkv[..., qw + kvw:], nkv)
-            if self.qk_norm:
-                # over each head's features, (b, heads, L, dh)
-                q = _rms_norm(q, params["qnorm"], 1e-6, 3)
-                k = _rms_norm(k, params["knorm"], 1e-6, 3)
-            if self.rope:
-                off = ctx.decode_pos if ctx.decode_pos is not None else 0
-                q, k = self._apply_rope(q, off), self._apply_rope(k, off)
+            q, k, v = self._heads(qkv, params, ctx)
         with sub_scope("core"):
             out = self._core(q, k, v, ctx)
         with sub_scope("out"):
@@ -1397,6 +1387,56 @@ class AttentionLayer(Layer):
         if ctx.channels_last:
             return [out.reshape(b, 1, L, d)]
         return [out.transpose(0, 2, 1).reshape(b, d, 1, L)]
+
+    def _heads(self, qkv, params, ctx):
+        """The qkv dot's output (b, L, qw + 2 kvw) as the core's operands:
+        q (b, nh, L, dh), k and v (b, nkv, L, dh), q and k normed over
+        each head's features (``qk_norm``) and rotated (``rope``). Counts
+        ``attn.prep.fused`` / ``attn.prep.xla`` once per traced layer."""
+        from ..utils import telemetry
+        b, L, _ = qkv.shape
+        nh, dh = self.nhead, self._dh()
+        nkv = self.nkvhead or nh
+        qw, kvw = nh * dh, self._kv_width()
+        if self._prep_fused(qkv, ctx):
+            # one kernel each way for the split, the norms and the
+            # rotation (ops/qk_prep_pallas.py); the lines below are its
+            # golden model and the path everywhere else
+            telemetry.count_path("attn.prep.fused")
+            cos = sin = None
+            if self.rope:
+                cos, sin = self._rope_angles(L, dh // 2)
+                cos = jnp.concatenate([cos, cos], axis=-1)
+                sin = jnp.concatenate([-sin, sin], axis=-1)
+            return ops.qk_prep(qkv, params.get("qnorm"), params.get("knorm"),
+                               cos, sin, nh, nkv, dh)
+        telemetry.count_path("attn.prep.xla")
+
+        def heads(t, n):  # (b, L, n*dh) -> (b, n, L, dh)
+            return t.reshape(b, L, n, dh).transpose(0, 2, 1, 3)
+
+        q = heads(qkv[..., :qw], nh)
+        k = heads(qkv[..., qw:qw + kvw], nkv)
+        v = heads(qkv[..., qw + kvw:], nkv)
+        if self.qk_norm:
+            # over each head's features, (b, heads, L, dh)
+            q = _rms_norm(q, params["qnorm"], 1e-6, 3)
+            k = _rms_norm(k, params["knorm"], 1e-6, 3)
+        if self.rope:
+            off = ctx.decode_pos if ctx.decode_pos is not None else 0
+            q, k = self._apply_rope(q, off), self._apply_rope(k, off)
+        return q, k, v
+
+    def _prep_fused(self, qkv, ctx):
+        """Whether the pass from the qkv dot to the core takes the fused
+        kernels: on a TPU (use_pallas), a training or scoring pass on one
+        device (no cache position, no mesh: pallas_call has no
+        partitioning rule), a layer with a norm or a rotation to fuse, and
+        a shape the kernels tile (ops.qk_prep_supported)."""
+        return (ops.use_pallas() and (self.rope or self.qk_norm)
+                and ctx.decode_pos is None and ctx.mesh is None
+                and ops.qk_prep_supported(qkv.shape[1], self._dh(),
+                                          qkv.shape[2], qkv.dtype))
 
     def _count_flash(self, q, k, causal):
         """The path account's ``attn.flash`` and, beside it, the static

@@ -269,6 +269,25 @@ def flash_schedule(q, k, causal: bool, window: int = 0,
     return _fa.schedule(q, k, causal, window, block_len)
 
 
+def qk_prep_supported(L: int, dh: int, width: int, dtype) -> bool:
+    """True when the fused pass between the qkv dot and the attention core
+    (ops/qk_prep_pallas.py) takes these shapes: ``L`` rows of ``width`` lanes,
+    heads of ``dh``."""
+    from . import qk_prep_pallas as _qp
+    return _qp.supports(L, dh, width, jnp.dtype(dtype).itemsize)
+
+
+def qk_prep(qkv, qnorm, knorm, cos, sin, nh: int, nkv: int, dh: int):
+    """The dot's ``qkv (b, L, (nh + 2 nkv) dh)`` to the core's ``q (b, nh,
+    L, dh)``, ``k``, ``v (b, nkv, L, dh)`` in one kernel each way: the
+    heads' split, QK-norm where ``qnorm`` / ``knorm`` are given, the
+    rotation where the float32 ``(L, dh)`` tables ``cos`` / ``sin`` are
+    (ops/qk_prep_pallas.py)."""
+    from . import qk_prep_pallas as _qp
+    return _qp.qk_prep(qkv, qnorm, knorm, cos, sin, nh, nkv, dh,
+                       pallas_interpret())
+
+
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """Tiles of the grouped product's kernel: rows in 512s (the callers pad
     to it), the contraction and the output columns whole up to 1024 and

@@ -312,10 +312,13 @@ def test_the_flash_path_runs_the_mask_and_counts_it_once_a_layer():
     assert gauges["attn.block_len"] == 4
     assert gauges["flash.block_q"] == sched["block_q"]
     assert kinds["skipped"] >= sum(kinds.values()) // 4   # the dead quadrant
-    assert paths == dict({"attn.flash": 1, "attn.blockdiff": 1}, **{
+    # heads of 16: the plain lines between the qkv dot and the core
+    assert paths == dict({"attn.flash": 1, "attn.blockdiff": 1,
+                          "attn.prep.xla": 1}, **{
         "flash.tiles." + k: n for k, n in kinds.items() if n})
     y_dense, gauges, paths = delta(False)
-    assert paths == {"attn.dense": 1, "attn.blockdiff": 1}
+    assert paths == {"attn.dense": 1, "attn.blockdiff": 1,
+                     "attn.prep.xla": 1}
     np.testing.assert_allclose(y_flash, y_dense, rtol=2e-4, atol=2e-5)
 
 
@@ -498,7 +501,15 @@ SMALL = dict(vocab=96, dim=D, nhead=4, nkvhead=2, head_dim=16, nlayer=2,
              expert_offset=2)
 
 
-def test_the_step_on_the_forced_flash_path_counts_its_paths():
+# heads of 16 keep the plain lines between the qkv dot and the core; heads
+# of 128 (the published size, four layers as the benchmark's cell) take
+# the fused pass in every layer (PR 37)
+@pytest.mark.parametrize("over, prep", [
+    ({}, {"attn.prep.xla": 2}),
+    (dict(nhead=2, nkvhead=1, head_dim=128, nlayer=4),
+     {"attn.prep.fused": 4}),
+], ids=["heads-of-16", "heads-of-128"])
+def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     """``Trainer.update`` from ``models.sdar_moe_conf``'s text with the
     kernels forced on (the interpreter), 512 rows: every attention layer
     takes the flash kernels under the new mask, every ``moe`` layer the
@@ -507,9 +518,11 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths():
     from cxxnet_tpu.nnet.trainer import Trainer
     from cxxnet_tpu.utils.config import parse_config_string
     seq = 256
+    small = dict(SMALL, **over)
+    n = small["nlayer"]
     conf = models.sdar_moe_conf(
         seq=seq, dev="cpu", extra_cfg="eval_train = 0\nhealth_monitor = 1\n"
-                                      "seed = 3\n", **SMALL)
+                                      "seed = 3\n", **small)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, seq), 0, 95)
     b = DataBatch()
     b.data, b.label = noise_batch(tokens, jax.random.PRNGKey(2), 4, 0.45,
@@ -533,12 +546,13 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths():
                       if n != before.get(k, 0)}
     loss, paths = run(True)
     assert {k: n for k, n in paths.items()
-            if not k.startswith("flash.tiles.")} == {
-        "attn.flash": 2, "attn.blockdiff": 2, "moe.sparse": 2,
-        "moe.bounded": 2, "loss.weighted": 1}
+            if not k.startswith("flash.tiles.")} == dict({
+        "attn.flash": n, "attn.blockdiff": n, "moe.sparse": n,
+        "moe.bounded": n, "loss.weighted": 1}, **prep)
     assert np.isfinite(loss) and 2.0 < loss < 12.0
     dense, paths = run(False)
-    assert paths.get("attn.dense") == 2 and "attn.flash" not in paths
+    assert paths.get("attn.dense") == n and "attn.flash" not in paths
+    assert paths.get("attn.prep.xla") == n and "attn.prep.fused" not in paths
     assert loss == pytest.approx(dense, rel=1e-5)
 
 

@@ -545,12 +545,42 @@ def test_the_flash_path_counts_its_tile_schedule_once_a_layer(window):
     kinds = {k: sched[k] for k in ("full", "edge", "skipped")}
     assert sum(kinds.values()) == (L2 // bq) * (L2 // bk)
     assert kinds["edge"] >= L2 // max(bq, bk)      # the diagonal's tiles
-    assert paths == dict({"attn.flash": 1}, **{
+    # heads of 16: the plain lines between the qkv dot and the core
+    assert paths == dict({"attn.flash": 1, "attn.prep.xla": 1}, **{
         "flash.tiles." + k: n for k, n in kinds.items() if n})
     y_dense, gauges, paths = delta(False)
-    assert paths == {"attn.dense": 1}
+    assert paths == {"attn.dense": 1, "attn.prep.xla": 1}
     assert not any(k.startswith("flash.") for k in gauges)
     np.testing.assert_allclose(y_flash, y_dense, rtol=2e-4, atol=2e-5)
+
+
+def test_the_step_on_the_forced_flash_path_fuses_the_rotation():
+    """``Trainer.update`` with the kernels forced on (the interpreter),
+    heads of the published 128 at L 256: the three layers with a rotation
+    take the fused pass between the qkv dot and the core, the global layer
+    (no rotation, no norm) keeps the plain lines, all four the flash
+    kernels; and the step gives the plain path's loss."""
+    conf = _conf(nhead=2, nkvhead=1, head_dim=128, window=64)
+    cfg = dict(CFG, seq_len=256, batch_per_chip=256)
+
+    def run(force):
+        before = telemetry.paths()
+        ops.set_use_pallas(force)
+        try:
+            program = cxxnet_lm_trainer.Program(conf, cfg, 1, SEED, {})
+            program.step()
+            program.sync()
+        finally:
+            ops.set_use_pallas(None)
+        return float(program.trainer.last_health[0]), {
+            k: n for k, n in _paths_since(before).items()
+            if k.startswith("attn.")}
+    loss, paths = run(True)
+    assert paths == {"attn.flash": 4, "attn.prep.fused": 3,
+                     "attn.prep.xla": 1}
+    plain, paths = run(False)
+    assert paths == {"attn.dense": 4, "attn.prep.xla": 4}
+    assert np.isfinite(loss) and loss == pytest.approx(plain, rel=1e-5)
 
 
 # ------------------------------------------------------------- the recipe

@@ -200,3 +200,41 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
         q.shape, q.shape, kv.shape, kv.shape]
     assert not re.search(r"bf16\[\d+,8,16384,128\]", text), text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# the pass between the qkv dot and the flash kernels (PR 37) at the two
+# language-model cells' shapes, and one float32 case with heads of two lane
+# tiles: a row tile at the whole width (5,120 / 4,608 lanes) has to fit the
+# VMEM the kernels ask for, the lane roll and the per-head column slices
+# have to take Mosaic's tiling, and the two kernels have to stand alone: no
+# transpose, no copy and no float32 tensor of the rows beside them
+@pytest.mark.parametrize("L, nh, nkv, dh, norm, rope, dtype", [
+    (16384, 32, 4, 128, True, True, jnp.bfloat16),    # sdar-ep8-train-8k
+    (8192, 28, 4, 128, False, True, jnp.bfloat16),    # smallthinker-ep4-...
+    (2048, 8, 2, 128, True, False, jnp.bfloat16),
+    (1024, 4, 2, 256, True, True, jnp.float32),
+], ids=["sdar", "smallthinker", "norm-alone", "heads-of-256-float32"])
+def test_qk_prep_kernels_compile_at_the_cells_shapes(one_chip, L, nh, nkv,
+                                                     dh, norm, rope, dtype):
+    from cxxnet_tpu.ops import qk_prep_pallas
+
+    def arg(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    width = (nh + 2 * nkv) * dh
+    assert qk_prep_pallas.supports(L, dh, width, jnp.dtype(dtype).itemsize)
+    gain = arg((dh,), jnp.float32) if norm else None
+    table = arg((L, dh), jnp.float32) if rope else None
+    heads = [arg((1, n, L, dh)) for n in (nh, nkv, nkv)]
+
+    def both(qkv, qn, kn, cos, sin, dq, dk, dv):
+        out, vjp = jax.vjp(lambda x, a, c: qk_prep_pallas.qk_prep(
+            x, a, c, cos, sin, nh, nkv, dh), qkv, qn, kn)
+        return out, vjp((dq, dk, dv))
+    compiled = jax.jit(both).lower(arg((1, L, width)), gain, gain, table,
+                                   table, *heads).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
+    assert not re.search(r"= \S+ (copy|transpose)\(", text), text
+    # the only residual is qkv itself: nothing of the rows' size is made
+    # beside the kernels' own results
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -12,6 +12,9 @@ of the benchmark's cells).
 Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      python tools/check_tpu_kernels.py blockdiff   (the flash kernels under
      the block-diffusion mask at its cell's shape alone, ~1 min)
+     python tools/check_tpu_kernels.py qkprep   (the fused pass between the
+     qkv dot and the attention core at both language-model cells' shapes
+     beside the plain lines, ~1 min)
 """
 
 import functools
@@ -34,6 +37,9 @@ def main():
                  % jax.default_backend())
     if sys.argv[1:] == ["blockdiff"]:
         _check_flash_under_the_block_diffusion_mask(np.random.RandomState(0))
+        return
+    if sys.argv[1:] == ["qkprep"]:
+        _check_qk_prep_at_the_cells_shapes(np.random.RandomState(0))
         return
     from cxxnet_tpu import ops
     from cxxnet_tpu.ops import pallas_kernels
@@ -227,6 +233,7 @@ def main():
         print("flash attention L=%d bf16 fwd + dq + dk/dv: OK" % L)
     _check_flash_at_the_cells_shape(rs)
     _check_flash_under_the_block_diffusion_mask(rs)
+    _check_qk_prep_at_the_cells_shapes(rs)
 
     # --- ring-step flash kernels, compiled ---
     # a 1-device sp mesh exercises the full kernel set (SMEM offsets,
@@ -434,6 +441,76 @@ def _check_flash_under_the_block_diffusion_mask(rs):
           "and x3.5)" % (sched, t_f, flops / t_f / 1e9, t_q,
                          flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9,
                          t_fb, 3.5 * flops / t_fb / 1e9))
+
+
+def _check_qk_prep_at_the_cells_shapes(rs):
+    """``AttentionLayer._heads`` (the qkv dot's output to the core's q, k,
+    v) at the two language-model cells' shapes, bf16: `sdar-ep8-train-8k`
+    (16,384 rows under the block-diffusion mask, 32 heads on 4 of 128,
+    QK-norm and the rotation) and `smallthinker-ep4-train-8k` (8,192 rows,
+    28 on 4, the rotation alone). The fused kernels (ops/qk_prep_pallas.py)
+    against the layer's plain lines: the worst gap of the three outputs
+    and of every gradient, then each path's ms forward and backward and
+    the kernels' GB/s by one honest pass (every operand read once, every
+    result written once). Wall clock around jitted calls: a call's
+    dispatch rides on each, which at these fractions of a millisecond is
+    a sizeable part; a trace of the step has the kernels' own time
+    (PERF.md section 5)."""
+    from cxxnet_tpu import ops
+    from cxxnet_tpu.layer import base, layers
+    ctx = base.ApplyContext(train=True)
+    for cell, L, nh, nkv, dh, conf in (
+            ("sdar-ep8-train-8k", 16384, 32, 4, 128,
+             {"qk_norm": "1", "attn_mask": "blockdiff", "block_len": "4",
+              "rope_base": "1000000"}),
+            ("smallthinker-ep4-train-8k", 8192, 28, 4, 128,
+             {"causal": "1", "attn_window": "4096",
+              "rope_base": "1500000"})):
+        layer = layers.AttentionLayer()
+        for key, val in dict(conf, nhead=nh, nkvhead=nkv, head_dim=dh,
+                             rope=1).items():
+            layer.set_param(key, str(val))
+        width = (nh + 2 * nkv) * dh
+        qkv = jnp.asarray(rs.randn(1, L, width), jnp.bfloat16)
+        params = {key: jnp.asarray(1 + 0.1 * rs.randn(dh), jnp.float32)
+                  for key in layer._norm_keys()}
+        dout = tuple(jnp.asarray(rs.randn(1, n, L, dh), jnp.bfloat16)
+                     for n in (nh, nkv, nkv))
+
+        def both(forward, x, p, g):
+            out, vjp = jax.vjp(forward, x, p)
+            return out, vjp(g)
+
+        got, ms = {}, {}
+        for path, flag in (("fused", True), ("xla", False)):
+            ops.set_use_pallas(flag)
+            try:
+                # a function of its own for each path: jit's cache knows
+                # nothing of the flag
+                fwd = jax.jit(lambda x, p: layer._heads(x, p, ctx))
+                fb = jax.jit(functools.partial(
+                    both, lambda x, p: layer._heads(x, p, ctx)))
+                got[path] = jax.tree_util.tree_leaves(fb(qkv, params, dout))
+                t_f = _ms(fwd, qkv, params, n=20)
+                ms[path] = (t_f, _ms(fb, qkv, params, dout, n=20) - t_f)
+            finally:
+                ops.set_use_pallas(None)
+        gaps = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b.astype(jnp.float32)))
+                      / jnp.max(jnp.abs(b.astype(jnp.float32))))
+                for a, b in zip(got["fused"], got["xla"])]
+        # one rounding fewer (none between norm and rotation): a bf16 ulp
+        assert max(gaps) < 2e-2, gaps
+        rows, tables = 2.0 * L * width, 2 * 4.0 * L * dh
+        gb_f = (2 * rows + tables) / 1e9
+        gb_b = ((3 if layer.qk_norm else 2) * rows + tables) / 1e9
+        print("qk_prep at %s's shape: OK (worst gap of q, k, v and the "
+              "gradients, over the largest value: %.2e); fused forward "
+              "%.3f ms = %.0f GB/s, backward %.3f ms = %.0f GB/s; the plain "
+              "lines forward %.3f ms, backward %.3f ms"
+              % (cell, max(gaps), ms["fused"][0],
+                 gb_f / ms["fused"][0] * 1e3, ms["fused"][1],
+                 gb_b / ms["fused"][1] * 1e3, ms["xla"][0], ms["xla"][1]))
 
 
 if __name__ == "__main__":
