@@ -1216,6 +1216,26 @@ class AttentionLayer(Layer):
         # under parallel.block_diffusion_keep's mask
         self.attn_mask = "causal"
         self.block_len = 0
+        # attn_mask = dsa with index_heads = J, index_dim = di, index_topk
+        # = k: learned sparse attention in training (DeepSeek-Sparse-
+        # Attention). An indexer of J heads of di features on one key head
+        # scores every causal pair from the DETACHED input, each query
+        # attends to the min(t + 1, k) keys of largest score (ops/dsa.py:
+        # exact, lax.top_k's set), and the indexer learns from the
+        # attention it selected for: the layer adds (1 / L) sum_t KL(mean
+        # over heads of the attention's probabilities || softmax of the
+        # index scores, both on the selected keys) to the step's loss.
+        # Leaves widx_q, widx_k, widx_w, and the key's LayerNorm idx_gain /
+        # idx_bias. Needs causal = 1.
+        self.index_heads = 0
+        self.index_dim = 0
+        self.index_topk = 0
+        self.batch_size = 1
+        self.update_period = 1
+        # what a layer under attn_mask = dsa leaves in ctx.layer_stats:
+        # the pairs the step attended, and the index loss a sequence
+        self.stat_names = ()
+        self.stat_limits = {}
 
     def set_param(self, name, val):
         super().set_param(name, val)
@@ -1224,12 +1244,15 @@ class AttentionLayer(Layer):
         if name == "qk_norm":
             self.qk_norm = int(val)
         if name == "attn_mask":
-            check(val in ("causal", "blockdiff"),
+            check(val in ("causal", "blockdiff", "dsa"),
                   "attn_mask must be causal (what the key causal then "
-                  "decides) or blockdiff")
+                  "decides), blockdiff or dsa")
             self.attn_mask = val
         if name == "block_len":
             self.block_len = int(val)
+        if name in ("index_heads", "index_dim", "index_topk", "batch_size",
+                    "update_period"):
+            setattr(self, name, int(val))
         if name == "causal":
             self.causal = int(val)
         if name == "rope":
@@ -1287,7 +1310,29 @@ class AttentionLayer(Layer):
             check(not self.block_len,
                   "block_len is the block of attn_mask = blockdiff and "
                   "means nothing under another mask")
+        if self._dsa():
+            check(self.index_heads > 0 and self.index_dim > 0
+                  and self.index_topk > 0,
+                  "attn_mask = dsa needs index_heads, index_dim and "
+                  "index_topk: the indexer's heads, their size and the keys "
+                  "a query keeps")
+            check(self.causal and not self.attn_window,
+                  "attn_mask = dsa selects among the keys at or before the "
+                  "query: causal must be 1 and attn_window 0 (a window "
+                  "together with a selection is not written)")
+            check(self.index_dim % 2 == 0,
+                  "attn_mask = dsa: the indexer's heads are rotated over "
+                  "all their features, index_dim must be even")
+            self.stat_names = ("dsa.selected", "dsa.index_loss")
+        else:
+            check(not (self.index_heads or self.index_dim
+                       or self.index_topk),
+                  "index_heads / index_dim / index_topk are the indexer of "
+                  "attn_mask = dsa and mean nothing under another mask")
         return [in_shapes[0]]
+
+    def _dsa(self):
+        return self.attn_mask == "dsa"
 
     def _blockdiff(self):
         """The block length where the mask is block diffusion's, else 0."""
@@ -1337,26 +1382,40 @@ class AttentionLayer(Layer):
                    rng, (qw, d), in_num=qw, out_num=d)}
         for key in self._norm_keys():
             out[key] = np.ones((self._dh(),), np.float32)
+        if self._dsa():
+            J, di = self.index_heads, self.index_dim
+            for key, n in (("widx_q", J * di), ("widx_k", di),
+                           ("widx_w", J)):
+                out[key] = self.param.rand_init_weight(
+                    rng, (d, n), in_num=d, out_num=n)
+            out["idx_gain"] = np.ones((di,), np.float32)
+            out["idx_bias"] = np.zeros((di,), np.float32)
         return out
 
     def _norm_keys(self):
         return ("qnorm", "knorm") if self.qk_norm else ()
 
+    def _index_keys(self):
+        """The indexer's leaves, in the order they are saved and visited:
+        three projections and its key's LayerNorm."""
+        return ("widx_q", "widx_k", "widx_w", "idx_gain",
+                "idx_bias") if self._dsa() else ()
+
     def save_model(self, w, params):
         self.param.save(w)
-        for key in ("wqkv", "wo") + self._norm_keys():
+        for key in ("wqkv", "wo") + self._norm_keys() + self._index_keys():
             w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
-        return {key: r.read_tensor()
-                for key in ("wqkv", "wo") + self._norm_keys()}
+        return {key: r.read_tensor() for key in
+                ("wqkv", "wo") + self._norm_keys() + self._index_keys()}
 
     def visit_order(self):
         # wo gets its own tag: one array per tag so the GetWeight/SetWeight
         # ABI (and per-tag updater scoping, e.g. wo:lr) can reach both
         return [("wmat", "wqkv"), ("wo", "wo")] + [
-            (key, key) for key in self._norm_keys()]
+            (key, key) for key in self._norm_keys() + self._index_keys()]
 
 
     layout_support = "nhwc"
@@ -1379,8 +1438,11 @@ class AttentionLayer(Layer):
         with sub_scope("qkv"):
             qkv = jnp.dot(seq, params["wqkv"])        # (b, L, qw + 2*kvw)
             q, k, v = self._heads(qkv, params, ctx)
-        with sub_scope("core"):
-            out = self._core(q, k, v, ctx)
+        if self._dsa():
+            out = self._dsa_core(seq, q, k, v, params, ctx)
+        else:
+            with sub_scope("core"):
+                out = self._core(q, k, v, ctx)
         with sub_scope("out"):
             out = out.transpose(0, 2, 1, 3).reshape(b, L, qw)  # merge heads
             out = jnp.dot(out, params["wo"])
@@ -1427,6 +1489,89 @@ class AttentionLayer(Layer):
             q, k = self._apply_rope(q, off), self._apply_rope(k, off)
         return q, k, v
 
+    def _index_operands(self, seq, params):
+        """The indexer's reading of the layer's input (b, L, d), detached:
+        qI (b, J, L, di) and kI (b, L, di), the key through its LayerNorm,
+        both rotated over all di features by the model's tables, and the
+        heads' weights w (b, L, J) float32, scaled J^-1/2 di^-1/2."""
+        b, L, _ = seq.shape
+        J, di = self.index_heads, self.index_dim
+        hd = jax.lax.stop_gradient(seq)
+        qi = jnp.dot(hd, params["widx_q"]).reshape(b, L, J, di) \
+            .transpose(0, 2, 1, 3)
+        ki = jnp.dot(hd, params["widx_k"]).astype(jnp.float32)
+        mean = jnp.mean(ki, -1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), -1, keepdims=True)
+        ki = (ki - mean) * jax.lax.rsqrt(var + 1e-6) \
+            * params["idx_gain"].astype(jnp.float32) \
+            + params["idx_bias"].astype(jnp.float32)
+        ki = ki.astype(seq.dtype)[:, None]                  # one key head
+        if self.rope:
+            qi, ki = self._apply_rope(qi), self._apply_rope(ki)
+        w = jnp.dot(hd, params["widx_w"],
+                    preferred_element_type=jnp.float32) \
+            * (J ** -0.5 * di ** -0.5)
+        return qi, ki[:, 0], w
+
+    def _dsa_core(self, seq, q, k, v, params, ctx):
+        """The core under a learned selection: index scores, the exact
+        top-k, the attention on the selected keys (in the flash kernels
+        where they take the shape, the selection streamed as an int8
+        mask), and in training the indexer's loss, which joins the step's
+        (``ctx.losses``) from here. Sub-scopes index / select / core /
+        index_loss."""
+        from ..ops import dsa
+        from ..utils import telemetry
+        b, nh, L, dh = q.shape
+        mesh = ctx.mesh
+        check(ctx.decode_pos is None,
+              "attention: attn_mask = dsa is written for training and "
+              "scoring whole sequences; the indexer's key cache and a "
+              "selection inside prefill / decode from a cache are not "
+              "written")
+        check(manual_axis_size(ctx, "sp") <= 1
+              and "sp" not in getattr(mesh, "axis_names", ()),
+              "attention: attn_mask = dsa under sequence parallelism "
+              "(ring / ulysses) is not written")
+        telemetry.count_path("attn.dsa")
+        telemetry.gauge("dsa.topk", min(self.index_topk, L))
+        telemetry.gauge("dsa.kept_scores", dsa.kept_scores(L, self.index_topk))
+        with sub_scope("index"):
+            qi, ki, w = self._index_operands(seq, params)
+            scores = dsa.index_scores(qi, ki, w)            # (b, L, L) f32
+        with sub_scope("select"):
+            sel = dsa.select(jax.lax.stop_gradient(scores), self.index_topk)
+        flash = (ops.use_pallas() and ops.flash_supported(L, dh)
+                 and (mesh is None or ctx.manual_tp))
+        with sub_scope("core"):
+            if flash:
+                self._count_flash(q, k, True, select=True)
+                out, lse = ops.flash_attention_selected(q, k, v, sel)
+            else:
+                telemetry.count_path("attn.dense")
+                probs, p = dsa.selected_probs_plain(q, k, sel, dh ** -0.5)
+                out = jnp.einsum(
+                    "bngqk,bnkd->bngqd", probs.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32) \
+                    .reshape(b, nh, L, dh).astype(q.dtype)
+        if not ctx.train:
+            return out
+        with sub_scope("index_loss"):
+            telemetry.count_path("loss.index")
+            if flash:
+                p = ops.selected_probs(*jax.lax.stop_gradient((q, k, lse)),
+                                       sel)
+            p = jax.lax.stop_gradient(p)
+            kl = dsa.index_loss(scores, sel, p) / L         # (b,)
+            ctx.losses.append(
+                jnp.sum(kl) / (self.batch_size * self.update_period))
+            # a sequence's count in whole numbers: float32 holds them
+            # exactly to 2^24, a sum over the batch's need not fit
+            n_sel = jnp.sum(sel, axis=(1, 2), dtype=jnp.int32)
+            ctx.layer_stats[ctx.conn_index] = jnp.stack(
+                [jnp.mean(n_sel.astype(jnp.float32)), jnp.mean(kl)])
+        return out
+
     def _prep_fused(self, qkv, ctx):
         """Whether the pass from the qkv dot to the core takes the fused
         kernels: on a TPU (use_pallas), a training or scoring pass on one
@@ -1438,14 +1583,14 @@ class AttentionLayer(Layer):
                 and ops.qk_prep_supported(qkv.shape[1], self._dh(),
                                           qkv.shape[2], qkv.dtype))
 
-    def _count_flash(self, q, k, causal):
+    def _count_flash(self, q, k, causal, select=False):
         """The path account's ``attn.flash`` and, beside it, the static
         tile schedule the kernels will walk for these shapes: the forward
         tile as gauges, and the (block_q, block_k) score tiles of one
         head's grid that take no mask, take one, and are never visited."""
         from ..utils import telemetry
         sched = ops.flash_schedule(q, k, causal, self.attn_window,
-                                   self._blockdiff())
+                                   self._blockdiff(), select)
         telemetry.count_path("attn.flash")
         telemetry.gauge("flash.block_q", sched["block_q"])
         telemetry.gauge("flash.block_k", sched["block_k"])

@@ -620,6 +620,93 @@ def sdar_moe_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
             "dev = %s\n" % dev + extra_cfg)
 
 
+def keye_dsa_netconfig(vocab: int = 151936, dim: int = 2048,
+                       nhead: int = 32, nkvhead: int = 4,
+                       head_dim: int = 128, nlayer: int = 48,
+                       n_expert: int = 128, top_k: int = 8,
+                       expert_width: int = 768, n_held: int = 0,
+                       expert_offset: int = 0, index_heads: int = 16,
+                       index_dim: int = 64, index_topk: int = 2048,
+                       rope_theta: float = 1e7, eps: float = 1e-6,
+                       remat: str = "moe") -> str:
+    """The language model of Keye-VL-2.0-30B-A3B (Kwai-Keye, 2025; the
+    defaults are the language model's keys of its published config.json,
+    ``model_type: KeyeVL2``) trained next-token on text, from the
+    netconfig DSL: embed -> nlayer x [ rmsnorm, attention (grouped-query,
+    a head size of its own, an rmsnorm over each head of q and k, rotary,
+    and ``attn_mask = dsa``: an indexer of ``index_heads`` heads of
+    ``index_dim`` on one key head reads the normed stream detached, each
+    query attends to the ``index_topk`` keys it ranks highest, and the
+    indexer learns from the attention it selected for, a term the layer
+    adds to the step's loss) + residual, rmsnorm, sparse SwiGLU experts
+    top_k of n_expert whose router reads the normed stream + residual ]
+    -> rmsnorm -> untied vocab head -> per-position softmax. The widths
+    and the block are ``sdar_moe_netconfig``'s (the same Qwen3-MoE
+    family); the mask and the indexer are this model's. The vision tower
+    is left out: text tokens only, the three position streams of
+    ``mrope_section`` are then one and the rotation is ``rope = 1``'s.
+
+    ``n_held`` / ``expert_offset`` / ``vocab`` / ``nlayer`` cut one chip's
+    share of an expert-parallel deployment as ``smallthinker_netconfig``'s
+    do; ``remat`` names the layer kinds recomputed in the backward pass."""
+    txt = """
+netconfig = start
+layer[0->emb] = embed:emb
+  vocab_size = %d
+  nhidden = %d
+  init_sigma = 1
+""" % (vocab, dim)
+    node = "emb"
+    for i in range(nlayer):
+        attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\n"
+                "attn_mask = dsa\nindex_heads = %d\nindex_dim = %d\n"
+                "index_topk = %d\nqk_norm = 1\n"
+                "rope = 1\nrope_base = %.10g\nremat = %d\n"
+                % (nkvhead, head_dim, index_heads, index_dim, index_topk,
+                   rope_theta, "attention" in remat))
+        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
+               "expert_offset = %d\nexpert_act = swiglu\nremat = %d\n"
+               % (n_expert, top_k, n_held or n_expert, expert_offset,
+                  "moe" in remat))
+        blk, node = _transformer_block(
+            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
+            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
+            norm_keys="eps = %.10g\n" % eps, init_sigma=None,
+            router_on_input=False)
+        txt += "\n" + blk
+    txt += """
+layer[%s->nf] = rmsnorm:norm_f
+  eps = %.10g
+layer[nf->logits] = conv:head
+  kernel_size = 1
+  nchannel = %d
+  no_bias = 1
+layer[+0] = softmax
+  seq = 1
+netconfig = end
+random_type = gaussian
+init_sigma = 0.02
+""" % (node, eps, vocab)
+    return txt
+
+
+KEYE_DSA_ADAMW = (SDAR_MOE_ADAMW
+                  + "idx_gain:wd = 0.0\nidx_bias:wd = 0.0\n")
+
+
+def keye_dsa_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
+                  extra_cfg: str = "", **kw) -> str:
+    """The whole training conf of the sparse-attention recipe: the
+    netconfig, the shapes, the next-token labels, and
+    ``smallthinker_conf``'s AdamW (assumed there as here; the gains of
+    the heads' norms and the indexer's LayerNorm take no decay)."""
+    return (keye_dsa_netconfig(**kw) + KEYE_DSA_ADAMW +
+            "input_shape = 1,1,%d\n" % seq +
+            "batch_size = %d\n" % batch_size +
+            "label_vec[0,%d) = label\n" % seq +
+            "dev = %s\n" % dev + extra_cfg)
+
+
 def transformer_lm_conf(vocab: int = 50, seq: int = 16,
                         batch_size: int = 8, dim: int = 64,
                         nhead: int = 4, nlayer: int = 2,

@@ -361,9 +361,12 @@ class NeuralNet:
         the layer's activations are recomputed during the backward pass
         instead of saved, trading FLOPs for HBM — how deep stacks and long
         contexts fit on a chip. Only side-effect-free layers qualify (no
-        loss accumulation, no state updates, no pairtest diffs); the rng
-        and epoch are passed as arguments so the recompute replays the
-        identical stochastic draw."""
+        state updates, no pairtest diffs, no loss layer); the rng and
+        epoch are passed as arguments so the recompute replays the
+        identical stochastic draw. What such a layer adds to the step's
+        loss of itself (``ctx.losses``: any layer may append a term; an
+        attention layer under a learned selection does) and its readings
+        (``ctx.layer_stats``) leave the checkpointed body as its outputs."""
         def pure(pp, xs, rng, epoch):
             c2 = ApplyContext(train=ctx.train, labels=None,
                               epoch=epoch, mesh=ctx.mesh,
@@ -372,9 +375,12 @@ class NeuralNet:
             c2.rng = rng
             c2.layer_index = getattr(ctx, "layer_index", pidx)
             c2.conn_index = ctx.conn_index
-            return tuple(lay.apply(pp, list(xs), c2)), c2.layer_stats
-        outs, stats = jax.checkpoint(pure)(p, tuple(ins), ctx.rng, ctx.epoch)
+            return (tuple(lay.apply(pp, list(xs), c2)), c2.layer_stats,
+                    tuple(c2.losses))
+        outs, stats, losses = jax.checkpoint(pure)(p, tuple(ins), ctx.rng,
+                                                   ctx.epoch)
         ctx.layer_stats.update(stats)
+        ctx.losses.extend(losses)
         return list(outs)
 
     def _apply_layer_range(self, params, values, ctx, base_rng,

@@ -2162,10 +2162,12 @@ class Trainer:
 
     # ------------------------------------------------------------------
     # every tag a layer's visit_order gives: fullc/conv (wmat, bias),
-    # attention (wo; qnorm, knorm with qk_norm = 1), moe (gate = the
-    # router, up, down), rmsnorm (gain)
+    # attention (wo; qnorm, knorm with qk_norm = 1; the indexer's widx_q,
+    # widx_k, widx_w, idx_gain, idx_bias with attn_mask = dsa), moe (gate =
+    # the router, up, down), rmsnorm (gain)
     _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain",
-                    "qnorm", "knorm")
+                    "qnorm", "knorm", "widx_q", "widx_k", "widx_w",
+                    "idx_gain", "idx_bias")
 
     def set_weight(self, value: np.ndarray, layer_name: str, tag: str) -> None:
         check(tag in self._WEIGHT_TAGS,

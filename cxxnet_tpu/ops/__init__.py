@@ -261,12 +261,29 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
 
 
 def flash_schedule(q, k, causal: bool, window: int = 0,
-                   block_len: int = 0) -> dict:
+                   block_len: int = 0, select: bool = False) -> dict:
     """The static tile schedule ``flash_attention`` walks for these
     operands (ops/flash_attn.schedule): what telemetry's ``flash.*``
-    gauges and counters report."""
+    gauges and counters report. ``select``: under a selection."""
     from . import flash_attn as _fa
-    return _fa.schedule(q, k, causal, window, block_len)
+    return _fa.schedule(q, k, causal, window, block_len, select)
+
+
+def flash_attention_selected(q, k, v, sel):
+    """Blocked attention where query t keeps the keys the int8 array
+    ``sel (b, L, L)`` marks (learned sparse attention; ops/dsa.select
+    makes it): the output and the rows' logsumexp (b, heads, L)."""
+    from . import flash_attn as _fa
+    return _fa.flash_attention_selected(q, k, v, sel, None,
+                                        pallas_interpret())
+
+
+def selected_probs(q, k, lse, sel):
+    """The heads' mean probability of ``flash_attention_selected``'s
+    attention on the kept scores, (b, L, L) float32, a tile at a time
+    (ops/flash_attn.selected_probs)."""
+    from . import flash_attn as _fa
+    return _fa.selected_probs(q, k, lse, sel, None, pallas_interpret())
 
 
 def qk_prep_supported(L: int, dh: int, width: int, dtype) -> bool:

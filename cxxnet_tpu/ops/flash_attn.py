@@ -31,6 +31,15 @@ three kernels, the same ``kind`` / ``mask`` protocol comparing block
 indices, no table, no second kernel. The quadrant of clean queries and
 noised keys is never visited.
 
+A fourth mask, and the first that is data (PR 38): a selection
+(``flash_attention_selected``). Each query keeps the keys an int8 array
+``sel (b, L, L)`` marks (learned sparse attention: an indexer chose them,
+all at or under the diagonal). The schedule is the causal one; every
+visited tile is an edge tile whose mask is the tile of ``sel`` streamed
+beside k (its transpose beside q in the dK/dV kernel) and not index
+arithmetic. The other three masks' kernels trace as before: they get no
+such operand.
+
 Forward: grid (batch*heads, q tiles, kv steps); the (m, l, acc) carry
 lives in VMEM scratch across the kv steps, m and l replicated along the
 lanes; the MXU sees (block_q, d) x (d, sub) and (block_q, sub) x (sub, d)
@@ -116,6 +125,10 @@ class _Geom(NamedTuple):
     # query keeps the clean keys of its own block and earlier ones. No
     # tile straddles the two copies (``_geom`` holds the tiles to that).
     block_len: int = 0
+    # a selection (``select``; causal besides): which scores of a visited
+    # tile are kept is data, an operand of the kernel, so no visited tile
+    # is ``full`` and ``mask`` is not asked
+    select: bool = False
 
     @property
     def masks(self) -> bool:
@@ -262,6 +275,8 @@ class _Geom(NamedTuple):
         keys [k0, k0 + nk): it holds a kept score; it holds no other."""
         needed = k0 < self.kv_len
         full = k0 + nk <= self.kv_len
+        if self.select:
+            full = full & False
         if self.block_len:
             B = self.block_len
             lo, hi, pq, pk = self._quadrant(q0, k0, xp)
@@ -355,8 +370,27 @@ def _visit(g: _Geom, q0, nq, k0, nk, live, body):
         functools.partial(body, True))
 
 
+def _selected(sel_ref, s, c: int):
+    """NEG_INF on the scores ``s`` of a body's pass ``c`` that the
+    selection's int8 block does not mark: its columns ``c`` of ``s``'s
+    width (the block lies as the scores do, the streamed side on the
+    lanes)."""
+    sub = s.shape[1]
+    keep = sel_ref[0, :, c * sub:(c + 1) * sub].astype(jnp.int32) != 0
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _with_sel(kernel, n_in: int):
+    """``kernel`` as pallas_call sees it when the selection's block is its
+    operand ``n_in``, after the plain kernel's own."""
+    def run(*refs, **kw):
+        return kernel(*refs[:n_in], *refs[n_in + 1:], sel_ref=refs[n_in],
+                      **kw)
+    return run
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, g):
+                m_scr, l_scr, acc_scr, *, scale, g, sel_ref=None):
     i, t = pl.program_id(1), pl.program_id(2)
     j, hi = g.kv_tile(i, t)
     sub, d = g.sub, q_ref.shape[-1]
@@ -372,7 +406,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, c * sub:(c + 1) * sub, :]
         v = v_ref[0, c * sub:(c + 1) * sub, :]
         s = _dot(q, k, _NT) * scale                         # (bq, sub) f32
-        if masked:
+        if masked and sel_ref is not None:
+            s = _selected(sel_ref, s, c)
+        elif masked:
             s = g.mask(s, i * g.bq, j * g.bk + c * sub, 1)
         m_prev = m_scr[...]                                 # (bq, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -396,7 +432,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               lse_scr, delta_scr, dq_scr, *, scale, g):
+               lse_scr, delta_scr, dq_scr, *, scale, g, sel_ref=None):
     i, t = pl.program_id(1), pl.program_id(2)
     j, hi = g.kv_tile(i, t)
     sub = g.sub
@@ -412,7 +448,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, c * sub:(c + 1) * sub, :]
         v = v_ref[0, c * sub:(c + 1) * sub, :]
         s = _dot(q, k, _NT) * scale
-        if masked:
+        if masked and sel_ref is not None:
+            s = _selected(sel_ref, s, c)
+        elif masked:
             s = g.mask(s, i * g.bq, j * g.bk + c * sub, 1)
         p = jnp.exp(s - _lanes(lse_scr[...], sub))          # (bq, sub) f32
         dp = _dot(do, v, _NT)
@@ -431,7 +469,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, g, steps):
+                *, scale, g, steps, sel_ref=None):
     j, t = pl.program_id(1), pl.program_id(2)
     # t runs over the group's heads x the q steps
     i, hi = g.q_tile(j, t, steps)
@@ -449,7 +487,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q = q_ref[0, c * sub:(c + 1) * sub, :]
         do = do_ref[0, c * sub:(c + 1) * sub, :]
         st = _dot(k, q, _NT) * scale                        # (bk, sub)
-        if masked:
+        if masked and sel_ref is not None:
+            # the selection's transpose: keys on the sublanes here too
+            st = _selected(sel_ref, st, c)
+        elif masked:
             st = g.mask(st, i * g.bq + c * sub, j * g.bk, 0)
         pt = jnp.exp(st - lse_ref[0, :, c * sub:(c + 1) * sub])
         dv_scr[...] += _dot(pt.astype(do.dtype), do)
@@ -629,7 +670,10 @@ def _kv_row_map(nh: int, nkv: int):
 
 
 def _geom(t: Tiles, L: int, causal: bool, window: int,
-          block_len: int = 0) -> _Geom:
+          block_len: int = 0, select: bool = False) -> _Geom:
+    if select:
+        assert causal and not window and not block_len, \
+            "a selection is causal, with no window and no blocks"
     if block_len:
         assert not causal and not window, \
             "the block-diffusion mask is neither causal nor windowed"
@@ -637,30 +681,48 @@ def _geom(t: Tiles, L: int, causal: bool, window: int,
             "block diffusion: tiles (%d, %d) must divide each copy's %d " \
             "rows" % (t.bq, t.bk, L // 2)
     return _Geom(t.bq, t.bk, t.sub, -(-L // t.bq), -(-L // t.bk),
-                 bool(causal), int(window), L, int(block_len))
+                 bool(causal), int(window), L, int(block_len), bool(select))
 
 
-def _params(kernel, t: Tiles, d, dtype, interpret):
+def _params(kernel, t: Tiles, d, dtype, interpret, select=False):
     if interpret:
         return None
-    return _dims(2 * _vmem_bytes(kernel, t, d, jnp.dtype(dtype).itemsize))
+    # a selection adds its int8 block, double-buffered, and one pass's
+    # tile of it widened to 32 bits for the compare
+    resident = t.bk if kernel == "dkv" else t.bq
+    sel = (2 * t.bq * t.bk + 4 * resident * t.sub) if select else 0
+    return _dims(2 * (_vmem_bytes(kernel, t, d, jnp.dtype(dtype).itemsize)
+                      + sel))
+
+
+def _sel_operand(kernel, n_in: int, sel, block, index_map):
+    """(kernel, extra specs, extra operands) of a call: with a selection
+    its block goes in as operand ``n_in``, behind the plain kernel's."""
+    if sel is None:
+        return kernel, [], []
+    return _with_sel(kernel, n_in), [pl.BlockSpec(block, index_map)], [sel]
 
 
 def _fwd_call(qf, kf, vf, L, to_kv, t: Tiles, causal, scale, window,
-              interpret, block_len=0):
+              interpret, block_len=0, sel=None):
     """Forward kernel on merged, padded (rows, Lp, d) arrays: the output
-    and the lane-dense logsumexp (rows, 1, Lpq)."""
+    and the lane-dense logsumexp (rows, 1, Lpq). ``sel`` (b, Lpq, Lpk)
+    int8: the selection, where the mask is one."""
     bh, Lpq, d = qf.shape
-    g = _geom(t, L, causal, window, block_len)
+    g = _geom(t, L, causal, window, block_len, sel is not None)
     kv_spec = pl.BlockSpec(
         (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
+    heads = bh // sel.shape[0] if sel is not None else 1
+    kernel, sel_specs, sel_args = _sel_operand(
+        _fwd_kernel, 3, sel, (1, t.bq, t.bk),
+        lambda r, i, s: (r // heads, i, g.kv_block(i, s)))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, g=g),
+        functools.partial(kernel, scale=scale, g=g),
         grid=(bh, g.n_q, g.kv_steps()),
         in_specs=[
             pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0)),
             kv_spec, kv_spec,
-        ],
+        ] + sel_specs,
         out_specs=[
             pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0)),
             pl.BlockSpec((1, 1, t.bq), lambda r, i, s: (r, 0, i)),
@@ -674,23 +736,29 @@ def _fwd_call(qf, kf, vf, L, to_kv, t: Tiles, causal, scale, window,
             pltpu.VMEM((t.bq, _LANES), jnp.float32),
             pltpu.VMEM((t.bq, d), jnp.float32),
         ],
-        compiler_params=_params("fwd", t, d, qf.dtype, interpret),
+        compiler_params=_params("fwd", t, d, qf.dtype, interpret,
+                                sel is not None),
         interpret=interpret,
-    )(qf, kf, vf)
+    )(qf, kf, vf, *sel_args)
 
 
 def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
-             scale, window, interpret, block_len=0):
+             scale, window, interpret, block_len=0, sel=None):
     bh, Lpq, d = qf.shape
-    g = _geom(t, L, causal, window, block_len)
+    g = _geom(t, L, causal, window, block_len, sel is not None)
     q_spec = pl.BlockSpec((1, t.bq, d), lambda r, i, s: (r, i, 0))
     kv_spec = pl.BlockSpec(
         (1, t.bk, d), lambda r, i, s: (to_kv(r), g.kv_block(i, s), 0))
     row_spec = pl.BlockSpec((1, 1, t.bq), lambda r, i, s: (r, 0, i))
+    heads = bh // sel.shape[0] if sel is not None else 1
+    kernel, sel_specs, sel_args = _sel_operand(
+        _dq_kernel, 6, sel, (1, t.bq, t.bk),
+        lambda r, i, s: (r // heads, i, g.kv_block(i, s)))
     return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, g=g),
+        functools.partial(kernel, scale=scale, g=g),
         grid=(bh, g.n_q, g.kv_steps()),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        + sel_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, Lpq, d), qf.dtype),
         scratch_shapes=[
@@ -698,19 +766,25 @@ def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
             pltpu.VMEM((t.bq, _LANES), jnp.float32),
             pltpu.VMEM((t.bq, d), jnp.float32),
         ],
-        compiler_params=_params("dq", t, d, qf.dtype, interpret),
+        compiler_params=_params("dq", t, d, qf.dtype, interpret,
+                                sel is not None),
         interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, lse, delta, *sel_args)
 
 
 def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
-              scale, window, interpret, block_len=0):
+              scale, window, interpret, block_len=0, sel_t=None):
     """dK and dV at key-value resolution: grid rows are the kv heads, the
     streamed dimension runs over the group's query heads x the q tiles
-    that reach the resident kv tile."""
+    that reach the resident kv tile. ``sel_t`` (b, Lpk, Lpq) int8: the
+    selection transposed, keys first, as the scores are here."""
     bkv, Lpk, d = kf.shape
-    g = _geom(t, L, causal, window, block_len)
+    g = _geom(t, L, causal, window, block_len, sel_t is not None)
     steps = g.q_steps()
+    kvheads = bkv // sel_t.shape[0] if sel_t is not None else 1
+    kernel, sel_specs, sel_args = _sel_operand(
+        _dkv_kernel, 6, sel_t, (1, t.bk, t.bq),
+        lambda r, j, s: (r // kvheads, j, g.q_block(j, s % steps)))
 
     # step s: query head s // steps of kv head r's group, its q tile
     q_spec = pl.BlockSpec((1, t.bq, d), lambda r, j, s: (
@@ -719,9 +793,10 @@ def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
         r * grp + s // steps, 0, g.q_block(j, s % steps)))
     kv_spec = pl.BlockSpec((1, t.bk, d), lambda r, j, s: (r, j, 0))
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, g=g, steps=steps),
+        functools.partial(kernel, scale=scale, g=g, steps=steps),
         grid=(bkv, g.n_k, grp * steps),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        + sel_specs,
         out_specs=[kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, Lpk, d), kf.dtype),
@@ -731,9 +806,10 @@ def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
             pltpu.VMEM((t.bk, d), jnp.float32),
             pltpu.VMEM((t.bk, d), jnp.float32),
         ],
-        compiler_params=_params("dkv", t, d, qf.dtype, interpret),
+        compiler_params=_params("dkv", t, d, qf.dtype, interpret,
+                                sel_t is not None),
         interpret=interpret,
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, lse, delta, *sel_args)
 
 
 def _shape_tiles(q, k, window, tiles, block_len=0):
@@ -746,37 +822,41 @@ def _shape_tiles(q, k, window, tiles, block_len=0):
 
 
 def schedule(q, k, causal: bool, window: int = 0,
-             block_len: int = 0) -> dict:
+             block_len: int = 0, select: bool = False) -> dict:
     """What ``flash_attention`` will do with these (b, heads, L, d)
     operands, from their shapes alone: the forward kernel's q tile and the
     kv columns one pass of its body takes (``block_q``, ``block_k``), and
     how many such score tiles of one head's grid hold no masked score,
     straddle an edge, or are never visited (``full``, ``edge``,
-    ``skipped``)."""
+    ``skipped``). Under a selection (``select``) every visited tile is an
+    edge tile: its mask is data."""
     fwd = _shape_tiles(q, k, window, None, block_len)[0]
     full, edge, skipped = tile_counts(
-        _geom(fwd, q.shape[2], causal, window, block_len))
+        _geom(fwd, q.shape[2], causal, window, block_len, select))
     return {"block_q": fwd.bq, "block_k": fwd.sub, "full": full,
             "edge": edge, "skipped": skipped}
 
 
 def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None,
-               block_len=0):
+               block_len=0, sel=None):
     b, h, L, d = q.shape
     if scale is None:
         scale = d ** -0.5
     assert window == 0 or causal, "window attention requires causal"
     t = _shape_tiles(q, k, window, tiles, block_len)[0]
-    qf = _pad_seq(_merge_bh(q), _padded_len(L, t.bq))
-    kf, vf = (_pad_seq(_merge_bh(x), _padded_len(L, t.bk)) for x in (k, v))
+    Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
+    qf = _pad_seq(_merge_bh(q), Lq)
+    kf, vf = (_pad_seq(_merge_bh(x), Lk) for x in (k, v))
     out, lse = _fwd_call(qf, kf, vf, L, _kv_row_map(h, k.shape[1]), t,
-                         causal, scale, window, interpret, block_len)
+                         causal, scale, window, interpret, block_len,
+                         None if sel is None else _pad_sel(sel, Lq, Lk))
     out = out[:, :L].reshape(b, h, L, d)
     # the residual is trimmed to L: the backward pads to its own tiles
     return out, (q, k, v, out, lse[:, :, :L])
 
 
-def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g):
+def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g,
+               sel=None):
     q, k, v, out, lse = res
     b, h, L, d = q.shape
     nkv = k.shape[1]
@@ -791,20 +871,131 @@ def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g):
                     * _merge_bh(out).astype(jnp.float32),
                     axis=-1)[:, None, :]
 
-    def padded(t):
+    def padded(t, transposed=False):
+        """A kernel's operands at its own tiles, and the selection's
+        block for it (``transposed``: keys first, as dK / dV's scores)."""
         Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
         qf, dof = (_pad_seq(_merge_bh(x), Lq) for x in (q, g))
         kf, vf = (_pad_seq(_merge_bh(x), Lk) for x in (k, v))
+        mask = None if sel is None else _pad_sel(sel, Lq, Lk)
+        if mask is not None and transposed:
+            mask = mask.transpose(0, 2, 1)
         return (qf, kf, vf, dof, _pad_seq(lse, Lq, 2),
-                _pad_seq(delta, Lq, 2))
+                _pad_seq(delta, Lq, 2)), mask
 
-    dq = _dq_call(*padded(t_dq), L, _kv_row_map(h, nkv), t_dq, causal,
-                  scale, window, interpret, block_len)
-    dk, dv = _dkv_call(*padded(t_dkv), L, h // nkv, t_dkv, causal, scale,
-                       window, interpret, block_len)
+    args, mask = padded(t_dq)
+    dq = _dq_call(*args, L, _kv_row_map(h, nkv), t_dq, causal, scale,
+                  window, interpret, block_len, mask)
+    args, mask = padded(t_dkv, True)
+    dk, dv = _dkv_call(*args, L, h // nkv, t_dkv, causal, scale, window,
+                       interpret, block_len, mask)
     return (dq[:, :L].reshape(b, h, L, d),
             dk[:, :L].reshape(b, nkv, L, d),
             dv[:, :L].reshape(b, nkv, L, d))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the fourth mask: a selection that is data
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def flash_attention_selected(q, k, v, sel, scale: Optional[float] = None,
+                             interpret: bool = False, tiles=None):
+    """``flash_attention`` where query t keeps the keys ``sel[b, t, :]``
+    marks: ``sel`` (b, L, L) int8, nonzero = kept, every kept key at or
+    under the diagonal and at least one a query. Returns the output and
+    the rows' logsumexp (b, h, L) float32 over the kept scores (what
+    ``selected_probs`` reads); the logsumexp passes no gradient back."""
+    out, res = _selected_fwd(q, k, v, sel, scale, interpret, tiles)
+    return out, res[4].reshape(q.shape[:3])
+
+
+def _pad_sel(sel, Lq: int, Lk: int):
+    return jnp.pad(sel, ((0, 0), (0, Lq - sel.shape[1]),
+                         (0, Lk - sel.shape[2])))
+
+
+def _selected_fwd(q, k, v, sel, scale, interpret, tiles):
+    """``_flash_fwd`` under the selection; the residual carries it."""
+    out, res = _flash_fwd(q, k, v, True, scale, interpret, 0, tiles, 0, sel)
+    return out, res + (sel,)
+
+
+def _selected_vjp_fwd(q, k, v, sel, scale, interpret, tiles):
+    out, res = _selected_fwd(q, k, v, sel, scale, interpret, tiles)
+    return (out, res[4].reshape(q.shape[:3])), res
+
+
+def _selected_vjp_bwd(scale, interpret, tiles, res, g):
+    # the logsumexp's cotangent, g[1], is not read
+    sel = res[5]
+    return _flash_bwd(True, scale, interpret, 0, tiles, 0, res[:5], g[0],
+                      sel) + (np.zeros(sel.shape, jax.dtypes.float0),)
+
+
+flash_attention_selected.defvjp(_selected_vjp_fwd, _selected_vjp_bwd)
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, sel_ref, p_ref, *, scale, nh, grp):
+    """One (bq, bk) tile of the heads' mean probability: for every query
+    head exp(q k^T scale - lse) on the kept scores, summed, over nh. A
+    tile wholly above the diagonal is nought."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    bq, bk = p_ref.shape[1], p_ref.shape[2]
+
+    @pl.when(j * bk > i * bq + (bq - 1))
+    def _():
+        p_ref[0] = jnp.zeros((bq, bk), jnp.float32)
+
+    @pl.when(j * bk <= i * bq + (bq - 1))
+    def _():
+        keep = sel_ref[0].astype(jnp.int32) != 0
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h in range(nh):
+            s = _dot(q_ref[0, h], k_ref[0, h // grp], _NT) * scale
+            lse = _lanes(_to_col(lse_ref[0, h:h + 1, :]), bk)
+            acc = acc + jnp.exp(s - lse)
+        p_ref[0] = jnp.where(keep, acc * (1.0 / nh), 0.0)
+
+
+def selected_probs(q, k, lse, sel, scale: Optional[float] = None,
+                   interpret: bool = False, block: Tuple[int, int] = None):
+    """The target an indexer learns from: ``(1 / nh) sum_h softmax_h`` of
+    the attention ``flash_attention_selected`` computed, on the kept
+    scores and nought elsewhere, (b, L, L) float32, made a (bq, bk) tile
+    at a time with every head's scores of the tile summed in VMEM: no
+    (heads, L, L) array. ``lse`` (b, nh, L) is that call's logsumexp. No
+    gradient is defined: the caller detaches its operands."""
+    b, nh, L, d = q.shape
+    nkv = k.shape[1]
+    if scale is None:
+        scale = d ** -0.5
+    bq, bk = block or (min(512, _fit(L, 512)), min(512, _fit(L, 512)))
+    Lq, Lk = _padded_len(L, bq), _padded_len(L, bk)
+    qf, kf = _pad_seq(q, Lq, 2), _pad_seq(k, Lk, 2)
+    lsef = _pad_seq(lse.astype(jnp.float32), Lq, 2)
+    itemsize = jnp.dtype(q.dtype).itemsize
+    vmem = (2 * (itemsize * max(d, _LANES) * (nh * bq + nkv * bk)
+                 + 4 * max(nh, 8) * bq + bq * bk + 4 * bq * bk)
+            + 4 * 4 * bq * bk)
+    p = pl.pallas_call(
+        functools.partial(_probs_kernel, scale=scale, nh=nh,
+                          grp=nh // nkv),
+        grid=(b, Lq // bq, Lk // bk),
+        in_specs=[
+            pl.BlockSpec((1, nh, bq, d), lambda r, i, j: (r, 0, i, 0)),
+            pl.BlockSpec((1, nkv, bk, d), lambda r, i, j: (r, 0, j, 0)),
+            pl.BlockSpec((1, nh, bq), lambda r, i, j: (r, 0, i)),
+            pl.BlockSpec((1, bq, bk), lambda r, i, j: (r, i, j)),
+        ],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda r, i, j: (r, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, Lq, Lk), jnp.float32),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=2 * vmem),
+        interpret=interpret,
+    )(qf, kf, lsef, _pad_sel(sel, Lq, Lk))
+    return p[:, :L, :L]

@@ -202,6 +202,39 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
+# the learned-sparse-attention cell's attention (`keye-ep8-train-8k`, PR 38):
+# 32 query heads on 4 key-value heads of 128 over 8,192 tokens, a selection
+# of 2,048 keys a query streamed as an int8 block beside k (its transpose
+# beside q in dK / dV) and widened to 32 bits for the compare, and the
+# target pass whose tile sums all 32 heads' probabilities in VMEM. Mosaic
+# has to take the int8 blocks and their tiling, the four kernels have to
+# stand alone but for the mask's transpose and pads, and no (heads, L, L)
+# array may be made beside them
+def test_flash_kernels_compile_under_a_selection(one_chip):
+    from cxxnet_tpu.ops import flash_attn
+    L = 8192
+    q = jax.ShapeDtypeStruct((1, 32, L, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, L, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    sel = jax.ShapeDtypeStruct((1, L, L), jnp.int8, sharding=one_chip)
+
+    def both(q_, k_, v_, sel_, do_):
+        (out, lse), vjp = jax.vjp(
+            lambda a, b, c: flash_attn.flash_attention_selected(
+                a, b, c, sel_), q_, k_, v_)
+        p = flash_attn.selected_probs(q_, k_, lse, sel_)
+        return (out, p) + vjp((do_, jnp.zeros_like(lse)))
+    compiled = jax.jit(both).lower(q, kv, kv, sel, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 4
+    assert [o.shape for o in compiled.out_info] == [
+        q.shape, (1, L, L), q.shape, kv.shape, kv.shape]
+    assert not re.search(r"\[(1,)?32,8192,8192\]", text), text
+    # beside the kernels: the selection's transpose for dK / dV (67 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
+
+
 # the pass between the qkv dot and the flash kernels (PR 37) at the two
 # language-model cells' shapes, and one float32 case with heads of two lane
 # tiles: a row tile at the whole width (5,120 / 4,608 lanes) has to fit the

@@ -15,6 +15,9 @@ Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      python tools/check_tpu_kernels.py qkprep   (the fused pass between the
      qkv dot and the attention core at both language-model cells' shapes
      beside the plain lines, ~1 min)
+     python tools/check_tpu_kernels.py dsa   (learned sparse attention at
+     `keye-ep8-train-8k`'s shape: index scores, selection, the flash
+     kernels under the selection and the target pass, each alone, ~2 min)
 """
 
 import functools
@@ -40,6 +43,9 @@ def main():
         return
     if sys.argv[1:] == ["qkprep"]:
         _check_qk_prep_at_the_cells_shapes(np.random.RandomState(0))
+        return
+    if sys.argv[1:] == ["dsa"]:
+        _check_learned_sparse_attention(np.random.RandomState(0))
         return
     from cxxnet_tpu import ops
     from cxxnet_tpu.ops import pallas_kernels
@@ -441,6 +447,109 @@ def _check_flash_under_the_block_diffusion_mask(rs):
           "and x3.5)" % (sched, t_f, flops / t_f / 1e9, t_q,
                          flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9,
                          t_fb, 3.5 * flops / t_fb / 1e9))
+
+
+def _check_learned_sparse_attention(rs):
+    """The parts of an attention layer under ``attn_mask = dsa`` at
+    `keye-ep8-train-8k`'s shape (8,192 rows, 32 query heads on 4 of 128,
+    an indexer of 16 heads of 64, 2,048 keys a query, bf16), each alone:
+    the index scores forward and backward, the selection, the three flash
+    kernels under the selection, the target pass. First, on one key-value
+    head with a group of two, the selection against ``lax.top_k``'s set
+    and the kernels against the plain lines; then each part's ms and its
+    rate (TFLOP/s by the kept scores for the kernels, by the causal
+    triangle for the index scores; GB/s of the scores read for the
+    selection)."""
+    from cxxnet_tpu.ops import dsa, flash_attn
+    L, d, J, di, topk = 8192, 128, 16, 64, 2048
+
+    def operands(nh, nkv):
+        q, do = (jnp.asarray(rs.randn(1, nh, L, d), jnp.bfloat16)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.bfloat16)
+                for _ in range(2))
+        return q, k, v, do
+    qi = jnp.asarray(rs.randn(1, J, L, di), jnp.bfloat16)
+    ki = jnp.asarray(rs.randn(1, L, di), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(1, L, J) / 32.0, jnp.float32)
+    scores = jax.jit(dsa.index_scores)(qi, ki, w)
+    sel = jax.jit(lambda s_: dsa.select(s_, topk))(scores)
+
+    # the selection is top_k's set, a row's count min(t + 1, topk)
+    @jax.jit
+    def by_top_k(s_):
+        rows = jnp.arange(L)[:, None]
+        masked = jnp.where(jnp.arange(L)[None, :] <= rows, s_[0], -jnp.inf)
+        _, idx = jax.lax.top_k(masked, topk)
+        took = jnp.arange(topk)[None, :] <= rows
+        hit = jnp.zeros((L, L), jnp.int32).at[
+            jnp.broadcast_to(rows, idx.shape), idx].add(
+                took.astype(jnp.int32))
+        return hit
+    assert np.array_equal(np.asarray(by_top_k(scores)) > 0,
+                          np.asarray(sel[0]) != 0)
+    assert int(jnp.sum(sel.astype(jnp.int32))) == dsa.kept_scores(L, topk)
+
+    def flash(q_, k_, v_):
+        return flash_attn.flash_attention_selected(q_, k_, v_, sel)[0]
+
+    def plain(q_, k_, v_):
+        probs, _ = dsa.selected_probs_plain(q_, k_, sel, d ** -0.5)
+        return jnp.einsum("bngqk,bnkd->bngqd", probs.astype(v_.dtype), v_,
+                          preferred_element_type=jnp.float32).reshape(
+                              q_.shape).astype(q_.dtype)
+    q, k, v, do = operands(2, 1)
+    got = jax.jit(functools.partial(_both, flash))(q, k, v, do)
+    want = jax.jit(functools.partial(_both, plain))(q, k, v, do)
+    gap = 0.0
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=1e-1, atol=1e-1)
+        gap = max(gap, float(np.max(np.abs(a - b)) / np.max(np.abs(b))))
+    lse = jax.jit(lambda *a: flash_attn.flash_attention_selected(
+        *a, sel)[1])(q, k, v)
+    p = jax.jit(lambda q_, k_, l_: flash_attn.selected_probs(
+        q_, k_, l_, sel))(q, k, lse)
+    p_want = jax.jit(lambda q_, k_: dsa.selected_probs_plain(
+        q_, k_, sel, d ** -0.5)[1])(q, k)
+    gap_p = float(jnp.max(jnp.abs(p - p_want)))
+    assert gap_p < 2e-2 and abs(float(jnp.mean(jnp.sum(p, -1))) - 1) < 1e-2
+    del got, want, p, p_want
+
+    nh, nkv = 32, 4
+    q, k, v, do = operands(nh, nkv)
+    kept = dsa.kept_scores(L, topk)
+    flops = 4.0 * nh * d * kept                  # forward, kept scores
+    tri = L * (L + 1) / 2
+    t_f = _ms(jax.jit(flash), q, k, v)
+    t_q = _ms(jax.jit(lambda *a: _both(flash, *a)[1]), q, k, v, do) - t_f
+    t_kv = _ms(jax.jit(lambda *a: _both(flash, *a)[2:]), q, k, v, do) - t_f
+    lse = jax.jit(lambda *a: flash_attn.flash_attention_selected(
+        *a, sel)[1])(q, k, v)
+    t_p = _ms(jax.jit(lambda q_, k_, l_: flash_attn.selected_probs(
+        q_, k_, l_, sel)), q, k, lse)
+    t_i = _ms(jax.jit(dsa.index_scores), qi, ki, w)
+    g = jnp.asarray(rs.randn(1, L, L), jnp.float32)
+    t_ib = _ms(jax.jit(lambda *a: jax.vjp(dsa.index_scores, *a[:3])[1](
+        a[3])), qi, ki, w, g) - t_i
+    t_s = _ms(jax.jit(lambda s_: dsa.select(s_, topk)), scores)
+    t_l = _ms(jax.jit(jax.value_and_grad(lambda s_, p_: jnp.sum(
+        dsa.index_loss(s_, sel, p_)))), scores, jnp.abs(g) / L)
+    sched = flash_attn.schedule(q, k, True, 0, 0, True)
+    i_flops = 2.0 * J * di * tri
+    print("learned sparse attention at the cell's shape: OK (worst gap to "
+          "the plain lines %.2e of the largest value, target %.2e); tiles "
+          "%s, %d of %d causal scores kept; index scores %.2f ms = %.1f "
+          "TFLOP/s (by the causal triangle), their backward %.2f ms; "
+          "selection %.2f ms = %.1f GB/s of scores read; flash forward "
+          "%.2f ms = %.1f TFLOP/s, dQ %.2f ms = %.1f TFLOP/s, dK/dV %.2f ms "
+          "= %.1f TFLOP/s (by the kept scores' FLOPs, x1, x1, x1.5); "
+          "target pass %.2f ms = %.1f TFLOP/s (2 head_dim FLOPs a kept "
+          "score a head); index loss and its gradient %.2f ms"
+          % (gap, gap_p, sched, kept, int(tri), t_i, i_flops / t_i / 1e9,
+             t_ib, t_s, 4.0 * L * L / t_s / 1e6, t_f, flops / t_f / 1e9,
+             t_q, flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9, t_p,
+             flops / 2 / t_p / 1e9, t_l))
 
 
 def _check_qk_prep_at_the_cells_shapes(rs):
