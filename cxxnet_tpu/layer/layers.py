@@ -1515,11 +1515,13 @@ class AttentionLayer(Layer):
 
     def _dsa_core(self, seq, q, k, v, params, ctx):
         """The core under a learned selection: index scores, the exact
-        top-k, the attention on the selected keys (in the flash kernels
-        where they take the shape, the selection streamed as an int8
-        mask), and in training the indexer's loss, which joins the step's
-        (``ctx.losses``) from here. Sub-scopes index / select / core /
-        index_loss."""
+        top-k (one kernel that sorts nothing where it takes the shape,
+        ``ops.dsa_select``, else ``lax.top_k``: ``attn.select.fused`` /
+        ``attn.select.xla`` once per traced layer), the attention on the
+        selected keys (in the flash kernels where they take the shape,
+        the selection streamed as an int8 mask), and in training the
+        indexer's loss, which joins the step's (``ctx.losses``) from
+        here. Sub-scopes index / select / core / index_loss."""
         from ..ops import dsa
         from ..utils import telemetry
         b, nh, L, dh = q.shape
@@ -1539,10 +1541,17 @@ class AttentionLayer(Layer):
         with sub_scope("index"):
             qi, ki, w = self._index_operands(seq, params)
             scores = dsa.index_scores(qi, ki, w)            # (b, L, L) f32
+        # pallas_call has no partitioning rule: no mesh, or a manual one
+        kernels = ops.use_pallas() and (mesh is None or ctx.manual_tp)
         with sub_scope("select"):
-            sel = dsa.select(jax.lax.stop_gradient(scores), self.index_topk)
-        flash = (ops.use_pallas() and ops.flash_supported(L, dh)
-                 and (mesh is None or ctx.manual_tp))
+            picked = jax.lax.stop_gradient(scores)
+            if kernels and ops.dsa_select_supported(L, self.index_topk):
+                telemetry.count_path("attn.select.fused")
+                sel = ops.dsa_select(picked, self.index_topk)
+            else:
+                telemetry.count_path("attn.select.xla")
+                sel = dsa.select(picked, self.index_topk)
+        flash = kernels and ops.flash_supported(L, dh)
         with sub_scope("core"):
             if flash:
                 self._count_flash(q, k, True, select=True)

@@ -305,6 +305,22 @@ def qk_prep(qkv, qnorm, knorm, cos, sin, nh: int, nkv: int, dh: int):
                        pallas_interpret())
 
 
+def dsa_select_supported(L: int, topk: int) -> bool:
+    """True when the selection's kernel (ops/dsa_select_pallas.py) takes
+    rows of ``L`` scores and ``topk`` keys a query."""
+    from . import dsa_select_pallas as _ds
+    return _ds.supports(L, topk)
+
+
+def dsa_select(scores, topk: int):
+    """Learned sparse attention's exact selection, ``ops/dsa.select``'s
+    int8 (b, L, L) array of the float32 ``scores``, from one kernel that
+    sorts nothing: a row's ``topk``-th value found by counting
+    (ops/dsa_select_pallas.py)."""
+    from . import dsa_select_pallas as _ds
+    return _ds.select(scores, topk, pallas_interpret())
+
+
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """Tiles of the grouped product's kernel: rows in 512s (the callers pad
     to it), the contraction and the output columns whole up to 1024 and

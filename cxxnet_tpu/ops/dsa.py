@@ -5,8 +5,10 @@ For queries t and keys s of one sequence, J index heads of ``di`` features
 on ONE key head:
 
     I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])          float32
-    S_t     = the min(t + 1, k) keys s <= t of largest I[t, s]
-              (equal scores: the lower s first, ``jax.lax.top_k``'s order)
+    S_t     = the min(t + 1, k) keys s <= t of largest I[t, s], in the
+              order of ``order_key``: the floats' own, with -0.0 read as
+              +0.0, a NaN beyond the infinity of its sign, and equal
+              scores to the lower s first
     L_idx   = (1 / L) sum_t KL(p[t, .] || softmax_{S_t}(I[t, .]))
 
 with p the main attention's probabilities on S_t, summed over its heads and
@@ -14,10 +16,13 @@ detached (``ops.flash_attn.selected_probs``).
 
 ``index_scores`` keeps no (J, L, L) array, forward or backward: both walk
 the queries a block at a time and the backward makes a block's products
-again from qI, kI and w. ``select`` is exact: the set ``lax.top_k`` gives,
-written as an int8 (b, L, L) array, which is what the flash kernels stream
-(``flash_attention_selected``). ``index_loss`` keeps one (b, L, L) float32
-residual, its own gradient.
+again from qI, kI and w. ``select`` is exact: the set ``lax.top_k`` gives
+on rows whose ``topk``-th value is no signed zero, written as an int8
+(b, L, L) array, which is what the flash kernels stream
+(``flash_attention_selected``). On a TPU the layer takes the same array
+from one kernel that sorts nothing (``ops/dsa_select_pallas.py``); the
+lines here are the path elsewhere and that kernel's golden model.
+``index_loss`` keeps one (b, L, L) float32 residual, its own gradient.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 QUERY_BLOCK = 512           # queries a pass of the index scores takes
+_INT_MIN = jnp.iinfo(jnp.int32).min
 
 
 def _blocks(L: int) -> int:
@@ -94,13 +100,26 @@ def _scores_bwd(res, g):
 index_scores.defvjp(_scores_fwd, _scores_bwd)
 
 
+def order_key(scores):
+    """Float32 scores as int32 keys of the same order, the selection's:
+    the bits of a float that is not negative order as they stand, those of
+    a negative one in reverse. ``-0.0`` becomes ``+0.0`` first, by a select
+    on the bits (``top_k`` on the floats puts it below; an ``x + 0.0`` a
+    simplifier may drop). A total order: the NaNs stand beyond the
+    infinity of their sign."""
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    bits = jnp.where(bits == _INT_MIN, 0, bits)
+    return bits ^ ((bits >> 31) & 0x7fffffff)
+
+
 def select(scores, topk: int):
     """``scores`` (b, L, L) float32 -> int8 (b, L, L): 1 on the
-    min(t + 1, topk) keys s <= t of largest score in row t, equal scores
-    to the lower s. Exact: ``lax.top_k`` finds the row's ``topk``-th value
-    and the last index it took at that value, and a key is kept where its
-    score is greater, or equal and its index no greater. Rows t < topk
-    keep every key at or before them and are not sorted."""
+    min(t + 1, topk) keys s <= t of largest score in row t by
+    ``order_key``, equal scores to the lower s. Exact, and that many on
+    every row whatever its values: ``lax.top_k`` over the keys finds the
+    row's ``topk``-th and the last index it took at that value, and a key
+    is kept where it is greater, or equal and its index no greater. Rows
+    t < topk keep every key at or before them and are not sorted."""
     L = scores.shape[-1]
     k = min(topk, L)
     t = jnp.arange(L, dtype=jnp.int32)[:, None]
@@ -108,12 +127,13 @@ def select(scores, topk: int):
     causal = s <= t
     if k == L:
         return jnp.broadcast_to(causal, scores.shape).astype(jnp.int8)
-    masked = jnp.where(causal, scores, -jnp.inf)
-    vals, idx = lax.top_k(masked[:, k:], k)             # the rows that choose
+    # the rows that choose
+    key = jnp.where(causal[k:], order_key(scores[:, k:]), _INT_MIN)
+    vals, idx = lax.top_k(key, k)
     thr = vals[..., -1:]
     last = jnp.max(jnp.where(vals == thr, idx.astype(jnp.int32), -1),
                    axis=-1, keepdims=True)
-    chosen = (masked[:, k:] > thr) | ((masked[:, k:] == thr) & (s <= last))
+    chosen = (key > thr) | ((key == thr) & (s <= last))
     keep = jnp.concatenate(
         [jnp.broadcast_to(causal[:k], scores[:, :k].shape),
          causal[k:] & chosen], axis=1)

@@ -1,7 +1,8 @@
 """Learned sparse attention in training (``attn_mask = dsa``), the program's
 parts on the CPU at a small size: the index scores and their blocked
-backward, the exact selection, the fourth mask of the flash kernels
-(``ops/flash_attn.py``, in the interpreter) and the target pass,
+backward, the exact selection (the plain lines and the kernel that sorts nothing,
+``ops/dsa_select_pallas.py``, in the interpreter), the fourth mask of the
+flash kernels (``ops/flash_attn.py``, in the interpreter) and the target pass,
 ``AttentionLayer`` with its indexer, the loss term a layer that is no loss
 layer adds to the step's, and the whole block through ``Trainer.update`` on
 the forced flash path. The plain side is the benchmark's reference
@@ -121,6 +122,133 @@ def test_a_planted_tie_astride_the_last_place_falls_to_the_lower_index():
     np.testing.assert_array_equal(ref, sel)
     # every other row is one long tie at -5: the lowest indices past key 0
     assert sorted(np.flatnonzero(sel[20])) == [1, 2, 3, 4]
+
+
+# ------------------------------------------- the selection without a sort
+def _sets_by_a_stable_sort(scores, topk):
+    """Row t's kept keys by numpy alone: the causal keys in a stable sort
+    by the order the docstring states (the float's bits, a negative's
+    reversed, -0.0 as +0.0), the first min(t + 1, topk) of them."""
+    bits = np.asarray(scores).view(np.int32).astype(np.int64)
+    bits[bits == -(1 << 31)] = 0
+    key = np.where(bits < 0, bits ^ 0x7fffffff, bits)
+    keep = np.zeros(scores.shape, bool)
+    for b in range(scores.shape[0]):
+        for t in range(scores.shape[1]):
+            order = np.argsort(-key[b, t, :t + 1], kind="stable")
+            keep[b, t, order[:topk]] = True
+    return keep
+
+
+def _row_counts(sel, topk):
+    rows = sel.shape[-1]
+    return np.broadcast_to(np.minimum(np.arange(rows) + 1, topk),
+                           sel.shape[:2])
+
+
+@pytest.mark.parametrize("rows,topk", [(256, 40), (384, 128)],
+                         ids=["a_block_astride_topk", "whole_blocks"])
+def test_the_fused_selection_is_the_plain_one_and_top_ks_set(rows, topk):
+    """The kernel in the interpreter on two sequences of random scores:
+    row blocks of 128, so at ``topk`` 40 the first block holds rows that
+    keep everything and rows that choose; at 384 rows the first block
+    searches nothing, and the chunks of 128 columns past a block's last
+    row are not walked."""
+    assert ops.dsa_select_supported(rows, topk)
+    scores = _plain_scores(*_index_operands(np.random.RandomState(11), 2, 2,
+                                            rows, 8))
+    sel = np.asarray(ops.dsa_select(scores, topk))
+    assert sel.dtype == np.int8 and sel.shape == scores.shape
+    np.testing.assert_array_equal(sel, np.asarray(dsa.select(scores, topk)))
+    np.testing.assert_array_equal(sel != 0, _sets_by_top_k(scores, topk))
+    np.testing.assert_array_equal(sel.sum(-1), _row_counts(sel, topk))
+    assert sel.sum() == 2 * dsa.kept_scores(rows, topk)
+
+
+def _hard_rows(case, rows, topk):
+    """(1, rows, rows) float32 scores whose rows are hard for a search on
+    the bits, and the keys row 100 must keep where the case plants them."""
+    rs = np.random.RandomState(12)
+    x = rs.randn(1, rows, rows).astype(np.float32)
+    want = None
+    if case == "tie_astride_the_last_place":
+        x[:] = -5.0
+        x[0, :, 0] = -6.0
+        x[0, 100, 40:40 + topk - 3] = 3.0
+        x[0, 100, [12, 21, 35, 90, 95]] = 1.0   # the tie: three places left
+        want = [12, 21, 35] + list(range(40, 40 + topk - 3))
+    elif case == "a_row_of_one_value":
+        x[:] = 0.25
+        want = list(range(topk))
+    elif case == "signed_zeros_astride_the_last_place":
+        x = np.where(rs.rand(1, rows, rows) < 0.5, 0.0, -0.0).astype(
+            np.float32)
+        x[0, :, 1] = -0.0
+        x[0, :, 2] = 0.0
+        x[0, 100, [50, 70]] = 1.0, 2.0
+        x[0, :, 5] = -1.0
+        want = [s for s in range(rows) if s != 5][:topk - 2] + [50, 70]
+    elif case == "negative_scores_only":
+        x = -np.abs(x) - 1e-3
+    elif case == "infinities":
+        x[rs.rand(1, rows, rows) < 0.85] = -np.inf
+        x[rs.rand(1, rows, rows) < 0.05] = np.inf
+    elif case == "denormals":
+        x = (rs.randint(-40, 40, (1, rows, rows)).astype(np.int64)
+             & 0x807fffff).astype(np.uint32).view(np.float32)
+        assert np.all(np.abs(x) < np.finfo(np.float32).tiny)
+    elif case == "nans_of_both_signs":
+        x[rs.rand(1, rows, rows) < 0.1] = np.nan
+        x[rs.rand(1, rows, rows) < 0.1] = -np.nan
+        x[0, 90:, :40] = np.nan                 # more NaNs than places
+    else:
+        raise AssertionError(case)
+    return np.ascontiguousarray(x, np.float32), want
+
+
+@pytest.mark.parametrize("case", [
+    "tie_astride_the_last_place", "a_row_of_one_value",
+    "signed_zeros_astride_the_last_place", "negative_scores_only",
+    "infinities", "denormals", "nans_of_both_signs"])
+def test_rows_that_are_hard_for_a_search_on_the_bits(case):
+    """Kernel and plain lines give one array, a stable sort by the stated
+    order gives the same sets, and a row keeps min(t + 1, topk) keys
+    whatever it holds: equal scores to the lower index, -0.0 and +0.0 one
+    value, the infinities and the NaNs in their places in the order."""
+    rows, topk = 128, 20
+    x, want = _hard_rows(case, rows, topk)
+    fused = np.asarray(ops.dsa_select(jnp.asarray(x), topk))
+    plain = np.asarray(dsa.select(jnp.asarray(x), topk))
+    np.testing.assert_array_equal(fused, plain)
+    np.testing.assert_array_equal(fused != 0, _sets_by_a_stable_sort(x, topk))
+    np.testing.assert_array_equal(fused.sum(-1), _row_counts(fused, topk))
+    assert not np.triu(fused[0], 1).any()
+    if want is not None:
+        assert sorted(np.flatnonzero(fused[0, 100])) == sorted(want)
+
+
+def test_the_plain_selection_counts_signed_zeros_as_one_value():
+    """What ``lax.top_k`` on the floats did not: -0.0 below +0.0 there,
+    equal in the compare behind it, and a row whose last place fell on a
+    zero kept more than ``topk`` keys."""
+    rs = np.random.RandomState(13)
+    x = np.round(rs.randn(1, 96, 96) * 2) / 2
+    x = np.where(rs.rand(1, 96, 96) < 0.5, x, -x).astype(np.float32)
+    assert (np.signbit(x) & (x == 0)).any() and (~np.signbit(x) & (x == 0)
+                                                 ).any()
+    sel = np.asarray(dsa.select(jnp.asarray(x), 32))
+    np.testing.assert_array_equal(sel.sum(-1), _row_counts(sel, 32))
+    np.testing.assert_array_equal(sel != 0, _sets_by_a_stable_sort(x, 32))
+
+
+@pytest.mark.parametrize("rows,topk,why", [
+    (64, 8, "rows that are no whole lane tiles"),
+    (200, 30, "rows that are no whole lane tiles"),
+    (256, 256, "a selection that keeps every key"),
+    (256, 4096, "a selection that keeps every key"),
+    (128 * 1024, 2048, "a row block beyond the VMEM budget")])
+def test_the_selection_kernel_refuses_what_it_does_not_tile(rows, topk, why):
+    assert not ops.dsa_select_supported(rows, topk), why
 
 
 def test_the_index_loss_and_its_gradient_agree_with_the_plain_lines():
@@ -414,14 +542,36 @@ def test_the_flash_path_runs_the_selection_and_counts_it_once_a_layer():
     assert gauges["flash.block_q"] == sched["block_q"]
     assert sched["full"] == 0 and sched["edge"] > 0
     assert paths == {"attn.flash": 1, "attn.dsa": 1, "loss.index": 1,
+                     "attn.select.fused": 1,
                      "attn.prep.xla": 1, "flash.tiles.edge": sched["edge"],
                      **({"flash.tiles.skipped": sched["skipped"]}
                         if sched["skipped"] else {})}
     y_dense, kl_dense, gauges, paths = delta(False)
     assert paths == {"attn.dense": 1, "attn.dsa": 1, "loss.index": 1,
-                     "attn.prep.xla": 1}
+                     "attn.select.xla": 1, "attn.prep.xla": 1}
     np.testing.assert_allclose(y_flash, y_dense, rtol=2e-4, atol=2e-5)
     assert kl_flash == pytest.approx(kl_dense, rel=1e-5)
+
+
+def test_a_layer_whose_rows_the_selection_kernel_does_not_tile_counts_xla():
+    """64 rows with the kernels forced on: ``ops.dsa_select_supported``
+    says no, the layer takes the plain lines and says so, and gives what
+    it gives with the kernels off."""
+    lay = _attention()
+    w = _attention_weights(lay)
+    x = np.random.RandomState(6).randn(2, D, 1, L).astype(np.float32)
+    assert not ops.dsa_select_supported(L, lay.index_topk)
+    before = telemetry.paths()
+    ops.set_use_pallas(True)
+    try:
+        y, _ = _apply(lay, w, x)
+    finally:
+        ops.set_use_pallas(None)
+    paths = {k: n - before.get(k, 0) for k, n in telemetry.paths().items()
+             if n != before.get(k, 0)}
+    assert paths.get("attn.select.xla") == 1
+    assert "attn.select.fused" not in paths
+    np.testing.assert_allclose(y, _apply(lay, w, x)[0], rtol=2e-4, atol=2e-5)
 
 
 # ------------------------------------------- a share of the deployment's layer
@@ -539,8 +689,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     health, names, paths = run(True)
     assert {k: n for k, n in paths.items()
             if not k.startswith("flash.tiles.")} == dict({
-        "attn.flash": n, "attn.dsa": n, "loss.index": n, "moe.sparse": n,
-        "moe.bounded": n}, **prep)
+        "attn.flash": n, "attn.dsa": n, "attn.select.fused": n,
+        "loss.index": n, "moe.sparse": n, "moe.bounded": n}, **prep)
     assert "flash.tiles.full" not in paths
     said = dict(zip(names, health[4:]))
     for i in range(n):
@@ -550,6 +700,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     dense, _, paths = run(False)
     assert paths.get("attn.dense") == n and "attn.flash" not in paths
     assert paths.get("attn.dsa") == n and paths.get("loss.index") == n
+    assert paths.get("attn.select.xla") == n
+    assert "attn.select.fused" not in paths
     assert health[0] == pytest.approx(dense[0], rel=1e-5)
 
 

@@ -271,3 +271,26 @@ def test_qk_prep_kernels_compile_at_the_cells_shapes(one_chip, L, nh, nkv,
     # the only residual is qkv itself: nothing of the rows' size is made
     # beside the kernels' own results
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# the selection of the learned-sparse-attention cell without a sort
+# (PR 39): 8,192 rows of 8,192 float32 scores, 2,048 keys a query. Mosaic
+# has to take a 128-row block at its whole length beside its int32 scratch,
+# the int32 counts' lane reduction, the rolled loops whose trip count comes
+# from the grid position, and the int8 store; the kernel has to stand
+# alone: HBM holds the scores and the selection and nothing else, and
+# nothing is sorted
+def test_the_selection_kernel_compiles_at_the_cells_shape(one_chip):
+    from cxxnet_tpu.ops import dsa_select_pallas
+    L, topk = 8192, 2048
+    assert dsa_select_pallas.supports(L, topk)
+    scores = jax.ShapeDtypeStruct((1, L, L), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda s: dsa_select_pallas.select(s, topk)).lower(
+        scores).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 1
+    assert not re.search(r"= \S+ (sort|copy|transpose)\(", text), text
+    assert "TopK" not in text
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert (out.shape, out.dtype) == ((1, L, L), jnp.int8)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

@@ -16,8 +16,9 @@ Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      qkv dot and the attention core at both language-model cells' shapes
      beside the plain lines, ~1 min)
      python tools/check_tpu_kernels.py dsa   (learned sparse attention at
-     `keye-ep8-train-8k`'s shape: index scores, selection, the flash
-     kernels under the selection and the target pass, each alone, ~2 min)
+     `keye-ep8-train-8k`'s shape: index scores, the selection by top_k and
+     by the kernel that sorts nothing, the flash kernels under the
+     selection and the target pass, each alone, ~2 min)
 """
 
 import functools
@@ -453,13 +454,16 @@ def _check_learned_sparse_attention(rs):
     """The parts of an attention layer under ``attn_mask = dsa`` at
     `keye-ep8-train-8k`'s shape (8,192 rows, 32 query heads on 4 of 128,
     an indexer of 16 heads of 64, 2,048 keys a query, bf16), each alone:
-    the index scores forward and backward, the selection, the three flash
-    kernels under the selection, the target pass. First, on one key-value
-    head with a group of two, the selection against ``lax.top_k``'s set
-    and the kernels against the plain lines; then each part's ms and its
+    the index scores forward and backward, the selection (by ``lax.top_k``
+    and by the kernel that sorts nothing), the three flash kernels under
+    the selection, the target pass. First, on one key-value head with a
+    group of two, the selection against ``lax.top_k``'s set, the fused
+    selection against the plain one array for array, and the flash
+    kernels against the plain lines; then each part's ms and its
     rate (TFLOP/s by the kept scores for the kernels, by the causal
     triangle for the index scores; GB/s of the scores read for the
     selection)."""
+    from cxxnet_tpu import ops
     from cxxnet_tpu.ops import dsa, flash_attn
     L, d, J, di, topk = 8192, 128, 16, 64, 2048
 
@@ -473,7 +477,8 @@ def _check_learned_sparse_attention(rs):
     ki = jnp.asarray(rs.randn(1, L, di), jnp.bfloat16)
     w = jnp.asarray(rs.randn(1, L, J) / 32.0, jnp.float32)
     scores = jax.jit(dsa.index_scores)(qi, ki, w)
-    sel = jax.jit(lambda s_: dsa.select(s_, topk))(scores)
+    plain_select = jax.jit(functools.partial(dsa.select, topk=topk))
+    sel = plain_select(scores)
 
     # the selection is top_k's set, a row's count min(t + 1, topk)
     @jax.jit
@@ -489,6 +494,19 @@ def _check_learned_sparse_attention(rs):
     assert np.array_equal(np.asarray(by_top_k(scores)) > 0,
                           np.asarray(sel[0]) != 0)
     assert int(jnp.sum(sel.astype(jnp.int32))) == dsa.kept_scores(L, topk)
+
+    # the kernel that sorts nothing gives the plain lines' array: on these
+    # scores, and on scores of few values and both zeros (rounded to
+    # quarters, signs at random), where rows tie astride the last place
+    assert ops.dsa_select_supported(L, topk)
+    fused = functools.partial(ops.dsa_select, topk=topk)
+    assert np.array_equal(np.asarray(fused(scores)), np.asarray(sel))
+    few = jnp.round(scores * 4) / 4 * jnp.asarray(
+        rs.choice([-1.0, 1.0], (1, L, L)), jnp.float32)
+    tied = fused(few)
+    assert np.array_equal(np.asarray(tied), np.asarray(plain_select(few)))
+    assert int(jnp.sum(tied.astype(jnp.int32))) == dsa.kept_scores(L, topk)
+    del few, tied
 
     def flash(q_, k_, v_):
         return flash_attn.flash_attention_selected(q_, k_, v_, sel)[0]
@@ -532,7 +550,8 @@ def _check_learned_sparse_attention(rs):
     g = jnp.asarray(rs.randn(1, L, L), jnp.float32)
     t_ib = _ms(jax.jit(lambda *a: jax.vjp(dsa.index_scores, *a[:3])[1](
         a[3])), qi, ki, w, g) - t_i
-    t_s = _ms(jax.jit(lambda s_: dsa.select(s_, topk)), scores)
+    t_s = _ms(plain_select, scores)
+    t_sf = _ms(fused, scores)
     t_l = _ms(jax.jit(jax.value_and_grad(lambda s_, p_: jnp.sum(
         dsa.index_loss(s_, sel, p_)))), scores, jnp.abs(g) / L)
     sched = flash_attn.schedule(q, k, True, 0, 0, True)
@@ -541,13 +560,16 @@ def _check_learned_sparse_attention(rs):
           "the plain lines %.2e of the largest value, target %.2e); tiles "
           "%s, %d of %d causal scores kept; index scores %.2f ms = %.1f "
           "TFLOP/s (by the causal triangle), their backward %.2f ms; "
-          "selection %.2f ms = %.1f GB/s of scores read; flash forward "
+          "selection by lax.top_k %.2f ms = %.1f GB/s of scores read, by "
+          "the kernel that sorts nothing (array-equal) %.2f ms = %.1f GB/s; "
+          "flash forward "
           "%.2f ms = %.1f TFLOP/s, dQ %.2f ms = %.1f TFLOP/s, dK/dV %.2f ms "
           "= %.1f TFLOP/s (by the kept scores' FLOPs, x1, x1, x1.5); "
           "target pass %.2f ms = %.1f TFLOP/s (2 head_dim FLOPs a kept "
           "score a head); index loss and its gradient %.2f ms"
           % (gap, gap_p, sched, kept, int(tri), t_i, i_flops / t_i / 1e9,
-             t_ib, t_s, 4.0 * L * L / t_s / 1e6, t_f, flops / t_f / 1e9,
+             t_ib, t_s, 4.0 * L * L / t_s / 1e6, t_sf,
+             4.0 * L * L / t_sf / 1e6, t_f, flops / t_f / 1e9,
              t_q, flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9, t_p,
              flops / 2 / t_p / 1e9, t_l))
 
