@@ -177,7 +177,6 @@ class ThreadBufferIterator(IIterator):
                         if not self.base.next():
                             break
                         item = self.base.value().deep_copy()
-                    telemetry.count("io.prefetch_batches")
                     # watchdog liveness: beaten per produced batch AND per
                     # queue-full poll tick, so only a producer genuinely
                     # wedged inside base.next() (hung read, dead decoder)

@@ -54,7 +54,6 @@ def _decode_rgb_chw(buf: bytes) -> np.ndarray:
     # entirely off-GIL (src/core/jpeg_decode.cc) — this is what lets the
     # imgbinx decode thread pool scale
     with telemetry.span("io.decode"):
-        telemetry.count("io.decode_bytes", len(buf))
         from ..utils import native
         out = native.decode_jpeg_chw(buf)
         if out is not None:

@@ -1440,6 +1440,9 @@ class LearnTask:
                 print("%-18s %7d %10.3f %9.2f %9.2f %9.2f" %
                       (name, a["count"], a["total_s"], a["p50_ms"],
                        a["p99_ms"], a["max_ms"]))
+        if summary.get("step_time_ms") is not None:
+            print("step time: %.2fms (mean of train.period)"
+                  % summary["step_time_ms"])
         comp = summary.get("compiles", {})
         if comp.get("count"):
             print("compiles: %d (%.2fs) %s" %

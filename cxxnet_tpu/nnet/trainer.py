@@ -148,6 +148,11 @@ class Trainer:
         # — a recompile of a PREVIOUSLY seen key is a rebuild (donation
         # path / packing change cleared the cache), not a new signature
         self._jit_seen_keys = set()
+        # entry time of the previous update() where the next call's entry
+        # is one step's period later (the histogram train.period); None where
+        # something else came between: a round's start, an eval, a
+        # checkpoint, a cleared (init_model, load_model) or newly built step
+        self._last_update_t0 = None
 
     # ------------------------------------------------------------------
     # configuration (reference SetParam, nnet_impl-inl.hpp:31-69)
@@ -650,6 +655,7 @@ class Trainer:
         Checkpoints are always CANONICAL (per-layer tensors): the PP
         stage-packing is a runtime placement, so a pipeline_parallel=4 run
         resumes fine as single-device or any other parallelism config."""
+        self._last_update_t0 = None
         self.net_cfg.save_net(w)
         w.write_raw(np.int64(self.epoch_counter).tobytes())
         blob = self.net.save_model_blob(self.canonical_params())
@@ -872,9 +878,7 @@ class Trainer:
     # ------------------------------------------------------------------
     def start_round(self, round_: int) -> None:
         self.round = round_
-        # progress gauge for the live /metrics scrape (no-op when
-        # telemetry is off; one event per round when on)
-        telemetry.gauge("train.round", int(round_))
+        self._last_update_t0 = None
         if self.test_on_server:
             self.check_replica_consistency()
 
@@ -1156,6 +1160,7 @@ class Trainer:
         if self._jit_cache:
             telemetry.count("jit.cache_clear")
         self._jit_cache.clear()
+        self._last_update_t0 = None
 
     def _watched_jit(self, key, name: str, build):
         """Build-or-fetch a jitted program in ``_jit_cache``, wrapped in
@@ -1182,14 +1187,19 @@ class Trainer:
                   with_health: bool = False):
         k = ("train", do_update, accumulate, with_accum, with_stats,
              with_health)
-        return self._watched_jit(
-            k, "jit.train_step",
-            lambda: self._make_train_step(do_update, accumulate,
-                                          with_accum, with_stats,
-                                          with_health))
+
+        def build():
+            # the call that builds a step lasts seconds: no period's start
+            self._last_update_t0 = None
+            return self._make_train_step(do_update, accumulate, with_accum,
+                                         with_stats, with_health)
+        return self._watched_jit(k, "jit.train_step", build)
 
     def _shard_batch(self, arr):
-        telemetry.count("io.h2d_bytes", int(getattr(arr, "nbytes", 0) or 0))
+        if not isinstance(arr, jax.Array):
+            # bytes that cross the host link: a resident batch moves none
+            telemetry.count("io.h2d_bytes",
+                            int(getattr(arr, "nbytes", 0) or 0))
         if self.mesh is None:
             return jnp.asarray(arr)
         sh = parallel.batch_sharding(self.mesh)
@@ -1230,12 +1240,22 @@ class Trainer:
 
     def update(self, batch) -> None:
         """One mini-batch (reference Update, nnet_impl-inl.hpp:141-185)."""
-        # the whole call: under a profiler session the three train.* spans
-        # land on the host plane of the trace (telemetry.span), where each
-        # idle gap of the device is put down to one of them
+        # the whole call: under a profiler session the train.* spans land
+        # on the host plane of the trace (telemetry.span), where each idle
+        # gap of the device is put down to one of them. The four kept ones
+        # (the call, and h2d + args + dispatch inside it; what is left is
+        # the call's self time) also stand in telemetry.kept() in a run
+        # that enabled nothing: the benchmark's host-side metrics
         # cxxlint: disable=timed-dispatch — host time by design, like
-        # train.step inside it: device time is read from the trace
-        with telemetry.span("train.update"):
+        # train.dispatch inside it: device time is read from the trace
+        with telemetry.span("train.update", keep=True) as call:
+            # back-to-back calls enter one step's period apart ON AVERAGE
+            # (the runtime holds the host to a fixed number of steps in
+            # flight, so entries come a few ms apart and then one a step):
+            # the histogram's mean is the step time, no percentile of it is
+            last, self._last_update_t0 = self._last_update_t0, call.t0
+            if last is not None:
+                telemetry.hist("train.period", call.t0 - last)
             need_update = (self.sample_counter + 1) % self.update_period == 0
             accumulate = self.sample_counter % self.update_period != 0
             with_accum = self.update_period > 1
@@ -1243,7 +1263,7 @@ class Trainer:
             with_health = self.health_monitor != 0
             step = self._get_step(need_update, accumulate, with_accum,
                                   with_stats, with_health)
-            with telemetry.span("train.h2d"):
+            with telemetry.span("train.h2d", keep=True):
                 data = self._shard_batch(batch.data)
                 label = self._shard_batch(batch.label)
             if with_accum and self.grad_accum is None:
@@ -1253,24 +1273,28 @@ class Trainer:
             if with_stats and self._metric_accum is None:
                 self._metric_accum = jnp.zeros(
                     (len(self.train_metric), 2), jnp.float32)
-            # the span covers DISPATCH (plus any trace+compile, which the
-            # jit watch separates out) — execution is async; the input-wait
-            # fraction the train loop reports is what exposes device stalls
-            # cxxlint: disable=timed-dispatch — dispatch-only by design (the
-            # comment above): device time shows up as the round's io-wait
-            # complement, compiles via the jit watch
+            # train.step (the name health, the perf ledger, the report and
+            # /metrics know) = train.args, every small dispatch that only
+            # makes an argument, + train.dispatch, the jitted call alone
+            # from its call to its return (plus any trace+compile, which
+            # the jit watch separates out): execution is async, and a call
+            # blocks for a whole step once the runtime's limit of steps in
+            # flight is reached. The step's time is train.period's mean;
+            # device time is read from the trace
+            # cxxlint: disable=timed-dispatch — host time by design
             with telemetry.span("train.step"):
-                (self.params, self.opt_state, self.grad_accum,
-                 self._metric_accum, self.last_health) = \
-                    step(self.params, self.opt_state, self.grad_accum,
-                         self._metric_accum, data, label,
-                         jnp.asarray(self.epoch_counter, jnp.int32),
-                         self._next_rng())
+                with telemetry.span("train.args", keep=True):
+                    epoch = jnp.asarray(self.epoch_counter, jnp.int32)
+                    rng = self._next_rng()
+                # cxxlint: disable=timed-dispatch — dispatch-only by design
+                with telemetry.span("train.dispatch", keep=True):
+                    (self.params, self.opt_state, self.grad_accum,
+                     self._metric_accum, self.last_health) = \
+                        step(self.params, self.opt_state, self.grad_accum,
+                             self._metric_accum, data, label, epoch, rng)
             if telemetry.enabled():
                 telemetry.count("train.images",
                                 batch.batch_size - batch.num_batch_padd)
-                if need_update and with_accum:
-                    telemetry.count("train.accum_flush")
             self.sample_counter += 1
             if self.sample_counter >= self.update_period:
                 self.sample_counter = 0
@@ -2117,6 +2141,7 @@ class Trainer:
     def evaluate(self, iter_eval, data_name: str) -> str:
         """Run metrics over an eval iterator; padding rows dropped
         (reference Evaluate, nnet_impl-inl.hpp:224-243)."""
+        self._last_update_t0 = None
         ret = ""
         if self.eval_train != 0 and len(self.train_metric):
             if self._metric_accum is not None:

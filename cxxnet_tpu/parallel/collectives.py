@@ -11,12 +11,8 @@ scheme, src/updater/updater_impl-inl.hpp:84, done by the compiler instead).
 These wrappers exist so higher layers (trainer, ring attention, pipeline)
 speak one vocabulary; each is a direct jax.lax collective.
 
-Telemetry: each wrapper bumps a ``collective.<op>`` counter when its
-Python body runs — under jit that is TRACE time, so the counters report
-how many collective ops each compiled program CONTAINS (per compile, not
-per executed step). Runtime cost of the collectives lives in the XLA
-profile (profile_dir); these counters are the cheap structural view that
-says which programs carry ring traffic at all.
+What the collectives cost at run time is in the profiler's trace
+(``profile_dir``, ``tools/trace_layers.py``).
 """
 
 from __future__ import annotations
@@ -26,21 +22,17 @@ from typing import Optional, Sequence, Union
 import jax
 from jax import lax
 
-from ..utils import telemetry
-
 AxisName = Union[str, Sequence[str]]
 
 
 def psum(x, axis_name: AxisName):
     """All-reduce sum over a mesh axis (gradient sync; replaces PS Push+Pull
     of summed gradients, src/updater/async_updater-inl.hpp:101-131)."""
-    telemetry.count("collective.psum")
     return lax.psum(x, axis_name)
 
 
 def pmean(x, axis_name: AxisName):
     """All-reduce mean (metric aggregation across data shards)."""
-    telemetry.count("collective.pmean")
     return lax.pmean(x, axis_name)
 
 
@@ -48,26 +40,22 @@ def all_gather(x, axis_name: AxisName, *, axis: int = 0, tiled: bool = True):
     """Gather shards along ``axis`` from every device on the mesh axis
     (replaces the `fullc_gather` activation allgather,
     src/updater/async_updater-inl.hpp:67-92)."""
-    telemetry.count("collective.all_gather")
     return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
 def reduce_scatter(x, axis_name: AxisName, *, axis: int = 0):
     """Reduce-scatter: sum across the axis, each device keeps one shard
     (the ZeRO / update_on_server gradient path)."""
-    telemetry.count("collective.reduce_scatter")
     return lax.psum_scatter(x, axis_name, scatter_dimension=axis, tiled=True)
 
 
 def ppermute(x, axis_name: AxisName, perm):
     """Point-to-point permutation over ICI neighbors (ring steps)."""
-    telemetry.count("collective.ppermute")
     return lax.ppermute(x, axis_name, perm)
 
 
 def ring_shift(x, axis_name: str, shift: int = 1):
     """Rotate shards around the ring: device i's value goes to i+shift."""
-    telemetry.count("collective.ring_shift")
     n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
@@ -75,7 +63,6 @@ def ring_shift(x, axis_name: str, shift: int = 1):
 
 def all_to_all(x, axis_name: AxisName, *, split_axis: int, concat_axis: int):
     """All-to-all redistribution (Ulysses-style sequence<->head reshard)."""
-    telemetry.count("collective.all_to_all")
     return lax.all_to_all(x, axis_name, split_axis=split_axis,
                           concat_axis=concat_axis, tiled=True)
 

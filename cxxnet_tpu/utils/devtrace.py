@@ -10,7 +10,8 @@ stat in the profiler's file reads
     jit(step)/health/...                             health_monitor's sums
 
 and ``telemetry.span`` puts the program's host spans (``train.update`` >
-``train.h2d``, ``train.step``) on the same clock. This module reads the
+``train.h2d``, ``train.step`` > ``train.args``, ``train.dispatch``) on the
+same clock. This module reads the
 file and reduces it, for the stretch between the first and the last run of
 the step module on the busiest device: device self time by phase and by
 layer x phase, the host spans' self times, and the longest idle gaps, each

@@ -34,12 +34,16 @@ arXiv:1802.04799). This module is that runtime spine:
 
 * **live gauges** — ``snapshot()`` joins each card against the
   program's *measured* latency histogram (``MEASURED_SERIES``: the
-  telemetry series the trainer already feeds — ``train.step``,
+  telemetry series the trainer already feeds — ``train.period``,
   ``decode.prefill``, ``decode.decode``, ...):
-  ``mfu_pct`` = flops / (measured p50 x peak), ``roofline_eff_pct`` =
-  predicted / measured p50 (under 100 = slower than the hardware
-  allows; over 100 usually means the measured series times DISPATCH,
-  not execution — flagged in doc/performance.md). Aggregates:
+  ``mfu_pct`` = flops / (measured time x peak), ``roofline_eff_pct`` =
+  predicted / measured time (under 100 = slower than the hardware
+  allows). The measured time is the series' p50, but for a series in
+  ``MEAN_SERIES`` its mean: the train step's is ``train.period``, the
+  distance between the entries of back-to-back ``Trainer.update`` calls,
+  whose mean is the step as the device paces it and whose p50 is not
+  (the dispatch returns in a few ms until the runtime's limit of steps
+  in flight holds it for a whole one). Aggregates:
   ``hbm_peak_bytes`` (max per-device peak over cards — the number the
   paged-KV allocator will be sized against) and ``hbm_headroom_bytes``
   vs the spec capacity. statusd renders all of it: ``/programz`` (the
@@ -78,7 +82,8 @@ from . import telemetry
 
 __all__ = [
     "DeviceSpec", "DEVICE_SPECS", "TARGET_DEVICE_KIND", "device_spec",
-    "current_device_spec", "MEASURED_SERIES", "Ledger", "ProfilerCapture",
+    "current_device_spec", "MEASURED_SERIES", "MEAN_SERIES", "measured_ms",
+    "Ledger", "ProfilerCapture",
     "ledger", "enable", "disable", "enabled", "drain", "reset",
     "decode_bound_tokens_per_s", "shapes_signature", "predicted_seconds",
     "footprint_bytes", "selftest",
@@ -157,16 +162,26 @@ def current_device_spec() -> Optional[DeviceSpec]:
 
 # program name -> the telemetry histogram that MEASURES its executions
 # (the join key between a card's predicted time and reality). These are
-# the series the trainer already feeds; doc/observability.md notes
-# which ones time dispatch rather than execution.
+# the series the trainer already feeds.
 MEASURED_SERIES = {
-    "jit.train_step": "train.step",
+    "jit.train_step": "train.period",
     "jit.eval_fwd": "eval.forward",
     "jit.predict": "predict",
     "jit.decode_prefill": "decode.prefill",
     "jit.decode_step": "decode.decode",
     "jit.beam_decode": "decode.beam",
 }
+
+# the series whose MEAN is the program's time, where the others' p50 is:
+# single periods are bimodal under the runtime's limit of steps in flight
+# (several entries a few ms apart, then one a step), their mean is the step
+MEAN_SERIES = frozenset({"train.period"})
+
+
+def measured_ms(series: str, stats: dict):
+    """The time a card divides by, from its series' ``Histogram.stats()``."""
+    return stats["mean_ms" if series in MEAN_SERIES else "p50_ms"]
+
 
 _DTYPE_SHORT = {
     "float32": "f32", "float64": "f64", "float16": "f16",
@@ -653,7 +668,8 @@ class Ledger:
     def snapshot(self) -> dict:
         """Everything the surfaces render: the spec, the cards joined
         against their measured latency histograms (mfu_pct /
-        roofline_eff_pct / measured p50+p99), and the HBM account
+        roofline_eff_pct over ``measured_ms``; measured p50+p99), and the
+        HBM account
         (peak = max card footprint; headroom vs spec capacity)."""
         spec = self.spec or current_device_spec()
         cards = self.cards()
@@ -684,15 +700,16 @@ class Ledger:
             c["measured_n"] = st["count"] if st else 0
             c["measured_p50_ms"] = st["p50_ms"] if st else None
             c["measured_p99_ms"] = st["p99_ms"] if st else None
+            c["measured_ms"] = measured_ms(series, st) if st else None
             c["mfu_pct"] = c["roofline_eff_pct"] = None
-            if st and st["p50_ms"]:
-                p50_s = st["p50_ms"] / 1e3
+            if c["measured_ms"]:
+                took_s = c["measured_ms"] / 1e3
                 if c["flops"] is not None and spec is not None:
                     c["mfu_pct"] = round(
-                        100.0 * c["flops"] / (p50_s * spec.peak_flops), 2)
+                        100.0 * c["flops"] / (took_s * spec.peak_flops), 2)
                 if c["predicted_s"] is not None:
                     c["roofline_eff_pct"] = round(
-                        100.0 * c["predicted_s"] / p50_s, 2)
+                        100.0 * c["predicted_s"] / took_s, 2)
             if c["peak_bytes"] is not None:
                 peak = max(peak or 0, c["peak_bytes"])
         decode_kv = None
@@ -988,9 +1005,9 @@ def _selftest_body(verbose: bool = False) -> int:
     evs = [e for e in reg.events() if e.get("ev") == "program_card"]
     assert evs and evs[-1]["flops"] == 2.0e12
 
-    # measured join: feed the train.step histogram at ~40ms -> MFU 50%
+    # measured join: feed the train.period histogram at 40ms -> MFU 50%
     for _ in range(10):
-        reg.hist("train.step", 0.040)
+        reg.hist("train.period", 0.040)
     snap = lg.snapshot()
     c = [c for c in snap["cards"] if c["name"] == "jit.train_step"][0]
     assert c["measured_n"] == 10

@@ -14,8 +14,9 @@ Endpoints:
 * ``/metrics`` — Prometheus text format (scrapable): every telemetry
   counter as a ``_total`` series, gauges, and the fixed-bucket latency
   histograms (``telemetry.HIST_BUCKETS``) as ``_seconds_bucket{le=...}``
-  series — step time, io wait, h2d, per-request serve latency. All series
-  carry a ``process`` label so a multihost scrape attributes shards.
+  series — step period, dispatch, io wait, h2d, per-request serve
+  latency. All series carry a ``process`` label so a multihost scrape
+  attributes shards.
   ``?json=1`` returns the RAW registry snapshot plus the SLO window —
   exact bucket counts, the fleet router's federation feed
   (utils/routerd.py ``federate_now``: the merge stays bucket-count
@@ -34,7 +35,9 @@ Endpoints:
   stops routing without restarting a process that is shutting down
   cleanly. The k8s liveness-probe contract.
 * ``/statusz`` — the human page: run config, round/batch progress,
-  step-time p50/p90/p99, recompile count and causes, checkpoint age,
+  the step time (the MEAN of ``train.period``; the dispatch's
+  p50/p90/p99 stand under their own name, ``train.dispatch``, among the
+  latency histograms), recompile count and causes, checkpoint age,
   device-memory gauges, counters, health detail.
 * ``/trace`` — a Chrome-trace JSON snapshot of the recent-event ring
   buffer (load in chrome://tracing or ui.perfetto.dev) — the last ~4096
@@ -482,9 +485,10 @@ def prometheus_metrics(snapshot: dict, progress: Optional[dict] = None,
                  "roofline-predicted execution time"),
                 ("cxxnet_program_compile_seconds", "compile_s", None),
                 ("cxxnet_program_mfu_pct", "mfu_pct",
-                 "achieved FLOPs vs chip peak at the measured p50"),
+                 "achieved FLOPs vs chip peak at the measured time "
+                 "(the series' p50; the train step's mean period)"),
                 ("cxxnet_program_roofline_eff_pct", "roofline_eff_pct",
-                 "predicted/measured p50 — low means slower than the "
+                 "predicted/measured time — low means slower than the "
                  "hardware allows"))
         for mname, field, help_ in fams:
             rows = [c for c in cards if _num(c.get(field))]
@@ -966,7 +970,7 @@ def programz_html(snap: dict) -> str:
                     % _mib(dkv) if dkv is not None else ""))
     parts.append("</pre><h2>programs</h2><pre>")
     cols = ("program", "shapes", "cause", "n", "compile_s", "GFLOPs",
-            "peak MiB", "pred ms", "p50 ms", "p99 ms", "MFU%", "eff%")
+            "peak MiB", "pred ms", "meas ms", "p99 ms", "MFU%", "eff%")
     fmt = "%-18s %-28s %-18s %3s %9s %9s %9s %8s %8s %8s %6s %6s"
     parts.append(fmt % cols)
 
@@ -989,7 +993,7 @@ def programz_html(snap: dict) -> str:
             esc(str(c.get("cause", "?"))), c.get("compiles", 0),
             num(c.get("compile_s")), num(c.get("flops"), 1e-9),
             _mib(c.get("peak_bytes")), num(c.get("predicted_s"), 1e3),
-            num(c.get("measured_p50_ms")) + ("*" if shared else ""),
+            num(c.get("measured_ms")) + ("*" if shared else ""),
             num(c.get("measured_p99_ms")),
             num(c.get("mfu_pct"), form="%.1f"),
             num(c.get("roofline_eff_pct"), form="%.1f")))
@@ -997,10 +1001,12 @@ def programz_html(snap: dict) -> str:
         parts.append("(no programs carded yet — nothing compiled since "
                      "the ledger was enabled)")
     parts.append("</pre><p>pred = max(flops/peak, bytes/bw) roofline; "
-                 "MFU% and eff% join the measured latency histogram "
+                 "meas = the measured latency histogram's p50, for the "
+                 "train step the mean of train.period; MFU% and eff% "
+                 "divide by it "
                  "(doc/performance.md \"Live program ledger\"); "
                  "* = several signatures of this program share one "
-                 "measured series, so p50/MFU/eff aggregate them; "
+                 "measured series, so meas/MFU/eff aggregate them; "
                  "<a href='/programz?json=1'>json</a> "
                  "<a href='/statusz'>statusz</a></p></body></html>")
     return "\n".join(parts)
@@ -2119,6 +2125,10 @@ class StatusServer:
             "%Y-%m-%d %H:%M:%S", time.localtime(self.t0_wall))))
         table("run", info)
         prog = sorted(self.progress.items())
+        if s.get("step_time_ms") is not None:
+            prog.append(("step time", "%s (mean of %d train.period)"
+                         % (_ms(s["step_time_ms"]),
+                            s["hists"]["train.period"]["count"])))
         table("progress", prog)
 
         channels = health_mod.channel_status()

@@ -30,6 +30,14 @@ arxiv 1802.04799). This module is that measurement substrate:
   / ``.edge`` / ``.skipped``): like the phase
   account it is the process's and outlives ``reset()``, so that a run that
   enabled nothing can still say what it timed.
+* **kept spans** — ``telemetry.span("train.h2d", keep=True)`` is a span that
+  also leaves ``(t0, dur)``, on ``time.perf_counter()``'s clock, in a small
+  always-on ring of its name's last ``KEPT_CAP`` occurrences (``kept()``):
+  the third account, for the few hot-path spans a benchmark metric reads
+  in runs that enabled nothing (``Trainer.update`` and its parts). Like the
+  other two it is the process's and outlives ``reset()``. Disabled and with
+  no profiler a kept span costs two clock reads and one append; a span
+  that is not kept stays the shared no-op.
 * **counters / gauges** — ``telemetry.count("train.images", n)`` accumulates
   monotonically; ``telemetry.gauge("device.bytes_in_use", v)`` records the
   latest value of a level. ``sample_device_memory()`` snapshots the
@@ -53,8 +61,9 @@ Sinks:
 * a Chrome-trace / Perfetto JSON export built from the span tree
   (``write_chrome_trace`` or ``chrome_trace``), loadable in
   chrome://tracing or https://ui.perfetto.dev;
-* an aggregate ``summary()`` dict (per-span totals, counters, compiles,
-  step-time percentiles) — printed by learn_task at end of run.
+* an aggregate ``summary()`` dict (per-span totals and percentiles,
+  counters, compiles, the step time as the mean ``train.period``) —
+  printed by learn_task at end of run.
 
 Disabled (the default) the module is near-zero overhead: ``span()`` returns
 a shared no-op context manager (no allocation), counters are one
@@ -105,6 +114,7 @@ from . import lockrank
 
 __all__ = [
     "enable", "disable", "enabled", "reset", "span", "phase", "phases",
+    "kept", "KEPT_CAP",
     "count", "count_path", "paths", "gauge",
     "hist", "event", "record_compile", "jit_watch",
     "sample_device_memory",
@@ -129,6 +139,14 @@ _PENDING_CAP = 65536
 # recent-event ring kept even WITH a log sink — the /trace endpoint's
 # snapshot source (statusd serves a live Chrome trace from it)
 _RING_CAP = 4096
+# occurrences the always-on account of kept spans holds of each name
+KEPT_CAP = 512
+# the histogram whose MEAN summary() gives as the train step's time: the
+# distance between the entries of back-to-back Trainer.update calls, which
+# the trainer feeds. A single distance is not the step's (the runtime lets
+# the host run a fixed number of steps ahead, so entries come a few ms
+# apart and then one a step), hence no percentile of it.
+_STEP_PERIOD = "train.period"
 
 # Fixed log-spaced histogram bucket upper bounds (seconds): 4 per decade,
 # 1µs .. 1000s. FIXED for every histogram in every process by design —
@@ -221,12 +239,14 @@ class Histogram:
         return self
 
     def stats(self) -> dict:
-        """Summary dict; the percentile fields are None (rendered "n/a",
-        serialized null) when the histogram never observed anything."""
+        """Summary dict; the mean (sum over count, both kept exactly) and
+        the percentile fields are None (rendered "n/a", serialized null)
+        when the histogram never observed anything."""
         if self.n == 0:
-            return {"count": 0, "sum_s": 0.0,
+            return {"count": 0, "sum_s": 0.0, "mean_ms": None,
                     "p50_ms": None, "p90_ms": None, "p99_ms": None}
         return {"count": self.n, "sum_s": round(self.sum, 6),
+                "mean_ms": round(1e3 * self.sum / self.n, 4),
                 "p50_ms": round(1e3 * self.percentile(50), 4),
                 "p90_ms": round(1e3 * self.percentile(90), 4),
                 "p99_ms": round(1e3 * self.percentile(99), 4)}
@@ -265,30 +285,39 @@ def _recording_annotation():
 
 
 class _Span:
-    __slots__ = ("reg", "name", "attrs", "t0", "depth", "ann")
+    __slots__ = ("reg", "name", "attrs", "t0", "depth", "ann", "ring")
 
-    def __init__(self, reg: "_Registry", name: str, attrs, ann=None):
+    def __init__(self, reg: "_Registry", name: str, attrs, ann=None,
+                 ring=None):
         self.reg = reg
         self.name = name
         self.attrs = attrs
         self.ann = ann       # the profiler's annotation of the same name
+        self.ring = ring     # a kept span's: its name's (t0, dur) ring
 
     def __enter__(self):
         if self.ann is not None:
             self.ann.__enter__()
-        stack = self.reg._stack()
-        self.depth = len(stack)
-        stack.append(self.name)
+        if self.reg.enabled:
+            stack = self.reg._stack()
+            self.depth = len(stack)
+            stack.append(self.name)
+        else:
+            # a kept span with telemetry disabled: nothing but the ring
+            self.depth = None
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
-        stack = self.reg._stack()
-        if stack and stack[-1] is self.name:
-            stack.pop()
-        self.reg._record_span(self.name, self.t0, dur, self.depth,
-                              self.attrs)
+        if self.ring is not None:
+            self.ring.append((self.t0, dur))
+        if self.depth is not None:
+            stack = self.reg._stack()
+            if stack and stack[-1] is self.name:
+                stack.pop()
+            self.reg._record_span(self.name, self.t0, dur, self.depth,
+                                  self.attrs)
         if self.ann is not None:
             self.ann.__exit__(*exc)
         return False
@@ -463,6 +492,9 @@ class _Registry:
         # the always-on account of paths: name -> times a traced layer
         # took that lowering in this process
         self.path_n: Dict[str, int] = {}
+        # the always-on account of kept spans: name -> the (t0, dur) of
+        # its last KEPT_CAP occurrences, on time.perf_counter()'s clock
+        self.kept_rings: Dict[str, deque] = {}
         self.reset()
 
     # -- lifecycle -----------------------------------------------------
@@ -606,14 +638,28 @@ class _Registry:
         if self._log_f is None and len(self._pending) > _PENDING_CAP:
             del self._pending[: _PENDING_CAP // 2]
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, keep: bool = False, **attrs):
         ann = _recording_annotation()
         if ann is not None:
             # a profiler session records: the span goes on its clock too
             ann = ann(name, **attrs)
+        if keep:
+            ring = self.kept_rings.get(name)
+            if ring is None:
+                with self._lock:
+                    ring = self.kept_rings.setdefault(
+                        name, deque(maxlen=KEPT_CAP))
+            return _Span(self, name, attrs or None, ann, ring)
         if not self.enabled:
             return _NULL_SPAN if ann is None else ann
         return _Span(self, name, attrs or None, ann)
+
+    def kept(self) -> Dict[str, list]:
+        """The kept spans' account: name -> [(t0, dur), ...], oldest
+        first, at most ``KEPT_CAP`` a name."""
+        with self._lock:
+            rings = list(self.kept_rings.items())
+        return {name: list(ring) for name, ring in rings}
 
     def phase(self, name: str, parts: bool = False) -> _Phase:
         return _Phase(self, name, parts)
@@ -819,9 +865,11 @@ class _Registry:
 
     def summary(self) -> dict:
         """Aggregate view: per-span totals, counters, gauges, compiles,
-        the set-up phases, and p50/p90/p99 duration percentiles per span
-        name."""
+        the set-up phases, p50/p90/p99 duration percentiles per span
+        name, and ``step_time_ms``: the mean of ``train.period`` (None
+        before two back-to-back ``Trainer.update`` calls)."""
         with self._lock:
+            period = self.hists.get(_STEP_PERIOD)
             spans = {}
             for name, (n, total, mx) in self.span_agg.items():
                 durs = sorted(self.span_durs[name])
@@ -839,6 +887,8 @@ class _Registry:
                 "gauges": dict(self.gauges),
                 "hists": {name: h.stats()
                           for name, h in self.hists.items()},
+                "step_time_ms": None if period is None
+                else period.stats()["mean_ms"],
                 "compiles": {
                     "count": len(self.compiles),
                     "total_s": round(sum(c["dur"] for c in self.compiles),
@@ -1380,8 +1430,14 @@ def reset() -> None:
     _REG.reset()
 
 
-def span(name: str, **attrs):
-    return _REG.span(name, **attrs)
+def span(name: str, keep: bool = False, **attrs):
+    return _REG.span(name, keep, **attrs)
+
+
+def kept() -> Dict[str, list]:
+    """The always-on account of kept spans (``span(name, keep=True)``):
+    name -> the (t0, dur) of its last ``KEPT_CAP`` occurrences."""
+    return _REG.kept()
 
 
 def phase(name: str) -> _Phase:

@@ -67,7 +67,7 @@ def test_cpu_backend_has_no_spec_and_no_mfu():
     assert card["flops"] == 1e9 and card["peak_bytes"] == 60
     assert card["predicted_s"] is None
     for _ in range(3):
-        reg.hist("train.step", 0.01)
+        reg.hist("train.period", 0.01)
     snap = lg.snapshot()
     assert snap["spec"] is None
     c = snap["cards"][0]
@@ -130,6 +130,50 @@ def test_card_math_flops_vs_bandwidth_bound():
         reg.disable()
 
 
+# six entries follow each other a dispatch apart, then four a step: what
+# the runtime's limit of steps in flight makes of back-to-back calls
+BIMODAL_PERIODS = ([0.004] * 6 + [0.100] * 4) * 3
+
+
+def test_train_step_card_divides_by_the_mean_period_not_its_p50():
+    lg, reg = make_ledger()
+    try:
+        lg.complete_card("jit.train_step", "s",
+                         cost={"flops": 2.12e12, "bytes accessed": 1.0},
+                         mem={})
+        for d in BIMODAL_PERIODS:
+            reg.hist("train.period", d)
+        c = lg.snapshot()["cards"][0]
+        assert c["measured_ms"] == pytest.approx(42.4)
+        assert c["measured_p50_ms"] < 6.0          # the dispatch's mode
+        # 2.12e12 / (0.0424 s x 100e12) = 50%; by the p50 it read > 350%
+        assert c["mfu_pct"] == pytest.approx(50.0)
+        assert c["roofline_eff_pct"] == pytest.approx(
+            100 * c["predicted_s"] / 0.0424, rel=1e-3)
+    finally:
+        lg.disable()
+        reg.disable()
+
+
+@pytest.mark.parametrize("program,series", sorted(
+    (p, s) for p, s in perf.MEASURED_SERIES.items()
+    if s not in perf.MEAN_SERIES))
+def test_every_other_card_still_divides_by_its_series_p50(program, series):
+    lg, reg = make_ledger()
+    try:
+        lg.complete_card(program, "s",
+                         cost={"flops": 1.0e12, "bytes accessed": 1.0},
+                         mem={})
+        for d in BIMODAL_PERIODS:
+            reg.hist(series, d)
+        c = lg.snapshot()["cards"][0]
+        assert c["measured_series"] == series
+        assert c["measured_ms"] == c["measured_p50_ms"] < 6.0
+    finally:
+        lg.disable()
+        reg.disable()
+
+
 def test_mfu_and_headroom_join_measured_hist():
     lg, reg = make_ledger()
     try:
@@ -141,15 +185,23 @@ def test_mfu_and_headroom_join_measured_hist():
         # no measurements yet: joins stay null, never fake zeros
         c = lg.snapshot()["cards"][0]
         assert c["mfu_pct"] is None and c["measured_p50_ms"] is None
-        # measured p50 ~20ms -> mfu = 1e12/(0.02*100e12) = 50%
+        # the dispatch's own series is not the step's: nothing joins it
         for _ in range(8):
-            reg.hist("train.step", 0.020)
+            reg.hist("train.step", 0.001)
+            reg.hist("train.dispatch", 0.001)
+        c = lg.snapshot()["cards"][0]
+        assert c["mfu_pct"] is None and c["measured_ms"] is None
+        # mean period 20ms -> mfu = 1e12/(0.02*100e12) = 50%
+        for _ in range(8):
+            reg.hist("train.period", 0.020)
         snap = lg.snapshot()
         c = snap["cards"][0]
+        assert c["measured_series"] == "train.period"
         assert c["measured_n"] == 8
-        assert 35.0 < c["mfu_pct"] < 65.0
-        # predicted 10ms vs measured ~20ms -> eff ~50%
-        assert 35.0 < c["roofline_eff_pct"] < 65.0
+        assert c["measured_ms"] == pytest.approx(20.0)
+        assert c["mfu_pct"] == pytest.approx(50.0)
+        # predicted 10ms vs measured 20ms -> eff 50%
+        assert c["roofline_eff_pct"] == pytest.approx(50.0)
         hbm = snap["hbm"]
         assert hbm["peak_bytes"] == 3 * 2**30
         assert hbm["headroom_bytes"] == 8 * 2.0**30 - 3 * 2**30
@@ -266,9 +318,10 @@ def test_programz_and_metrics_render_the_ledger():
                               "temp_size_in_bytes": 1 << 20,
                               "output_size_in_bytes": 0})
         for _ in range(4):
-            reg.hist("train.step", 0.05)
+            reg.hist("train.period", 0.05)
         page = _scrape(base + "/programz").read().decode()
         assert "jit.train_step" in page and "MFU" in page
+        assert "meas ms" in page and "50.00" in page
         assert "headroom" in page
         doc = json.loads(_scrape(base + "/programz?json=1").read())
         assert doc["cards"][0]["name"] == "jit.train_step"
@@ -400,7 +453,7 @@ def test_report_program_ledger_section():
     import telemetry_report as tr
     h = telemetry.Histogram()
     for _ in range(6):
-        h.observe(0.04)                      # measured p50 ~40ms
+        h.observe(0.04)                      # mean period 40ms
     events = [
         {"ev": "meta", "pid": 1, "t0_wall": 100.0, "p": 0, "ts": 0.0},
         {"ev": "program_card", "p": 0, "ts": 1.0,
@@ -412,16 +465,22 @@ def test_report_program_ledger_section():
          "error": None, "spec": "test", "spec_peak_flops": 100e12,
          "spec_hbm_bw": 500e9},
         {"ev": "hists", "p": 0, "ts": 2.0,
-         "hists": {"train.step": h.to_dict()}},
+         "hists": {"train.period": h.to_dict()}},
     ]
     agg = tr.aggregate(events)
     pg = agg["programs"]
     assert pg["count"] == 1
     row = pg["cards"][0]
     assert row["name"] == "jit.train_step"
-    # mfu = 2e12 / (0.04 * 100e12) = 50% (bucketed p50: loose bounds)
-    assert 30.0 < row["mfu_pct"] < 70.0
-    assert 30.0 < row["roofline_eff_pct"] < 70.0
+    # mfu = 2e12 / (0.04 * 100e12) = 50%: the mean is exact, no bucket's
+    assert row["measured_ms"] == pytest.approx(40.0)
+    assert row["mfu_pct"] == pytest.approx(50.0)
+    assert row["roofline_eff_pct"] == pytest.approx(50.0)
+    # a log from before train.period has the dispatch's series alone:
+    # the card reads no time off it (it gave an MFU 60 times too high)
+    old = events[:2] + [dict(events[2], hists={"train.step": h.to_dict()})]
+    row = tr.aggregate(old)["programs"]["cards"][0]
+    assert row["measured_ms"] is None and row["mfu_pct"] is None
     assert pg["hbm_peak_bytes"] == 16
     assert pg["top_by_compile"] == ["jit.train_step"]
     assert pg["top_by_gap"] == ["jit.train_step"]
@@ -470,7 +529,7 @@ def test_real_train_step_produces_a_program_card():
         b.data = rs.rand(8, 1, 1, 16).astype(np.float32)
         b.label = rs.randint(0, 10, (8, 1)).astype(np.float32)
         b.batch_size = 8
-        for _ in range(3):
+        for _ in range(4):
             tr.update(b)
         assert perf.drain(60.0), "carder thread never finished"
         card = perf.ledger().card("jit.train_step")
@@ -485,8 +544,10 @@ def test_real_train_step_produces_a_program_card():
         snap = perf.ledger().snapshot()
         c = [c for c in snap["cards"]
              if c["name"] == "jit.train_step"][0]
-        # the measured join fired (3 train.step spans recorded)
-        assert c["measured_n"] >= 3
+        # the measured join fired: the call that built the step starts no
+        # period, the two calls that follow a plain call each end one
+        assert c["measured_series"] == "train.period"
+        assert c["measured_n"] == 2
         assert c["mfu_pct"] is None and c["roofline_eff_pct"] is None
     finally:
         perf.disable()

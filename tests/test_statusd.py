@@ -236,6 +236,38 @@ def test_prometheus_format_validity(registry, server):
 
 
 # ----------------------------------------------------------------------
+# the step time is the MEAN period; the dispatch keeps its own name
+def test_statusz_step_time_is_the_mean_period_not_a_dispatch_percentile(
+        registry, server):
+    code, page = _get(server, "/statusz")
+    assert code == 200 and "step time" not in page      # nothing fed yet
+    assert registry.summary()["step_time_ms"] is None
+    # six entries a dispatch apart, then four a step, a group (fed, never
+    # clocked): what the runtime's limit of steps in flight makes of
+    # back-to-back update calls
+    for d in ([0.004] * 6 + [0.100] * 4) * 3:
+        registry.hist("train.period", d)
+        registry.hist("train.dispatch", 0.0035)
+    s = registry.summary()
+    assert s["step_time_ms"] == pytest.approx(42.4)
+    assert s["hists"]["train.period"]["mean_ms"] == pytest.approx(42.4)
+    code, page = _get(server, "/statusz")
+    (row,) = [ln for ln in page.splitlines() if "step time" in ln]
+    assert "42.40ms" in row and "mean of 30 train.period" in row
+    assert "p50" not in row
+    # the dispatch's percentiles stand under the dispatch's name
+    (disp,) = [ln for ln in page.splitlines()
+               if ln.startswith("train.dispatch")]
+    assert "p50=" in disp and "p99=" in disp
+    code, metrics = _get(server, "/metrics")
+    series = _parse_prom(metrics)
+    (n,) = series["cxxnet_train_period_seconds_count"]
+    (total,) = series["cxxnet_train_period_seconds_sum"]
+    assert float(total[1]) / int(n[1]) == pytest.approx(0.0424)
+    assert "cxxnet_train_dispatch_seconds_bucket" in series
+
+
+# ----------------------------------------------------------------------
 # histogram primitive: merge exactness
 def test_histogram_merge_exactness():
     rs = np.random.RandomState(7)
@@ -433,8 +465,8 @@ def test_empty_histogram_sentinel_and_na(registry, server):
     h = Histogram()
     assert h.percentile(50) is None and h.percentile(99) is None
     st = h.stats()
-    assert st == {"count": 0, "sum_s": 0.0, "p50_ms": None,
-                  "p90_ms": None, "p99_ms": None}
+    assert st == {"count": 0, "sum_s": 0.0, "mean_ms": None,
+                  "p50_ms": None, "p90_ms": None, "p99_ms": None}
     registry.declare_hist("serve.ttft")
     code, page = _get(server, "/statusz")
     assert code == 200 and "serve.ttft" in page

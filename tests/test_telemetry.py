@@ -373,3 +373,181 @@ def test_report_tool_rejects_malformed(tmp_path):
         telemetry_report.main([str(empty)])
     assert e.value.code == 2
     assert telemetry_report.main([str(tmp_path / "missing.jsonl")]) == 1
+
+
+# ----------------------------------------------------------------------
+# kept spans: the third always-on account (beside phases() and paths())
+@pytest.fixture
+def kept_rings(monkeypatch):
+    """An empty account for the test: it is the process's and outlives
+    reset(), so what other tests kept would stand in it."""
+    monkeypatch.setattr(telemetry._REG, "kept_rings", {})
+
+
+def _profiled(tmp_path):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    return jax.profiler.stop_trace
+
+
+@pytest.mark.parametrize("mode", ["disabled", "enabled", "profiler"])
+def test_a_kept_span_is_recorded_whatever_else_records(mode, tmp_path,
+                                                       kept_rings):
+    stop = None
+    if mode == "enabled":
+        telemetry.enable()
+    elif mode == "profiler":
+        stop = _profiled(tmp_path)
+    try:
+        with telemetry.span("k.outer", keep=True) as outer:
+            with telemetry.span("k.inner", keep=True):
+                pass
+            with telemetry.span("k.plain"):
+                pass
+    finally:
+        if stop is not None:
+            stop()
+    kept = telemetry.kept()
+    assert set(kept) == {"k.outer", "k.inner"}      # k.plain is not kept
+    ((o0, odur),), ((i0, idur),) = kept["k.outer"], kept["k.inner"]
+    assert o0 == outer.t0 and odur >= 0
+    assert o0 <= i0 and i0 + idur <= o0 + odur      # perf_counter's clock
+    # beside the account a kept span is the span it was: the event and
+    # the histogram where telemetry is enabled, nothing where it is not
+    names = [e["name"] for e in _spans(telemetry.events())]
+    hists = telemetry.summary()["hists"]
+    if mode == "enabled":
+        assert names == ["k.inner", "k.plain", "k.outer"]
+        assert hists["k.outer"]["count"] == hists["k.inner"]["count"] == 1
+    else:
+        assert names == [] and hists == {}
+
+
+def test_the_ring_holds_the_last_512_of_a_name_and_no_more(kept_rings):
+    assert telemetry.KEPT_CAP == 512
+    seen = []
+    for i in range(10000):
+        with telemetry.span("k.many", keep=True) as sp:
+            pass
+        seen.append(sp.t0)
+    with telemetry.span("k.once", keep=True):
+        pass
+    kept = telemetry.kept()
+    assert [t0 for t0, _ in kept["k.many"]] == seen[-512:]
+    assert len(kept["k.once"]) == 1                 # a ring a name
+
+
+def test_kept_outlives_reset_and_enable(kept_rings):
+    with telemetry.span("k.a", keep=True):
+        pass
+    first = telemetry.kept()
+    telemetry.reset()
+    assert telemetry.kept() == first
+    telemetry.enable()
+    telemetry.disable()
+    with telemetry.span("k.a", keep=True):
+        pass
+    assert telemetry.kept()["k.a"][:1] == first["k.a"]
+    assert len(telemetry.kept()["k.a"]) == 2
+    # what kept() hands out is a copy
+    telemetry.kept()["k.a"].clear()
+    assert len(telemetry.kept()["k.a"]) == 2
+
+
+def test_a_span_that_is_not_kept_is_still_the_shared_noop(kept_rings):
+    with telemetry.span("k.a", keep=True):
+        pass
+    # the hot path's cost, pinned: identity, not equality
+    assert telemetry.span("k.a") is telemetry.span("k.b", attr=1) \
+        is telemetry._NULL_SPAN
+    assert telemetry.span("k.a", keep=False) is telemetry._NULL_SPAN
+    assert telemetry.span("k.a", keep=True) is not telemetry._NULL_SPAN
+    with telemetry.span("k.a"):
+        pass
+    assert len(telemetry.kept()["k.a"]) == 1
+
+
+def test_two_threads_keep_to_their_own_order_without_loss(kept_rings):
+    import sys
+    import threading
+    n = 200                    # 2 x 200 fits the ring of the shared name
+    mine = {"t1": [], "t2": []}
+
+    def work(own):
+        for _ in range(n):
+            with telemetry.span("k.shared", keep=True):
+                with telemetry.span("k." + own, keep=True) as sp:
+                    pass
+            mine[own].append(sp.t0)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(own,))
+                   for own in mine]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    kept = telemetry.kept()
+    for own, t0s in mine.items():
+        assert [t0 for t0, _ in kept["k." + own]] == t0s
+    assert len(kept["k.shared"]) == 2 * n
+    # every inner span lies inside one outer span of the shared ring
+    outers = kept["k.shared"]
+    for own in mine:
+        for t0, dur in kept["k." + own]:
+            assert any(o0 <= t0 and t0 + dur <= o0 + od
+                       for o0, od in outers)
+
+
+def test_summary_step_time_is_the_mean_period(kept_rings):
+    telemetry.enable()
+    assert telemetry.summary()["step_time_ms"] is None
+    # six entries a dispatch apart, then four a step, a group: fed times
+    for d in ([0.004] * 6 + [0.100] * 4) * 5:
+        telemetry.hist("train.period", d)
+    s = telemetry.summary()
+    assert s["step_time_ms"] == pytest.approx(42.4)
+    h = s["hists"]["train.period"]
+    assert h["mean_ms"] == pytest.approx(1e3 * h["sum_s"] / h["count"])
+    assert h["p50_ms"] < 6.0 and h["p90_ms"] > 50.0     # neither is the step
+
+
+@pytest.mark.parametrize("log", ["with_period", "older_without_period"])
+def test_report_prints_the_mean_period_as_the_step_time(log, tmp_path,
+                                                        capsys):
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import telemetry_report
+
+    path = str(tmp_path / "r.jsonl")
+    telemetry.enable(path)
+    for d in [0.004] * 6 + [0.100] * 4:
+        telemetry.span_event("train.step", 0.0, 0.0036)
+        if log == "with_period":
+            telemetry.span_event("train.dispatch", 0.0, 0.0035)
+            telemetry.hist("train.period", d)
+    telemetry.finish(close=True)
+    assert telemetry_report.main([path]) == 0
+    out = capsys.readouterr().out
+    titles = [ln for ln in out.splitlines() if ln.startswith("== ")]
+    if log == "with_period":
+        assert "== step time (mean of train.period) ==" in titles
+        assert "n=10  mean=42.40ms" in out
+        assert "== dispatch percentiles (train.dispatch) ==" in titles
+    else:
+        # what an older log printed, labelled for what it is
+        assert not any("step time" in t and "not the step time" not in t
+                       for t in titles)
+        assert any(t.startswith("== dispatch percentiles (train.step")
+                   for t in titles)
+    # no percentile stands under a "step time" heading
+    for i, ln in enumerate(out.splitlines()):
+        if ln.startswith("== step time"):
+            assert "p50" not in out.splitlines()[i + 1]

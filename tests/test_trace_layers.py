@@ -209,12 +209,21 @@ def test_train_spans_land_on_the_host_plane_with_telemetry_disabled(tmp_path):
         jax.profiler.stop_trace()
     assert telemetry.events() == []           # nothing went to the registry
     spans = sorted(_host_spans(str(tmp_path)), key=lambda s: s[1])
-    assert [s[0] for s in spans] == ["train.update", "train.h2d",
-                                     "train.step"] * 3
-    for upd, h2d, step in zip(spans[0::3], spans[1::3], spans[2::3]):
-        assert upd[3] == h2d[3] == step[3]    # one thread's line
+    assert [s[0] for s in spans] == [
+        "train.update", "train.h2d", "train.step", "train.args",
+        "train.dispatch"] * 3
+    for upd, h2d, step, args, disp in zip(*(spans[i::5] for i in range(5))):
+        # one thread's line
+        assert upd[3] == h2d[3] == step[3] == args[3] == disp[3]
         assert upd[1] <= h2d[1] and h2d[1] + h2d[2] <= step[1]
-        assert step[1] + step[2] <= upd[1] + upd[2]
+        assert step[1] <= args[1] and args[1] + args[2] <= disp[1]
+        assert disp[1] + disp[2] <= step[1] + step[2] <= upd[1] + upd[2]
+    # the four kept ones also stand in the always-on account, the three
+    # calls last: the same spans on the host's clock, nothing enabled
+    kept = telemetry.kept()
+    assert all(len(kept[n]) >= 3 for n in (
+        "train.update", "train.h2d", "train.args", "train.dispatch"))
+    assert "train.step" not in kept
 
 
 def test_an_enabled_span_is_recorded_and_annotated(tmp_path):
@@ -253,7 +262,8 @@ def test_warm_update_asks_the_profiler_only_whether_it_records(monkeypatch):
     assert telemetry.span("train.h2d") is telemetry.span("train.step")
     calls.update(is_enabled=0)
     tr.update(b)
-    assert calls == {"is_enabled": 3, "made": 0}
+    # train.update > train.h2d, train.step > train.args, train.dispatch
+    assert calls == {"is_enabled": 5, "made": 0}
 
 
 def test_telemetry_imports_and_spans_without_jax():
@@ -518,6 +528,25 @@ def test_reduce_ops_phases_rows_spans_and_gaps():
     assert r["idle_gaps"][0] == ["io.decode", pytest.approx(105e-9)]
     assert r["idle_gaps"][1] == ["train.update", pytest.approx(20e-9)]
     assert r["idle_gap_s_by_span"]["io.decode"] == pytest.approx(105e-9)
+
+
+def test_an_idle_gap_is_named_by_the_step_spans_parts():
+    """``Trainer.update`` opens ``train.args`` and ``train.dispatch`` inside
+    ``train.step``: a gap the device spends waiting on the jitted call reads
+    ``train.dispatch``, no longer ``train.step``, with no change here."""
+    devices, spans = _toy_trace()
+    spans = [s for s in spans if s[0] != "io.decode"] + [
+        ("train.args", 200, 15, "t0"), ("train.dispatch", 215, 135, "t0")]
+    r = devtrace.reduce_ops(devices, spans, "jit_step")
+    by = {s["name"]: s for s in r["host_spans"]}
+    assert set(by) == {"train.update", "train.h2d", "train.step",
+                       "train.args", "train.dispatch"}
+    assert by["train.step"]["self_s"] == pytest.approx(0.0)
+    assert by["train.dispatch"]["self_s"] == pytest.approx(135e-9)
+    assert by["train.update"]["self_s"] == pytest.approx(230e-9)
+    assert r["idle_gaps"][0] == ["train.dispatch", pytest.approx(105e-9)]
+    assert "train.step" not in r["idle_gap_s_by_span"]
+    assert "train.dispatch" in devtrace.format_report(r)
 
 
 def test_reduce_ops_agrees_with_the_benchmarks_reduction_on_busy_time():

@@ -11,7 +11,8 @@ Usage:
 
 Prints top spans by total time, recompile count/causes/seconds, per-round
 breakdowns, counters/gauges, fixed-bucket latency histograms (bucket table
-+ p50/p90/p99), step-time percentiles, a training-health section
++ p50/p90/p99), the step time (the mean ``train.period``) and the
+dispatch's percentiles, a training-health section
 (anomalies/rollbacks/watchdog stalls/corrupt records, utils/health.py),
 a serving section (shed rate, deadline-miss rate, circuit-breaker
 transitions, per-request p50/p99 from the ``serve.request`` histogram,
@@ -86,7 +87,7 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
 
 from cxxnet_tpu.utils import autopsy  # noqa: E402
-from cxxnet_tpu.utils.perf import MEASURED_SERIES  # noqa: E402
+from cxxnet_tpu.utils.perf import MEASURED_SERIES, measured_ms  # noqa: E402
 from cxxnet_tpu.utils.telemetry import (  # noqa: E402
     HIST_BUCKETS, Histogram, count_by, events_to_chrome, fmt_ms,
     percentile)
@@ -616,18 +617,20 @@ def aggregate(events):
                    "peak_bytes": ev.get("peak_bytes"),
                    "predicted_s": ev.get("predicted_s"),
                    "status": ev.get("status"), "error": ev.get("error"),
+                   # the series' p50; the train step's mean period
+                   "measured_ms": measured_ms(series, st) if st else None,
                    "measured_p50_ms": st["p50_ms"] if st else None,
                    "measured_p99_ms": st["p99_ms"] if st else None,
                    "mfu_pct": None, "roofline_eff_pct": None}
-            if st and st["p50_ms"]:
-                p50_s = st["p50_ms"] / 1e3
+            if row["measured_ms"]:
+                took_s = row["measured_ms"] / 1e3
                 peak = ev.get("spec_peak_flops")
                 if row["flops"] is not None and peak:
                     row["mfu_pct"] = round(
-                        100.0 * row["flops"] / (p50_s * peak), 2)
+                        100.0 * row["flops"] / (took_s * peak), 2)
                 if row["predicted_s"] is not None:
                     row["roofline_eff_pct"] = round(
-                        100.0 * row["predicted_s"] / p50_s, 2)
+                        100.0 * row["predicted_s"] / took_s, 2)
             rows.append(row)
         gapped = [r for r in rows
                   if r["roofline_eff_pct"] is not None]
@@ -759,12 +762,23 @@ def print_report(agg, top=15):
         if cliff["stalled_requests"]:
             print("  stalled requests: %s"
                   % ", ".join(cliff["stalled_requests"][:16]))
-    step = spans.get("train.step")
-    if step:
-        print("\n== step-time percentiles (train.step dispatch) ==")
+    period = agg.get("hists", {}).get("train.period")
+    if period and period["count"]:
+        # single periods are bimodal (the runtime holds the host to a few
+        # steps in flight): their mean is the step, no percentile of them
+        print("\n== step time (mean of train.period) ==")
+        print("n=%d  mean=%.2fms" % (period["count"], period["mean_ms"]))
+    # the jitted call alone; a log from before train.period has it only
+    # inside train.step, with the arguments' small dispatches
+    disp, title = spans.get("train.dispatch"), "train.dispatch"
+    if not disp:
+        disp, title = spans.get("train.step"), (
+            "train.step: a log without train.period; not the step time")
+    if disp:
+        print("\n== dispatch percentiles (%s) ==" % title)
         print("n=%d  p50=%.2fms  p90=%.2fms  p99=%.2fms  max=%.2fms" %
-              (step["count"], step["p50_ms"], step["p90_ms"],
-               step["p99_ms"], step["max_ms"]))
+              (disp["count"], disp["p50_ms"], disp["p90_ms"],
+               disp["p99_ms"], disp["max_ms"]))
     if agg.get("hists"):
         print("\n== latency histograms (fixed log-spaced buckets, "
               "merge-exact) ==")
@@ -986,7 +1000,7 @@ def print_report(agg, top=15):
                  if hbm is not None else "n/a"))
         print("%-18s %-26s %3s %9s %10s %9s %9s %9s %7s %7s" %
               ("program", "shapes", "n", "compile_s", "GFLOPs",
-               "peak_MiB", "pred_ms", "p50_ms", "MFU%", "eff%"))
+               "peak_MiB", "pred_ms", "meas_ms", "MFU%", "eff%"))
 
         def _n(v, scale=1.0, form="%.2f"):
             return "n/a" if v is None else form % (v * scale)
@@ -997,7 +1011,7 @@ def print_report(agg, top=15):
                    r["compile_s"], _n(r["flops"], 1e-9),
                    _n(r["peak_bytes"], 1.0 / (1 << 20), "%.1f"),
                    _n(r["predicted_s"], 1e3),
-                   _n(r["measured_p50_ms"]),
+                   _n(r["measured_ms"]),
                    _n(r["mfu_pct"], form="%.1f"),
                    _n(r["roofline_eff_pct"], form="%.1f")))
             if r.get("status") == "error":

@@ -8,7 +8,8 @@ For the stretch between the first and the last run of the step module on
 the busiest device: device self time by phase (forward / backward / update /
 health / other) and by layer x phase with the operations that make each row
 up, the program's host spans (``train.update`` > ``train.h2d``,
-``train.step``) with count and self time, and the ten longest idle gaps,
+``train.step`` > ``train.args``, ``train.dispatch``) with count and self
+time, and the ten longest idle gaps,
 each named by the innermost program span that covers its middle. The
 arithmetic is cxxnet_tpu/utils/devtrace.py; doc/observability.md says where
 the names come from. Needs neither jax nor a chip.
