@@ -1514,7 +1514,12 @@ class AttentionLayer(Layer):
         return qi, ki[:, 0], w
 
     def _dsa_core(self, seq, q, k, v, params, ctx):
-        """The core under a learned selection: index scores, the exact
+        """The core under a learned selection: index scores (in training
+        their backward from one kernel over the causal tiles where it takes
+        the shape, ``ops.dsa_index_bwd``, else the plain blocked lines:
+        ``attn.index_bwd.fused`` / ``attn.index_bwd.xla`` once per traced
+        layer; the loss's gradient, the only one they get, is nought above
+        the diagonal), the exact
         top-k (one kernel that sorts nothing where it takes the shape,
         ``ops.dsa_select``, else ``lax.top_k``: ``attn.select.fused`` /
         ``attn.select.xla`` once per traced layer), the attention on the
@@ -1538,11 +1543,16 @@ class AttentionLayer(Layer):
         telemetry.count_path("attn.dsa")
         telemetry.gauge("dsa.topk", min(self.index_topk, L))
         telemetry.gauge("dsa.kept_scores", dsa.kept_scores(L, self.index_topk))
-        with sub_scope("index"):
-            qi, ki, w = self._index_operands(seq, params)
-            scores = dsa.index_scores(qi, ki, w)            # (b, L, L) f32
         # pallas_call has no partitioning rule: no mesh, or a manual one
         kernels = ops.use_pallas() and (mesh is None or ctx.manual_tp)
+        with sub_scope("index"):
+            qi, ki, w = self._index_operands(seq, params)
+            fused = kernels and ops.dsa_index_bwd_supported(
+                L, qi.shape[1], qi.shape[3], qi.dtype)
+            if ctx.train:
+                telemetry.count_path("attn.index_bwd.fused" if fused
+                                     else "attn.index_bwd.xla")
+            scores = dsa.index_scores(qi, ki, w, fused)     # (b, L, L) f32
         with sub_scope("select"):
             picked = jax.lax.stop_gradient(scores)
             if kernels and ops.dsa_select_supported(L, self.index_topk):

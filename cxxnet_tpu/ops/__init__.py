@@ -321,6 +321,22 @@ def dsa_select(scores, topk: int):
     return _ds.select(scores, topk, pallas_interpret())
 
 
+def dsa_index_bwd_supported(L: int, J: int, di: int, dtype) -> bool:
+    """True when the index scores' backward kernel
+    (ops/dsa_index_pallas.py) takes sequences of ``L`` rows and ``J``
+    index heads of ``di`` in ``dtype``."""
+    from . import dsa_index_pallas as _di
+    return _di.supports(L, J, di, jnp.dtype(dtype).itemsize)
+
+
+def dsa_index_bwd(qi, ki, w, g):
+    """``ops/dsa._scores_bwd``'s (dq, dk, dw) from one kernel that walks
+    the causal tiles alone and makes each tile's products again in VMEM;
+    ``g`` is read on s <= t (ops/dsa_index_pallas.py)."""
+    from . import dsa_index_pallas as _di
+    return _di.scores_bwd(qi, ki, w, g, pallas_interpret())
+
+
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """Tiles of the grouped product's kernel: rows in 512s (the callers pad
     to it), the contraction and the output columns whole up to 1024 and

@@ -16,7 +16,12 @@ detached (``ops.flash_attn.selected_probs``).
 
 ``index_scores`` keeps no (J, L, L) array, forward or backward: both walk
 the queries a block at a time and the backward makes a block's products
-again from qI, kI and w. ``select`` is exact: the set ``lax.top_k`` gives
+again from qI, kI and w. On a TPU the layer takes the backward from one
+kernel that visits the causal tiles alone (``fused``,
+``ops/dsa_index_pallas.py``): it reads the scores' gradient on s <= t
+only, which holds for the layer's, ``index_loss``'s, nought above the
+diagonal; the lines here are the path elsewhere and that kernel's golden
+model. ``select`` is exact: the set ``lax.top_k`` gives
 on rows whose ``topk``-th value is no signed zero, written as an int8
 (b, L, L) array, which is what the flash kernels stream
 (``flash_attention_selected``). On a TPU the layer takes the same array
@@ -26,6 +31,8 @@ lines here are the path elsewhere and that kernel's golden model.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,14 +59,18 @@ def _by_block(x, axis: int, blk: int):
     return jnp.moveaxis(x.reshape(shape), axis, 0)
 
 
-@jax.custom_vjp
-def index_scores(qi, ki, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def index_scores(qi, ki, w, fused=False):
     """``qi`` (b, J, L, di), ``ki`` (b, L, di), ``w`` (b, L, J) float32 ->
-    I (b, L, L) float32, every pair (the caller keeps s <= t)."""
-    return _scores_fwd(qi, ki, w)[0]
+    I (b, L, L) float32, every pair (the caller keeps s <= t). With
+    ``fused`` (the caller gates on ``ops.dsa_index_bwd_supported``) the
+    backward is ``ops.dsa_index_bwd``'s kernel, the gradient of the scores
+    on s <= t alone: the gradient it is handed must be nought above the
+    diagonal, as ``index_loss``'s is."""
+    return _scores_fwd(qi, ki, w, fused)[0]
 
 
-def _scores_fwd(qi, ki, w):
+def _scores_fwd(qi, ki, w, fused):
     L = qi.shape[2]
     blk = _blocks(L)
 
@@ -72,7 +83,10 @@ def _scores_fwd(qi, ki, w):
     return out, (qi, ki, w)
 
 
-def _scores_bwd(res, g):
+def _scores_bwd(fused, res, g):
+    if fused:
+        from . import dsa_index_bwd
+        return dsa_index_bwd(*res, g)
     qi, ki, w = res
     b, J, L, di = qi.shape
     blk = _blocks(L)

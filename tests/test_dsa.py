@@ -5,7 +5,9 @@ backward, the exact selection (the plain lines and the kernel that sorts nothing
 flash kernels (``ops/flash_attn.py``, in the interpreter) and the target pass,
 ``AttentionLayer`` with its indexer, the loss term a layer that is no loss
 layer adds to the step's, and the whole block through ``Trainer.update`` on
-the forced flash path. The plain side is the benchmark's reference
+the forced flash path; the index scores' backward as one kernel over the
+causal tiles (``ops/dsa_index_pallas.py``, in the interpreter) against
+their plain lines. The plain side is the benchmark's reference
 (``benchmark/references/keye_dsa.py``); the whole model against it through
 the cell's own ``run_cell`` is ``tests/benchmark/test_keye_dsa.py``."""
 
@@ -64,6 +66,78 @@ def test_the_index_scores_and_their_blocked_backward_agree(rows):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     for a, b in zip(vjp(g), vjp_w(g)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-3)
+
+
+def _causal_grad(rs, rows, case):
+    """(2, rows, rows) float32 gradients of the scores, nought above the
+    diagonal as ``index_loss``'s is: dense on the causal triangle, on a
+    selection's pairs alone, or on the tiles of 128 astride the diagonal
+    alone, so that whole tiles below it are nought."""
+    g = np.tril(rs.randn(2, rows, rows)).astype(np.float32)
+    if case == "selection":
+        scores = _plain_scores(*_index_operands(rs, 2, 2, rows, 8))
+        g = g * np.asarray(dsa.select(scores, rows // 8), np.float32)
+    elif case == "diagonal_tiles":
+        tile = np.arange(rows) // 128
+        g = g * (tile[:, None] == tile[None, :])
+    return jnp.asarray(g)
+
+
+@pytest.mark.parametrize("rows,J,di", [(128, 3, 8), (384, 2, 16)],
+                         ids=["one_tile", "three_by_three_tiles"])
+@pytest.mark.parametrize("case", ["causal", "selection", "diagonal_tiles"])
+def test_the_fused_index_backward_is_the_plain_one(rows, J, di, case):
+    """The kernel in the interpreter (``fused``) against the plain blocked
+    lines and plain autodiff, the gradient nought above the diagonal: at
+    384 rows the tiles are 128, so kI's gradient is summed over three
+    query blocks, dq and dw over up to three key blocks, and the tiles
+    astride the diagonal mask the gradient."""
+    rs = np.random.RandomState(7)
+    assert ops.dsa_index_bwd_supported(rows, J, di, jnp.float32)
+    qi, ki, w = _index_operands(rs, 2, J, rows, di)
+    g = _causal_grad(rs, rows, case)
+    ops.set_use_pallas(True)
+    try:
+        got, vjp = jax.vjp(lambda *a: dsa.index_scores(*a, True), qi, ki, w)
+        fused = vjp(g)
+    finally:
+        ops.set_use_pallas(None)
+    np.testing.assert_array_equal(got, dsa.index_scores(qi, ki, w))
+    plain = jax.vjp(dsa.index_scores, qi, ki, w)[1](g)
+    auto = jax.vjp(_plain_scores, qi, ki, w)[1](g)
+    for a, b, c in zip(fused, plain, auto):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-3)
+
+
+def test_the_fused_index_backward_reads_no_gradient_above_the_diagonal():
+    """A gradient that is NOT nought above the diagonal: the kernel reads
+    the causal triangle of it alone, so it gives the plain lines' answer
+    on the triangle and nothing of the rest."""
+    rs = np.random.RandomState(8)
+    rows = 256
+    qi, ki, w = _index_operands(rs, 1, 2, rows, 8)
+    g = jnp.asarray(rs.randn(1, rows, rows), jnp.float32)
+    ops.set_use_pallas(True)
+    try:
+        fused = jax.vjp(lambda *a: dsa.index_scores(*a, True),
+                        qi, ki, w)[1](g)
+    finally:
+        ops.set_use_pallas(None)
+    plain = jax.vjp(dsa.index_scores, qi, ki, w)[1](jnp.tril(g))
+    for a, b in zip(fused, plain):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("rows,J,di,why", [
+    (64, 2, 8, "rows that are no whole lane tiles"),
+    (200, 16, 64, "rows that are no whole lane tiles"),
+    (512, 2, 12, "heads of a width the MXU's operands do not tile"),
+    (128 * 1024, 16, 64, "a resident dK beyond the VMEM budget")])
+def test_the_index_backward_kernel_refuses_what_it_does_not_tile(
+        rows, J, di, why):
+    assert not ops.dsa_index_bwd_supported(rows, J, di, jnp.bfloat16), why
 
 
 def _sets_by_top_k(scores, topk):
@@ -542,13 +616,14 @@ def test_the_flash_path_runs_the_selection_and_counts_it_once_a_layer():
     assert gauges["flash.block_q"] == sched["block_q"]
     assert sched["full"] == 0 and sched["edge"] > 0
     assert paths == {"attn.flash": 1, "attn.dsa": 1, "loss.index": 1,
-                     "attn.select.fused": 1,
+                     "attn.select.fused": 1, "attn.index_bwd.fused": 1,
                      "attn.prep.xla": 1, "flash.tiles.edge": sched["edge"],
                      **({"flash.tiles.skipped": sched["skipped"]}
                         if sched["skipped"] else {})}
     y_dense, kl_dense, gauges, paths = delta(False)
     assert paths == {"attn.dense": 1, "attn.dsa": 1, "loss.index": 1,
-                     "attn.select.xla": 1, "attn.prep.xla": 1}
+                     "attn.select.xla": 1, "attn.index_bwd.xla": 1,
+                     "attn.prep.xla": 1}
     np.testing.assert_allclose(y_flash, y_dense, rtol=2e-4, atol=2e-5)
     assert kl_flash == pytest.approx(kl_dense, rel=1e-5)
 
@@ -571,6 +646,10 @@ def test_a_layer_whose_rows_the_selection_kernel_does_not_tile_counts_xla():
              if n != before.get(k, 0)}
     assert paths.get("attn.select.xla") == 1
     assert "attn.select.fused" not in paths
+    assert not ops.dsa_index_bwd_supported(L, lay.index_heads, lay.index_dim,
+                                           jnp.float32)
+    assert paths.get("attn.index_bwd.xla") == 1
+    assert "attn.index_bwd.fused" not in paths
     np.testing.assert_allclose(y, _apply(lay, w, x)[0], rtol=2e-4, atol=2e-5)
 
 
@@ -690,7 +769,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     assert {k: n for k, n in paths.items()
             if not k.startswith("flash.tiles.")} == dict({
         "attn.flash": n, "attn.dsa": n, "attn.select.fused": n,
-        "loss.index": n, "moe.sparse": n, "moe.bounded": n}, **prep)
+        "attn.index_bwd.fused": n, "loss.index": n, "moe.sparse": n,
+        "moe.bounded": n}, **prep)
     assert "flash.tiles.full" not in paths
     said = dict(zip(names, health[4:]))
     for i in range(n):
@@ -702,6 +782,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     assert paths.get("attn.dsa") == n and paths.get("loss.index") == n
     assert paths.get("attn.select.xla") == n
     assert "attn.select.fused" not in paths
+    assert paths.get("attn.index_bwd.xla") == n
+    assert "attn.index_bwd.fused" not in paths
     assert health[0] == pytest.approx(dense[0], rel=1e-5)
 
 

@@ -294,3 +294,29 @@ def test_the_selection_kernel_compiles_at_the_cells_shape(one_chip):
     out, = jax.tree_util.tree_leaves(compiled.out_info)
     assert (out.shape, out.dtype) == ((1, L, L), jnp.int8)
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# the index scores' backward of the same cell as one kernel over the causal
+# tiles (PR 41): 16 index heads of 64 on 8,192 rows, bf16, the scores'
+# float32 gradient. Mosaic has to take the 512 x 512 tiles with the head
+# loop inside, the dynamic lane offset of the resident transposed dK, and
+# the index maps that repeat the diagonal's blocks past it; nothing of the
+# plain lines' (16, 512, 8,192) float32 products goes through HBM
+def test_the_index_backward_kernel_compiles_at_the_cells_shape(one_chip):
+    from cxxnet_tpu.ops import dsa_index_pallas
+    L, J, di = 8192, 16, 64
+    assert dsa_index_pallas.supports(L, J, di, 2)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(dsa_index_pallas.scores_bwd).lower(
+        arg((1, J, L, di), jnp.bfloat16), arg((1, L, di), jnp.bfloat16),
+        arg((1, L, J), jnp.float32), arg((1, L, L), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 1
+    assert not re.search(r"= \S+ (dot|convolution)\(", text), text
+    dq, dk, dw = jax.tree_util.tree_leaves(compiled.out_info)
+    assert (dq.shape, dq.dtype) == ((1, J, L, di), jnp.bfloat16)
+    assert (dk.shape, dk.dtype) == ((1, L, di), jnp.bfloat16)
+    assert (dw.shape, dw.dtype) == ((1, L, J), jnp.float32)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20

@@ -454,7 +454,9 @@ def _check_learned_sparse_attention(rs):
     """The parts of an attention layer under ``attn_mask = dsa`` at
     `keye-ep8-train-8k`'s shape (8,192 rows, 32 query heads on 4 of 128,
     an indexer of 16 heads of 64, 2,048 keys a query, bf16), each alone:
-    the index scores forward and backward, the selection (by ``lax.top_k``
+    the index scores forward and backward (the plain lines, and since
+    PR 41 the kernel over the causal tiles against them: each gradient's
+    worst gap, both paths' ms), the selection (by ``lax.top_k``
     and by the kernel that sorts nothing), the three flash kernels under
     the selection, the target pass. First, on one key-value head with a
     group of two, the selection against ``lax.top_k``'s set, the fused
@@ -464,7 +466,7 @@ def _check_learned_sparse_attention(rs):
     triangle for the index scores; GB/s of the scores read for the
     selection)."""
     from cxxnet_tpu import ops
-    from cxxnet_tpu.ops import dsa, flash_attn
+    from cxxnet_tpu.ops import dsa, dsa_index_pallas, flash_attn
     L, d, J, di, topk = 8192, 128, 16, 64, 2048
 
     def operands(nh, nkv):
@@ -550,6 +552,30 @@ def _check_learned_sparse_attention(rs):
     g = jnp.asarray(rs.randn(1, L, L), jnp.float32)
     t_ib = _ms(jax.jit(lambda *a: jax.vjp(dsa.index_scores, *a[:3])[1](
         a[3])), qi, ki, w, g) - t_i
+    # the index scores' backward as one kernel over the causal tiles
+    # (PR 41) against the plain lines, the scores' gradient shaped as the
+    # layer's: random on the selection, nought elsewhere
+    assert ops.dsa_index_bwd_supported(L, J, di, qi.dtype)
+    g_sel = g * sel.astype(jnp.float32)
+    plain_bwd = jax.jit(lambda *a: dsa._scores_bwd(False, a[:3], a[3]))
+    fused_bwd = jax.jit(ops.dsa_index_bwd)
+    gaps_ib = []
+    for a, b in zip(fused_bwd(qi, ki, w, g_sel), plain_bwd(qi, ki, w, g_sel)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        gaps_ib.append(float(np.max(np.abs(a - b)) / np.max(np.abs(b))))
+    assert max(gaps_ib) < 2e-2, gaps_ib
+    t_ibp = _ms(plain_bwd, qi, ki, w, g_sel)
+    t_ibf = _ms(fused_bwd, qi, ki, w, g_sel)
+    tile = dsa_index_pallas.tile(L)
+    n_t = L // tile
+    ib_flops = 3 * 2.0 * J * di * n_t * (n_t + 1) / 2 * tile ** 2
+    print("index scores' backward at the cell's shape: the kernel over the "
+          "causal tiles against the plain lines, worst gap dq %.2e dk %.2e "
+          "dw %.2e of the largest value; plain %.2f ms = %.1f TFLOP/s, "
+          "kernel %.2f ms = %.1f TFLOP/s (three products over the %d "
+          "causal tiles of %d)"
+          % (*gaps_ib, t_ibp, ib_flops / t_ibp / 1e9, t_ibf,
+             ib_flops / t_ibf / 1e9, n_t * (n_t + 1) // 2, tile))
     t_s = _ms(plain_select, scores)
     t_sf = _ms(fused, scores)
     t_l = _ms(jax.jit(jax.value_and_grad(lambda s_, p_: jnp.sum(
