@@ -282,6 +282,12 @@ class Layer:
     def state_keys(self) -> Tuple[str, ...]:
         return ()
 
+    # fixed param keys: in visit_order (SetWeight / GetWeight and the
+    # checkpoint reach them) but trained by nothing: no updater, no
+    # optimizer state, no decay, kept float32 under a compute dtype
+    def fixed_keys(self) -> Tuple[str, ...]:
+        return ()
+
 
 def sub_scope(name: str):
     """A ``jax.named_scope`` a layer opens inside its own, for a part of its

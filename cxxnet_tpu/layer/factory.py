@@ -52,6 +52,8 @@ kAdd = 34
 kMoE = 35
 kIm2Seq = 36
 kRMSNorm = 37
+kShortConv = 38
+kGatedFFN = 39
 kPairTestGap = 1024
 
 _NAME2TYPE = {
@@ -93,6 +95,8 @@ _NAME2TYPE = {
     "moe": kMoE,
     "im2seq": kIm2Seq,
     "rmsnorm": kRMSNorm,
+    "shortconv": kShortConv,
+    "ffn": kGatedFFN,
 }
 
 _TYPE2CLS = {
@@ -130,6 +134,8 @@ _TYPE2CLS = {
     kMoE: L.MoELayer,
     kIm2Seq: L.Im2SeqLayer,
     kRMSNorm: L.RMSNormLayer,
+    kShortConv: L.ShortConvLayer,
+    kGatedFFN: L.GatedFFNLayer,
 }
 
 

@@ -1208,8 +1208,9 @@ class AttentionLayer(Layer):
         self.decode_chunk = 0
         # qk_norm = 1: an rmsnorm over each head's features on q and on k,
         # before the rotation (leaves qnorm / knorm of head_dim, shared by
-        # the heads; eps 1e-6)
+        # the heads; eps qk_norm_eps, 1e-6 unless set)
         self.qk_norm = 0
+        self.qk_norm_eps = 1e-6
         # attn_mask = blockdiff with block_len = B: block-diffusion
         # training. The rows are [noised copy | clean copy] of one
         # sequence, both at positions 0..rows/2-1 (the rotation wraps),
@@ -1243,6 +1244,8 @@ class AttentionLayer(Layer):
             self.nhead = int(val)
         if name == "qk_norm":
             self.qk_norm = int(val)
+        if name == "qk_norm_eps":
+            self.qk_norm_eps = float(val)
         if name == "attn_mask":
             check(val in ("causal", "blockdiff", "dsa"),
                   "attn_mask must be causal (what the key causal then "
@@ -1482,8 +1485,8 @@ class AttentionLayer(Layer):
         v = heads(qkv[..., qw + kvw:], nkv)
         if self.qk_norm:
             # over each head's features, (b, heads, L, dh)
-            q = _rms_norm(q, params["qnorm"], 1e-6, 3)
-            k = _rms_norm(k, params["knorm"], 1e-6, 3)
+            q = _rms_norm(q, params["qnorm"], self.qk_norm_eps, 3)
+            k = _rms_norm(k, params["knorm"], self.qk_norm_eps, 3)
         if self.rope:
             off = ctx.decode_pos if ctx.decode_pos is not None else 0
             q, k = self._apply_rope(q, off), self._apply_rope(k, off)
@@ -1596,11 +1599,12 @@ class AttentionLayer(Layer):
         kernels: on a TPU (use_pallas), a training or scoring pass on one
         device (no cache position, no mesh: pallas_call has no
         partitioning rule), a layer with a norm or a rotation to fuse, and
-        a shape the kernels tile (ops.qk_prep_supported)."""
+        a shape and a norm's eps the kernels take (ops.qk_prep_supported)."""
         return (ops.use_pallas() and (self.rope or self.qk_norm)
                 and ctx.decode_pos is None and ctx.mesh is None
                 and ops.qk_prep_supported(qkv.shape[1], self._dh(),
-                                          qkv.shape[2], qkv.dtype))
+                                          qkv.shape[2], qkv.dtype,
+                                          self.qk_norm_eps))
 
     def _count_flash(self, q, k, causal, select=False):
         """The path account's ``attn.flash`` and, beside it, the static
@@ -2105,7 +2109,12 @@ class MoELayer(Layer):
 
     Keys: ``nexpert``; ``top_k`` (0 = all): the weights are the softmax
     over the token's top k float32 logits (which is the softmax over all
-    experts renormalised over those k);
+    experts renormalised over those k); ``score = sigmoid`` instead: the
+    top k of ``sigmoid(logits) + b`` are chosen, weighted by their
+    ``sigmoid(logits)`` over its sum over the k (+ 1e-6), where ``b`` is
+    the leaf ``expert_bias`` (``expert_bias = 1``: ``nexpert`` float32
+    values that choose and never weight, a fixed leaf: no gradient, no
+    update; without it b = 0);
     ``expert_act`` = ``relu`` (one matrix an expert, ``nhidden`` outputs),
     ``reglu`` (three: ``relu(x Wg) * (x Wu)`` of width ``nhidden``, then
     ``Wd`` back to the input's width) or ``swiglu`` (the same three with
@@ -2168,9 +2177,17 @@ class MoELayer(Layer):
         self.n_held = 0
         self.expert_offset = 0
         self.expert_act = "relu"
+        self.score = "softmax"
+        self.expert_bias = 0
 
     def set_param(self, name, val):
         super().set_param(name, val)
+        if name == "score":
+            check(val in ("softmax", "sigmoid"),
+                  "score must be softmax or sigmoid")
+            self.score = val
+        if name == "expert_bias":
+            self.expert_bias = int(val)
         if name == "nexpert":
             self.n_expert = int(val)
         if name == "top_k":
@@ -2205,6 +2222,9 @@ class MoELayer(Layer):
         check(self.n_expert > 0, "must set nexpert")
         check(self.param.num_hidden > 0, "must set nhidden")
         check(self.top_k <= self.n_expert, "top_k cannot exceed nexpert")
+        check(not self.expert_bias or self.score == "sigmoid",
+              "moe: expert_bias chooses among sigmoid scores; it needs "
+              "score = sigmoid")
         check(0 <= self.expert_offset
               and self.expert_offset + self._held() <= self.n_expert,
               "moe: experts [expert_offset, expert_offset + nexpert_held) "
@@ -2228,43 +2248,61 @@ class MoELayer(Layer):
                 rng, (held, din, f), in_num=din, out_num=f)
             out["down"] = self.param.rand_init_weight(
                 rng, (held, f, din), in_num=f, out_num=din)
+        out.update((key, np.zeros((e,), np.float32))
+                   for key in self.fixed_keys())
         return out
 
     def _keys(self):
         return ("gate", "experts") + (
             ("up", "down") if self._gated() else ())
 
+    def fixed_keys(self):
+        return ("expert_bias",) if self.expert_bias else ()
+
     def save_model(self, w, params):
         self.param.save(w)
         import struct
         w.write_raw(struct.pack("<ii", self.n_expert, self.top_k))
-        for key in self._keys():
+        for key in self._keys() + self.fixed_keys():
             w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
         import struct
         self.n_expert, self.top_k = struct.unpack("<ii", r.read_raw(8))
-        return {key: r.read_tensor() for key in self._keys()}
+        return {key: r.read_tensor()
+                for key in self._keys() + self.fixed_keys()}
 
     def visit_order(self):
         # ``experts`` is the one matrix of a relu expert and the gate
         # matrix Wg of a reglu or swiglu one; ``gate`` is the router
         return [("wmat", "experts"), ("gate", "gate")] + (
-            [("up", "up"), ("down", "down")] if self._gated() else [])
+            [("up", "up"), ("down", "down")] if self._gated() else []) + [
+            (key, key) for key in self.fixed_keys()]
 
-    def _top(self, logits):
-        """(T, k) expert indices and their weights, float32."""
+    def _top(self, logits, bias=None):
+        """(T, k) expert indices and their weights, float32: by
+        ``score`` and, under sigmoid scores, the expert bias (which
+        chooses and does not weight)."""
         k = self.top_k if 0 < self.top_k < self.n_expert else self.n_expert
         # exact-k from top_k indices (a >=kth-value threshold would keep
         # every tied expert — common in bf16)
-        vals, idx = jax.lax.top_k(logits, k)
-        return idx, jax.nn.softmax(vals, axis=-1)
+        if self.score == "softmax":
+            vals, idx = jax.lax.top_k(logits, k)
+            w = jax.nn.softmax(vals, axis=-1)
+        else:
+            s = jax.nn.sigmoid(logits)
+            pick = s if bias is None else \
+                s + jax.lax.stop_gradient(bias).astype(s.dtype)
+            idx = jax.lax.top_k(pick, k)[1]
+            w = jnp.take_along_axis(s, idx, axis=-1)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        return idx, w
 
-    def _gate_probs(self, x2, gate):
+    def _gate_probs(self, x2, gate, bias=None):
         """(T, nexpert) routing weights, nought outside each token's top k."""
         logits = jnp.dot(x2, gate.T, preferred_element_type=jnp.float32)
-        idx, w = self._top(logits)
+        idx, w = self._top(logits, bias)
         return jnp.sum(jax.nn.one_hot(idx, self.n_expert, dtype=w.dtype)
                        * w[..., None], axis=1)
 
@@ -2284,7 +2322,7 @@ class MoELayer(Layer):
         with sub_scope("route"):
             logits = jnp.dot(xr, params["gate"].T,
                              preferred_element_type=jnp.float32)
-            idx, w = self._top(logits)                      # (T, k)
+            idx, w = self._top(logits, params.get("expert_bias"))
             k = idx.shape[1]
             # pair p = j T + t is token t's j-th choice: choice-major, so
             # that (k T, d) <-> (k, T, d) splits a leading axis (k = 6 on
@@ -2346,12 +2384,15 @@ class MoELayer(Layer):
                   "and no ep axis")
             check(self.expert_act == "relu" and self._held() == self.n_expert,
                   "moe: the 'ep' mesh paths take relu experts, all held")
+        if self.score == "sigmoid":
+            telemetry.count_path("moe.sigmoid")
         if self._seq:
             telemetry.count_path("moe.sparse")
             out = self._sparse(x2, xr2, params, ctx)
         else:
             telemetry.count_path("moe.dense")
-            probs = self._gate_probs(xr2, params["gate"])
+            probs = self._gate_probs(xr2, params["gate"],
+                                     params.get("expert_bias"))
             if n_ep > 1:
                 # same contract as expert_parallel_ffn (parallel/tensor.py):
                 # an indivisible expert count fails loudly, not silently
@@ -2385,3 +2426,160 @@ class MoELayer(Layer):
         out = out.reshape(x.shape[:3] + (-1,))              # (b, 1, L, dout)
         return [out if ctx.channels_last
                 else jnp.transpose(out, (0, 3, 1, 2))]
+
+
+def _seq_rows(x, ctx):
+    """A sequence node (b, d, 1, L), or its channels-last (b, 1, L, d), as
+    (b, L, d)."""
+    if ctx.channels_last:
+        b, _, L, d = x.shape
+        return x.reshape(b, L, d)
+    b, d, _, L = x.shape
+    return x.reshape(b, d, L).transpose(0, 2, 1)
+
+
+def _seq_node(y, ctx):
+    """``_seq_rows``' inverse: (b, L, d) back to the node's layout."""
+    b, L, d = y.shape
+    if ctx.channels_last:
+        return y.reshape(b, 1, L, d)
+    return y.transpose(0, 2, 1).reshape(b, d, 1, L)
+
+
+def short_conv(bcx, w):
+    """The gated short convolution's core on ``bcx (b, L, 3d)`` = [B | C |
+    x] and the taps ``w (d, K)``: ``u = B * x``, ``v_t = sum_j w[:, j]
+    u_{t - K + 1 + j}`` (depthwise, causal within each row, zeros before
+    its start), ``C * v``, (b, L, d) in ``bcx``'s dtype. K shifted slices
+    of one zero-padded copy, products in float32: XLA fuses the pass into
+    one loop each way."""
+    d, K = w.shape
+    L = bcx.shape[1]
+    B, C, x = (bcx[..., i * d:(i + 1) * d].astype(jnp.float32)
+               for i in range(3))
+    u = jnp.pad(B * x, ((0, 0), (K - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    v = sum(u[:, j:j + L] * wf[:, j] for j in range(K))
+    return (C * v).astype(bcx.dtype)
+
+
+class _NamedLeavesLayer(Layer):
+    """A layer whose leaves are saved, loaded and visited under their own
+    names, in the order of ``_KEYS``."""
+
+    _KEYS: tuple = ()
+
+    def save_model(self, w, params):
+        self.param.save(w)
+        for key in self._KEYS:
+            w.write_tensor(params[key])
+
+    def load_model(self, r):
+        self.param.load(r)
+        return {key: r.read_tensor() for key in self._KEYS}
+
+    def visit_order(self):
+        return [(key, key) for key in self._KEYS]
+
+
+class ShortConvLayer(_NamedLeavesLayer):
+    """Gated short causal convolution over a sequence node (b, d, 1, L)
+    (beyond the reference; LFM2's conv mixer, ``Lfm2ShortConv``):
+    ``[B | C | x] = h W_in``, ``u = B * x``, ``v_t = sum_j w[:, j]
+    u_{t-K+1+j}`` depthwise and causal within each row of the batch, zeros
+    before a row's start, ``y = (C * v) W_out``; both gates linear, no
+    nonlinearity; no conv bias (LFM2's ``conv_bias`` is false). Key
+    ``kernel`` (K, default 3: ``conv_L_cache``). Leaves ``w_in (d, 3d)``,
+    ``w_conv (d, K)``, ``w_out (d, d)``. Sub-scopes in / core / out
+    (``AttentionLayer``'s qkv / core / out). Training and scoring whole
+    sequences only: the conv's K - 1 rows of state a sequence in a
+    decode cache are not written (ROADMAP R5)."""
+
+    type_name = "shortconv"
+    layout_support = "nhwc"
+
+    def __init__(self):
+        super().__init__()
+        self.kernel = 3
+
+    def set_param(self, name, val):
+        super().set_param(name, val)
+        if name == "kernel":
+            self.kernel = int(val)
+
+    def infer_shape(self, in_shapes):
+        check(len(in_shapes) == 1,
+              "ShortConvLayer only support 1-1 connection")
+        b, d, h, L = in_shapes[0]
+        check(h == 1 and d > 1,
+              "shortconv input must be a sequence (batch, d_model, 1, seq)")
+        check(self.kernel >= 1, "shortconv: kernel must be positive")
+        self.param.num_input_channel = d
+        return [in_shapes[0]]
+
+    def init_params(self, rng):
+        d, K = self.param.num_input_channel, self.kernel
+        return {"w_in": self.param.rand_init_weight(
+                    rng, (d, 3 * d), in_num=d, out_num=3 * d),
+                "w_conv": self.param.rand_init_weight(
+                    rng, (d, K), in_num=K, out_num=K),
+                "w_out": self.param.rand_init_weight(
+                    rng, (d, d), in_num=d, out_num=d)}
+
+    _KEYS = ("w_in", "w_conv", "w_out")
+
+    def apply(self, params, inputs, ctx):
+        check(ctx.decode_pos is None,
+              "shortconv: decoding from a cache is not written (the conv's "
+              "%d rows of state a sequence beside the key-value cache)"
+              % (self.kernel - 1))
+        check(manual_axis_size(ctx, "sp") <= 1
+              and "sp" not in getattr(ctx.mesh, "axis_names", ()),
+              "shortconv: a sequence split over 'sp' is not written")
+        seq = _seq_rows(inputs[0], ctx)
+        with sub_scope("in"):
+            bcx = jnp.dot(seq, params["w_in"])
+        with sub_scope("core"):
+            y = short_conv(bcx, params["w_conv"])
+        with sub_scope("out"):
+            out = jnp.dot(y, params["w_out"])
+        return [_seq_node(out, ctx)]
+
+
+class GatedFFNLayer(_NamedLeavesLayer):
+    """Dense gated feed-forward over a sequence node (b, d, 1, L) (beyond
+    the reference; the leading dense layers of a mixture-of-experts LM):
+    ``W_d(silu(h W_g) * h W_u)`` a position, width ``nhidden``. One
+    swiglu expert of ``moe``'s, by the same product code
+    (``_expert_rows``) and the same tags: ``wmat`` (W_g, d x f), ``up``
+    (d x f), ``down`` (f x d)."""
+
+    type_name = "ffn"
+    layout_support = "nhwc"
+
+    def infer_shape(self, in_shapes):
+        check(len(in_shapes) == 1, "GatedFFNLayer only support 1-1 "
+              "connection")
+        b, d, h, L = in_shapes[0]
+        check(h == 1 and d > 1,
+              "ffn input must be a sequence (batch, d_model, 1, seq)")
+        check(self.param.num_hidden > 0, "ffn: must set nhidden")
+        self.param.num_input_channel = d
+        return [in_shapes[0]]
+
+    def init_params(self, rng):
+        d, f = self.param.num_input_channel, self.param.num_hidden
+        return {"wmat": self.param.rand_init_weight(
+                    rng, (d, f), in_num=d, out_num=f),
+                "up": self.param.rand_init_weight(
+                    rng, (d, f), in_num=d, out_num=f),
+                "down": self.param.rand_init_weight(
+                    rng, (f, d), in_num=f, out_num=d)}
+
+    _KEYS = ("wmat", "up", "down")
+
+    def apply(self, params, inputs, ctx):
+        seq = _seq_rows(inputs[0], ctx)
+        mats = {"experts": params["wmat"], "up": params["up"],
+                "down": params["down"]}
+        return [_seq_node(_expert_rows("swiglu", jnp.dot, seq, mats), ctx)]

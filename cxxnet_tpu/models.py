@@ -348,21 +348,26 @@ def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
                        norm=False, ffn_kind: str = "conv",
                        ffn_keys: str = "", norm_keys: str = "",
                        init_sigma: Optional[float] = 0.05,
-                       router_on_input: bool = True
+                       router_on_input: bool = True,
+                       mixer: str = "attention"
                        ) -> Tuple[str, str]:
     """One transformer block in the DSL, shared by the LM, ViT and
     mixture-of-experts builders so the block shape lives in one place.
     Residuals connect the BLOCK INPUT (pre-norm form): out = x +
-    att(norm(x)), then + ffn(norm(.)). ``norm``: False, ``"batch_norm"``
-    (True; moving_average) or ``"rmsnorm"`` before each sub-block;
-    attn_keys are extra per-attention config lines (causal/rope/GQA/
-    window/head_dim). ``ffn_kind``: ``"conv"`` (two 1x1 convs of width
-    ``ffn`` around a relu) or ``"moe"`` (one ``moe`` layer of expert width
-    ``ffn`` whose router reads the block's input, before the norm and the
-    attention, or with ``router_on_input`` False the normed node its
-    experts read: the layer's one-input form; ``ffn_keys`` are its lines).
-    ``norm_keys``: lines of both norm layers. ``init_sigma`` None leaves
-    the weights' spread to the conf's global key."""
+    mix(norm(x)), then + ffn(norm(.)). ``norm``: False, ``"batch_norm"``
+    (True; moving_average) or ``"rmsnorm"`` before each sub-block.
+    ``mixer``: ``"attention"`` (layer ``<p>_att`` of ``nhead`` heads) or
+    ``"shortconv"`` (layer ``<p>_conv``, a gated short causal conv);
+    attn_keys are the mixer's extra config lines (causal/rope/GQA/window/
+    head_dim, or the conv's kernel). ``ffn_kind``: ``"conv"`` (two 1x1
+    convs of width ``ffn`` around a relu), ``"ffn"`` (one dense gated
+    ``ffn`` layer ``<p>_ffn`` of width ``ffn``) or ``"moe"`` (one ``moe``
+    layer of expert width ``ffn`` whose router reads the block's input,
+    before the norm and the mixer, or with ``router_on_input`` False the
+    normed node its experts read: the layer's one-input form);
+    ``ffn_keys`` are the ffn's lines. ``norm_keys``: lines of both norm
+    layers. ``init_sigma`` None leaves the weights' spread to the conf's
+    global key."""
     def keys(text):
         return "".join("  %s\n" % ln.strip()
                        for ln in text.splitlines() if ln.strip())
@@ -379,21 +384,24 @@ def _transformer_block(p: str, node_in: str, dim: int, nhead: int,
         att_in = p + "n1"
     sigma = "" if init_sigma is None else \
         "  init_sigma = %.10g\n" % init_sigma
-    txt += """layer[%(ai)s->%(p)satt] = attention:%(p)s_att
-  nhead = %(nh)d
-%(sg)s%(ak)slayer[%(in)s,%(p)satt->%(p)sres1] = add
-""" % {"ai": att_in, "in": node_in, "p": p, "nh": nhead, "sg": sigma,
-       "ak": keys(attn_keys)}
+    node, layer = {"attention": ("att", "attention:%s_att\n  nhead = %d"
+                                 % (p, nhead)),
+                   "shortconv": ("conv", "shortconv:%s_conv" % p)}[mixer]
+    txt += """layer[%(ai)s->%(p)s%(n)s] = %(lay)s
+%(sg)s%(ak)slayer[%(in)s,%(p)s%(n)s->%(p)sres1] = add
+""" % {"ai": att_in, "in": node_in, "p": p, "n": node, "lay": layer,
+       "sg": sigma, "ak": keys(attn_keys)}
     ffn_in = p + "res1"
     if norm:
         txt += ("layer[%(p)sres1->%(p)sn2] = %(norm)s:%(p)s_%(s)s2\n%(nk)s"
                 % {"p": p, "norm": norm, "s": short, "nk": norm_keys})
         ffn_in = p + "n2"
-    if ffn_kind == "moe":
-        txt += """layer[%(fi)s->%(p)sf2] = moe:%(p)s_moe
+    if ffn_kind in ("moe", "ffn"):
+        txt += """layer[%(fi)s->%(p)sf2] = %(k)s:%(p)s_%(k)s
   nhidden = %(ffn)d
-%(fk)s""" % {"fi": ffn_in + (("," + node_in) if router_on_input else ""),
-            "p": p, "ffn": ffn, "fk": keys(ffn_keys)}
+%(fk)s""" % {"fi": ffn_in + (("," + node_in) if router_on_input
+                             and ffn_kind == "moe" else ""),
+            "p": p, "k": ffn_kind, "ffn": ffn, "fk": keys(ffn_keys)}
     else:
         txt += """layer[%(fi)s->%(p)sf1] = conv:%(p)s_ffn1
   kernel_size = 1
@@ -405,6 +413,63 @@ layer[%(p)sr->%(p)sf2] = conv:%(p)s_ffn2
 %(sg)s""" % {"fi": ffn_in, "p": p, "ffn": ffn, "dim": dim, "sg": sigma}
     txt += "layer[%(p)sres1,%(p)sf2->%(p)sout] = add\n" % {"p": p}
     return txt, p + "out"
+
+
+def _decoder_lm(vocab: int, dim: int, nhead: int, blocks, eps: float,
+                norm_f_keys: str = "", loss_keys: str = "") -> str:
+    """The decoder language models' netconfig: embed (normal(0, 1)) ->
+    one ``_transformer_block`` (rmsnorm, the weights' spread the global
+    normal(0, 0.02)) a dict of ``blocks``, whose entries are that
+    function's keyword arguments (``ffn`` the FFN's width, ``mixer``,
+    ``attn_keys``, ``ffn_kind``, ``ffn_keys``, ``router_on_input``) ->
+    rmsnorm ``norm_f`` -> untied vocab head, no bias -> per-position
+    softmax. ``norm_f_keys`` / ``loss_keys``: further lines of the last
+    norm and of the loss."""
+    txt = """
+netconfig = start
+layer[0->emb] = embed:emb
+  vocab_size = %d
+  nhidden = %d
+  init_sigma = 1
+""" % (vocab, dim)
+    node = "emb"
+    for i, blk in enumerate(blocks):
+        t, node = _transformer_block(
+            "b%d" % i, node, dim, nhead, norm="rmsnorm",
+            norm_keys="eps = %.10g\n" % eps, init_sigma=None, **blk)
+        txt += "\n" + t
+    txt += """
+layer[%s->nf] = rmsnorm:norm_f
+  eps = %.10g
+%slayer[nf->logits] = conv:head
+  kernel_size = 1
+  nchannel = %d
+  no_bias = 1
+layer[+0] = softmax
+  seq = 1
+%snetconfig = end
+random_type = gaussian
+init_sigma = 0.02
+""" % (node, eps, norm_f_keys, vocab, loss_keys)
+    return txt
+
+
+def _lm_conf(netconfig: str, adamw: str, rows: int, batch_size: int,
+             dev: str, extra_cfg: str, labels: str) -> str:
+    """A decoder language model's whole training conf: the netconfig, its
+    AdamW block, the shapes (``rows`` a sequence) and the label fields."""
+    return (netconfig + adamw + "input_shape = 1,1,%d\n" % rows +
+            "batch_size = %d\n" % batch_size + labels +
+            "dev = %s\n" % dev + extra_cfg)
+
+
+def _moe_keys(n_expert: int, top_k: int, n_held: int, expert_offset: int,
+              act: str, remat: str, extra: str = "") -> str:
+    """The lines of one chip's share of a ``moe`` layer."""
+    return ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
+            "expert_offset = %d\nexpert_act = %s\n%sremat = %d\n"
+            % (n_expert, top_k, n_held or n_expert, expert_offset, act,
+               extra, "moe" in remat.split()))
 
 
 def transformer_lm_netconfig(vocab: int, dim: int = 64, nhead: int = 4,
@@ -469,48 +534,27 @@ def smallthinker_netconfig(vocab: int = 151936, dim: int = 2560,
     keeps its ``n_expert`` outputs. ``remat`` names the layer kinds whose
     activations are recomputed in the backward pass ("moe", "attention",
     both with a blank between, or "")."""
-    txt = """
-netconfig = start
-layer[0->emb] = embed:emb
-  vocab_size = %d
-  nhidden = %d
-  init_sigma = 1
-""" % (vocab, dim)
-    node = "emb"
+    blocks = []
     for i in range(nlayer):
         local = period[i % len(period)]
         attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\nrope = %d\n"
                 "rope_base = %.10g\nattn_window = %d\nremat = %d\n"
                 % (nkvhead, head_dim, local, rope_theta,
                    window if local else 0, "attention" in remat))
-        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
-               "expert_offset = %d\nexpert_act = reglu\nremat = %d\n"
-               % (n_expert, top_k, n_held or n_expert, expert_offset,
-                  "moe" in remat))
-        blk, node = _transformer_block(
-            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
-            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
-            norm_keys="eps = %.10g\n" % eps, init_sigma=None)
-        txt += "\n" + blk
-    txt += """
-layer[%s->nf] = rmsnorm:norm_f
-  eps = %.10g
-layer[nf->logits] = conv:head
-  kernel_size = 1
-  nchannel = %d
-  no_bias = 1
-layer[+0] = softmax
-  seq = 1
-netconfig = end
-random_type = gaussian
-init_sigma = 0.02
-""" % (node, eps, vocab)
-    return txt
+        blocks.append(dict(ffn=expert_width, attn_keys=attn, ffn_kind="moe",
+                           ffn_keys=_moe_keys(n_expert, top_k, n_held,
+                                              expert_offset, "reglu",
+                                              remat)))
+    return _decoder_lm(vocab, dim, nhead, blocks, eps)
 
 
 SMALLTHINKER_ADAMW = ("updater = adamw\neta = 0.0000003\nbeta1 = 0.9\n"
                       "beta2 = 0.95\nadam_eps = 1e-08\nwd = 0.1\n"
                       "gain:wd = 0.0\n")
+
+
+def _next_token(seq: int) -> str:
+    return "label_vec[0,%d) = label\n" % seq
 
 
 def smallthinker_conf(seq: int = 8192, batch_size: int = 1,
@@ -525,11 +569,8 @@ def smallthinker_conf(seq: int = 8192, batch_size: int = 1,
     3e-6 the routers' loads still drift by a fifth within 45 steps; at
     3e-7 the bfloat16 copies of the weights hardly change and the routing
     is nearly at rest: PERF.md section 6, PR 29.)"""
-    return (smallthinker_netconfig(**kw) + SMALLTHINKER_ADAMW +
-            "input_shape = 1,1,%d\n" % seq +
-            "batch_size = %d\n" % batch_size +
-            "label_vec[0,%d) = label\n" % seq +
-            "dev = %s\n" % dev + extra_cfg)
+    return _lm_conf(smallthinker_netconfig(**kw), SMALLTHINKER_ADAMW, seq,
+                    batch_size, dev, extra_cfg, _next_token(seq))
 
 
 def sdar_moe_netconfig(vocab: int = 151936, dim: int = 2048,
@@ -560,46 +601,18 @@ def sdar_moe_netconfig(vocab: int = 151936, dim: int = 2048,
     ``n_held`` / ``expert_offset`` / ``vocab`` / ``nlayer`` cut one chip's
     share of an expert-parallel deployment as ``smallthinker_netconfig``'s
     do; ``remat`` names the layer kinds recomputed in the backward pass."""
-    txt = """
-netconfig = start
-layer[0->emb] = embed:emb
-  vocab_size = %d
-  nhidden = %d
-  init_sigma = 1
-""" % (vocab, dim)
-    node = "emb"
-    for i in range(nlayer):
-        attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 0\n"
-                "attn_mask = blockdiff\nblock_len = %d\nqk_norm = 1\n"
-                "rope = 1\nrope_base = %.10g\nremat = %d\n"
-                % (nkvhead, head_dim, block_len, rope_theta,
-                   "attention" in remat))
-        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
-               "expert_offset = %d\nexpert_act = swiglu\nremat = %d\n"
-               % (n_expert, top_k, n_held or n_expert, expert_offset,
-                  "moe" in remat))
-        blk, node = _transformer_block(
-            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
-            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
-            norm_keys="eps = %.10g\n" % eps, init_sigma=None,
-            router_on_input=False)
-        txt += "\n" + blk
-    txt += """
-layer[%s->nf] = rmsnorm:norm_f
-  eps = %.10g
-  seq_rows = %d
-layer[nf->logits] = conv:head
-  kernel_size = 1
-  nchannel = %d
-  no_bias = 1
-layer[+0] = softmax
-  seq = 1
-  weight_target = loss_weight
-netconfig = end
-random_type = gaussian
-init_sigma = 0.02
-""" % (node, eps, seq, vocab)
-    return txt
+    attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 0\n"
+            "attn_mask = blockdiff\nblock_len = %d\nqk_norm = 1\n"
+            "rope = 1\nrope_base = %.10g\nremat = %d\n"
+            % (nkvhead, head_dim, block_len, rope_theta,
+               "attention" in remat))
+    blk = dict(ffn=expert_width, attn_keys=attn, ffn_kind="moe",
+               ffn_keys=_moe_keys(n_expert, top_k, n_held, expert_offset,
+                                  "swiglu", remat),
+               router_on_input=False)
+    return _decoder_lm(vocab, dim, nhead, [blk] * nlayer, eps,
+                       norm_f_keys="  seq_rows = %d\n" % seq,
+                       loss_keys="  weight_target = loss_weight\n")
 
 
 SDAR_MOE_ADAMW = (SMALLTHINKER_ADAMW
@@ -612,12 +625,10 @@ def sdar_moe_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
     netconfig at ``seq`` tokens (2 ``seq`` rows), the shapes, the two
     label fields, and ``smallthinker_conf``'s AdamW (assumed there as
     here; the gains of the heads' norms take no decay either)."""
-    return (sdar_moe_netconfig(seq=seq, **kw) + SDAR_MOE_ADAMW +
-            "input_shape = 1,1,%d\n" % (2 * seq) +
-            "batch_size = %d\n" % batch_size +
-            "label_vec[0,%d) = label\n" % seq +
-            "label_vec[%d,%d) = loss_weight\n" % (seq, 2 * seq) +
-            "dev = %s\n" % dev + extra_cfg)
+    return _lm_conf(sdar_moe_netconfig(seq=seq, **kw), SDAR_MOE_ADAMW,
+                    2 * seq, batch_size, dev, extra_cfg,
+                    _next_token(seq) + "label_vec[%d,%d) = loss_weight\n"
+                    % (seq, 2 * seq))
 
 
 def keye_dsa_netconfig(vocab: int = 151936, dim: int = 2048,
@@ -649,45 +660,17 @@ def keye_dsa_netconfig(vocab: int = 151936, dim: int = 2048,
     ``n_held`` / ``expert_offset`` / ``vocab`` / ``nlayer`` cut one chip's
     share of an expert-parallel deployment as ``smallthinker_netconfig``'s
     do; ``remat`` names the layer kinds recomputed in the backward pass."""
-    txt = """
-netconfig = start
-layer[0->emb] = embed:emb
-  vocab_size = %d
-  nhidden = %d
-  init_sigma = 1
-""" % (vocab, dim)
-    node = "emb"
-    for i in range(nlayer):
-        attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\n"
-                "attn_mask = dsa\nindex_heads = %d\nindex_dim = %d\n"
-                "index_topk = %d\nqk_norm = 1\n"
-                "rope = 1\nrope_base = %.10g\nremat = %d\n"
-                % (nkvhead, head_dim, index_heads, index_dim, index_topk,
-                   rope_theta, "attention" in remat))
-        moe = ("nexpert = %d\ntop_k = %d\nnexpert_held = %d\n"
-               "expert_offset = %d\nexpert_act = swiglu\nremat = %d\n"
-               % (n_expert, top_k, n_held or n_expert, expert_offset,
-                  "moe" in remat))
-        blk, node = _transformer_block(
-            "b%d" % i, node, dim, nhead, expert_width, attn_keys=attn,
-            norm="rmsnorm", ffn_kind="moe", ffn_keys=moe,
-            norm_keys="eps = %.10g\n" % eps, init_sigma=None,
-            router_on_input=False)
-        txt += "\n" + blk
-    txt += """
-layer[%s->nf] = rmsnorm:norm_f
-  eps = %.10g
-layer[nf->logits] = conv:head
-  kernel_size = 1
-  nchannel = %d
-  no_bias = 1
-layer[+0] = softmax
-  seq = 1
-netconfig = end
-random_type = gaussian
-init_sigma = 0.02
-""" % (node, eps, vocab)
-    return txt
+    attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\n"
+            "attn_mask = dsa\nindex_heads = %d\nindex_dim = %d\n"
+            "index_topk = %d\nqk_norm = 1\n"
+            "rope = 1\nrope_base = %.10g\nremat = %d\n"
+            % (nkvhead, head_dim, index_heads, index_dim, index_topk,
+               rope_theta, "attention" in remat))
+    blk = dict(ffn=expert_width, attn_keys=attn, ffn_kind="moe",
+               ffn_keys=_moe_keys(n_expert, top_k, n_held, expert_offset,
+                                  "swiglu", remat),
+               router_on_input=False)
+    return _decoder_lm(vocab, dim, nhead, [blk] * nlayer, eps)
 
 
 KEYE_DSA_ADAMW = (SDAR_MOE_ADAMW
@@ -700,11 +683,81 @@ def keye_dsa_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
     netconfig, the shapes, the next-token labels, and
     ``smallthinker_conf``'s AdamW (assumed there as here; the gains of
     the heads' norms and the indexer's LayerNorm take no decay)."""
-    return (keye_dsa_netconfig(**kw) + KEYE_DSA_ADAMW +
-            "input_shape = 1,1,%d\n" % seq +
-            "batch_size = %d\n" % batch_size +
-            "label_vec[0,%d) = label\n" % seq +
-            "dev = %s\n" % dev + extra_cfg)
+    return _lm_conf(keye_dsa_netconfig(**kw), KEYE_DSA_ADAMW, seq,
+                    batch_size, dev, extra_cfg, _next_token(seq))
+
+
+# LFM2-24B-A2B's ``layer_types`` as published: a period of an attention
+# layer and three conv layers, after two conv layers
+LFM2_LAYER_TYPES = ("conv", "conv") + (
+    "full_attention", "conv", "conv", "conv") * 9 + ("full_attention",
+                                                     "conv")
+
+
+def lfm2_moe_netconfig(vocab: int = 65536, dim: int = 2048,
+                       nhead: int = 32, nkvhead: int = 8,
+                       head_dim: int = 64,
+                       layer_types=LFM2_LAYER_TYPES, n_dense: int = 2,
+                       dense_width: int = 11776, n_expert: int = 64,
+                       top_k: int = 4, expert_width: int = 1536,
+                       n_held: int = 0, expert_offset: int = 0,
+                       conv_kernel: int = 3, rope_theta: float = 1e6,
+                       eps: float = 1e-5, remat: str = "moe ffn") -> str:
+    """LFM2-24B-A2B (LiquidAI; the defaults are its published
+    config.json, ``model_type: lfm2_moe``) trained next-token, from the
+    netconfig DSL: embed -> one block a ``layer_types`` entry [ rmsnorm,
+    a mixer + residual, rmsnorm, an FFN + residual ] -> rmsnorm
+    (``embedding_norm``) -> untied vocab head -> per-position softmax.
+    The mixer is the layer's kind: ``conv`` a gated short causal conv of
+    ``conv_kernel`` taps (``shortconv``), ``full_attention`` grouped-query
+    attention with an rmsnorm over each head of q and k (eps ``eps``) and
+    rotary positions at ``rope_theta``. The first ``n_dense`` blocks'
+    FFN is a dense SwiGLU of ``dense_width`` (``ffn``), the others' a
+    ``moe`` layer: sparse SwiGLU experts top_k of n_expert whose router
+    reads the normed stream, sigmoid scores, the top k of score + the
+    fixed ``expert_bias`` chosen and weighted by their scores over their
+    sum (``routed_scaling_factor`` is 1: no scale is written). No bias
+    anywhere; matrices start at normal(0, 0.02) and the embedding at
+    normal(0, 1), for
+    ``smallthinker_netconfig``'s reason.
+
+    ``n_held`` / ``expert_offset`` / ``vocab`` / ``layer_types`` /
+    ``n_dense`` cut one chip's share of an expert-parallel deployment as
+    ``smallthinker_netconfig``'s do; ``remat`` names the layer kinds whose
+    activations are made again in the backward pass (``moe``, ``ffn``,
+    ``attention``, blank-separated)."""
+    kinds = remat.split()
+    attn = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\nqk_norm = 1\n"
+            "qk_norm_eps = %.10g\nrope = 1\nrope_base = %.10g\n"
+            "remat = %d\n" % (nkvhead, head_dim, eps, rope_theta,
+                               "attention" in kinds))
+    conv = "kernel = %d\n" % conv_kernel
+    moe = _moe_keys(n_expert, top_k, n_held, expert_offset, "swiglu", remat,
+                    "score = sigmoid\nexpert_bias = 1\n")
+    dense = "remat = %d\n" % ("ffn" in kinds)
+    blocks = []
+    for i, kind in enumerate(layer_types):
+        mixer = {"conv": ("shortconv", conv),
+                 "full_attention": ("attention", attn)}[kind]
+        ffn = (dict(ffn=dense_width, ffn_kind="ffn", ffn_keys=dense)
+               if i < n_dense else
+               dict(ffn=expert_width, ffn_kind="moe", ffn_keys=moe,
+                    router_on_input=False))
+        blocks.append(dict(ffn, mixer=mixer[0], attn_keys=mixer[1]))
+    return _decoder_lm(vocab, dim, nhead, blocks, eps)
+
+
+LFM2_ADAMW = SDAR_MOE_ADAMW
+
+
+def lfm2_moe_conf(seq: int = 8192, batch_size: int = 2, dev: str = "tpu",
+                  extra_cfg: str = "", **kw) -> str:
+    """The whole training conf of the LFM2 recipe: the netconfig, the
+    shapes, the next-token labels, and ``smallthinker_conf``'s AdamW
+    (assumed there as here; the norms' gains take no decay, the expert
+    bias no step at all: it is a fixed leaf)."""
+    return _lm_conf(lfm2_moe_netconfig(**kw), LFM2_ADAMW, seq, batch_size,
+                    dev, extra_cfg, _next_token(seq))
 
 
 def transformer_lm_conf(vocab: int = 50, seq: int = 16,

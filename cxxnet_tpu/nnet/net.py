@@ -182,13 +182,15 @@ class NeuralNet:
         """Cast master params to the compute dtype for the layer-visible
         view; grads flow back in f32. Non-trainable state
         (layer.state_keys(), e.g. BN running stats) stays f32 so EMAs never
-        accumulate bf16 rounding."""
+        accumulate bf16 rounding, and so do fixed leaves
+        (layer.fixed_keys(), e.g. a router's expert bias)."""
         cdt = self.compute_dtype
         with jax.named_scope("cast_params"):
             return [
                 {k: (jnp.asarray(v).astype(cdt)
                      if (jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating)
-                         and k not in self.layers[i].state_keys()) else v)
+                         and k not in self.layers[i].state_keys()
+                         and k not in self.layers[i].fixed_keys()) else v)
                  for k, v in p.items()}
                 for i, p in enumerate(params)]
 

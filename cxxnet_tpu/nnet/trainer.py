@@ -400,12 +400,15 @@ class Trainer:
 
     def _build_updaters(self) -> None:
         """One Updater per (connection, weight tag), configured from global +
-        per-layer cfg (reference InitUpdaters, neural_net-inl.hpp:177-203)."""
+        per-layer cfg (reference InitUpdaters, neural_net-inl.hpp:177-203);
+        none for a layer's fixed leaves (``Layer.fixed_keys``)."""
         self.updaters: List[Dict[str, object]] = []
         for i, lay in enumerate(self.net.layers):
             ups: Dict[str, object] = {}
             if not self.net.is_shared[i]:
                 for tag, key in lay.visit_order():
+                    if key in lay.fixed_keys():
+                        continue
                     up = create_updater(self.net_cfg.updater_type, tag)
                     for k, v in self.net_cfg.defcfg:
                         up.set_param(k, v)
@@ -2192,7 +2195,8 @@ class Trainer:
     # the router, up, down), rmsnorm (gain)
     _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain",
                     "qnorm", "knorm", "widx_q", "widx_k", "widx_w",
-                    "idx_gain", "idx_bias")
+                    "idx_gain", "idx_bias", "w_in", "w_conv", "w_out",
+                    "expert_bias")
 
     def set_weight(self, value: np.ndarray, layer_name: str, tag: str) -> None:
         check(tag in self._WEIGHT_TAGS,

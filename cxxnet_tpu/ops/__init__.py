@@ -286,12 +286,14 @@ def selected_probs(q, k, lse, sel):
     return _fa.selected_probs(q, k, lse, sel, None, pallas_interpret())
 
 
-def qk_prep_supported(L: int, dh: int, width: int, dtype) -> bool:
+def qk_prep_supported(L: int, dh: int, width: int, dtype,
+                      eps: float = 1e-6) -> bool:
     """True when the fused pass between the qkv dot and the attention core
     (ops/qk_prep_pallas.py) takes these shapes: ``L`` rows of ``width`` lanes,
-    heads of ``dh``."""
+    heads of ``dh``, a QK-norm of ``eps`` (the kernels' is 1e-6)."""
     from . import qk_prep_pallas as _qp
-    return _qp.supports(L, dh, width, jnp.dtype(dtype).itemsize)
+    return eps == _qp._EPS and _qp.supports(L, dh, width,
+                                             jnp.dtype(dtype).itemsize)
 
 
 def qk_prep(qkv, qnorm, knorm, cos, sin, nh: int, nkv: int, dh: int):

@@ -173,6 +173,33 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
+# `lfm2-ep8-train-8k`'s attention (PR 42): 32 query heads on 8 key-value
+# heads of 64, two sequences of 8,192 rows, full causal. A head of 64 is
+# half a lane tile: the tiles are the head of 128's (`_vmem_bytes` counts a
+# block at 128 lanes either way), and Mosaic has to take the narrower blocks
+def test_flash_kernels_compile_at_head_dim_64(one_chip):
+    from cxxnet_tpu.ops import flash_attn
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    assert flash_attn._tiling(8192, 64, 2, 0) \
+        == flash_attn._tiling(8192, 128, 2, 0)
+
+    def both(q_, k_, v_, do_):
+        out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
+            a, b, c, True, None, False, 0), q_, k_, v_)
+        return (out,) + vjp(do_)
+    compiled = jax.jit(both).lower(q, kv, kv, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert [o.shape for o in compiled.out_info] == [
+        q.shape, q.shape, kv.shape, kv.shape]
+    # no temp bound here: alone, the compiler lays the 64-wide arguments
+    # out with the rows minor and copies them to the kernels' row-major
+    # layout and back (436 MB); in the step XLA makes q, k, v itself
+
+
 # the block-diffusion cell's attention (`sdar-ep8-train-8k`): 32 query heads
 # on 4 key-value heads of 128, 16,384 rows (a noised and a clean copy of
 # 8,192 tokens), block length 4. The two-run walk is scalar arithmetic in
