@@ -1567,7 +1567,7 @@ class AttentionLayer(Layer):
         flash = kernels and ops.flash_supported(L, dh)
         with sub_scope("core"):
             if flash:
-                self._count_flash(q, k, True, select=True)
+                self._count_flash(q, k, True, ctx.train, select=True)
                 out, lse = ops.flash_attention_selected(q, k, v, sel)
             else:
                 telemetry.count_path("attn.dense")
@@ -1606,15 +1606,23 @@ class AttentionLayer(Layer):
                                           qkv.shape[2], qkv.dtype,
                                           self.qk_norm_eps))
 
-    def _count_flash(self, q, k, causal, select=False):
+    def _count_flash(self, q, k, causal, train, select=False):
         """The path account's ``attn.flash`` and, beside it, the static
         tile schedule the kernels will walk for these shapes: the forward
         tile as gauges, and the (block_q, block_k) score tiles of one
-        head's grid that take no mask, take one, and are never visited."""
+        head's grid that take no mask, take one, and are never visited.
+        In training also which backward the shape takes: the fused kernel
+        (``attn.bwd.fused``) or the dQ and dK/dV pair (``attn.bwd.split``)."""
         from ..utils import telemetry
         sched = ops.flash_schedule(q, k, causal, self.attn_window,
                                    self._blockdiff(), select)
         telemetry.count_path("attn.flash")
+        if train:
+            fused = ops.flash_fused_bwd_supported(
+                q.shape[2], q.shape[3], q.dtype, self.attn_window,
+                self._blockdiff(), select)
+            telemetry.count_path("attn.bwd.fused" if fused
+                                 else "attn.bwd.split")
         telemetry.gauge("flash.block_q", sched["block_q"])
         telemetry.gauge("flash.block_k", sched["block_k"])
         for kind in ("full", "edge", "skipped"):
@@ -1666,7 +1674,7 @@ class AttentionLayer(Layer):
                 # O(L)-memory flash kernel for long prompts, instead of
                 # (L, l_max) dense scores against the cache
                 if ops.use_pallas() and ops.flash_supported(L, dh):
-                    self._count_flash(q, k, True)
+                    self._count_flash(q, k, True, ctx.train)
                     out = ops.flash_attention(q, k, v, causal=True,
                                               window=self.attn_window)
                 else:
@@ -1758,7 +1766,7 @@ class AttentionLayer(Layer):
             # GQA: the kernel reads grouped k/v natively (BlockSpec row
             # map) — K/V HBM traffic stays nkvhead-sized
             causal = bool(self.causal)
-            self._count_flash(q, k, causal)
+            self._count_flash(q, k, causal, ctx.train)
             if mesh is None or ctx.manual_tp:
                 # inside a pipeline stage body the code is ALREADY
                 # per-device (the stage shard_map sliced the microbatch);

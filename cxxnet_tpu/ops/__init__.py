@@ -260,6 +260,16 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                                window, None, block_len)
 
 
+def flash_fused_bwd_supported(L: int, d: int, dtype, window: int = 0,
+                              block_len: int = 0,
+                              select: bool = False) -> bool:
+    """True when ``flash_attention``'s backward at this shape is the one
+    fused kernel, whose whole-length accumulators fit VMEM; else it is the
+    dQ and dK/dV pair (ops/flash_attn.fused_bwd_supported)."""
+    from . import flash_attn as _fa
+    return _fa.fused_bwd_supported(L, d, dtype, window, block_len, select)
+
+
 def flash_schedule(q, k, causal: bool, window: int = 0,
                    block_len: int = 0, select: bool = False) -> dict:
     """The static tile schedule ``flash_attention`` walks for these

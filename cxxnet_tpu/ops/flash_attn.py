@@ -36,9 +36,10 @@ A fourth mask, and the first that is data (PR 38): a selection
 ``sel (b, L, L)`` marks (learned sparse attention: an indexer chose them,
 all at or under the diagonal). The schedule is the causal one; every
 visited tile is an edge tile whose mask is the tile of ``sel`` streamed
-beside k (its transpose beside q in the dK/dV kernel) and not index
-arithmetic. The other three masks' kernels trace as before: they get no
-such operand.
+beside k and not index arithmetic. ``sel`` is read queries first
+everywhere: the kernels on transposed scores turn a pass's rows of it in
+VMEM (no transpose of it in HBM). The other three masks' kernels trace as
+before: they get no such operand.
 
 Forward: grid (batch*heads, q tiles, kv steps); the (m, l, acc) carry
 lives in VMEM scratch across the kv steps, m and l replicated along the
@@ -50,15 +51,25 @@ Backward (FlashAttention-2 factorization): with P = exp(S - lse) the
 gradients are
     dV = Pᵀ dO
     dS = P ∘ (dO Vᵀ - D),  D = rowsum(dO ∘ O)
-    dQ = scale · dS K      (kernel: q tiles resident, kv streams)
-    dK = scale · dSᵀ Q     (kernel: kv tiles resident, q streams)
-computed by two kernels that recompute S blockwise from the saved lse; D
-is computed once (fused XLA reduce) and, like lse, travels as a lane-dense
-(batch*heads, 1, L) row. The dQ kernel turns its rows of lse and D into
-lane-replicated columns once per q tile. The dK/dV kernel works on the
+    dQ = scale · dS K
+    dK = scale · dSᵀ Q
+recomputing S blockwise from the saved lse; D is computed once (fused XLA
+reduce) and, like lse, travels as a lane-dense (batch*heads, 1, L) row.
+
+One kernel (``_bwd_kernel``) makes all three from one visit of each
+live score tile: five products and one ``exp`` a tile. It works on the
 transposed scores (kv rows, q lanes), so lse and D broadcast along
-sublanes and every product is a plain matmul; its grid rows are the
-key-value heads and its steps run over the group's query heads x the q
+sublanes. Its grid is (kv head, the group's query head, kv tile, q step):
+dK and dV of the kv head's whole length stay in float32 VMEM across the
+group's heads, dQ of the current head's whole length across the kv tiles,
+kept transposed (d, L) so that its product ``Kᵀ dSᵀ`` is a plain one, Kᵀ
+made once a kv tile; each is written once, when its last contribution is
+in — no partial sum goes through HBM. Where those accumulators do not fit
+VMEM (``fused_bwd_supported``: long L) the backward is the two kernels it
+replaced, which stay its golden model: a dQ kernel (q tiles resident, kv
+streams, lse and D turned into lane-replicated columns once per q tile)
+and a dK/dV kernel on the transposed scores whose grid rows are the
+key-value heads and whose steps run over the group's query heads x the q
 tiles that reach the kv tile, so dK and dV are summed over the group in
 float32 scratch and written once at key-value resolution — O(L) memory
 end to end.
@@ -91,6 +102,9 @@ _LANES = 128
 # what one kernel's blocks, scratch and score tiles may add up to by
 # _vmem_bytes; the compiler is given twice that (a v5e core has 128 MiB)
 VMEM_BUDGET = 32 << 20
+# the most the fused backward may ask of the compiler: its tiles' part
+# twice, as above, and its whole-length accumulators once (_accumulators)
+BWD_VMEM_LIMIT = 100 << 20
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -380,6 +394,15 @@ def _selected(sel_ref, s, c: int):
     return jnp.where(keep, s, NEG_INF)
 
 
+def _selected_t(sel_ref, st, c: int):
+    """``_selected`` for transposed scores ``st`` (keys on the sublanes):
+    the selection's block lies queries first, so pass ``c`` takes its rows
+    ``c`` of ``st``'s width and turns them in VMEM."""
+    sub = st.shape[1]
+    keep = sel_ref[0, c * sub:(c + 1) * sub, :].astype(jnp.int32).T != 0
+    return jnp.where(keep, st, NEG_INF)
+
+
 def _with_sel(kernel, n_in: int):
     """``kernel`` as pallas_call sees it when the selection's block is its
     operand ``n_in``, after the plain kernel's own."""
@@ -488,8 +511,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, c * sub:(c + 1) * sub, :]
         st = _dot(k, q, _NT) * scale                        # (bk, sub)
         if masked and sel_ref is not None:
-            # the selection's transpose: keys on the sublanes here too
-            st = _selected(sel_ref, st, c)
+            st = _selected_t(sel_ref, st, c)
         elif masked:
             st = g.mask(st, i * g.bq + c * sub, j * g.bk, 0)
         pt = jnp.exp(st - lse_ref[0, :, c * sub:(c + 1) * sub])
@@ -508,10 +530,74 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dqt_scr, dk_scr, dv_scr, kt_scr,
+                *, scale, g, sel_ref=None):
+    """dQ, dK and dV from one visit of each live score tile. Grid step
+    (kv head, the group's head h, kv tile j, q step t); the scores are
+    transposed as in ``_dkv_kernel``. dK / dV of the kv head's whole
+    length (``dk_scr``, ``dv_scr``) gather over the group's heads, dQᵀ of
+    head h's whole length (``dqt_scr``, (d, L)) over the kv tiles."""
+    h, j, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    i, hi = g.q_tile(j, t)
+    sub = g.sub
+    last = t == pl.num_programs(3) - 1
+    rows = pl.ds(pl.multiple_of(j * g.bk, g.bk), g.bk)
+
+    @pl.when((j == 0) & (t == 0))
+    def _():
+        dqt_scr[...] = jnp.zeros_like(dqt_scr)
+
+    @pl.when(t == 0)
+    def _():
+        kt_scr[...] = k_ref[0].T
+        @pl.when(h == 0)
+        def _():
+            dk_scr[rows, :] = jnp.zeros((g.bk, dk_scr.shape[1]), jnp.float32)
+            dv_scr[rows, :] = jnp.zeros((g.bk, dv_scr.shape[1]), jnp.float32)
+
+    def body(c, masked):
+        k, v = k_ref[0], v_ref[0]
+        q = q_ref[0, c * sub:(c + 1) * sub, :]
+        do = do_ref[0, c * sub:(c + 1) * sub, :]
+        st = _dot(k, q, _NT) * scale                        # (bk, sub)
+        if masked and sel_ref is not None:
+            st = _selected_t(sel_ref, st, c)
+        elif masked:
+            st = g.mask(st, i * g.bq + c * sub, j * g.bk, 0)
+        pt = jnp.exp(st - lse_ref[0, :, c * sub:(c + 1) * sub])
+        dv_scr[rows, :] += _dot(pt.astype(do.dtype), do)
+        dpt = _dot(v, do, _NT)
+        dst = (pt * (dpt - delta_ref[0, :, c * sub:(c + 1) * sub])).astype(
+            q.dtype)
+        dk_scr[rows, :] += _dot(dst, q)
+        cols = pl.ds(pl.multiple_of(i * g.bq + c * sub, sub), sub)
+        dqt_scr[:, cols] += _dot(kt_scr[...], dst)          # (d, sub)
+
+    for c in range(g.bq // sub):
+        _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
+               functools.partial(body, c))
+
+    @pl.when((j == pl.num_programs(2) - 1) & last)
+    def _():
+        # dQ's block is the head's whole length: turned a q tile at a time
+        def turn(n, carry):
+            cols = pl.ds(pl.multiple_of(n * g.bq, g.bq), g.bq)
+            dq_ref[0, cols, :] = (dqt_scr[:, cols].T * scale).astype(
+                dq_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, g.n_q, turn, 0)
+
+    @pl.when((h == pl.num_programs(1) - 1) & last)
+    def _():
+        dk_ref[0] = (dk_scr[rows, :] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[rows, :].astype(dv_ref.dtype)
+
+
 class Tiles(NamedTuple):
     """One kernel's tiling: q and kv rows a grid step holds, and the
-    columns of the streamed block (kv; q in the dK/dV kernel) one pass of
-    the body takes."""
+    columns of the streamed block (kv; q in the dK/dV and the fused
+    backward kernels) one pass of the body takes."""
     bq: int
     bk: int
     sub: int
@@ -522,16 +608,28 @@ def _vmem_bytes(kernel: str, t: Tiles, d: int, itemsize: int) -> int:
     these tiles, by arithmetic: its double-buffered blocks and rows of
     statistics, its float32 scratch, and the float32 score tiles of one
     pass (s, p and a mask's select going forward; s, p, dp, ds and their
-    bf16 copies going back)."""
+    bf16 copies going back). ``bwd``, the fused backward, has the dK/dV
+    kernel's blocks, Kᵀ of a kv tile for scratch and one score tile more
+    (dSᵀ as dQ's operand); its whole-length part is ``_accumulators``."""
     lanes = max(d, _LANES)
-    n_q, n_k = {"fwd": (2, 2), "dq": (3, 2), "dkv": (2, 4)}[kernel]
+    n_q, n_k = {"fwd": (2, 2), "dq": (3, 2), "dkv": (2, 4),
+                "bwd": (2, 4)}[kernel]
     blocks = itemsize * lanes * (n_q * t.bq + n_k * t.bk)
     rows = 0 if kernel == "fwd" else 2 * 8 * 4 * t.bq
-    resident = t.bk if kernel == "dkv" else t.bq
-    scratch = 4 * resident * (
-        2 * lanes if kernel == "dkv" else 2 * _LANES + lanes)
-    scores = 4 * resident * t.sub * (3 if kernel == "fwd" else 5)
+    resident = t.bk if kernel in ("dkv", "bwd") else t.bq
+    scratch = {"dkv": 4 * resident * 2 * lanes,
+               "bwd": itemsize * d * resident}.get(
+                   kernel, 4 * resident * (2 * _LANES + lanes))
+    scores = 4 * resident * t.sub * {"fwd": 3, "bwd": 6}.get(kernel, 5)
     return 2 * (blocks + rows) + scratch + scores
+
+
+def _accumulators(t: Tiles, L: int, d: int, itemsize: int) -> int:
+    """The fused backward's whole-length VMEM: dQᵀ of one head and dK, dV
+    of one kv head in float32, and dQ's output block, double-buffered."""
+    lanes = max(d, _LANES)
+    Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
+    return 4 * (d * Lq + 2 * lanes * Lk) + 2 * itemsize * lanes * Lq
 
 
 def _fit(L: int, target: int) -> int:
@@ -608,12 +706,42 @@ def supports(L: int, d: int, block_len: int = 0) -> bool:
     return pltpu is not None and L >= 128 and d % 8 == 0
 
 
-def _dims(vmem_bytes: Optional[int] = None):
+def fused_bwd_supported(L: int, d: int, dtype, window: int = 0,
+                        block_len: int = 0, select: bool = False,
+                        tiles=None) -> bool:
+    """Whether the backward of these (L, d) operands takes the fused
+    kernel: what it asks of the compiler (``_vmem_limit``: the dK/dV
+    tiles' part twice, the whole-length accumulators once) within
+    BWD_VMEM_LIMIT. Past it, long L, the backward is the split pair.
+    ``tiles`` as ``flash_attention`` takes them."""
+    itemsize = jnp.dtype(dtype).itemsize
+    t = (tiles or _tiling(L, d, itemsize, window, block_len))[2]
+    return _vmem_limit("bwd", t, d, itemsize, select, L) <= BWD_VMEM_LIMIT
+
+
+def _vmem_limit(kernel: str, t: Tiles, d: int, itemsize: int,
+                select: bool = False, L: int = 0) -> int:
+    """The VMEM a kernel asks of the compiler: twice its tiles' part, with
+    a selection's int8 block (double-buffered) and one pass's tile of it
+    widened to 32 bits for the compare (and that turned, on transposed
+    scores); for the fused backward its accumulators once besides."""
+    turned = kernel in ("dkv", "bwd")
+    resident = t.bk if turned else t.bq
+    sel = (2 * t.bq * t.bk + 4 * resident * t.sub * (1 + turned)) \
+        if select else 0
+    whole = _accumulators(t, L, d, itemsize) if kernel == "bwd" else 0
+    return 2 * (_vmem_bytes(kernel, t, d, itemsize) + sel) + whole
+
+
+def _dims(vmem_bytes: Optional[int] = None, grid_dims: int = 3):
     # the innermost stream dim carries the scratch accumulator across steps:
     # must be sequential ("arbitrary"); batch*heads and the tile dim are
-    # parallel (Mosaic may split them over the two TensorCores)
+    # parallel (Mosaic may split them over the two TensorCores). The fused
+    # backward's accumulators span its head and kv-tile dims too
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",) + (
+            ("parallel", "arbitrary") if grid_dims == 3
+            else ("arbitrary",) * (grid_dims - 1)),
         vmem_limit_bytes=vmem_bytes)
 
 
@@ -632,8 +760,9 @@ def flash_attention(q, k, v, causal: bool = False,
     neither causal nor windowed); the caller gates on supports().
     `interpret=True` runs the kernels in the Pallas interpreter so CPU
     tests cover the exact kernel code. ``tiles`` is the tests' way to run
-    several tiles at a small L: three ``Tiles`` (forward, dQ, dK/dV) in
-    place of ``_tiling``'s choice.
+    several tiles at a small L: three ``Tiles`` (forward, dQ, dK/dV; the
+    fused backward takes the dK/dV kernel's) in place of ``_tiling``'s
+    choice.
     """
     out, _ = _flash_fwd(q, k, v, causal, scale, interpret, window, tiles,
                         block_len)
@@ -684,15 +813,11 @@ def _geom(t: Tiles, L: int, causal: bool, window: int,
                  bool(causal), int(window), L, int(block_len), bool(select))
 
 
-def _params(kernel, t: Tiles, d, dtype, interpret, select=False):
+def _params(kernel, t: Tiles, d, dtype, interpret, select=False, L=0):
     if interpret:
         return None
-    # a selection adds its int8 block, double-buffered, and one pass's
-    # tile of it widened to 32 bits for the compare
-    resident = t.bk if kernel == "dkv" else t.bq
-    sel = (2 * t.bq * t.bk + 4 * resident * t.sub) if select else 0
-    return _dims(2 * (_vmem_bytes(kernel, t, d, jnp.dtype(dtype).itemsize)
-                      + sel))
+    return _dims(_vmem_limit(kernel, t, d, jnp.dtype(dtype).itemsize, select,
+                             L), 4 if kernel == "bwd" else 3)
 
 
 def _sel_operand(kernel, n_in: int, sel, block, index_map):
@@ -773,18 +898,18 @@ def _dq_call(qf, kf, vf, dof, lse, delta, L, to_kv, t: Tiles, causal,
 
 
 def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
-              scale, window, interpret, block_len=0, sel_t=None):
+              scale, window, interpret, block_len=0, sel=None):
     """dK and dV at key-value resolution: grid rows are the kv heads, the
     streamed dimension runs over the group's query heads x the q tiles
-    that reach the resident kv tile. ``sel_t`` (b, Lpk, Lpq) int8: the
-    selection transposed, keys first, as the scores are here."""
+    that reach the resident kv tile. ``sel`` (b, Lpq, Lpk) int8: the
+    selection, queries first; the kernel turns its tiles."""
     bkv, Lpk, d = kf.shape
-    g = _geom(t, L, causal, window, block_len, sel_t is not None)
+    g = _geom(t, L, causal, window, block_len, sel is not None)
     steps = g.q_steps()
-    kvheads = bkv // sel_t.shape[0] if sel_t is not None else 1
+    kvheads = bkv // sel.shape[0] if sel is not None else 1
     kernel, sel_specs, sel_args = _sel_operand(
-        _dkv_kernel, 6, sel_t, (1, t.bk, t.bq),
-        lambda r, j, s: (r // kvheads, j, g.q_block(j, s % steps)))
+        _dkv_kernel, 6, sel, (1, t.bq, t.bk),
+        lambda r, j, s: (r // kvheads, g.q_block(j, s % steps), j))
 
     # step s: query head s // steps of kv head r's group, its q tile
     q_spec = pl.BlockSpec((1, t.bq, d), lambda r, j, s: (
@@ -807,7 +932,54 @@ def _dkv_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
             pltpu.VMEM((t.bk, d), jnp.float32),
         ],
         compiler_params=_params("dkv", t, d, qf.dtype, interpret,
-                                sel_t is not None),
+                                sel is not None),
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, delta, *sel_args)
+
+
+def _bwd_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
+              scale, window, interpret, block_len=0, sel=None):
+    """dQ, dK and dV from the fused kernel, on the dK/dV kernel's tiles:
+    grid (kv heads, the group's query heads, kv tiles, q steps). ``sel``
+    (b, Lpq, Lpk) int8: the selection, queries first."""
+    bkv, Lpk, d = kf.shape
+    bh, Lpq, _ = qf.shape
+    g = _geom(t, L, causal, window, block_len, sel is not None)
+    kvheads = bkv // sel.shape[0] if sel is not None else 1
+    kernel, sel_specs, sel_args = _sel_operand(
+        _bwd_kernel, 6, sel, (1, t.bq, t.bk),
+        lambda r, h, j, s: (r // kvheads, g.q_block(j, s), j))
+    # step (r, h, j, s): query head h of kv head r's group, its q tile
+    q_spec = pl.BlockSpec((1, t.bq, d), lambda r, h, j, s: (
+        r * grp + h, g.q_block(j, s), 0))
+    row_spec = pl.BlockSpec((1, 1, t.bq), lambda r, h, j, s: (
+        r * grp + h, 0, g.q_block(j, s)))
+    kv_spec = pl.BlockSpec((1, t.bk, d), lambda r, h, j, s: (r, j, 0))
+    # dK / dV leave at the group's last head: before it their block stands
+    # still, so nothing is written back
+    dkv_spec = pl.BlockSpec((1, t.bk, d), lambda r, h, j, s: (
+        r, jnp.where(h == grp - 1, j, 0), 0))
+    dq_spec = pl.BlockSpec((1, Lpq, d), lambda r, h, j, s: (
+        r * grp + h, 0, 0))
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, g=g),
+        grid=(bkv, grp, g.n_k, g.q_steps()),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+        + sel_specs,
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, Lpq, d), qf.dtype),
+            jax.ShapeDtypeStruct((bkv, Lpk, d), kf.dtype),
+            jax.ShapeDtypeStruct((bkv, Lpk, d), vf.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, Lpq), jnp.float32),
+            pltpu.VMEM((Lpk, d), jnp.float32),
+            pltpu.VMEM((Lpk, d), jnp.float32),
+            pltpu.VMEM((d, t.bk), kf.dtype),
+        ],
+        compiler_params=_params("bwd", t, d, qf.dtype, interpret,
+                                sel is not None, L),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta, *sel_args)
 
@@ -856,39 +1028,47 @@ def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None,
 
 
 def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g,
-               sel=None):
+               sel=None, fused=None):
+    """The three gradients: from the fused kernel where
+    ``fused_bwd_supported`` (or ``fused``, the tests' and the tools' way
+    to ask for either path) says so, else from the split pair."""
     q, k, v, out, lse = res
     b, h, L, d = q.shape
     nkv = k.shape[1]
     if scale is None:
         scale = d ** -0.5
     _, t_dq, t_dkv = _shape_tiles(q, k, window, tiles, block_len)
+    if fused is None:
+        fused = fused_bwd_supported(L, d, q.dtype, window, block_len,
+                                    sel is not None, tiles)
     # D = rowsum(dO ∘ O), computed once here (cheap elementwise + reduce,
-    # XLA fuses it) and given to both kernels as a lane-dense row like
+    # XLA fuses it) and given to the kernels as a lane-dense row like
     # lse; padded rows have dO = 0 so their D is 0 and every padded-row
     # contribution to dk/dv vanishes
     delta = jnp.sum(_merge_bh(g).astype(jnp.float32)
                     * _merge_bh(out).astype(jnp.float32),
                     axis=-1)[:, None, :]
 
-    def padded(t, transposed=False):
-        """A kernel's operands at its own tiles, and the selection's
-        block for it (``transposed``: keys first, as dK / dV's scores)."""
+    def padded(t):
+        """A kernel's operands at its own tiles, the selection's last."""
         Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
         qf, dof = (_pad_seq(_merge_bh(x), Lq) for x in (q, g))
         kf, vf = (_pad_seq(_merge_bh(x), Lk) for x in (k, v))
-        mask = None if sel is None else _pad_sel(sel, Lq, Lk)
-        if mask is not None and transposed:
-            mask = mask.transpose(0, 2, 1)
         return (qf, kf, vf, dof, _pad_seq(lse, Lq, 2),
-                _pad_seq(delta, Lq, 2)), mask
+                _pad_seq(delta, Lq, 2)), (
+                    None if sel is None else _pad_sel(sel, Lq, Lk))
 
-    args, mask = padded(t_dq)
-    dq = _dq_call(*args, L, _kv_row_map(h, nkv), t_dq, causal, scale,
-                  window, interpret, block_len, mask)
-    args, mask = padded(t_dkv, True)
-    dk, dv = _dkv_call(*args, L, h // nkv, t_dkv, causal, scale, window,
-                       interpret, block_len, mask)
+    if fused:
+        args, mask = padded(t_dkv)
+        dq, dk, dv = _bwd_call(*args, L, h // nkv, t_dkv, causal, scale,
+                               window, interpret, block_len, mask)
+    else:
+        args, mask = padded(t_dq)
+        dq = _dq_call(*args, L, _kv_row_map(h, nkv), t_dq, causal, scale,
+                      window, interpret, block_len, mask)
+        args, mask = padded(t_dkv)
+        dk, dv = _dkv_call(*args, L, h // nkv, t_dkv, causal, scale,
+                           window, interpret, block_len, mask)
     return (dq[:, :L].reshape(b, h, L, d),
             dk[:, :L].reshape(b, nkv, L, d),
             dv[:, :L].reshape(b, nkv, L, d))

@@ -314,7 +314,7 @@ def test_the_flash_path_runs_the_mask_and_counts_it_once_a_layer():
     assert kinds["skipped"] >= sum(kinds.values()) // 4   # the dead quadrant
     # heads of 16: the plain lines between the qkv dot and the core
     assert paths == dict({"attn.flash": 1, "attn.blockdiff": 1,
-                          "attn.prep.xla": 1}, **{
+                          "attn.prep.xla": 1, "attn.bwd.fused": 1}, **{
         "flash.tiles." + k: n for k, n in kinds.items() if n})
     y_dense, gauges, paths = delta(False)
     assert paths == {"attn.dense": 1, "attn.blockdiff": 1,
@@ -547,7 +547,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     loss, paths = run(True)
     assert {k: n for k, n in paths.items()
             if not k.startswith("flash.tiles.")} == dict({
-        "attn.flash": n, "attn.blockdiff": n, "moe.sparse": n,
+        "attn.flash": n, "attn.blockdiff": n, "attn.bwd.fused": n,
+        "moe.sparse": n,
         "moe.bounded": n, "loss.weighted": 1}, **prep)
     assert np.isfinite(loss) and 2.0 < loss < 12.0
     dense, paths = run(False)

@@ -616,6 +616,7 @@ def test_the_flash_path_runs_the_selection_and_counts_it_once_a_layer():
     assert gauges["flash.block_q"] == sched["block_q"]
     assert sched["full"] == 0 and sched["edge"] > 0
     assert paths == {"attn.flash": 1, "attn.dsa": 1, "loss.index": 1,
+                     "attn.bwd.fused": 1,
                      "attn.select.fused": 1, "attn.index_bwd.fused": 1,
                      "attn.prep.xla": 1, "flash.tiles.edge": sched["edge"],
                      **({"flash.tiles.skipped": sched["skipped"]}
@@ -769,6 +770,7 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths(over, prep):
     assert {k: n for k, n in paths.items()
             if not k.startswith("flash.tiles.")} == dict({
         "attn.flash": n, "attn.dsa": n, "attn.select.fused": n,
+        "attn.bwd.fused": n,
         "attn.index_bwd.fused": n, "loss.index": n, "moe.sparse": n,
         "moe.bounded": n}, **prep)
     assert "flash.tiles.full" not in paths

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from cxxnet_tpu import ops
+from cxxnet_tpu.ops import dsa
 from cxxnet_tpu.ops.flash_attn import flash_attention, supports
 from cxxnet_tpu.parallel.ring import attention_reference
 
@@ -450,3 +451,102 @@ class TestTiledKernels:
             assert a.dtype == jnp.bfloat16 and a.shape == b.shape
             np.testing.assert_allclose(np.asarray(a, np.float32),
                                        np.asarray(b), rtol=0.1, atol=0.1)
+
+
+# ------------------------------------------------- the fused backward
+def _selection(rs, L, share=0.3):
+    """A random causal selection (1, L, L) int8 that keeps the diagonal."""
+    pos = np.arange(L)
+    keep = (rs.rand(L, L) < share) | (pos[None, :] == pos[:, None])
+    return jnp.asarray((keep & (pos[None, :] <= pos[:, None]))[None],
+                       jnp.int8)
+
+
+def _selected_reference(sel):
+    def attend(q, k, v):
+        probs, _ = dsa.selected_probs_plain(q, k, sel, q.shape[-1] ** -0.5)
+        return jnp.einsum("bngqk,bnkd->bngqd", probs, v).reshape(q.shape)
+    return attend
+
+
+SMALL = (Tiles(128, 128, 128),) * 3
+FUSED_CASES = [
+    # mask (causal, window, block_len, selection), L, h, nkv, d, tiles
+    ("causal-group1-heads64", (True, 0, 0, False), 256, 2, 2, 64, None),
+    ("causal-pads-group4-wide", (True, 0, 0, False), 600, 4, 1, 32,
+     TestTiledKernels.WIDE),
+    ("window-group4-heads128", (True, 200, 0, False), 768, 4, 1, 128,
+     TestTiledKernels.RECT),
+    ("full-pads-heads128", (False, 0, 0, False), 300, 2, 1, 128, None),
+    ("blockdiff-group4-tiles", (False, 0, 4, False), 512, 4, 1, 64, SMALL),
+    ("selection-group7-pads", (True, 0, 0, True), 384, 7, 1, 64,
+     TestTiledKernels.RECT),
+]
+
+
+@pytest.mark.parametrize("mask,L,h,nkv,d,tiles",
+                         [c[1:] for c in FUSED_CASES],
+                         ids=[c[0] for c in FUSED_CASES])
+def test_the_fused_backward_matches_the_split_pair_and_the_reference(
+        mask, L, h, nkv, d, tiles):
+    """dQ, dK and dV of the one kernel against the dQ and dK/dV pair on
+    the same forward's residuals, and against the dense reference's
+    gradients: every mask, groups of 1, 4 and 7 (dK / dV summed over the
+    group in the kernel), heads of 32, 64 and 128, lengths that pad, and
+    several tiles a head at small L."""
+    causal, window, block_len, select = mask
+    rs = np.random.RandomState(L + h)
+    q, w = (jnp.asarray(rs.randn(1, h, L, d), jnp.float32) for _ in "qw")
+    k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.float32) for _ in "kv")
+    sel = _selection(rs, L) if select else None
+    _, res = fa._flash_fwd(q, k, v, causal, None, True, window, tiles,
+                           block_len, sel)
+
+    def backward(fused):
+        return fa._flash_bwd(causal, None, True, window, tiles, block_len,
+                             res, w, sel, fused)
+    fused, split = backward(True), backward(False)
+    attend = _selected_reference(sel) if select else (
+        lambda q_, k_, v_: attention_reference(
+            q_, k_, v_, causal=causal, window=window, block_len=block_len))
+    want = jax.vjp(attend, q, k, v)[1](w)
+    for a, b, c in zip(fused, split, want):
+        assert a.shape == b.shape == c.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _kernels_in_the_gradient(q, k, v):
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q_, k_, v_: jnp.sum(
+        flash_attention(q_, k_, v_, True, None, True)), argnums=(0, 1, 2)))(
+            q, k, v)
+    return str(jaxpr).count("pallas_call[")
+
+
+def test_the_gate_gives_the_split_pair_where_the_accumulators_do_not_fit(
+        monkeypatch):
+    """``fused_bwd_supported`` by the VMEM arithmetic alone: true at the
+    four language-model cells' shapes, false at 32,768 rows of 128, whose
+    whole-length float32 accumulators (48 MiB) and dQ's block take the
+    kernel past BWD_VMEM_LIMIT; and where it is false the backward is the
+    pair of kernels, the same shape rule run at a small L with the limit
+    brought under what it asks."""
+    bf16 = jnp.bfloat16
+    assert fa.fused_bwd_supported(16384, 128, bf16, block_len=4)     # sdar
+    assert fa.fused_bwd_supported(8192, 128, bf16, select=True)      # keye
+    assert fa.fused_bwd_supported(8192, 128, bf16, 4096)     # smallthinker
+    assert fa.fused_bwd_supported(8192, 64, bf16)                    # lfm2
+    assert not fa.fused_bwd_supported(32768, 128, bf16)
+    t = fa._tiling(32768, 128, 2, 0)[2]
+    assert fa._accumulators(t, 32768, 128, 2) >= 48 << 20
+
+    L, d = 256, 64
+    q, k, v = _rand_qkv(np.random.RandomState(8), b=1, h=2, L=L, d=d)
+    assert fa.fused_bwd_supported(L, d, jnp.float32)
+    assert _kernels_in_the_gradient(q, k, v) == 2       # forward + fused
+    asks = fa._vmem_limit("bwd", fa._tiling(L, d, 4, 0)[2], d, 4, False, L)
+    monkeypatch.setattr(fa, "BWD_VMEM_LIMIT", asks - 1)
+    assert not fa.fused_bwd_supported(L, d, jnp.float32)
+    assert _kernels_in_the_gradient(q, k, v) == 3       # forward, dQ, dK/dV

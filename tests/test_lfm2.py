@@ -470,7 +470,8 @@ def test_the_step_on_the_forced_flash_path_counts_its_paths():
     health, paths = run(True)
     assert {k: n for k, n in paths.items()
             if not k.startswith("flash.tiles.")} == {
-        "attn.flash": 1, "attn.prep.xla": 1, "moe.sparse": 4,
+        "attn.flash": 1, "attn.prep.xla": 1, "attn.bwd.fused": 1,
+        "moe.sparse": 4,
         "moe.sigmoid": 4}
     dense, paths = run(False)
     assert paths.get("attn.dense") == 1 and "attn.flash" not in paths

@@ -199,7 +199,8 @@ def test_the_layer_takes_the_kernels_inside_its_qkv_scope():
             k: n - before.get(k, 0) for k, n in telemetry.paths().items()
             if n != before.get(k, 0) and not k.startswith("flash.")}
     y, paths = run(True)
-    assert paths == {"attn.prep.fused": 1, "attn.flash": 1}
+    assert paths == {"attn.prep.fused": 1, "attn.flash": 1,
+                     "attn.bwd.fused": 1}
     y_plain, paths = run(False)
     assert paths == {"attn.prep.xla": 1, "attn.dense": 1}
     np.testing.assert_allclose(y, y_plain, rtol=2e-4, atol=2e-5)

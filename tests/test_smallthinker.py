@@ -546,7 +546,8 @@ def test_the_flash_path_counts_its_tile_schedule_once_a_layer(window):
     assert sum(kinds.values()) == (L2 // bq) * (L2 // bk)
     assert kinds["edge"] >= L2 // max(bq, bk)      # the diagonal's tiles
     # heads of 16: the plain lines between the qkv dot and the core
-    assert paths == dict({"attn.flash": 1, "attn.prep.xla": 1}, **{
+    assert paths == dict({"attn.flash": 1, "attn.prep.xla": 1,
+                          "attn.bwd.fused": 1}, **{
         "flash.tiles." + k: n for k, n in kinds.items() if n})
     y_dense, gauges, paths = delta(False)
     assert paths == {"attn.dense": 1, "attn.prep.xla": 1}
@@ -576,7 +577,8 @@ def test_the_step_on_the_forced_flash_path_fuses_the_rotation():
             k: n for k, n in _paths_since(before).items()
             if k.startswith("attn.")}
     loss, paths = run(True)
-    assert paths == {"attn.flash": 4, "attn.prep.fused": 3,
+    assert paths == {"attn.flash": 4, "attn.bwd.fused": 4,
+                     "attn.prep.fused": 3,
                      "attn.prep.xla": 1}
     plain, paths = run(False)
     assert paths == {"attn.dense": 4, "attn.prep.xla": 4}

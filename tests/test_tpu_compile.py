@@ -145,7 +145,8 @@ def test_sparse_moe_layer_compiles_and_its_cost_follows_the_pairs(
 # 4,096 window. Mosaic has to take the tiles `_tiling` chooses (blocks of
 # (1, 1, bq) statistics rows, their transposes, score tiles of up to
 # 1,024 x 1,024 float32 under the VMEM limit the kernels ask for), and the
-# backward has to be the three kernels alone: dK / dV leave the kernel at
+# backward has to be the fused kernel alone, its whole-length
+# accumulators inside the limit it asks: dK / dV leave the kernel at
 # key-value resolution, so no sum over the group stands beside it
 @pytest.mark.parametrize("window", [0, 4096])
 def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
@@ -158,6 +159,7 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
                          flash_attn._tiling(8192, 128, 2, window)):
         assert flash_attn._vmem_bytes(kernel, t, 128, 2) \
             <= flash_attn.VMEM_BUDGET
+    assert flash_attn.fused_bwd_supported(8192, 128, jnp.bfloat16, window)
 
     def both(q_, k_, v_, do_):
         out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
@@ -165,7 +167,7 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
         return (out,) + vjp(do_)
     compiled = jax.jit(both).lower(q, kv, kv, q).compile()
     text = compiled.as_text()
-    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
     assert [o.shape for o in compiled.out_info] == [
         q.shape, q.shape, kv.shape, kv.shape]
     # nothing of query-head size is summed after the kernels
@@ -176,7 +178,8 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, window):
 # `lfm2-ep8-train-8k`'s attention (PR 42): 32 query heads on 8 key-value
 # heads of 64, two sequences of 8,192 rows, full causal. A head of 64 is
 # half a lane tile: the tiles are the head of 128's (`_vmem_bytes` counts a
-# block at 128 lanes either way), and Mosaic has to take the narrower blocks
+# block at 128 lanes either way), and Mosaic has to take the narrower blocks;
+# the fused backward keeps dQ transposed, (64, 8,192) float32
 def test_flash_kernels_compile_at_head_dim_64(one_chip):
     from cxxnet_tpu.ops import flash_attn
     q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
@@ -185,6 +188,7 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
                               sharding=one_chip)
     assert flash_attn._tiling(8192, 64, 2, 0) \
         == flash_attn._tiling(8192, 128, 2, 0)
+    assert flash_attn.fused_bwd_supported(8192, 64, jnp.bfloat16)
 
     def both(q_, k_, v_, do_):
         out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
@@ -192,7 +196,7 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
         return (out,) + vjp(do_)
     compiled = jax.jit(both).lower(q, kv, kv, q).compile()
     text = compiled.as_text()
-    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
     assert [o.shape for o in compiled.out_info] == [
         q.shape, q.shape, kv.shape, kv.shape]
     # no temp bound here: alone, the compiler lays the 64-wide arguments
@@ -204,7 +208,8 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
 # on 4 key-value heads of 128, 16,384 rows (a noised and a clean copy of
 # 8,192 tokens), block length 4. The two-run walk is scalar arithmetic in
 # the index maps and the kernels' bodies (floor divisions, selects), the mask
-# a shift and two compares on an edge tile: Mosaic has to take both
+# a shift and two compares on an edge tile: Mosaic has to take both, and
+# the fused backward's 16,384-row accumulators (24 MiB) the VMEM it asks
 def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
     from cxxnet_tpu.ops import flash_attn
     q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16,
@@ -215,6 +220,8 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
                          flash_attn._tiling(16384, 128, 2, 0, 4)):
         assert flash_attn._vmem_bytes(kernel, t, 128, 2) \
             <= flash_attn.VMEM_BUDGET
+    assert flash_attn.fused_bwd_supported(16384, 128, jnp.bfloat16,
+                                          block_len=4)
 
     def both(q_, k_, v_, do_):
         out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
@@ -222,7 +229,7 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
         return (out,) + vjp(do_)
     compiled = jax.jit(both).lower(q, kv, kv, q).compile()
     text = compiled.as_text()
-    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
     assert [o.shape for o in compiled.out_info] == [
         q.shape, q.shape, kv.shape, kv.shape]
     assert not re.search(r"bf16\[\d+,8,16384,128\]", text), text
@@ -231,12 +238,12 @@ def test_flash_kernels_compile_under_the_block_diffusion_mask(one_chip):
 
 # the learned-sparse-attention cell's attention (`keye-ep8-train-8k`, PR 38):
 # 32 query heads on 4 key-value heads of 128 over 8,192 tokens, a selection
-# of 2,048 keys a query streamed as an int8 block beside k (its transpose
-# beside q in dK / dV) and widened to 32 bits for the compare, and the
-# target pass whose tile sums all 32 heads' probabilities in VMEM. Mosaic
-# has to take the int8 blocks and their tiling, the four kernels have to
-# stand alone but for the mask's transpose and pads, and no (heads, L, L)
-# array may be made beside them
+# of 2,048 keys a query streamed as an int8 block beside k, read queries
+# first by the fused backward too and turned in VMEM, widened to 32
+# bits for the compare, and the target pass whose tile sums all 32 heads'
+# probabilities in VMEM. Mosaic has to take the int8 blocks and their
+# tiling, the three kernels have to stand alone: no transpose of the
+# selection (67 MB) and no (heads, L, L) array beside them
 def test_flash_kernels_compile_under_a_selection(one_chip):
     from cxxnet_tpu.ops import flash_attn
     L = 8192
@@ -254,12 +261,13 @@ def test_flash_kernels_compile_under_a_selection(one_chip):
         return (out, p) + vjp((do_, jnp.zeros_like(lse)))
     compiled = jax.jit(both).lower(q, kv, kv, sel, q).compile()
     text = compiled.as_text()
-    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 4
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 3
     assert [o.shape for o in compiled.out_info] == [
         q.shape, (1, L, L), q.shape, kv.shape, kv.shape]
     assert not re.search(r"\[(1,)?32,8192,8192\]", text), text
-    # beside the kernels: the selection's transpose for dK / dV (67 MB)
-    assert compiled.memory_analysis().temp_size_in_bytes < 80 << 20
+    # the selection keys first, as the parent made it for dK / dV: a copy
+    assert not re.search(r"= s8\[1,8192,8192\]\{1,2,0", text), text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
 # the pass between the qkv dot and the flash kernels (PR 37) at the two

@@ -19,6 +19,9 @@ Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      `keye-ep8-train-8k`'s shape: index scores, the selection by top_k and
      by the kernel that sorts nothing, the flash kernels under the
      selection and the target pass, each alone, ~2 min)
+     python tools/check_tpu_kernels.py bwd   (the flash backward alone,
+     the fused kernel beside the split pair, at smallthinker's, sdar's and
+     lfm2's shapes: worst gap, ms and TFLOP/s; keye's is in `dsa`)
 """
 
 import functools
@@ -47,6 +50,9 @@ def main():
         return
     if sys.argv[1:] == ["dsa"]:
         _check_learned_sparse_attention(np.random.RandomState(0))
+        return
+    if sys.argv[1:] == ["bwd"]:
+        _check_fused_backward_at_the_cells_shapes(np.random.RandomState(0))
         return
     from cxxnet_tpu import ops
     from cxxnet_tpu.ops import pallas_kernels
@@ -240,6 +246,7 @@ def main():
         print("flash attention L=%d bf16 fwd + dq + dk/dv: OK" % L)
     _check_flash_at_the_cells_shape(rs)
     _check_flash_under_the_block_diffusion_mask(rs)
+    _check_fused_backward_at_the_cells_shapes(rs)
     _check_qk_prep_at_the_cells_shapes(rs)
 
     # --- ring-step flash kernels, compiled ---
@@ -355,6 +362,67 @@ def _both(attend, q_, k_, v_, do_):
     return (out,) + vjp(do_)
 
 
+def _fused_against_split(what, q, k, v, do, flops, causal=True, window=0,
+                         block_len=0, sel=None):
+    """The flash backward alone on one forward's residuals, the fused
+    kernel beside the split pair it replaced: each gradient's
+    worst gap to the split pair's over its largest value, and both paths'
+    ms and TFLOP/s by the mask's FLOPs (``flops``, the forward's, x2.5:
+    dV, dP, dK and dQ; the recomputed S is not counted)."""
+    from cxxnet_tpu.ops import flash_attn
+    res = jax.jit(lambda q_, k_, v_, s_: flash_attn._flash_fwd(
+        q_, k_, v_, causal, None, False, window, None, block_len, s_)[1])(
+            q, k, v, sel)
+
+    def path(fused):
+        return jax.jit(lambda r, g, s_: flash_attn._flash_bwd(
+            causal, None, False, window, None, block_len, r, g, s_, fused))
+    fused, split = path(True), path(False)
+    gaps = []
+    for a, b in zip(fused(res, do, sel), split(res, do, sel)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        gaps.append(float(np.max(np.abs(a - b)) / np.max(np.abs(b))))
+    assert max(gaps) < 2e-2, gaps
+    t_fused, t_split = _ms(fused, res, do, sel), _ms(split, res, do, sel)
+    print("flash backward %s: fused against the split pair, worst gap dq "
+          "%.2e dk %.2e dv %.2e of the largest value; split %.2f ms = %.1f "
+          "TFLOP/s, fused %.2f ms = %.1f TFLOP/s (%+.1f%%; by the mask's "
+          "FLOPs x2.5)" % (what, *gaps, t_split, 2.5 * flops / t_split / 1e9,
+                           t_fused, 2.5 * flops / t_fused / 1e9,
+                           100.0 * (t_fused / t_split - 1)))
+    return t_fused, t_split
+
+
+def _check_fused_backward_at_the_cells_shapes(rs):
+    """``_fused_against_split`` at three language-model cells' shapes
+    (bf16): smallthinker's global and 4,096-window layers, sdar's
+    block-diffusion mask and lfm2's heads of 64 on two sequences; keye's
+    selection is in ``_check_learned_sparse_attention``, on its own
+    selection."""
+    from benchmark import lm_flops
+
+    def operands(b, nh, nkv, L, d):
+        q, do = (jnp.asarray(rs.randn(b, nh, L, d), jnp.bfloat16)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rs.randn(b, nkv, L, d), jnp.bfloat16)
+                for _ in range(2))
+        return q, k, v, do
+    args = operands(1, 28, 4, 8192, 128)
+    for window in (0, 4096):
+        _fused_against_split(
+            "at smallthinker's shape, window=%d" % window, *args,
+            lm_flops.flash_attention(8192, 28, 4, 128, window)["flops"],
+            True, window)
+    half = 8192
+    _fused_against_split(
+        "under the block-diffusion mask at sdar's shape",
+        *operands(1, 32, 4, 2 * half, 128),
+        4.0 * 32 * 128 * (half * half + half * 4), False, 0, 4)
+    _fused_against_split(
+        "at heads of 64, lfm2's shape", *operands(2, 32, 8, 8192, 64),
+        2 * lm_flops.flash_attention(8192, 32, 8, 64)["flops"])
+
+
 def _check_flash_at_the_cells_shape(rs):
     """The language-model cell's attention (`smallthinker-ep4-train-8k`:
     28 query heads on 4 key-value heads of 128, 8,192 tokens, bf16, the
@@ -405,9 +473,9 @@ def _check_flash_under_the_block_diffusion_mask(rs):
     heads of 128, bf16): forward and the three gradients against the dense
     masked softmax on one key-value head with a group of two (the tiles do
     not follow the heads; a head's float32 scores are 1 GB), then, at the
-    cell's 32 query heads on 4, each kernel's time and its rate by the
-    scores the mask keeps (a gradient asked for alone leaves the other
-    backward kernel dead code)."""
+    cell's 32 query heads on 4, the forward's and the whole pass's time
+    and rate by the scores the mask keeps (the backward alone, fused and
+    split: ``_check_fused_backward_at_the_cells_shapes``)."""
     from cxxnet_tpu.ops import flash_attn
     from cxxnet_tpu.parallel.ring import attention_reference
     L, d, B = 16384, 128, 4
@@ -437,17 +505,13 @@ def _check_flash_under_the_block_diffusion_mask(rs):
     half = L // 2
     flops = 4.0 * nh * d * (half * half + half * B)     # forward, kept scores
     t_f = _ms(jax.jit(flash), q, k, v)
-    t_q = _ms(jax.jit(lambda *a: _both(flash, *a)[1]), q, k, v, do) - t_f
-    t_kv = _ms(jax.jit(lambda *a: _both(flash, *a)[2:]), q, k, v, do) - t_f
     t_fb = _ms(jax.jit(functools.partial(_both, flash)), q, k, v, do)
     sched = flash_attn.schedule(q, k, False, 0, B)
     print("flash attention under the block-diffusion mask at the cell's "
-          "shape: OK; tiles %s; forward %.2f ms = %.1f TFLOP/s, dQ %.2f ms "
-          "= %.1f TFLOP/s, dK/dV %.2f ms = %.1f TFLOP/s, forward + backward "
-          "%.2f ms = %.1f TFLOP/s (by the kept scores' FLOPs, x1, x1, x1.5 "
-          "and x3.5)" % (sched, t_f, flops / t_f / 1e9, t_q,
-                         flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9,
-                         t_fb, 3.5 * flops / t_fb / 1e9))
+          "shape: OK; tiles %s; forward %.2f ms = %.1f TFLOP/s, forward + "
+          "backward %.2f ms = %.1f TFLOP/s (by the kept scores' FLOPs, x1 "
+          "and x3.5)" % (sched, t_f, flops / t_f / 1e9, t_fb,
+                         3.5 * flops / t_fb / 1e9))
 
 
 def _check_learned_sparse_attention(rs):
@@ -542,8 +606,8 @@ def _check_learned_sparse_attention(rs):
     flops = 4.0 * nh * d * kept                  # forward, kept scores
     tri = L * (L + 1) / 2
     t_f = _ms(jax.jit(flash), q, k, v)
-    t_q = _ms(jax.jit(lambda *a: _both(flash, *a)[1]), q, k, v, do) - t_f
-    t_kv = _ms(jax.jit(lambda *a: _both(flash, *a)[2:]), q, k, v, do) - t_f
+    _fused_against_split("under the selection at the cell's shape", q, k,
+                         v, do, flops, sel=sel)
     lse = jax.jit(lambda *a: flash_attn.flash_attention_selected(
         *a, sel)[1])(q, k, v)
     t_p = _ms(jax.jit(lambda q_, k_, l_: flash_attn.selected_probs(
@@ -589,14 +653,12 @@ def _check_learned_sparse_attention(rs):
           "selection by lax.top_k %.2f ms = %.1f GB/s of scores read, by "
           "the kernel that sorts nothing (array-equal) %.2f ms = %.1f GB/s; "
           "flash forward "
-          "%.2f ms = %.1f TFLOP/s, dQ %.2f ms = %.1f TFLOP/s, dK/dV %.2f ms "
-          "= %.1f TFLOP/s (by the kept scores' FLOPs, x1, x1, x1.5); "
-          "target pass %.2f ms = %.1f TFLOP/s (2 head_dim FLOPs a kept "
-          "score a head); index loss and its gradient %.2f ms"
+          "%.2f ms = %.1f TFLOP/s (by the kept scores' FLOPs; the backward "
+          "above); target pass %.2f ms = %.1f TFLOP/s (2 head_dim FLOPs a "
+          "kept score a head); index loss and its gradient %.2f ms"
           % (gap, gap_p, sched, kept, int(tri), t_i, i_flops / t_i / 1e9,
              t_ib, t_s, 4.0 * L * L / t_s / 1e6, t_sf,
-             4.0 * L * L / t_sf / 1e6, t_f, flops / t_f / 1e9,
-             t_q, flops / t_q / 1e9, t_kv, 1.5 * flops / t_kv / 1e9, t_p,
+             4.0 * L * L / t_sf / 1e6, t_f, flops / t_f / 1e9, t_p,
              flops / 2 / t_p / 1e9, t_l))
 
 
