@@ -10,6 +10,7 @@ forward functions, which our golden tests verify (tests/test_layers.py).
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax
@@ -1160,6 +1161,26 @@ class MultiLogisticLayer(LossLayerBase):
         return jnp.sum(bce) * self._scale()
 
 
+def yarn_inv_freq(base: float, rot: int, factor: float, original_len: float,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's (rot / 2,) rotation frequencies, float64 (Peng et al. 2023;
+    transformers' ``_compute_yarn_parameters``): the plain frequencies
+    ``base^(-2i / rot)`` where a pair turns more than ``beta_fast`` times
+    over ``original_len`` positions, those over ``factor`` where it turns
+    fewer than ``beta_slow`` times, and a linear ramp over the pairs
+    between, the range's ends rounded outward."""
+    half = rot // 2
+    plain = base ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+
+    def pair_of(turns):
+        return rot * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return plain / factor * ramp + plain * (1 - ramp)
+
+
 class AttentionLayer(Layer):
     """Multi-head self-attention over sequence nodes (b, D, 1, L) — channels
     hold d_model so `conv kernel_size=1` serves as the position-wise FFN in
@@ -1188,6 +1209,25 @@ class AttentionLayer(Layer):
         # embed pos_embed = 0.
         self.rope = 0
         self.rope_base = 10000.0
+        # rope_dim > 0: rotate the first rope_dim features of each head
+        # (the (first half, second half) pairs within them) and pass the
+        # others through; 0 -> the whole head
+        self.rope_dim = 0
+        # rope_type = yarn: YaRN's frequencies (Peng et al. 2023, as
+        # transformers' _compute_yarn_parameters makes them) from
+        # yarn_factor, yarn_original_len (the positions the model was
+        # fitted to), yarn_beta_fast / yarn_beta_slow (the correction
+        # range, in rotations), and cosines and sines both scaled by
+        # yarn_attention_factor (0 -> 0.1 ln(yarn_factor) + 1)
+        self.rope_type = "default"
+        self.yarn = {"yarn_factor": 1.0, "yarn_original_len": 0.0,
+                     "yarn_beta_fast": 32.0, "yarn_beta_slow": 1.0,
+                     "yarn_attention_factor": 0.0}
+        self.yarn_keys = set()          # the yarn_* keys the conf gave
+        # head_gate = 1: each head's output times sigmoid(h W_gate), one
+        # scalar a head and position from the layer's input h (leaf wgate,
+        # d x nhead), before wo
+        self.head_gate = 0
         # nkvhead < nhead: grouped-query attention — k/v projections carry
         # only nkvhead heads, broadcast to the query heads at dispatch
         # (0 -> = nhead, classic MHA)
@@ -1262,6 +1302,17 @@ class AttentionLayer(Layer):
             self.rope = int(val)
         if name == "rope_base":
             self.rope_base = float(val)
+        if name == "rope_dim":
+            self.rope_dim = int(val)
+        if name == "rope_type":
+            check(val in ("default", "yarn"),
+                  "rope_type must be default or yarn")
+            self.rope_type = val
+        if name in self.yarn:
+            self.yarn[name] = float(val)
+            self.yarn_keys.add(name)
+        if name == "head_gate":
+            self.head_gate = int(val)
         if name == "nkvhead":
             self.nkvhead = int(val)
         if name == "head_dim":
@@ -1293,6 +1344,22 @@ class AttentionLayer(Layer):
                   "attention: rope rotates pairs of features and needs an "
                   "even head size, got %d (key head_dim, else d_model / "
                   "nhead)" % self._dh())
+        if self.rope_dim:
+            check(self.rope and self.rope_dim % 2 == 0
+                  and 0 < self.rope_dim <= self._dh(),
+                  "attention: rope_dim = %d must be an even number of a "
+                  "head's %d features, with rope = 1"
+                  % (self.rope_dim, self._dh()))
+        if self.rope_type == "yarn":
+            check(self.rope and self.yarn["yarn_factor"] > 1
+                  and self.yarn["yarn_original_len"] > 0,
+                  "attention: rope_type = yarn needs rope = 1, yarn_factor "
+                  "> 1 and yarn_original_len, the positions the model was "
+                  "fitted to")
+        else:
+            check(not self.yarn_keys,
+                  "attention: %s mean nothing without rope_type = yarn"
+                  % ", ".join(sorted(self.yarn_keys)))
         if self.nkvhead:
             check(self.nhead % self.nkvhead == 0,
                   "nkvhead must divide nhead")
@@ -1347,30 +1414,47 @@ class AttentionLayer(Layer):
     def _rope_angles(self, L, half, offset=0):
         """Float32 cosines and sines, (L, half), of the rotation's angles:
         row r stands at position ``offset`` + r, under the block-diffusion
-        mask at r mod L/2."""
+        mask at r mod L/2. Under ``rope_type = yarn`` the frequencies are
+        YaRN's (``yarn_inv_freq``) and both tables carry its attention
+        factor."""
         pos = offset + jnp.arange(L, dtype=jnp.float32)[:, None]
         if self._blockdiff():
             pos = pos % (L // 2)
+        if self.rope_type == "yarn":
+            y = self.yarn
+            inv = jnp.asarray(yarn_inv_freq(
+                self.rope_base, 2 * half, y["yarn_factor"],
+                y["yarn_original_len"], y["yarn_beta_fast"],
+                y["yarn_beta_slow"]), jnp.float32)
+            scale = y["yarn_attention_factor"] or \
+                0.1 * math.log(y["yarn_factor"]) + 1.0
+            ang = pos * inv
+            return jnp.cos(ang) * scale, jnp.sin(ang) * scale
         inv = jnp.power(self.rope_base,
                         -jnp.arange(half, dtype=jnp.float32) / half)
         ang = pos * inv                                     # (L, half)
         return jnp.cos(ang), jnp.sin(ang)
 
-    def _apply_rope(self, x, offset=0):
+    def _apply_rope(self, x, offset=0, rot=0):
         """Rotary embedding on (b, nh, L, dh): rotate the (first-half,
-        second-half) feature pairs by position-dependent angles (Su et al.
-        2021) — relative offsets enter the q.k phase directly. ``offset``
-        is the global position of row 0 (KV-cached decode steps). Under
-        the block-diffusion mask the rows are two copies of one sequence
-        and row r stands at position r mod L/2."""
-        half = x.shape[-1] // 2
+        second-half) feature pairs of the first ``rot`` features (all where
+        0) by position-dependent angles (Su et al. 2021) and pass the
+        others through — relative offsets enter the q.k phase directly.
+        ``offset`` is the global position of row 0 (KV-cached decode
+        steps). Under the block-diffusion mask the rows are two copies of
+        one sequence and row r stands at position r mod L/2."""
+        rot = rot or x.shape[-1]
+        half = rot // 2
         cos, sin = self._rope_angles(x.shape[2], half, offset)
         # the rotation itself in float32 whatever the compute type: a
         # bf16 cosine keeps 8 bits of an angle that runs to thousands
         x1 = x[..., :half].astype(jnp.float32)
-        x2 = x[..., half:].astype(jnp.float32)
-        return jnp.concatenate([x1 * cos - x2 * sin,
-                                x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+        x2 = x[..., half:rot].astype(jnp.float32)
+        out = jnp.concatenate([x1 * cos - x2 * sin,
+                               x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+        if rot < x.shape[-1]:
+            out = jnp.concatenate([out, x[..., rot:]], axis=-1)
+        return out
 
     def _kv_width(self):
         return (self.nkvhead or self.nhead) * self._dh()
@@ -1385,6 +1469,9 @@ class AttentionLayer(Layer):
                    rng, (qw, d), in_num=qw, out_num=d)}
         for key in self._norm_keys():
             out[key] = np.ones((self._dh(),), np.float32)
+        if self.head_gate:
+            out["wgate"] = self.param.rand_init_weight(
+                rng, (d, self.nhead), in_num=d, out_num=self.nhead)
         if self._dsa():
             J, di = self.index_heads, self.index_dim
             for key, n in (("widx_q", J * di), ("widx_k", di),
@@ -1404,21 +1491,27 @@ class AttentionLayer(Layer):
         return ("widx_q", "widx_k", "widx_w", "idx_gain",
                 "idx_bias") if self._dsa() else ()
 
+    def _own_keys(self):
+        """The leaves saved, loaded and visited under their own names, in
+        that order: the heads' norms, the indexer's, the output gate."""
+        return self._norm_keys() + self._index_keys() + (
+            ("wgate",) if self.head_gate else ())
+
     def save_model(self, w, params):
         self.param.save(w)
-        for key in ("wqkv", "wo") + self._norm_keys() + self._index_keys():
+        for key in ("wqkv", "wo") + self._own_keys():
             w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
         return {key: r.read_tensor() for key in
-                ("wqkv", "wo") + self._norm_keys() + self._index_keys()}
+                ("wqkv", "wo") + self._own_keys()}
 
     def visit_order(self):
         # wo gets its own tag: one array per tag so the GetWeight/SetWeight
         # ABI (and per-tag updater scoping, e.g. wo:lr) can reach both
         return [("wmat", "wqkv"), ("wo", "wo")] + [
-            (key, key) for key in self._norm_keys() + self._index_keys()]
+            (key, key) for key in self._own_keys()]
 
 
     layout_support = "nhwc"
@@ -1446,6 +1539,9 @@ class AttentionLayer(Layer):
         else:
             with sub_scope("core"):
                 out = self._core(q, k, v, ctx)
+        if self.head_gate:
+            with sub_scope("gate"):
+                out = self._gate(seq, params["wgate"], out)
         with sub_scope("out"):
             out = out.transpose(0, 2, 1, 3).reshape(b, L, qw)  # merge heads
             out = jnp.dot(out, params["wo"])
@@ -1453,16 +1549,34 @@ class AttentionLayer(Layer):
             return [out.reshape(b, 1, L, d)]
         return [out.transpose(0, 2, 1).reshape(b, d, 1, L)]
 
+    def _gate(self, seq, wgate, out):
+        """The core's output (b, nh, L, dh) times ``sigmoid(seq wgate)``
+        (b, L, nh), one scalar a head and position, in float32. Counts
+        ``attn.gate`` once per traced layer."""
+        from ..utils import telemetry
+        telemetry.count_path("attn.gate")
+        g = jax.nn.sigmoid(jnp.dot(seq, wgate,
+                                   preferred_element_type=jnp.float32))
+        return (out.astype(jnp.float32)
+                * jnp.transpose(g, (0, 2, 1))[..., None]).astype(out.dtype)
+
     def _heads(self, qkv, params, ctx):
         """The qkv dot's output (b, L, qw + 2 kvw) as the core's operands:
         q (b, nh, L, dh), k and v (b, nkv, L, dh), q and k normed over
-        each head's features (``qk_norm``) and rotated (``rope``). Counts
-        ``attn.prep.fused`` / ``attn.prep.xla`` once per traced layer."""
+        each head's features (``qk_norm``) and rotated (``rope``: the
+        first ``rope_dim`` features, YaRN's tables under ``rope_type =
+        yarn``). Counts ``attn.prep.fused`` / ``attn.prep.xla``, and
+        ``attn.rope.partial`` / ``attn.rope.yarn`` where they apply, once
+        per traced layer."""
         from ..utils import telemetry
         b, L, _ = qkv.shape
         nh, dh = self.nhead, self._dh()
         nkv = self.nkvhead or nh
         qw, kvw = nh * dh, self._kv_width()
+        if self.rope and 0 < self.rope_dim < dh:
+            telemetry.count_path("attn.rope.partial")
+        if self.rope and self.rope_type == "yarn":
+            telemetry.count_path("attn.rope.yarn")
         if self._prep_fused(qkv, ctx):
             # one kernel each way for the split, the norms and the
             # rotation (ops/qk_prep_pallas.py); the lines below are its
@@ -1489,7 +1603,9 @@ class AttentionLayer(Layer):
             k = _rms_norm(k, params["knorm"], self.qk_norm_eps, 3)
         if self.rope:
             off = ctx.decode_pos if ctx.decode_pos is not None else 0
-            q, k = self._apply_rope(q, off), self._apply_rope(k, off)
+            rot = (self.rope_dim,) if self.rope_dim else ()
+            q = self._apply_rope(q, off, *rot)
+            k = self._apply_rope(k, off, *rot)
         return q, k, v
 
     def _index_operands(self, seq, params):
@@ -1598,9 +1714,12 @@ class AttentionLayer(Layer):
         """Whether the pass from the qkv dot to the core takes the fused
         kernels: on a TPU (use_pallas), a training or scoring pass on one
         device (no cache position, no mesh: pallas_call has no
-        partitioning rule), a layer with a norm or a rotation to fuse, and
-        a shape and a norm's eps the kernels take (ops.qk_prep_supported)."""
+        partitioning rule), a layer with a norm or a rotation to fuse, a
+        rotation of whole heads (the kernels pair feature i with i + dh/2),
+        and a shape and a norm's eps the kernels take
+        (ops.qk_prep_supported)."""
         return (ops.use_pallas() and (self.rope or self.qk_norm)
+                and not self.rope_dim % self._dh()
                 and ctx.decode_pos is None and ctx.mesh is None
                 and ops.qk_prep_supported(qkv.shape[1], self._dh(),
                                           qkv.shape[2], qkv.dtype,
@@ -2123,6 +2242,11 @@ class MoELayer(Layer):
     the leaf ``expert_bias`` (``expert_bias = 1``: ``nexpert`` float32
     values that choose and never weight, a fixed leaf: no gradient, no
     update; without it b = 0);
+    ``routed_scale``: the routing weights times this factor (1 unless
+    set), so the routed sum with them;
+    ``nshared_hidden`` > 0: a shared expert of that width, of the routed
+    experts' kind, over every row and added once, unweighted (leaves
+    ``shared_wmat``, ``shared_up``, ``shared_down``; sub-scope shared);
     ``expert_act`` = ``relu`` (one matrix an expert, ``nhidden`` outputs),
     ``reglu`` (three: ``relu(x Wg) * (x Wu)`` of width ``nhidden``, then
     ``Wd`` back to the input's width) or ``swiglu`` (the same three with
@@ -2187,9 +2311,15 @@ class MoELayer(Layer):
         self.expert_act = "relu"
         self.score = "softmax"
         self.expert_bias = 0
+        self.routed_scale = 1.0
+        self.nshared_hidden = 0
 
     def set_param(self, name, val):
         super().set_param(name, val)
+        if name == "routed_scale":
+            self.routed_scale = float(val)
+        if name == "nshared_hidden":
+            self.nshared_hidden = int(val)
         if name == "score":
             check(val in ("softmax", "sigmoid"),
                   "score must be softmax or sigmoid")
@@ -2233,6 +2363,11 @@ class MoELayer(Layer):
         check(not self.expert_bias or self.score == "sigmoid",
               "moe: expert_bias chooses among sigmoid scores; it needs "
               "score = sigmoid")
+        check(self.routed_scale > 0, "moe: routed_scale must be positive")
+        check(self.nshared_hidden >= 0 and (
+            not self.nshared_hidden or self._gated()),
+              "moe: the shared expert is one of the routed experts' kind "
+              "and needs expert_act reglu or swiglu")
         check(0 <= self.expert_offset
               and self.expert_offset + self._held() <= self.n_expert,
               "moe: experts [expert_offset, expert_offset + nexpert_held) "
@@ -2256,6 +2391,14 @@ class MoELayer(Layer):
                 rng, (held, din, f), in_num=din, out_num=f)
             out["down"] = self.param.rand_init_weight(
                 rng, (held, f, din), in_num=f, out_num=din)
+        if self.nshared_hidden:
+            fs = self.nshared_hidden
+            out["shared_wmat"] = self.param.rand_init_weight(
+                rng, (din, fs), in_num=din, out_num=fs)
+            out["shared_up"] = self.param.rand_init_weight(
+                rng, (din, fs), in_num=din, out_num=fs)
+            out["shared_down"] = self.param.rand_init_weight(
+                rng, (fs, din), in_num=fs, out_num=din)
         out.update((key, np.zeros((e,), np.float32))
                    for key in self.fixed_keys())
         return out
@@ -2264,6 +2407,10 @@ class MoELayer(Layer):
         return ("gate", "experts") + (
             ("up", "down") if self._gated() else ())
 
+    def _shared_keys(self):
+        return ("shared_wmat", "shared_up", "shared_down") \
+            if self.nshared_hidden else ()
+
     def fixed_keys(self):
         return ("expert_bias",) if self.expert_bias else ()
 
@@ -2271,27 +2418,27 @@ class MoELayer(Layer):
         self.param.save(w)
         import struct
         w.write_raw(struct.pack("<ii", self.n_expert, self.top_k))
-        for key in self._keys() + self.fixed_keys():
+        for key in self._keys() + self._shared_keys() + self.fixed_keys():
             w.write_tensor(params[key])
 
     def load_model(self, r):
         self.param.load(r)
         import struct
         self.n_expert, self.top_k = struct.unpack("<ii", r.read_raw(8))
-        return {key: r.read_tensor()
-                for key in self._keys() + self.fixed_keys()}
+        return {key: r.read_tensor() for key in
+                self._keys() + self._shared_keys() + self.fixed_keys()}
 
     def visit_order(self):
         # ``experts`` is the one matrix of a relu expert and the gate
         # matrix Wg of a reglu or swiglu one; ``gate`` is the router
         return [("wmat", "experts"), ("gate", "gate")] + (
             [("up", "up"), ("down", "down")] if self._gated() else []) + [
-            (key, key) for key in self.fixed_keys()]
+            (key, key) for key in self._shared_keys() + self.fixed_keys()]
 
     def _top(self, logits, bias=None):
         """(T, k) expert indices and their weights, float32: by
         ``score`` and, under sigmoid scores, the expert bias (which
-        chooses and does not weight)."""
+        chooses and does not weight); the weights times ``routed_scale``."""
         k = self.top_k if 0 < self.top_k < self.n_expert else self.n_expert
         # exact-k from top_k indices (a >=kth-value threshold would keep
         # every tied expert — common in bf16)
@@ -2305,6 +2452,8 @@ class MoELayer(Layer):
             idx = jax.lax.top_k(pick, k)[1]
             w = jnp.take_along_axis(s, idx, axis=-1)
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        if self.routed_scale != 1.0:
+            w = w * self.routed_scale
         return idx, w
 
     def _gate_probs(self, x2, gate, bias=None):
@@ -2428,6 +2577,16 @@ class MoELayer(Layer):
                                           mesh, batch_axis=batch_axis)
             else:
                 out = self._dense(x2, probs, params)
+        if self.routed_scale != 1.0:
+            telemetry.gauge("moe.routed_scale", self.routed_scale)
+        if self.nshared_hidden:
+            with sub_scope("shared"):
+                telemetry.count_path("moe.shared")
+                out = out.astype(x2.dtype) + _expert_rows(
+                    self.expert_act, jnp.dot, x2,
+                    {"experts": params["shared_wmat"],
+                     "up": params["shared_up"],
+                     "down": params["shared_down"]})
         out = out.astype(x.dtype)
         if not self._seq:
             return [out.reshape(b, 1, 1, -1)]

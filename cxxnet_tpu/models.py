@@ -421,10 +421,11 @@ def _decoder_lm(vocab: int, dim: int, nhead: int, blocks, eps: float,
     one ``_transformer_block`` (rmsnorm, the weights' spread the global
     normal(0, 0.02)) a dict of ``blocks``, whose entries are that
     function's keyword arguments (``ffn`` the FFN's width, ``mixer``,
-    ``attn_keys``, ``ffn_kind``, ``ffn_keys``, ``router_on_input``) ->
-    rmsnorm ``norm_f`` -> untied vocab head, no bias -> per-position
-    softmax. ``norm_f_keys`` / ``loss_keys``: further lines of the last
-    norm and of the loss."""
+    ``attn_keys``, ``ffn_kind``, ``ffn_keys``, ``router_on_input``, and
+    ``nhead`` where a block has heads of its own count; ``prefix``, the
+    block's names, ``b<i>`` unless given) -> rmsnorm ``norm_f`` -> untied
+    vocab head, no bias -> per-position softmax. ``norm_f_keys`` /
+    ``loss_keys``: further lines of the last norm and of the loss."""
     txt = """
 netconfig = start
 layer[0->emb] = embed:emb
@@ -434,9 +435,11 @@ layer[0->emb] = embed:emb
 """ % (vocab, dim)
     node = "emb"
     for i, blk in enumerate(blocks):
+        blk = dict(blk)
         t, node = _transformer_block(
-            "b%d" % i, node, dim, nhead, norm="rmsnorm",
-            norm_keys="eps = %.10g\n" % eps, init_sigma=None, **blk)
+            blk.pop("prefix", "b%d" % i), node, dim, blk.pop("nhead", nhead),
+            norm="rmsnorm", norm_keys="eps = %.10g\n" % eps,
+            init_sigma=None, **blk)
         txt += "\n" + t
     txt += """
 layer[%s->nf] = rmsnorm:norm_f
@@ -758,6 +761,99 @@ def lfm2_moe_conf(seq: int = 8192, batch_size: int = 2, dev: str = "tpu",
     bias no step at all: it is a fixed leaf)."""
     return _lm_conf(lfm2_moe_netconfig(**kw), LFM2_ADAMW, seq, batch_size,
                     dev, extra_cfg, _next_token(seq))
+
+
+# Laguna-XS.2's ``layer_types`` as published: a full-attention layer and
+# three sliding-window layers, ten times
+LAGUNA_LAYER_TYPES = ("full_attention", "sliding_attention",
+                      "sliding_attention", "sliding_attention") * 10
+# the full layers' rotation, ``rope_parameters.full_attention``: YaRN over
+# half of each head (the window layers' is plain, over the whole head)
+LAGUNA_FULL_ROPE = dict(theta=5e5, rope_dim=64, factor=64.0,
+                        original_len=4096, beta_fast=64.0, beta_slow=1.0,
+                        attention_factor=1.4158883083359672)
+
+
+def laguna_moe_netconfig(vocab: int = 100352, dim: int = 2048,
+                         full_heads: int = 48, window_heads: int = 64,
+                         nkvhead: int = 8, head_dim: int = 128,
+                         layer_types=LAGUNA_LAYER_TYPES, window: int = 512,
+                         full_rope=None, window_theta: float = 1e4,
+                         n_dense: int = 1,
+                         dense_width: int = 8192, n_expert: int = 256,
+                         top_k: int = 8, expert_width: int = 512,
+                         shared_width: int = 512, routed_scale: float = 2.5,
+                         n_held: int = 0, expert_offset: int = 0,
+                         eps: float = 1e-6, remat: str = "moe ffn") -> str:
+    """Laguna-XS.2 (poolside; the defaults are its published config.json,
+    ``model_type: laguna``) trained next-token, from the netconfig DSL:
+    embed -> one block a ``layer_types`` entry [ rmsnorm, attention +
+    residual, rmsnorm, an FFN + residual ] -> rmsnorm -> untied vocab
+    head -> per-position softmax. Attention is grouped-query on
+    ``nkvhead`` heads of ``head_dim`` with each head's output times a
+    sigmoid gate of the normed input (``head_gate``); its kind is the
+    layer's: ``full_attention`` causal with ``full_heads`` query heads and
+    YaRN rotation over the first ``rope_dim`` features of a head
+    (``full_rope``: ``LAGUNA_FULL_ROPE``'s keys), ``sliding_attention``
+    causal inside a window of ``window`` keys with ``window_heads`` query
+    heads and plain rotation over the whole head at ``window_theta``. The
+    first ``n_dense`` blocks' FFN is a dense SwiGLU of ``dense_width``
+    (``ffn``), the others' a ``moe`` layer: SwiGLU experts top_k of
+    n_expert whose router reads the normed stream, sigmoid scores, the
+    top k weighted by their scores over their sum times ``routed_scale``,
+    and one shared SwiGLU expert of ``shared_width`` over every row. No
+    bias anywhere; matrices start at normal(0, 0.02) and the embedding at
+    normal(0, 1), for ``smallthinker_netconfig``'s reason. The window
+    blocks are named ``b<i>w`` (``b<i>w_att``), the full ones ``b<i>``.
+
+    ``n_held`` / ``expert_offset`` / ``vocab`` / ``layer_types`` /
+    ``n_dense`` cut one chip's share of an expert-parallel deployment as
+    ``smallthinker_netconfig``'s do; ``remat`` names the layer kinds whose
+    activations are made again in the backward pass (``moe``, ``ffn``,
+    ``attention``, blank-separated)."""
+    kinds = remat.split()
+    full = dict(LAGUNA_FULL_ROPE, **(full_rope or {}))
+    common = ("nkvhead = %d\nhead_dim = %d\ncausal = 1\nhead_gate = 1\n"
+              "rope = 1\n" % (nkvhead, head_dim))
+    attn = {"full_attention": (full_heads, "", common + (
+                "rope_base = %.10g\nrope_dim = %d\nrope_type = yarn\n"
+                "yarn_factor = %.10g\nyarn_original_len = %d\n"
+                "yarn_beta_fast = %.10g\nyarn_beta_slow = %.10g\n"
+                "yarn_attention_factor = %.17g\n"
+                % (full["theta"], full["rope_dim"], full["factor"],
+                   full["original_len"], full["beta_fast"],
+                   full["beta_slow"], full["attention_factor"]))),
+            "sliding_attention": (window_heads, "w", common + (
+                "rope_base = %.10g\nattn_window = %d\n"
+                % (window_theta, window)))}
+    moe = _moe_keys(n_expert, top_k, n_held, expert_offset, "swiglu", remat,
+                    "score = sigmoid\nrouted_scale = %.10g\n"
+                    "nshared_hidden = %d\n" % (routed_scale, shared_width))
+    dense = "remat = %d\n" % ("ffn" in kinds)
+    blocks = []
+    for i, kind in enumerate(layer_types):
+        heads, tag, keys = attn[kind]
+        ffn = (dict(ffn=dense_width, ffn_kind="ffn", ffn_keys=dense)
+               if i < n_dense else
+               dict(ffn=expert_width, ffn_kind="moe", ffn_keys=moe,
+                    router_on_input=False))
+        blocks.append(dict(ffn, nhead=heads, prefix="b%d%s" % (i, tag),
+                           attn_keys=keys + "remat = %d\n"
+                           % ("attention" in kinds)))
+    return _decoder_lm(vocab, dim, full_heads, blocks, eps)
+
+
+LAGUNA_ADAMW = SDAR_MOE_ADAMW
+
+
+def laguna_moe_conf(seq: int = 8192, batch_size: int = 1, dev: str = "tpu",
+                    extra_cfg: str = "", **kw) -> str:
+    """The whole training conf of the Laguna recipe: the netconfig, the
+    shapes, the next-token labels, and ``smallthinker_conf``'s AdamW
+    (assumed there as here; the norms' gains take no decay, every matrix,
+    the heads' gates and the shared expert's among them, 0.1)."""
+    return _lm_conf(laguna_moe_netconfig(**kw), LAGUNA_ADAMW, seq,
+                    batch_size, dev, extra_cfg, _next_token(seq))
 
 
 def transformer_lm_conf(vocab: int = 50, seq: int = 16,

@@ -2191,12 +2191,15 @@ class Trainer:
     # ------------------------------------------------------------------
     # every tag a layer's visit_order gives: fullc/conv (wmat, bias),
     # attention (wo; qnorm, knorm with qk_norm = 1; the indexer's widx_q,
-    # widx_k, widx_w, idx_gain, idx_bias with attn_mask = dsa), moe (gate =
-    # the router, up, down), rmsnorm (gain)
+    # widx_k, widx_w, idx_gain, idx_bias with attn_mask = dsa; wgate with
+    # head_gate = 1), moe (gate = the router, up, down; shared_wmat,
+    # shared_up, shared_down with nshared_hidden; expert_bias), rmsnorm
+    # (gain), shortconv (w_in, w_conv, w_out)
     _WEIGHT_TAGS = ("bias", "wmat", "wo", "gate", "up", "down", "gain",
                     "qnorm", "knorm", "widx_q", "widx_k", "widx_w",
                     "idx_gain", "idx_bias", "w_in", "w_conv", "w_out",
-                    "expert_bias")
+                    "expert_bias", "wgate", "shared_wmat", "shared_up",
+                    "shared_down")
 
     def set_weight(self, value: np.ndarray, layer_name: str, tag: str) -> None:
         check(tag in self._WEIGHT_TAGS,

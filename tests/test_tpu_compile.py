@@ -204,6 +204,37 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
     # layout and back (436 MB); in the step XLA makes q, k, v itself
 
 
+# `laguna-ep8-train-8k`'s attention: 48 query heads on 8 key-value
+# heads of 128, causal (query groups of 6, the first group count that is no
+# power of two), and 64 on 8 in a window of 512 keys (groups of 8), 8,192
+# rows. Under the window `_tiling` gives resident tiles of 256 and streamed
+# ones of 512, so a band of 512 keys meets two or three streamed tiles a
+# resident one; both take the fused backward
+@pytest.mark.parametrize("nh,window", [(48, 0), (64, 512)],
+                         ids=["full-48-on-8", "window-512-64-on-8"])
+def test_flash_kernels_compile_at_laguna_shapes(one_chip, nh, window):
+    from cxxnet_tpu.ops import flash_attn
+    q = jax.ShapeDtypeStruct((1, nh, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    tiles = flash_attn._tiling(8192, 128, 2, window)
+    if window:
+        assert (tiles[0].bq, tiles[0].bk) == (256, 512)
+    assert flash_attn.fused_bwd_supported(8192, 128, jnp.bfloat16, window)
+
+    def both(q_, k_, v_, do_):
+        out, vjp = jax.vjp(lambda a, b, c: flash_attn.flash_attention(
+            a, b, c, True, None, False, window), q_, k_, v_)
+        return (out,) + vjp(do_)
+    compiled = jax.jit(both).lower(q, kv, kv, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall('custom_call_target="tpu_custom_call"', text)) == 2
+    assert [o.shape for o in compiled.out_info] == [
+        q.shape, q.shape, kv.shape, kv.shape]
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
 # the block-diffusion cell's attention (`sdar-ep8-train-8k`): 32 query heads
 # on 4 key-value heads of 128, 16,384 rows (a noised and a clean copy of
 # 8,192 tokens), block length 4. The two-run walk is scalar arithmetic in
@@ -281,7 +312,9 @@ def test_flash_kernels_compile_under_a_selection(one_chip):
     (8192, 28, 4, 128, False, True, jnp.bfloat16),    # smallthinker-ep4-...
     (2048, 8, 2, 128, True, False, jnp.bfloat16),
     (1024, 4, 2, 256, True, True, jnp.float32),
-], ids=["sdar", "smallthinker", "norm-alone", "heads-of-256-float32"])
+    (8192, 64, 8, 128, False, True, jnp.bfloat16),    # laguna's window layers
+], ids=["sdar", "smallthinker", "norm-alone", "heads-of-256-float32",
+        "laguna-window"])
 def test_qk_prep_kernels_compile_at_the_cells_shapes(one_chip, L, nh, nkv,
                                                      dh, norm, rope, dtype):
     from cxxnet_tpu.ops import qk_prep_pallas

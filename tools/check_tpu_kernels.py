@@ -22,6 +22,11 @@ Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      python tools/check_tpu_kernels.py bwd   (the flash backward alone,
      the fused kernel beside the split pair, at smallthinker's, sdar's and
      lfm2's shapes: worst gap, ms and TFLOP/s; keye's is in `dsa`)
+     python tools/check_tpu_kernels.py laguna   (the flash kernels at
+     `laguna-ep8-train-8k`'s two attention shapes alone: 48 query heads on
+     8 key-value heads of 128, causal, and 64 on 8 in a window of 512,
+     8,192 rows; against the dense reference, then ms and TFLOP/s by the
+     kept scores, ~2 min)
 """
 
 import functools
@@ -53,6 +58,9 @@ def main():
         return
     if sys.argv[1:] == ["bwd"]:
         _check_fused_backward_at_the_cells_shapes(np.random.RandomState(0))
+        return
+    if sys.argv[1:] == ["laguna"]:
+        _check_flash_at_laguna_shapes(np.random.RandomState(0))
         return
     from cxxnet_tpu import ops
     from cxxnet_tpu.ops import pallas_kernels
@@ -426,21 +434,35 @@ def _check_fused_backward_at_the_cells_shapes(rs):
 def _check_flash_at_the_cells_shape(rs):
     """The language-model cell's attention (`smallthinker-ep4-train-8k`:
     28 query heads on 4 key-value heads of 128, 8,192 tokens, bf16, the
-    global layer and a 4,096 window): forward and the three gradients
-    against the dense reference, one key-value head's group at a time
-    (its float32 scores are 1.9 GB), then the kernels' time and their
-    rate by the mask's FLOPs, so that a reader has both without a trace."""
+    global layer and a 4,096 window): ``_flash_against_dense``."""
+    _flash_against_dense(rs, "the cell's shape", 28, 4, (0, 4096))
+
+
+def _check_flash_at_laguna_shapes(rs):
+    """`laguna-ep8-train-8k`'s attention, 8,192 tokens, bf16: the full
+    layers' 48 query heads on 8 key-value heads of 128, causal (query
+    groups of 6), and the window layers' 64 on 8 in a window of 512
+    (groups of 8): ``_flash_against_dense``."""
+    _flash_against_dense(rs, "laguna's full layers (48 on 8)", 48, 8, (0,))
+    _flash_against_dense(rs, "laguna's window layers (64 on 8)", 64, 8,
+                         (512,))
+
+
+def _flash_against_dense(rs, what, nh, nkv, windows, L=8192, d=128):
+    """One attention shape: forward and the three gradients against the
+    dense reference, one key-value head's group at a time (its float32
+    scores are 1.9 GB), then the kernels' time and their rate by the
+    mask's FLOPs, so that a reader has both without a trace."""
     from benchmark import lm_flops
     from cxxnet_tpu.ops import flash_attn
     from cxxnet_tpu.parallel.ring import attention_reference
-    nh, nkv, L, d = 28, 4, 8192, 128
     grp = nh // nkv
     q, do = (jnp.asarray(rs.randn(1, nh, L, d), jnp.bfloat16)
              for _ in range(2))
     k, v = (jnp.asarray(rs.randn(1, nkv, L, d), jnp.bfloat16)
             for _ in range(2))
 
-    for window in (0, 4096):
+    for window in windows:
         flash = jax.jit(functools.partial(_both, lambda q_, k_, v_: (
             flash_attn.flash_attention(q_, k_, v_, True, None, False,
                                        window))))
@@ -460,10 +482,10 @@ def _check_flash_at_the_cells_shape(rs):
             q_, k_, v_, True, None, False, window))
         flops = lm_flops.flash_attention(L, nh, nkv, d, window)["flops"]
         t_f, t_fb = _ms(fwd, q, k, v), _ms(flash, q, k, v, do)
-        print("flash attention at the cell's shape, window=%d: OK; forward "
+        print("flash attention at %s, window=%d: OK; forward "
               "%.2f ms = %.1f TFLOP/s, forward + backward %.2f ms = %.1f "
               "TFLOP/s (by the mask's FLOPs, x1 and x3.5)"
-              % (window, t_f, flops / t_f / 1e9, t_fb,
+              % (what, window, t_f, flops / t_f / 1e9, t_fb,
                  3.5 * flops / t_fb / 1e9))
 
 
