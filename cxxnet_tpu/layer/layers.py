@@ -1731,11 +1731,15 @@ class AttentionLayer(Layer):
         tile as gauges, and the (block_q, block_k) score tiles of one
         head's grid that take no mask, take one, and are never visited.
         In training also which backward the shape takes: the fused kernel
-        (``attn.bwd.fused``) or the dQ and dK/dV pair (``attn.bwd.split``)."""
+        (``attn.bwd.fused``) or the dQ and dK/dV pair (``attn.bwd.split``);
+        and ``attn.flash.band`` where a window narrower than the tiles takes
+        the band walk, whose sub-tiles are then the tiles counted."""
         from ..utils import telemetry
         sched = ops.flash_schedule(q, k, causal, self.attn_window,
                                    self._blockdiff(), select)
         telemetry.count_path("attn.flash")
+        if sched["band"]:
+            telemetry.count_path("attn.flash.band")
         if train:
             fused = ops.flash_fused_bwd_supported(
                 q.shape[2], q.shape[3], q.dtype, self.attn_window,

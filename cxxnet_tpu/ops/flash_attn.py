@@ -20,6 +20,17 @@ static-shape predicate each: only tiles that straddle the diagonal, the
 window's trailing edge or the tail take ``_mask``. Tiles are sized from
 (L, d, dtype, window) for the chip's VMEM, not a constant.
 
+A window narrower than those tiles, of 128 keys or more, has a walk of its
+own, the band walk (``BandTiles``): resident and streamed blocks of one
+size no narrower than the window, so that a step meets the band either on
+its diagonal or one block before it, and a body that takes the step in
+(sub x sub) sub-tiles by a static plan of each of those offsets
+(``_Geom.band_plan``): a resident sub-row's kept sub-columns as runs,
+each run one product, only the runs on the band's two edges masked, every
+other sub-tile never visited. The forward folds a sub-row's runs into one
+online-softmax update; the fused backward walks the same band transposed,
+a kv sub-row at a time.
+
 A third mask beside causal and causal-with-window (PR 36): block
 diffusion's training mask (``block_len`` > 0). The rows are a noised and
 a clean copy of one sequence; a resident tile then meets TWO runs of
@@ -143,6 +154,9 @@ class _Geom(NamedTuple):
     # tile are kept is data, an operand of the kernel, so no visited tile
     # is ``full`` and ``mask`` is not asked
     select: bool = False
+    # the band walk (``BandTiles``; causal with a window, bq == bk): a
+    # step takes its blocks in (sub x sub) sub-tiles by ``band_plan``
+    band: bool = False
 
     @property
     def masks(self) -> bool:
@@ -329,6 +343,45 @@ class _Geom(NamedTuple):
                 keep = jnp.logical_and(keep, qpos - kpos < self.window)
         return jnp.where(keep, s, NEG_INF)
 
+    def band_offsets(self):
+        """The band walk: where a live forward step's streamed block starts
+        against its resident tile, ``j * bk - i * bq``, each once (0 on the
+        diagonal, -bk one block before it). The backward's steps are the
+        same pairs turned: their offsets are these negated."""
+        lo, hi = self.kv_range(np.arange(self.n_q), np)
+        return sorted({int(j * self.bk - i * self.bq)
+                       for i in range(self.n_q)
+                       for j in range(lo[i], hi[i] + 1)})
+
+    def band_plan(self, offset: int, turned: bool = False):
+        """The band walk's static plan of a step whose streamed block
+        starts ``offset`` rows after its resident tile: for each sub-row of
+        the resident tile its runs of kept sub-columns, ``(first, count,
+        masked)``, by ``kind`` asked at the sub-tile's grain; a run is one
+        product, and only a masked one pays ``mask``. ``turned``: the
+        resident tile holds keys and the streamed block queries (the
+        backward). The tail is left out: a causal row keeps no key past
+        itself, so the padded keys are masked already wherever a real row
+        meets them."""
+        sub, n = self.sub, self.bq // self.sub
+        rows = np.arange(n)[:, None] * sub
+        cols = offset + np.arange(self.bk // sub)[None, :] * sub
+        q0, k0 = (cols, rows) if turned else (rows, cols)
+        needed, full = self._replace(kv_len=1 << 30).kind(
+            q0, sub, k0, sub, np)
+        plan = []
+        for r in range(n):
+            runs = []
+            for c in np.nonzero(needed[r])[0].tolist():
+                masked = not full[r, c]
+                if runs and runs[-1][0] + runs[-1][1] == c \
+                        and runs[-1][2] == masked:
+                    runs[-1] = (runs[-1][0], runs[-1][1] + 1, masked)
+                else:
+                    runs.append((c, 1, masked))
+            plan.append(tuple(runs))
+        return tuple(plan)
+
     def _block_of(self, pos):
         """Block index of an array of positions (none negative)."""
         B = self.block_len
@@ -339,7 +392,22 @@ class _Geom(NamedTuple):
 
 def tile_counts(g: _Geom) -> Tuple[int, int, int]:
     """(full, edge, skipped) score tiles of (bq, sub) one head's forward
-    grid holds: what telemetry's ``flash.tiles.*`` count."""
+    grid holds: what telemetry's ``flash.tiles.*`` count. Under the band
+    walk the tiles are its (sub x sub) sub-tiles, as its plans visit
+    them."""
+    if g.band:
+        counts = {}
+        for offset in g.band_offsets():
+            runs = [r for row in g.band_plan(offset) for r in row]
+            counts[offset] = np.array(
+                [sum(n for _, n, masked in runs if not masked),
+                 sum(n for _, n, masked in runs if masked)])
+        lo, hi = g.kv_range(np.arange(g.n_q), np)
+        n_full, n_edge = sum(counts[j * g.bk - i * g.bq]
+                             for i in range(g.n_q)
+                             for j in range(lo[i], hi[i] + 1)).tolist()
+        total = (g.n_q * g.bq // g.sub) * (g.n_k * g.bk // g.sub)
+        return n_full, n_edge, total - n_full - n_edge
     q0, k0 = np.meshgrid(np.arange(g.n_q) * g.bq,
                          np.arange(g.n_k * g.bk // g.sub) * g.sub,
                          indexing="ij")
@@ -442,9 +510,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + _dot(
             p.astype(v.dtype), v)
 
-    for c in range(g.bk // sub):
-        _visit(g, i * g.bq, g.bq, j * g.bk + c * sub, sub, j <= hi,
-               functools.partial(body, c))
+    if g.band:
+        _band_steps(g, g.band_offsets(), j * g.bk - i * g.bq, j <= hi,
+                    functools.partial(_fwd_strip, q_ref, k_ref, v_ref,
+                                      m_scr, l_scr, acc_scr, scale=scale,
+                                      g=g, q0=i * g.bq, k0=j * g.bk))
+    else:
+        for c in range(g.bk // sub):
+            _visit(g, i * g.bq, g.bq, j * g.bk + c * sub, sub, j <= hi,
+                   functools.partial(body, c))
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _():
@@ -452,6 +526,99 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc_scr[...] / _lanes(l, d)).astype(o_ref.dtype)
         # lse = m + log(l): per-row logsumexp for the backward recompute
         lse_ref[0] = _to_row(m_scr[...] + jnp.log(l))
+
+
+def _band_steps(g: _Geom, offsets, offset, live, strip, turned=False):
+    """The band walk of one grid step: for each offset a step can hold,
+    where the step holds it, ``strip(r, runs)`` for each resident sub-row
+    of that offset's plan that keeps a score (``_Geom.band_plan``). Rows
+    whose runs are the row before's moved one sub-column on (the band's
+    steady part) go through a loop over a traced ``r``, ``_STEADY_ROWS``
+    of them an iteration, so that the kernel's text does not grow with
+    the block."""
+    for off in offsets:
+        plan = g.band_plan(off, turned)
+
+        @pl.when(jnp.logical_and(live, offset == off))
+        def _(plan=plan):
+            for r0, r1, runs in _steady(plan):
+                def row(r, r0=r0, runs=runs):
+                    strip(r, tuple((c + r - r0, n, masked)
+                                   for c, n, masked in runs))
+
+                def rows(i, carry, r0=r0, row=row):
+                    for u in range(_STEADY_ROWS):
+                        row(r0 + i * _STEADY_ROWS + u)
+                    return carry
+                loops = (r1 - r0) // _STEADY_ROWS
+                if loops < 2:       # a single pass is written out
+                    loops = 0
+                else:
+                    jax.lax.fori_loop(0, loops, rows, 0)
+                for r in range(r0 + loops * _STEADY_ROWS, r1):
+                    row(r)
+
+
+# sub-rows an iteration of the steady rows' loop takes: a TPU v5e read a
+# window layer's kernels at 7.90 / 7.13 / 6.68 ms at 1 / 2 / 4 (blocks of
+# 2,048) against 6.23 with every sub-row written out, whose bodies took
+# three times as long to trace and lower (PERF.md section 6)
+_STEADY_ROWS = 4
+
+
+def _steady(plan):
+    """A band plan's rows that keep a score, as (first, end, first's runs):
+    each row from first to end has the row before's runs one sub-column
+    on."""
+    out = []
+    for r, runs in enumerate(plan):
+        if not runs:
+            continue
+        if out and out[-1][1] == r and runs == tuple(
+                (c + r - out[-1][0], n, m) for c, n, m in out[-1][2]):
+            out[-1] = (out[-1][0], r + 1, out[-1][2])
+        else:
+            out.append((r, r + 1, runs))
+    return out
+
+
+def _at(start, size):
+    """``size`` rows (or lanes) from ``start``: a static slice, or a
+    dynamic one whose start is a multiple of 128."""
+    if isinstance(start, int):
+        return slice(start, start + size)
+    return pl.ds(pl.multiple_of(start, _LANES), size)
+
+
+def _fwd_strip(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, r, runs, *,
+               scale, g, q0, k0):
+    """Sub-row ``r`` of the resident q tile (which starts at row q0) over
+    its runs of the streamed kv block (at k0): one product pair a run and
+    one online-softmax update for them all."""
+    sub, d = g.sub, q_ref.shape[-1]
+    rows = _at(r * sub, sub)
+    q = q_ref[0, rows, :]
+    s = []
+    for c, n, masked in runs:
+        sc = _dot(q, k_ref[0, _at(c * sub, n * sub), :], _NT) * scale
+        if masked:
+            sc = g.mask(sc, q0 + r * sub, k0 + c * sub, 1)
+        s.append(sc)
+    m_prev = m_scr[rows, :]
+    m_new = m_prev
+    for sc in s:
+        m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_scr[rows, :] * alpha
+    acc = acc_scr[rows, :] * _lanes(alpha, d)
+    for (c, n, _), sc in zip(runs, s):
+        p = jnp.exp(sc - _lanes(m_new, n * sub))
+        l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc + _dot(p.astype(v_ref.dtype),
+                         v_ref[0, _at(c * sub, n * sub), :])
+    m_scr[rows, :] = m_new
+    l_scr[rows, :] = l_new
+    acc_scr[rows, :] = acc
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -520,9 +687,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dst = pt * (dpt - delta_ref[0, :, c * sub:(c + 1) * sub])
         dk_scr[...] += _dot(dst.astype(q.dtype), q)
 
-    for c in range(g.bq // sub):
-        _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
-               functools.partial(body, c))
+    if g.band:
+        _band_steps(g, [-o for o in g.band_offsets()], i * g.bq - j * g.bk,
+                    i <= hi, functools.partial(
+                        _bwd_strip, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                        delta_ref, dk_scr, dv_scr, scale=scale, g=g,
+                        q0=i * g.bq, k0=j * g.bk, row0=0), turned=True)
+    else:
+        for c in range(g.bq // sub):
+            _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
+                   functools.partial(body, c))
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _():
@@ -574,9 +748,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         cols = pl.ds(pl.multiple_of(i * g.bq + c * sub, sub), sub)
         dqt_scr[:, cols] += _dot(kt_scr[...], dst)          # (d, sub)
 
-    for c in range(g.bq // sub):
-        _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
-               functools.partial(body, c))
+    if g.band:
+        _band_steps(g, [-o for o in g.band_offsets()], i * g.bq - j * g.bk,
+                    i <= hi, functools.partial(
+                        _bwd_strip, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                        delta_ref, dk_scr, dv_scr, scale=scale, g=g,
+                        q0=i * g.bq, k0=j * g.bk, row0=j * g.bk,
+                        dq=(dqt_scr, kt_scr)), turned=True)
+    else:
+        for c in range(g.bq // sub):
+            _visit(g, i * g.bq + c * sub, sub, j * g.bk, g.bk, i <= hi,
+                   functools.partial(body, c))
 
     @pl.when((j == pl.num_programs(2) - 1) & last)
     def _():
@@ -594,6 +776,39 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[rows, :].astype(dv_ref.dtype)
 
 
+def _bwd_strip(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_scr,
+               dv_scr, r, runs, *, scale, g, q0, k0, row0, dq=None):
+    """The band walk of the kernels on transposed scores: sub-row ``r`` of
+    the resident kv tile (which starts at row k0, and at row ``row0`` of
+    ``dk_scr`` / ``dv_scr``) over its runs of the streamed q block (at
+    q0); dK and dV of the sub-row gathered over its runs and added once.
+    ``dq``: the fused kernel's (dQᵀ, Kᵀ) scratch, dQᵀ added a run at a
+    time."""
+    sub = g.sub
+    rows = _at(r * sub, sub)
+    k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+    if dq is not None:
+        dqt_scr, kt = dq[0], dq[1][:, rows]
+    dk = dv = 0.0
+    for c, n, masked in runs:
+        cols = _at(c * sub, n * sub)
+        q, do = q_ref[0, cols, :], do_ref[0, cols, :]
+        st = _dot(k, q, _NT) * scale                        # (sub, n sub)
+        if masked:
+            st = g.mask(st, q0 + c * sub, k0 + r * sub, 0)
+        pt = jnp.exp(st - lse_ref[0, :, cols])
+        dv = dv + _dot(pt.astype(do.dtype), do)
+        dpt = _dot(v, do, _NT)
+        dst = (pt * (dpt - delta_ref[0, :, cols])).astype(q.dtype)
+        dk = dk + _dot(dst, q)
+        if dq is not None:
+            at = _at(q0 + c * sub, n * sub)
+            dqt_scr[:, at] += _dot(kt, dst)                 # (d, n sub)
+    at = _at(row0 + r * sub, sub)
+    dk_scr[at, :] += dk
+    dv_scr[at, :] += dv
+
+
 class Tiles(NamedTuple):
     """One kernel's tiling: q and kv rows a grid step holds, and the
     columns of the streamed block (kv; q in the dK/dV and the fused
@@ -601,6 +816,14 @@ class Tiles(NamedTuple):
     bq: int
     bk: int
     sub: int
+    band = False        # not a field: ``BandTiles`` sets it
+
+
+class BandTiles(Tiles):
+    """The band walk's tiles (``_Geom.band_plan``): square blocks whose
+    bodies take both in (sub x sub) sub-tiles."""
+    __slots__ = ()
+    band = True
 
 
 def _vmem_bytes(kernel: str, t: Tiles, d: int, itemsize: int) -> int:
@@ -664,7 +887,10 @@ def _tiling(L: int, d: int, itemsize: int, window: int,
     with it, its tiles do not. Under the block-diffusion mask the L rows
     are two copies of L / 2 positions, a query sees the keys of about its
     position as a causal one does, and the sizes are those of L / 2, cut
-    to divide it: no tile straddles the copies."""
+    to divide it: no tile straddles the copies. A window of a sub-tile's
+    width or more but no wider than these tiles span takes the band walk's
+    tiles for the forward and the dK/dV (and fused) kernels instead
+    (``_band_tiles``)."""
     half = L // 2 if block_len else 0
     L = half or L
     span = min(L, window) if window else L
@@ -694,7 +920,32 @@ def _tiling(L: int, d: int, itemsize: int, window: int,
             sub_f, sub_b = min(sub_f, stream), min(sub_b, stream)
         else:
             res //= 2
-    return tiles()
+    fwd, dq, dkv = tiles()
+    band = None if half else _band_tiles(L, window, fwd.bq + fwd.bk)
+    if band and all(_vmem_bytes(kernel, band, d, itemsize) <= VMEM_BUDGET
+                    for kernel in ("fwd", "dkv", "bwd")):
+        return band, dq, band
+    return fwd, dq, dkv
+
+
+def _band_tiles(L: int, window: int, span: int) -> Optional[Tiles]:
+    """The band walk's tiles, for a window of at least a sub-tile, at most
+    the row walk's resident tile and streamed block together (``span``;
+    past it those tiles are sized from the window and visit little past
+    it) and at most a block: square blocks of up to 2,048 rows in
+    sub-tiles of 128, by what a TPU v5e read (PERF.md section 6: a
+    band of 512 keys at 8,192 rows, forward + backward 7.93 / 6.74 / 6.23
+    ms at blocks of 512 / 1,024 / 2,048, the grid steps' fixed cost;
+    sub-tiles of 256 read 5.88 ms for 1.5 visited scores a kept one
+    against 1.25). The walk visits about (window + 128) / window of the
+    kept scores; under a sub-tile's width 128 x 128 tiles already visit
+    what it would. None where the window takes the row walk. The dQ
+    kernel of the split backward keeps the row walk's tiles; its dK/dV
+    kernel walks the band as the fused one does."""
+    block = _fit(L, 2048)
+    if _LANES <= window <= min(span, block):
+        return BandTiles(block, block, _LANES)
+    return None
 
 
 def supports(L: int, d: int, block_len: int = 0) -> bool:
@@ -787,6 +1038,7 @@ def _padded_len(L: int, block: int) -> int:
     return -(-L // block) * block
 
 
+@functools.lru_cache(maxsize=None)
 def _kv_row_map(nh: int, nkv: int):
     """Grid row (over b*nh) -> K/V array row (over b*nkv): grouped-query
     attention reads the SHARED kv head of each query-head group straight
@@ -809,8 +1061,14 @@ def _geom(t: Tiles, L: int, causal: bool, window: int,
         assert L % 2 == 0 and L // 2 % t.bq == 0 and L // 2 % t.bk == 0, \
             "block diffusion: tiles (%d, %d) must divide each copy's %d " \
             "rows" % (t.bq, t.bk, L // 2)
+    if t.band:
+        assert causal and window > 0 and not block_len and not select \
+            and t.bq == t.bk and t.bq % t.sub == 0, \
+            "the band walk: causal with a window, square blocks of whole " \
+            "sub-tiles"
     return _Geom(t.bq, t.bk, t.sub, -(-L // t.bq), -(-L // t.bk),
-                 bool(causal), int(window), L, int(block_len), bool(select))
+                 bool(causal), int(window), L, int(block_len), bool(select),
+                 bool(t.band))
 
 
 def _params(kernel, t: Tiles, d, dtype, interpret, select=False, L=0):
@@ -984,6 +1242,14 @@ def _bwd_call(qf, kf, vf, dof, lse, delta, L, grp, t: Tiles, causal,
     )(qf, kf, vf, dof, lse, delta, *sel_args)
 
 
+# the band walk's two kernels under jit, every argument past the arrays
+# static: all layers of one shape share one trace and one lowering of
+# their bodies, which hold the plans' sub-rows written out (three window
+# layers traced apiece added seconds to a step's build)
+_fwd_band_call = jax.jit(_fwd_call, static_argnums=tuple(range(3, 12)))
+_bwd_band_call = jax.jit(_bwd_call, static_argnums=tuple(range(6, 15)))
+
+
 def _shape_tiles(q, k, window, tiles, block_len=0):
     b, h, L, d = q.shape
     assert h % k.shape[1] == 0, "query heads must be a multiple of kv heads"
@@ -1000,13 +1266,15 @@ def schedule(q, k, causal: bool, window: int = 0,
     kv columns one pass of its body takes (``block_q``, ``block_k``), and
     how many such score tiles of one head's grid hold no masked score,
     straddle an edge, or are never visited (``full``, ``edge``,
-    ``skipped``). Under a selection (``select``) every visited tile is an
-    edge tile: its mask is data."""
+    ``skipped``); ``band``: whether it takes the band walk, whose tiles
+    are its (sub x sub) sub-tiles. Under a selection (``select``) every
+    visited tile is an edge tile: its mask is data."""
     fwd = _shape_tiles(q, k, window, None, block_len)[0]
     full, edge, skipped = tile_counts(
         _geom(fwd, q.shape[2], causal, window, block_len, select))
-    return {"block_q": fwd.bq, "block_k": fwd.sub, "full": full,
-            "edge": edge, "skipped": skipped}
+    return {"block_q": fwd.sub if fwd.band else fwd.bq, "block_k": fwd.sub,
+            "full": full, "edge": edge, "skipped": skipped,
+            "band": fwd.band}
 
 
 def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None,
@@ -1019,9 +1287,10 @@ def _flash_fwd(q, k, v, causal, scale, interpret, window=0, tiles=None,
     Lq, Lk = _padded_len(L, t.bq), _padded_len(L, t.bk)
     qf = _pad_seq(_merge_bh(q), Lq)
     kf, vf = (_pad_seq(_merge_bh(x), Lk) for x in (k, v))
-    out, lse = _fwd_call(qf, kf, vf, L, _kv_row_map(h, k.shape[1]), t,
-                         causal, scale, window, interpret, block_len,
-                         None if sel is None else _pad_sel(sel, Lq, Lk))
+    call = _fwd_band_call if t.band else _fwd_call
+    out, lse = call(qf, kf, vf, L, _kv_row_map(h, k.shape[1]), t, causal,
+                    scale, window, interpret, block_len,
+                    None if sel is None else _pad_sel(sel, Lq, Lk))
     out = out[:, :L].reshape(b, h, L, d)
     # the residual is trimmed to L: the backward pads to its own tiles
     return out, (q, k, v, out, lse[:, :, :L])
@@ -1060,8 +1329,9 @@ def _flash_bwd(causal, scale, interpret, window, tiles, block_len, res, g,
 
     if fused:
         args, mask = padded(t_dkv)
-        dq, dk, dv = _bwd_call(*args, L, h // nkv, t_dkv, causal, scale,
-                               window, interpret, block_len, mask)
+        call = _bwd_band_call if t_dkv.band else _bwd_call
+        dq, dk, dv = call(*args, L, h // nkv, t_dkv, causal, scale, window,
+                          interpret, block_len, mask)
     else:
         args, mask = padded(t_dq)
         dq = _dq_call(*args, L, _kv_row_map(h, nkv), t_dq, causal, scale,

@@ -27,7 +27,8 @@ arxiv 1802.04799). This module is that measurement substrate:
   a layer took when its step was traced (``moe.sparse`` / ``moe.dense``,
   ``attn.flash`` / ``attn.dense``, once per traced layer; beside
   ``attn.flash`` the tiles its static schedule holds, ``flash.tiles.full``
-  / ``.edge`` / ``.skipped``): like the phase
+  / ``.edge`` / ``.skipped``, and ``attn.flash.band`` where it takes the
+  band walk of a short window): like the phase
   account it is the process's and outlives ``reset()``, so that a run that
   enabled nothing can still say what it timed.
 * **kept spans** — ``telemetry.span("train.h2d", keep=True)`` is a span that

@@ -131,7 +131,7 @@ def test_the_cells_schedule():
                         jnp.zeros((1, 4, 16384, 128), jnp.bfloat16),
                         False, 0, 4)
     assert sched == {"block_q": 1024, "block_k": 512, "full": 112,
-                     "edge": 48, "skipped": 352}
+                     "edge": 48, "skipped": 352, "band": False}
     # the other masks' tiles are what they were
     assert fa._tiling(8192, 128, 2, 0) == (
         Tiles(1024, 1024, 512), Tiles(1024, 1024, 1024),

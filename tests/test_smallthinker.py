@@ -510,8 +510,10 @@ def test_head_dim_is_checked_with_a_message_that_names_the_key():
 @pytest.mark.parametrize("window", [0, 160])
 def test_the_flash_path_counts_its_tile_schedule_once_a_layer(window):
     """Forced through the interpreter at L 256: ``attn.flash`` once, the
-    forward tile as gauges, one head's tiles by kind in the path account;
-    the dense path leaves all of it alone."""
+    forward tile as gauges, one head's tiles by kind in the path account
+    (a window of 160 takes the band walk, ``attn.flash.band``, and its
+    tiles are the walk's sub-tiles); the dense path leaves all of it
+    alone."""
     L2 = 256
     lay = AttentionLayer()
     for k, v in {"nhead": 4, "nkvhead": 2, "head_dim": 16, "causal": 1,
@@ -546,9 +548,11 @@ def test_the_flash_path_counts_its_tile_schedule_once_a_layer(window):
     assert sum(kinds.values()) == (L2 // bq) * (L2 // bk)
     assert kinds["edge"] >= L2 // max(bq, bk)      # the diagonal's tiles
     # heads of 16: the plain lines between the qkv dot and the core
+    assert sched["band"] == (window == 160)
     assert paths == dict({"attn.flash": 1, "attn.prep.xla": 1,
                           "attn.bwd.fused": 1}, **{
-        "flash.tiles." + k: n for k, n in kinds.items() if n})
+        "flash.tiles." + k: n for k, n in kinds.items() if n}, **(
+            {"attn.flash.band": 1} if sched["band"] else {}))
     y_dense, gauges, paths = delta(False)
     assert paths == {"attn.dense": 1, "attn.prep.xla": 1}
     assert not any(k.startswith("flash.") for k in gauges)

@@ -207,9 +207,10 @@ def test_flash_kernels_compile_at_head_dim_64(one_chip):
 # `laguna-ep8-train-8k`'s attention: 48 query heads on 8 key-value
 # heads of 128, causal (query groups of 6, the first group count that is no
 # power of two), and 64 on 8 in a window of 512 keys (groups of 8), 8,192
-# rows. Under the window `_tiling` gives resident tiles of 256 and streamed
-# ones of 512, so a band of 512 keys meets two or three streamed tiles a
-# resident one; both take the fused backward
+# rows. Under the window `_tiling` gives the band walk's blocks of 2,048 in
+# sub-tiles of 128, so a band of 512 keys meets two blocks a resident one
+# and the bodies take static slices of both at the sub-tile's grain, dQ's
+# whole-length accumulator a dynamic one; both take the fused backward
 @pytest.mark.parametrize("nh,window", [(48, 0), (64, 512)],
                          ids=["full-48-on-8", "window-512-64-on-8"])
 def test_flash_kernels_compile_at_laguna_shapes(one_chip, nh, window):
@@ -220,7 +221,7 @@ def test_flash_kernels_compile_at_laguna_shapes(one_chip, nh, window):
                               sharding=one_chip)
     tiles = flash_attn._tiling(8192, 128, 2, window)
     if window:
-        assert (tiles[0].bq, tiles[0].bk) == (256, 512)
+        assert tiles[0] == tiles[2] == flash_attn.BandTiles(2048, 2048, 128)
     assert flash_attn.fused_bwd_supported(8192, 128, jnp.bfloat16, window)
 
     def both(q_, k_, v_, do_):

@@ -26,7 +26,8 @@ Run: python tools/check_tpu_kernels.py   (requires a TPU-backed jax)
      `laguna-ep8-train-8k`'s two attention shapes alone: 48 query heads on
      8 key-value heads of 128, causal, and 64 on 8 in a window of 512,
      8,192 rows; against the dense reference, then ms and TFLOP/s by the
-     kept scores, ~2 min)
+     kept scores, and for the window its schedule: visited / kept, the
+     masked share and TFLOP/s by the visited scores too, ~2 min)
 """
 
 import functools
@@ -487,6 +488,24 @@ def _flash_against_dense(rs, what, nh, nkv, windows, L=8192, d=128):
               "TFLOP/s (by the mask's FLOPs, x1 and x3.5)"
               % (what, window, t_f, flops / t_f / 1e9, t_fb,
                  3.5 * flops / t_fb / 1e9))
+        if window:
+            # the forward's schedule: the scores its tiles visit against
+            # those the mask keeps, and the rate by the visited ones
+            sched = flash_attn.schedule(q, k, True, window)
+            visited = (sched["full"] + sched["edge"]) * sched["block_q"] \
+                * sched["block_k"] / (lm_flops.mean_keys(L, window) * L)
+            print("  window=%d schedule: %s tiles of %d x %d, %d full / %d "
+                  "edge / %d skipped a head; visited / kept %.3f, masked "
+                  "share of the visited tiles %.2f; forward %.1f TFLOP/s "
+                  "by the kept scores, %.1f by the visited; forward + "
+                  "backward %.1f / %.1f"
+                  % (window, "band" if sched["band"] else "row",
+                     sched["block_q"], sched["block_k"], sched["full"],
+                     sched["edge"], sched["skipped"], visited,
+                     sched["edge"] / (sched["full"] + sched["edge"]),
+                     flops / t_f / 1e9, visited * flops / t_f / 1e9,
+                     3.5 * flops / t_fb / 1e9,
+                     visited * 3.5 * flops / t_fb / 1e9))
 
 
 def _check_flash_under_the_block_diffusion_mask(rs):
